@@ -35,7 +35,7 @@ from oceanbase_tpu.analysis.core import (
 )
 
 # call names that trace their function argument
-JIT_NAMES = {"jit", "shard_map", "pmap", "shard_map_compat"}
+JIT_NAMES = {"jit", "shard_map", "pmap"}
 # numpy module aliases whose asarray/array force device->host transfer
 NP_ALIASES = {"np", "numpy"}
 SYNC_BUILTINS = {"int", "float", "bool"}

@@ -32,6 +32,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from oceanbase_tpu.datatypes import SqlType, TypeKind
 from oceanbase_tpu.exec import diag
@@ -57,7 +58,6 @@ def top_n(rel: Relation, key: ir.Expr, ascending: bool, k: int) -> Relation:
     """Fused ORDER BY <single key> LIMIT k via lax.top_k (≙ top-N sort
     pushdown, ob_sort_vec_op top-n path).  Result rows arrive in sort
     order; ties may order differently from the stable full sort."""
-    import jax.lax as lax
 
     n = rel.capacity
     m = rel.mask_or_true()
@@ -109,7 +109,7 @@ def compact(rel: Relation, capacity: int | None = None,
         live_n = jnp.sum(m.astype(jnp.int64))
         diag.push("compact_overflow", jnp.maximum(live_n - cap, 0),
                   capacity=cap)
-    order = jnp.argsort(~m, stable=True)  # live rows first, stable
+    order = _lexsort((~m,))  # live rows first, stable
     idx = order[:cap]
     live = jnp.take(m, idx)
     out = rel.gather(idx, mask=live)
@@ -119,6 +119,19 @@ def compact(rel: Relation, capacity: int | None = None,
 # ---------------------------------------------------------------------------
 # sort
 # ---------------------------------------------------------------------------
+
+
+def _lexsort(keys: Sequence[jax.Array]) -> jax.Array:
+    """``jnp.lexsort``'s permutation (last key primary), from an UNSTABLE
+    sort that takes the row number as its last key.  Ties break exactly
+    as the stable sort breaks them, so the answer is the same, and the
+    TPU compiler takes about half as long over it: a stable lexsort of
+    three int64 keys at 1M rows compiled for a v5e in 448 s, this form
+    in 243 s; a one-key argsort in 106 s against 43 s (PR 22)."""
+    iota = lax.iota(jnp.int32, keys[0].shape[0])
+    order = lax.sort((*reversed(tuple(keys)), iota),
+                     num_keys=len(keys) + 1, is_stable=False)[-1]
+    return order.astype(jnp.int64)
 
 
 def _sort_key_arrays(rel: Relation, keys: Sequence[ir.Expr],
@@ -163,7 +176,7 @@ def sort_rows(rel: Relation, keys: Sequence[ir.Expr],
     if ascending is None:
         ascending = [True] * len(keys)
     karrs, m = _sort_key_arrays(rel, keys, ascending, nulls_first)
-    order = jnp.lexsort(tuple(karrs))
+    order = _lexsort(karrs)
     live = jnp.take(m, order)
     return rel.gather(order, mask=live)
 
@@ -279,7 +292,7 @@ def hash_groupby(
         if c.valid is not None:
             minor_to_major.append((~c.valid).astype(jnp.int8))
     minor_to_major.append((~m).astype(jnp.int8))
-    order = jnp.lexsort(tuple(minor_to_major))
+    order = _lexsort(minor_to_major)
 
     s_live = jnp.take(m, order)
     s_keys = {name: c.gather(order) for name, c in key_cols.items()}
@@ -482,7 +495,7 @@ def _count_distinct(minor_to_major, order, s_data, s_valid, s_live,
     first-occurrence flags per group."""
     ac = eval_expr(spec.arg, rel)
     mm = [ac.data] + list(minor_to_major)
-    order2 = jnp.lexsort(tuple(mm))
+    order2 = _lexsort(mm)
     # recompute lanes in the second order
     m = rel.mask_or_true()
     l2 = jnp.take(m, order2)
@@ -531,7 +544,7 @@ def scalar_agg(rel: Relation, aggs: Sequence[AggSpec]) -> Relation:
             out[spec.name] = Column(cnt[None], None, SqlType.int_())
             continue
         if spec.fn == "count_distinct":
-            order = jnp.argsort(ac.data)
+            order = _lexsort((ac.data,))
             d = jnp.take(ac.data, order)
             w = jnp.take(weight, order)
             newval = jnp.concatenate([jnp.ones(1, jnp.bool_), d[1:] != d[:-1]])
@@ -647,7 +660,7 @@ def join(
     # build: sort right by key, dead/null-key rows pushed to the end
     BIG = jnp.asarray(_INT_MAX, dtype=jnp.int64)
     rkey_s = jnp.where(rvalid, rkey, BIG)
-    border = jnp.argsort(rkey_s)
+    border = _lexsort((rkey_s,))
     rkey_sorted = jnp.take(rkey_s, border)
     n_build = jnp.sum(rvalid.astype(jnp.int64))
 

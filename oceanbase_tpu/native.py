@@ -35,10 +35,18 @@ def _load():
             return _lib
         if not os.path.exists(_SO) and not _build_attempted:
             _build_attempted = True
+            # several processes may find the library missing at once:
+            # each builds under its own name, and the rename is atomic
+            tmp = f"{_SO}.{os.getpid()}.tmp"
             try:
-                subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                               capture_output=True, timeout=120)
+                subprocess.run(
+                    ["make", "-C", _NATIVE_DIR,
+                     f"TARGET={os.path.basename(tmp)}"],
+                    check=True, capture_output=True, timeout=120)
+                os.replace(tmp, _SO)
             except Exception:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
                 return None
         if not os.path.exists(_SO):
             return None

@@ -100,13 +100,16 @@ def q6_filter_sum(shipdate, discount, quantity, extendedprice, live,
     # plain XLA.  (A (1,128) per-step out block would be ideal but Mosaic
     # requires the trailing block dims divisible by (8,128) or whole.)
     MAX_BLOCKS = 1024  # 8.4M rows per call
+    # block indices are int32 on the chip: under jax_enable_x64 a Python 0
+    # would trace as int64, which Mosaic does not legalize
+    zero = np.int32(0)
     total = jnp.zeros((), jnp.int64)
     for s in range(0, nblocks, MAX_BLOCKS):
         nb = min(MAX_BLOCKS, nblocks - s)
         rows = slice(s * _SUB, (s + nb) * _SUB)
-        blk = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0),
+        blk = pl.BlockSpec((_SUB, _LANE), lambda i: (i, zero),
                            memory_space=pltpu.VMEM)
-        out_blk = pl.BlockSpec((nb, _LANE), lambda i: (0, 0),
+        out_blk = pl.BlockSpec((nb, _LANE), lambda i: (zero, zero),
                                memory_space=pltpu.VMEM)
         hi, lo = pl.pallas_call(
             kernel,

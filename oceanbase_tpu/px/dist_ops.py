@@ -31,7 +31,6 @@ from oceanbase_tpu.px.exchange import (
     all_to_all_repartition,
     broadcast_gather,
     exchange_by_dest,
-    shard_map_compat,
     shard_relation,
     unshard_relation,
 )
@@ -127,8 +126,9 @@ def dist_groupby(
 
     spec = P(axis)
     run = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             fn, mesh=mesh, in_specs=(spec,), out_specs=(spec, P()),
+            check_vma=False,
         )
     )
     out, overflow = run(sharded)

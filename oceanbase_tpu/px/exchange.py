@@ -36,27 +36,6 @@ from oceanbase_tpu.vector.column import Column, Relation
 PX_AXIS = "px"
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Bind ``shard_map`` across jax API generations: the function moved
-    from ``jax.experimental.shard_map`` (replication check kwarg
-    ``check_rep``) to ``jax.shard_map`` (``check_vma``).  The check is
-    disabled either way — shard bodies mix collectives with per-shard
-    relation outputs, which the checker cannot type."""
-    import inspect
-
-    try:
-        sm = jax.shard_map  # jax >= 0.6
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def default_mesh(n_devices: int | None = None, axis: str = PX_AXIS):
     devs = jax.devices()
     if n_devices is not None:

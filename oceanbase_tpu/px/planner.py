@@ -49,7 +49,6 @@ from oceanbase_tpu.px.dist_ops import (
 from oceanbase_tpu.px.exchange import (
     broadcast_gather,
     default_mesh,
-    shard_map_compat,
     shard_relation,
     shard_relation_by_hash,
     unshard_relation,
@@ -610,10 +609,10 @@ def _px_compiled(plan_key, holder, mesh, axis, ndev, factor, table_names):
                 total_ovf = total_ovf + jnp.asarray(v, dtype=jnp.int64)
         return rel, jax.lax.psum(total_ovf, axis)
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=({t: P(axis) for t in table_names},),
-        out_specs=(P(axis), P()),
+        out_specs=(P(axis), P()), check_vma=False,
     ))
 
 

@@ -49,7 +49,6 @@ def test_hot_key_detection():
     from oceanbase_tpu.px.dist_ops import _HOT_SENTINEL, _global_hot_keys
     from oceanbase_tpu.px.exchange import (
         default_mesh,
-        shard_map_compat,
         shard_relation,
     )
     from oceanbase_tpu.vector import from_numpy
@@ -69,8 +68,9 @@ def test_hot_key_detection():
 
     from jax.sharding import PartitionSpec as P
 
-    out = jax.jit(shard_map_compat(
-        body, mesh=mesh, in_specs=(P("px"),), out_specs=P("px")))(sharded)
+    out = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("px"),), out_specs=P("px"),
+        check_vma=False))(sharded)
     hot = set(np.asarray(out).reshape(8, -1)[0].tolist())
     hot.discard(_HOT_SENTINEL)
     assert 42 in hot and 77 in hot

@@ -1,0 +1,164 @@
+"""Compile the main path's programs for a DESCRIBED TPU v5e (no chip).
+
+The TPU compiler is installed in the sandbox and compiles for a topology
+that is described, not attached (on-chip-measurement guide, section 2.3):
+what it refuses here costs no chip time.  Nothing runs, so these cases
+say nothing of results or times; ``chip_smoke.py`` is the chip run.
+
+This is the only file that describes the chip.  The topology is described
+inside a fixture, never at import: one process at a time may load the TPU
+library, and every xdist worker imports every test file.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from oceanbase_tpu.datatypes import date_to_days
+
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip: keep it off here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _fits(compiled):
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) < V5E_HBM_BYTES, ma
+
+
+@pytest.mark.parametrize("rows", [6_002_357, 8_193])
+def test_q6_kernel_compiles_for_v5e(rows, one_chip, no_persistent_cache):
+    """The Pallas Q6 kernel through Mosaic (interpret=False) at TPC-H
+    SF1's lineitem length and at a ragged one."""
+    from oceanbase_tpu.ops import q6_filter_sum
+
+    col = jax.ShapeDtypeStruct((rows,), jnp.int64, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    compiled = q6_filter_sum.lower(
+        col, col, col, col, live,
+        ship_lo=date_to_days("1994-01-01"),
+        ship_hi=date_to_days("1995-01-01"),
+        disc_lo=5, disc_hi=7, qty_hi=2400, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def tpch_session():
+    from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
+    from oceanbase_tpu.sql import Session
+
+    tables, types = gen_tpch(sf=0.01)
+    sess = Session()
+    for name, arrays in tables.items():
+        sess.catalog.load_numpy(
+            name, arrays,
+            types={k: v for k, v in types.items() if k in arrays},
+            primary_key=TPCH_PRIMARY_KEYS[name])
+    return sess
+
+
+@pytest.mark.parametrize("qnum", [1, 6, 3, 14])
+def test_plan_program_compiles_for_v5e(qnum, tpch_session, one_chip,
+                                       no_persistent_cache, monkeypatch):
+    """The plan programs Session.execute builds for a smoke query, at the
+    shapes of an SF0.01 load, accepted by the chip's compiler."""
+    from oceanbase_tpu.bench.tpch_queries import QUERIES
+    from oceanbase_tpu.exec import plan as qplan
+
+    programs = []
+    call = qplan._PlanExecutable.call
+
+    def spy(self, tables):
+        programs.append((self._run, tables))
+        return call(self, tables)
+
+    monkeypatch.setattr(qplan._PlanExecutable, "call", spy)
+    assert tpch_session.execute(QUERIES[qnum]).rowcount > 0
+    assert programs
+    for run, tables in programs:
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tables)
+        _fits(run.lower(shapes).compile())
+
+
+def test_px_groupby_exchange_compiles_for_four_chips(topo,
+                                                     no_persistent_cache):
+    """One PX program on the 2x2 mesh: partial agg -> all_to_all by key
+    hash -> final agg (Q1's group-by exchange), as
+    ``__graft_entry__.dryrun_multichip`` drives it.  Fed shapes with a
+    NamedSharding: a described device cannot take a device_put."""
+    from oceanbase_tpu.exec.ops import AggSpec
+    from oceanbase_tpu.expr import ir
+    from oceanbase_tpu.px.dist_ops import dist_groupby_shard
+    from oceanbase_tpu.px.exchange import datahub_psum
+    from oceanbase_tpu.vector import from_numpy
+
+    ndev = len(topo.devices)
+    assert ndev == 4
+    mesh = Mesh(np.array(topo.devices), ("px",))
+    # a small shard: the v5e compiler's time for this program grows with
+    # the rows (3 s at 512, 28 s at 64K, 158 s at 1M; sandbox, PR 22), and
+    # what the case guards is the mesh and the collective, not the size
+    rows = 2048 * ndev
+    li = from_numpy({"flag": np.zeros(8, np.int64),
+                     "qty": np.zeros(8, np.int64),
+                     "price": np.zeros(8, np.int64)})
+    sharded = NamedSharding(mesh, P("px"))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((rows,), x.dtype, sharding=sharded),
+        li)
+
+    def step(shard):
+        filtered = shard.with_mask(
+            (shard.columns["qty"].data < 40) & shard.mask_or_true())
+        grouped, ovf = dist_groupby_shard(
+            filtered, {"flag": ir.col("flag")},
+            [AggSpec("s", "sum", ir.col("price")),
+             AggSpec("c", "count_star"),
+             AggSpec("a", "avg", ir.col("qty"))],
+            ndev=ndev, local_cap=32, out_cap=32)
+        return grouped, datahub_psum(ovf)
+
+    compiled = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(P("px"),), out_specs=(P("px"), P()),
+        check_vma=False)).lower(shapes).compile()
+    assert "all-to-all" in compiled.as_text()
+    _fits(compiled)
