@@ -26,6 +26,7 @@ Design notes (tpu-first re-imaginations of the reference components):
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -224,6 +225,20 @@ def _agg_result_type(fn: str, argt: SqlType | None) -> SqlType:
     return argt
 
 
+def _scoped_segment_reduce(fn):
+    """The group-by's scatter reductions under one scope name, so a
+    profile reads "GroupBy#k/groupby.segment_reduce" (HLO metadata only)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kw):
+        with jax.named_scope("groupby.segment_reduce"):
+            return fn(*args, **kw)
+    return scoped
+
+
+_segment_sum = _scoped_segment_reduce(jax.ops.segment_sum)
+
+
+@_scoped_segment_reduce
 def _segment_agg(fn: str, data, weight, gid, num_segments, dtype):
     """weight: bool lane = live & arg-valid (identity applied when False)."""
     if fn in ("count", "count_star"):
@@ -425,7 +440,7 @@ def _lowcard_groupby(rel, group_by, aggs, out_capacity, n, m):
     nseg = prod + 1
 
     out_cols: dict[str, Column] = {}
-    counts = jax.ops.segment_sum(m.astype(jnp.int64), gid,
+    counts = _segment_sum(m.astype(jnp.int64), gid,
                                  num_segments=nseg)[:prod]
     occupied = counts > 0
 
@@ -455,14 +470,14 @@ def _lowcard_groupby(rel, group_by, aggs, out_capacity, n, m):
         if ac.dtype.kind == TypeKind.BOOL:
             ac = cast_column(ac, SqlType.int_())
         weight = m if ac.valid is None else (m & ac.valid)
-        cnt = jax.ops.segment_sum(weight.astype(jnp.int64), gid,
+        cnt = _segment_sum(weight.astype(jnp.int64), gid,
                                   num_segments=nseg)[:prod]
         if spec.fn == "count":
             out_cols[spec.name] = Column(cnt, None, SqlType.int_())
             continue
         if spec.fn in ("sum", "avg"):
             d = jnp.where(weight, ac.data, jnp.zeros((), ac.data.dtype))
-            s = jax.ops.segment_sum(d, gid, num_segments=nseg)[:prod]
+            s = _segment_sum(d, gid, num_segments=nseg)[:prod]
             if spec.fn == "sum":
                 out_cols[spec.name] = Column(
                     s, cnt > 0, _agg_result_type("sum", ac.dtype))
@@ -660,13 +675,18 @@ def join(
     # build: sort right by key, dead/null-key rows pushed to the end
     BIG = jnp.asarray(_INT_MAX, dtype=jnp.int64)
     rkey_s = jnp.where(rvalid, rkey, BIG)
-    border = _lexsort((rkey_s,))
-    rkey_sorted = jnp.take(rkey_s, border)
+    # the two expensive steps carry a scope of their own: HLO metadata
+    # only (device ops read "HashJoin#k/join.probe" in a profile), no
+    # cache key sees it
+    with jax.named_scope("join.sort_build"):
+        border = _lexsort((rkey_s,))
+        rkey_sorted = jnp.take(rkey_s, border)
     n_build = jnp.sum(rvalid.astype(jnp.int64))
 
     lkey_p = jnp.where(lvalid, lkey, BIG - 1)
-    lo = jnp.searchsorted(rkey_sorted, lkey_p, side="left")
-    hi = jnp.searchsorted(rkey_sorted, lkey_p, side="right")
+    with jax.named_scope("join.probe"):
+        lo = jnp.searchsorted(rkey_sorted, lkey_p, side="left")
+        hi = jnp.searchsorted(rkey_sorted, lkey_p, side="right")
     # lo/hi ∈ [0, rn] so counts <= rn always — no clamp needed
     counts = jnp.where(lvalid, hi - lo, 0)
 
@@ -809,8 +829,9 @@ def index_probe(
     # BIG-1 (not BIG): the pad keys are BIG, so a dead probe lane's
     # sentinel must sort strictly below them to report zero matches
     lkey_p = jnp.where(lvalid, lkey, BIG - 1)
-    lo = jnp.searchsorted(skey, lkey_p, side="left")
-    hi = jnp.searchsorted(skey, lkey_p, side="right")
+    with jax.named_scope("join.probe"):
+        lo = jnp.searchsorted(skey, lkey_p, side="left")
+        hi = jnp.searchsorted(skey, lkey_p, side="right")
     counts = jnp.where(lvalid, hi - lo, 0)
 
     cap = out_capacity if out_capacity is not None else max(ln, sn)

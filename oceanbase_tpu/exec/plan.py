@@ -13,6 +13,7 @@ hooks at this layer via the plan monitor (server/monitor.py).
 
 from __future__ import annotations
 
+import copy
 import functools
 import hashlib
 import re
@@ -35,17 +36,6 @@ qmetrics.declare("plan.executions", "counter",
                  "execute_plan calls", )
 qmetrics.declare("plan.compiles", "counter",
                  "XLA trace+compile events (per plan x input signature)")
-qmetrics.declare("plan.execute_s", "histogram",
-                 "whole-plan execution wall time", unit="s")
-qmetrics.declare("plan.compile_s", "histogram",
-                 "XLA lower+compile wall time", unit="s")
-qmetrics.declare("plan.flops_compiled", "counter",
-                 "XLA cost_analysis flops of freshly compiled programs")
-qmetrics.declare("plan.bytes_compiled", "counter",
-                 "XLA cost_analysis bytes-accessed of compiled programs")
-qmetrics.declare("plan.qerror", "histogram",
-                 "worst per-operator estimate-vs-actual q-error per "
-                 "monitored execution (1.0 = perfect estimate)")
 qmetrics.declare("plan.capacity_retries", "counter",
                  "CapacityOverflow re-plans (the retry ladder the "
                  "cardinality-feedback store exists to shorten)")
@@ -58,11 +48,6 @@ qmetrics.declare("plan.feedback_corrections", "counter",
 qmetrics.declare("plan.regressions", "counter",
                  "plan-regression watchdog flag transitions "
                  "(gv$plan_history.regressed going up)")
-qmetrics.declare("plan.flops_executed", "counter",
-                 "cost_analysis flops of the program behind each "
-                 "execution (measured device work, the CBO's substrate)")
-qmetrics.declare("plan.bytes_executed", "counter",
-                 "cost_analysis bytes-accessed per execution")
 qmetrics.declare("plan.host_s", "histogram",
                  "host half of the execution split: bind + dispatch "
                  "until the runtime hands back futures", unit="s")
@@ -529,9 +514,38 @@ def monitored_postorder(node: PlanNode,
     return out
 
 
+def _postorder(node: PlanNode) -> list:
+    out = []
+    for c in node.children():
+        out.extend(_postorder(c))
+    out.append(node)
+    return out
+
+
+# id(node) -> postorder position, for the duration of one root's lowering
+_scope_pos = threading.local()
+
+
 def _lower(node: PlanNode, tables: dict[str, Relation],
            parent: "PlanNode | None" = None) -> Relation:
-    rel = _lower_inner(node, tables)
+    # every operator lowers under the scope "<Type>#<postorder position>"
+    # (children nest inside: the INNERMOST scope of a device op is its
+    # operator).  A scope is HLO metadata only — the plan fingerprint,
+    # the AOT signature and the persistent cache's keys do not see it —
+    # and it is what lets a profile say "HashJoin#5/join.probe" where it
+    # used to say fusion.168
+    pos = getattr(_scope_pos, "pos", None)
+    root = pos is None or id(node) not in pos
+    if root:
+        saved = pos
+        pos = _scope_pos.pos = {
+            id(n): k for k, n in enumerate(_postorder(node))}
+    try:
+        with jax.named_scope(f"{type(node).__name__}#{pos[id(node)]}"):
+            rel = _lower_inner(node, tables)
+    finally:
+        if root:
+            _scope_pos.pos = saved
     # per-operator row accounting (no-op unless a monitor is collecting);
     # the optimizer's static estimate rides along host-side so the
     # monitor can q-error it against the measured count
@@ -712,7 +726,8 @@ def prepare_index_probes(catalog, plan: PlanNode,
         st.sidecar_build_s += dt
         qmetrics.inc("plan.sidecar_builds", table=node.table)
         qmetrics.observe("plan.sidecar_build_s", dt, table=node.table)
-        add_exec_times(sidecar_build_s=dt)
+        # the statement's sidecar_build_s, not the `tables` span's too
+        qtrace.book_owned("sidecar_build_s", int(dt * 1e9))
 
 
 def _input_signature(tables: dict[str, Relation]) -> tuple:
@@ -838,25 +853,31 @@ class _PlanExecutable:
         # (plan walk + jaxpr build), compile() the XLA backend half —
         # the time model attributes them separately (lower_s/compile_s)
         # while last_compile_s stays their sum for the existing
-        # gv$plan_cache column and the dispatch subtraction below
-        t0 = time.perf_counter()
-        lowered = self._run.lower(tables)
-        t1 = time.perf_counter()
-        exe = lowered.compile()
-        t2 = time.perf_counter()
-        dt = t2 - t0
-        flops, nbytes, peak = _xla_analysis(exe)
+        # gv$plan_cache column.  This bracket is the ONE source of both
+        # phases on the serial path: JAX's own compile events inside it
+        # count into jax.compile_ns but book nothing (bracketed_compile),
+        # and a collector pause inside is gc_s, not lowering.
+        acc = _exec_acc()
         st = self.stats
+        with qtrace.span("xla.compile", plan_hash=st.plan_hash) as csp, \
+                qtrace.bracketed_compile():
+            g0 = acc.gc_s
+            t0 = time.perf_counter()
+            lowered = self._run.lower(tables)
+            lower_s = time.perf_counter() - t0 - (acc.gc_s - g0)
+            exe = lowered.compile()
+            flops, nbytes, peak = _xla_analysis(exe)
+            csp.tags.update(flops=flops, bytes_accessed=nbytes,
+                            peak_memory=peak)
+        acc.lower_s += lower_s
+        acc.compile_s += max(csp.self_s - lower_s, 0.0)
         st.xla_traces += 1
-        st.last_compile_s = dt
-        st.last_lower_s = t1 - t0
+        st.last_compile_s = csp.elapsed_s
+        st.last_lower_s = lower_s
         st.flops = flops
         st.bytes_accessed = nbytes
         st.peak_memory = peak
         qmetrics.inc("plan.compiles")
-        qmetrics.observe("plan.compile_s", dt)
-        qmetrics.inc("plan.flops_compiled", int(flops))
-        qmetrics.inc("plan.bytes_compiled", int(nbytes))
         if len(self._execs) >= self.MAX_SIGNATURES:
             self._execs.pop(next(iter(self._execs)))
         entry = (exe, flops, nbytes, peak)
@@ -878,8 +899,6 @@ class _PlanExecutable:
                     entry = self._compile(tables, sig)
                     compiled_now = True
         exe, flops, nbytes, _peak = entry
-        qmetrics.inc("plan.flops_executed", int(flops))
-        qmetrics.inc("plan.bytes_executed", int(nbytes))
         return exe(tables), compiled_now, flops, nbytes
 
 
@@ -928,7 +947,6 @@ def time_split_enabled() -> bool:
     return _TIME_SPLIT
 
 
-@dataclass
 class ExecTimes:
     """Per-statement execution accounting, accumulated across every
     execute_plan call (retries, granule chunks, spill sub-plans) plus
@@ -937,40 +955,48 @@ class ExecTimes:
     — the numerators the roofline prediction prices against ``calls``
     launches of measured ``device_s``.
 
-    The named phases decompose the host half (the gv$time_model rows):
-    ``bind_s`` parse/optimize/bind (session-recorded), ``sidecar_build_s``
-    index-probe sidecar rebuilds, ``lower_s``/``compile_s`` the two
-    windows of a fresh XLA trace, ``dispatch_s`` the per-execution host
-    time until the runtime hands back futures, ``merge_s`` the DTL
-    coordinator's fragment concatenation.  ``host_s`` stays the legacy
-    aggregate (local dispatch + remote fragments' host halves), so
-    phase sums and the aggregate are reconciled by workload_bench, not
-    assumed equal."""
+    The named phases (``PHASES``, server/trace.py) decompose the host
+    half — the gv$sql_audit columns and gv$time_model rows.  Most are
+    the SELF time of the span of that boundary (``trace.PHASE_OF``:
+    ``parse`` -> ``parse_s`` ... ``materialize`` -> ``materialize_s``),
+    booked when the span closes; ``sidecar_build_s``, and ``lower_s`` /
+    ``compile_s`` on the serial AOT path, are bracketed by their owner;
+    ``trace_s`` / ``cache_lookup_s`` (and ``lower_s`` / ``compile_s``
+    wherever jit dispatch compiles implicitly: the PX program, eager
+    ops) come from JAX's own compile events; ``gc_s`` from the
+    collector's callbacks.  Whoever books time inside an open span
+    charges it to that span as child time, so no second is owned twice
+    and ``elapsed_s - queue_s - phase_sum()`` is the unowned rest
+    (``other_s``).  ``close_s`` is the statement's work after its root
+    span closed (metrics, trace retention, the audit row): outside
+    ``elapsed_s``.  ``host_s`` stays the legacy aggregate (local
+    dispatch + remote fragments' host halves).
 
-    host_s: float = 0.0
-    device_s: float = 0.0
-    flops: float = 0.0
-    bytes: float = 0.0
-    calls: int = 0
-    bind_s: float = 0.0
-    sidecar_build_s: float = 0.0
-    lower_s: float = 0.0
-    compile_s: float = 0.0
-    dispatch_s: float = 0.0
-    merge_s: float = 0.0
+    A plain class whose fields default at CLASS level: a statement's
+    fresh accumulator is an empty instance (the session makes one per
+    statement), and only what a statement books becomes an instance
+    attribute."""
+
+    host_s = device_s = flops = bytes = 0.0
+    calls = 0
+    bind_s = sidecar_build_s = lower_s = compile_s = dispatch_s = 0.0
+    merge_s = parse_s = admission_s = virtuals_s = prepare_s = 0.0
+    tables_s = device_copy_s = trace_s = cache_lookup_s = shard_s = 0.0
+    unshard_s = monitor_s = record_s = materialize_s = gc_s = 0.0
+    close_s = 0.0
+
+    def __repr__(self):
+        return f"ExecTimes({vars(self)})"
 
     #: the host-phase decomposition, in pipeline order (shared by
     #: gv$sql_audit columns, gv$time_model rows and the report builder)
-    PHASES = ("bind_s", "sidecar_build_s", "lower_s", "compile_s",
-              "dispatch_s", "merge_s")
+    PHASES = qtrace.PHASES
 
     def phase_sum(self) -> float:
         """Sum of the named host phases + device_s — what the
         time-model-sums-to-wall reconciliation compares against the
         audited statement elapsed."""
-        return (self.bind_s + self.sidecar_build_s + self.lower_s
-                + self.compile_s + self.dispatch_s + self.merge_s
-                + self.device_s)
+        return sum(getattr(self, p) for p in self.PHASES) + self.device_s
 
     def worst_phase(self) -> tuple[str, float]:
         """(phase name, seconds) of the dominant host phase — the
@@ -980,51 +1006,42 @@ class ExecTimes:
 
 
 def _exec_acc() -> ExecTimes:
-    acc = getattr(_exec_flags, "times", None)
+    acc = qtrace.statement_times()
     if acc is None:
-        acc = _exec_flags.times = ExecTimes()
+        acc = ExecTimes()
+        qtrace.set_statement_times(acc)
     return acc
 
 
-def reset_exec_times():
+def reset_exec_times() -> ExecTimes:
     """Statement start: the session clears the accumulator alongside
-    reset_compile_flag()."""
-    _exec_flags.times = ExecTimes()
+    reset_compile_flag().  -> the fresh accumulator: the statement's
+    audit row keeps it, so what closes after the row was written
+    (``close_s``) still lands in it.  It lives in the tracer's
+    per-thread state, where a closing span books its self time."""
+    acc = ExecTimes()
+    qtrace.set_statement_times(acc)
+    return acc
 
 
 def exec_times() -> ExecTimes:
     """Snapshot of this thread's statement-scoped accumulator."""
-    acc = _exec_acc()
-    return ExecTimes(acc.host_s, acc.device_s, acc.flops, acc.bytes,
-                     acc.calls, acc.bind_s, acc.sidecar_build_s,
-                     acc.lower_s, acc.compile_s, acc.dispatch_s,
-                     acc.merge_s)
+    return copy.copy(_exec_acc())
 
 
-def add_exec_times(host_s: float = 0.0, device_s: float = 0.0,
-                   flops: float = 0.0, bytes: float = 0.0,  # noqa: A002
-                   calls: int = 0, bind_s: float = 0.0,
-                   sidecar_build_s: float = 0.0, lower_s: float = 0.0,
-                   compile_s: float = 0.0, dispatch_s: float = 0.0,
-                   merge_s: float = 0.0):
+def add_exec_times(**seconds):
     """Fold externally measured work into the statement accumulator —
     DTL coordinators merge the split their remote fragments shipped
     back, so a pushed-down statement's device_s covers the cluster.
-    The phase kwargs feed the time-model decomposition (the session
-    records bind_s, prepare_index_probes sidecar_build_s, the DTL
-    coordinator merge_s)."""
+    Keywords are ``ExecTimes`` fields (prepare_index_probes books
+    sidecar_build_s, the DTL coordinator merge_s)."""
+    for name, v in seconds.items():
+        _book_phase(name, v)
+
+
+def _book_phase(name: str, seconds: float):
     acc = _exec_acc()
-    acc.host_s += float(host_s)
-    acc.device_s += float(device_s)
-    acc.flops += float(flops)
-    acc.bytes += float(bytes)
-    acc.calls += int(calls)
-    acc.bind_s += float(bind_s)
-    acc.sidecar_build_s += float(sidecar_build_s)
-    acc.lower_s += float(lower_s)
-    acc.compile_s += float(compile_s)
-    acc.dispatch_s += float(dispatch_s)
-    acc.merge_s += float(merge_s)
+    setattr(acc, name, getattr(acc, name) + seconds)
 
 
 @functools.lru_cache(maxsize=256)
@@ -1054,8 +1071,7 @@ class _PlanHolder:
 def execute_plan(plan: PlanNode, tables: dict[str, Relation],
                  check_overflow: bool = True,
                  monitor_out: list | None = None,
-                 monitor_collect: bool = True,
-                 op_spans: bool = True) -> Relation:
+                 monitor_collect: bool = True) -> Relation:
     """Compile (cached) + run a plan against device tables.
 
     ≙ ObExecutor::execute_plan (src/sql/executor/ob_executor.cpp:37); the
@@ -1067,9 +1083,7 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
     compiles twice and breaks the shape-bucket compile-count invariant.
     ``monitor_collect`` is the cheap per-execution sampling switch: when
     False the lanes still run on device (same executable) but the host
-    skips the transfer, the ledger rows, and the op spans.  ``op_spans``
-    suppresses the per-operator trace spans (DTL fragments ship the
-    compact ``ops`` reply field instead of paying span wire cost).
+    skips the transfer and the ledger rows.
 
     Raises diag.CapacityOverflow when any static-capacity operator
     (join expansion, exchange buffer) overflowed — results would be
@@ -1081,41 +1095,43 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
     from oceanbase_tpu.server import admission as qadmission
 
     qadmission.checkpoint()
-    key = plan.fingerprint()
-    needed = referenced_tables(plan)
-    # IndexProbe sidecars are session-injected relations, not catalog
-    # tables — referenced_tables() deliberately omits them (its other
-    # callers resolve names against the catalog), so re-add them here
-    # or the filter below would strip the probe's sorted-key input
-    stack = [plan]
-    while stack:
-        n = stack.pop()
-        if isinstance(n, IndexProbe):
-            needed.add(IndexProbe.sidecar_name(n.table, n.index))
-        stack.extend(n.children())
     with_monitor = monitor_out is not None
-    bundle = _compiled(key, _PlanHolder(plan, key), with_monitor)
-    stats = bundle.stats
-    diag_names = bundle.diag_names
-    monitor_names = bundle.monitor_names
-    root_op = type(plan).__name__
-    # full-link trace: one HOST-side span per plan execution, closed at
-    # the result boundary below (never inside the jit-traced `run` body)
-    with qtrace.span("plan.execute", plan_hash=stats.plan_hash) as tsp:
-        t0 = time.perf_counter()
-        (out, diag_vals, diag_total, mon_vals), compiled_now, flops, \
-            nbytes = bundle.call(
-                {k: v for k, v in tables.items() if k in needed})
-        stats.executions += 1
-        host_s = time.perf_counter() - t0
-        if compiled_now:
-            # first execution at a signature pays lower()+compile()
-            # inside the window above; that one-time cost is already
-            # attributed (gv$plan_cache.last_compile_s, the xla.compile
-            # span) and must not read as a per-execution dispatch stall
-            # in gv$sql_audit.host_s — the same exclusion the PR 8
-            # plan-history watchdog applies to its latency baselines
-            host_s = max(host_s - stats.last_compile_s, 0.0)
+    # full-link trace: one HOST-side span per plan execution with one
+    # child per phase, each closed at a result boundary below (never
+    # inside the jit-traced `run` body).  The children's self times are
+    # the statement's dispatch_s / device_s / monitor_s (trace.PHASE_OF)
+    with qtrace.span("plan.execute") as tsp:
+        with qtrace.span("plan.dispatch") as dsp:
+            key = plan.fingerprint()
+            needed = referenced_tables(plan)
+            # IndexProbe sidecars are session-injected relations, not
+            # catalog tables — referenced_tables() deliberately omits
+            # them (its other callers resolve names against the
+            # catalog), so re-add them here or the filter below would
+            # strip the probe's sorted-key input
+            stack = [plan]
+            while stack:
+                n = stack.pop()
+                if isinstance(n, IndexProbe):
+                    needed.add(IndexProbe.sidecar_name(n.table, n.index))
+                stack.extend(n.children())
+            bundle = _compiled(key, _PlanHolder(plan, key), with_monitor)
+            stats = bundle.stats
+            (out, diag_vals, diag_total, mon_vals), compiled_now, flops, \
+                nbytes = bundle.call(
+                    {k: v for k, v in tables.items() if k in needed})
+            stats.executions += 1
+        # a first execution at a signature pays lower()+compile() inside
+        # the window above as the xla.compile child span: the dispatch
+        # span's SELF time is the per-execution dispatch, and the
+        # one-time cost does not read as a dispatch stall in
+        # gv$sql_audit.host_s — the same exclusion the PR 8 plan-history
+        # watchdog applies to its latency baselines
+        host_s = dsp.self_s
+        diag_names = bundle.diag_names
+        monitor_names = bundle.monitor_names
+        root_op = type(plan).__name__
+        tsp.tags["plan_hash"] = stats.plan_hash
         device_s = 0.0
         if _TIME_SPLIT:
             # the host/device split: dispatch returned futures above;
@@ -1126,106 +1142,87 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             # single fused program whose output buffers all fulfill at
             # completion — and keeps the split's cost O(1), not
             # O(output tree) (the <=2% profile_bench budget).
-            t1 = time.perf_counter()
-            jax.block_until_ready(  # obcheck: ok(trace.host-sync)
-                diag_total)
-            device_s = time.perf_counter() - t1
-            stats.device_s_total += device_s
-            stats.host_s_total += host_s
-            stats.device_executions += 1
-            stats.device_flops += flops
-            stats.device_bytes += nbytes
-            qmetrics.observe("plan.host_s", host_s, op=root_op)
-            qmetrics.observe("plan.device_s", device_s, op=root_op)
-            tsp.tags["host_s"] = round(host_s, 6)
-            tsp.tags["device_s"] = round(device_s, 6)
-        acc = _exec_acc()
-        acc.host_s += host_s
-        acc.device_s += device_s
-        acc.flops += flops
-        acc.bytes += nbytes
-        acc.calls += 1
-        # time-model phases: host_s already has the compile window
-        # subtracted above, so it IS the dispatch phase; a fresh trace
-        # additionally books its two compile windows
-        acc.dispatch_s += host_s
-        if compiled_now:
-            acc.lower_s += stats.last_lower_s
-            acc.compile_s += max(
-                stats.last_compile_s - stats.last_lower_s, 0.0)
-        plan_elapsed = time.perf_counter() - t0
-        qmetrics.inc("plan.executions", op=root_op)
-        qmetrics.observe("plan.execute_s", plan_elapsed, op=root_op)
-        if compiled_now:
-            _exec_flags.compiled = True
-            tsp.tags["compiled"] = 1
-            # compile-vs-execute attribution: the lower+compile wall
-            # time IS the XLA trace+compile cost the shape-bucket
-            # policy amortizes (gv$plan_cache.last_compile_s), now with
-            # the program's measured flops/bytes riding the span tags
-            qtrace.add_span("xla.compile", stats.last_compile_s,
-                            plan_hash=stats.plan_hash,
-                            flops=stats.flops,
-                            bytes_accessed=stats.bytes_accessed,
-                            peak_memory=stats.peak_memory)
-        if with_monitor and monitor_collect:
-            # audited: opt-in plan-monitor collection materializes
-            # per-op row counts; only with enable_sql_plan_monitor set.
-            # Each row is the estimate-vs-actual ledger entry: the
-            # binder's est_rows beside the measured output rows with
-            # their q-error (gv$sql_plan_monitor row shape).
-            import numpy as _np
+            with qtrace.span("plan.device_wait") as wsp:
+                jax.block_until_ready(  # obcheck: ok(trace.host-sync)
+                    diag_total)
+            device_s = wsp.elapsed_s
+        # the execution's books — cache stats, histograms and, when this
+        # execution is sampled, the per-operator counts — are one span,
+        # so that what follows the wait has a name on the timeline
+        with qtrace.span("plan.monitor"):
+            if _TIME_SPLIT:
+                stats.device_s_total += device_s
+                stats.host_s_total += host_s
+                stats.device_executions += 1
+                stats.device_flops += flops
+                stats.device_bytes += nbytes
+                qmetrics.observe("plan.host_s", host_s, op=root_op)
+                qmetrics.observe("plan.device_s", device_s, op=root_op)
+                tsp.tags["host_s"] = round(host_s, 6)
+                tsp.tags["device_s"] = round(device_s, 6)
+            acc = _exec_acc()
+            acc.host_s += host_s
+            acc.flops += flops
+            acc.bytes += nbytes
+            acc.calls += 1
+            plan_elapsed = dsp.elapsed_s + device_s
+            qmetrics.inc("plan.executions", op=root_op)
+            if compiled_now:
+                _exec_flags.compiled = True
+                tsp.tags["compiled"] = 1
+            if with_monitor and monitor_collect:
+                # audited: opt-in plan-monitor collection materializes
+                # per-op row counts; only with enable_sql_plan_monitor
+                # set.  Each row is the estimate-vs-actual ledger entry:
+                # the binder's est_rows beside the measured output rows
+                # with their q-error (gv$sql_plan_monitor row shape).
+                import numpy as _np
 
-            # audited result-boundary sync: ONE transfer materializes
-            # every per-op count
-            mon_host = _np.asarray(mon_vals)  # obcheck: ok(trace.host-sync)
-            # estimates come from the CURRENT plan, not the ones the
-            # cached executable captured at trace time: the compile
-            # cache keys on fingerprint() (est-insensitive by design),
-            # so after ANALYZE / table growth a re-bound plan reuses
-            # the executable but must report its own refreshed est_rows
-            live = monitored_postorder(plan)
-            ests = ([n.est_rows for n in live]
-                    if len(live) == len(monitor_names)
-                    else [e for _, e in monitor_names])
-            op_rows = []
-            for i, ((n, _tr_est), v) in enumerate(
-                    zip(monitor_names, mon_host)):
-                est = ests[i]
-                act = int(v)
-                op_rows.append({"op": n, "pos": i, "est": est,
-                                "rows": act, "q_error": q_error(est, act),
-                                "elapsed_s": 0.0})
-            if op_rows:
-                # the plan runs as ONE fused XLA program, so per-op wall
-                # time is not separable; the root carries the plan total
-                op_rows[-1]["elapsed_s"] = plan_elapsed
-                worst = max(op_rows, key=lambda r: r["q_error"])
-                if worst["q_error"] > 0.0:
-                    qmetrics.observe("plan.qerror", worst["q_error"])
-            monitor_out.extend(op_rows)
-            if op_spans and qtrace.current() is not None:
-                # per-operator breakdown under the plan.execute span
-                # (the plan-monitor lanes already paid the transfer;
-                # bulk emission pays one lock, not one per op)
-                qtrace.add_spans([
-                    ("op." + r["op"], 0.0,
-                     {"rows": r["rows"], "est": r["est"] or 0,
-                      "q": round(r["q_error"], 3)})
-                    for r in op_rows])
-    if check_overflow and diag_vals:
-        # audited result-boundary sync: ONE host read decides validity;
-        # the per-lane detail below only materializes on the error path
-        total = int(diag_total)  # obcheck: ok(trace.host-sync)
-        if total > 0:
-            vals = [int(v) for v in diag_vals]  # obcheck: ok(trace.host-sync)
-            drops = [(n, cap, v)
-                     for (n, cap), v in zip(diag_names, vals) if v > 0]
-            detail = ", ".join(f"{n}={v}" for n, _cap, v in drops)
-            raise diag.CapacityOverflow(
-                f"operator capacity exceeded ({detail} rows dropped); "
-                f"re-plan with larger out_capacity", drops=drops,
-            )
+                # audited result-boundary sync: ONE transfer
+                # materializes every per-op count
+                mon_host = _np.asarray(mon_vals)  # obcheck: ok(trace.host-sync)
+                # estimates come from the CURRENT plan, not the ones the
+                # cached executable captured at trace time: the compile
+                # cache keys on fingerprint() (est-insensitive by
+                # design), so after ANALYZE / table growth a re-bound
+                # plan reuses the executable but must report its own
+                # refreshed est_rows
+                live = monitored_postorder(plan)
+                ests = ([n.est_rows for n in live]
+                        if len(live) == len(monitor_names)
+                        else [e for _, e in monitor_names])
+                op_rows = []
+                for i, ((n, _tr_est), v) in enumerate(
+                        zip(monitor_names, mon_host)):
+                    est = ests[i]
+                    act = int(v)
+                    op_rows.append({"op": n, "pos": i, "est": est,
+                                    "rows": act,
+                                    "q_error": q_error(est, act),
+                                    "elapsed_s": 0.0})
+                if op_rows:
+                    # the plan runs as ONE fused XLA program, so per-op
+                    # wall time is not separable; the root carries the
+                    # plan total
+                    op_rows[-1]["elapsed_s"] = plan_elapsed
+                monitor_out.extend(op_rows)
+        if check_overflow and diag_vals:
+            with qtrace.span("plan.overflow_check"):
+                # audited result-boundary sync: ONE host read decides
+                # validity; the per-lane detail below only materializes
+                # on the error path
+                total = int(diag_total)  # obcheck: ok(trace.host-sync)
+                if total > 0:
+                    vals = [int(v) for v in diag_vals]  # obcheck: ok(trace.host-sync)
+                    drops = [(n, cap, v)
+                             for (n, cap), v in zip(diag_names, vals)
+                             if v > 0]
+                    detail = ", ".join(f"{n}={v}" for n, _cap, v in drops)
+                    raise diag.CapacityOverflow(
+                        f"operator capacity exceeded ({detail} rows "
+                        f"dropped); re-plan with larger out_capacity",
+                        drops=drops,
+                    )
     # operator-close checkpoint: a killed/expired statement unwinds at
     # the result boundary instead of riding out the rest of the plan
     qadmission.checkpoint()

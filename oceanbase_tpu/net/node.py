@@ -32,6 +32,7 @@ from oceanbase_tpu.net.rpc import RpcClient, RpcError, RpcServer
 from oceanbase_tpu.palf.cluster import NoQuorum, NotLeader
 from oceanbase_tpu.palf.netcluster import NetPalf
 from oceanbase_tpu.server import admission as qadmission
+from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.share.location import LocationCache
 from oceanbase_tpu.storage.integrity import CorruptionError, arrays_crc
 
@@ -88,6 +89,9 @@ class NodeDatabase:
             / 1000.0)
         self.trace_registry = TraceRegistry(
             int(self.config["trace_ring_spans"]))
+        from oceanbase_tpu.server.trace import install_runtime_hooks
+
+        install_runtime_hooks()
         self.dtl_metrics = DtlMetrics()
         self.dtl = None  # DtlExchange, installed by NodeServer
         self.health = None  # HealthMonitor, installed by NodeServer
@@ -173,7 +177,6 @@ class NodeServer:
         import uuid
 
         from oceanbase_tpu.net import rebuild as _rebuild
-        from oceanbase_tpu.server import trace as qtrace
         from oceanbase_tpu.storage.recovery import RecoveryState
 
         self.recovery = RecoveryState(node_id)
@@ -526,7 +529,6 @@ class NodeServer:
             raise dtl.DtlLagging(
                 f"node {self.node_id} applied lsn "
                 f"{self.palf.replica.applied_lsn} < {applied_lsn}")
-        from oceanbase_tpu.server import trace as qtrace
 
         # coordinator-propagated cancellation: the fragment runs under a
         # RemoteCtx observing the token's flag, so execute_plan's
@@ -820,7 +822,8 @@ class NodeServer:
                 lag = (self.palf.replica.applied_lsn
                        - int(self.engine.meta.get("wal_lsn", 0)))
                 if lag >= int(self.config["checkpoint_lag_entries"]):
-                    self.tenant.checkpoint()
+                    with qtrace.span("checkpoint.round", lag=lag):
+                        self.tenant.checkpoint()
             except Exception:
                 pass  # transient flush failure: retry next interval
             try:
@@ -846,7 +849,8 @@ class NodeServer:
                     continue
                 last = time.monotonic()
                 if bool(self.config["enable_scrub"]):
-                    self.scrubber.run_once()
+                    with qtrace.span("scrub.round"):
+                        self.scrubber.run_once()
             except Exception:
                 pass  # transient (peer churn mid-round): next round
 

@@ -288,8 +288,7 @@ class _Handler(socketserver.BaseRequestHandler):
                             "error": str(e)}
                     qmetrics.inc("rpc.served", verb=str(verb), ok=0)
                 if tctx is not None and tctx.spans:
-                    resp["spans"] = [s.to_wire()
-                                     for s in tctx.snapshot()]
+                    resp["spans"] = tctx.wire_spans()
             payload = encode_msg(resp)
             if faults is not None:
                 # the handler RAN by now — a reply fault is the

@@ -573,7 +573,7 @@ def execute_fragment(ts, plan_enc: dict, snapshot: int, part: int,
 
     before = exec_times()
     out = execute_plan(remote, {scan.table: rel}, monitor_out=mon,
-                       monitor_collect=with_ops, op_spans=False)
+                       monitor_collect=with_ops)
     after = exec_times()
     # compact wire shape (bare int list, µs-quantized): the pushdown
     # reply's whole point is its tiny wire cost vs the snapshot pull —

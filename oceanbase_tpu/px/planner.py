@@ -639,6 +639,9 @@ def execute_plan_distributed(plan: pp.PlanNode, tables: dict,
 
 def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
                          top, scalar_agg, droot) -> Relation:
+    from oceanbase_tpu.exec.plan import add_exec_times, mark_compiled
+    from oceanbase_tpu.server import trace as qtrace
+    from oceanbase_tpu.share.kvcache import relation_bytes
 
     # partition-wise co-sharding of one scan-to-scan join's base tables
     affinity, elide = choose_affinity(droot, tables)
@@ -657,58 +660,72 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
     needed = pp.referenced_tables(droot)
     sharded = {}
     for t in needed:
-        if t in affinity:
-            sharded[t] = shard_relation_by_hash(tables[t], affinity[t],
-                                                mesh, axis)
-        else:
-            sharded[t] = shard_relation(tables[t], mesh, axis)
+        # device -> host -> devices: every statement pays it per table
+        with qtrace.span("px.shard", table=t,
+                         bytes=relation_bytes(tables[t]),
+                         by="hash" if t in affinity else "block"):
+            if t in affinity:
+                sharded[t] = shard_relation_by_hash(
+                    tables[t], affinity[t], mesh, axis)
+            else:
+                sharded[t] = shard_relation(tables[t], mesh, axis)
 
-    partial_specs = final_specs = post = None
-    if scalar_agg is not None:
-        partial_specs, final_specs, post = split_aggs(scalar_agg.aggs)
+    with qtrace.span("px.program") as psp:
+        partial_specs = final_specs = post = None
+        if scalar_agg is not None:
+            partial_specs, final_specs, post = split_aggs(scalar_agg.aggs)
 
-    # cache key: fingerprint covers the whole plan INCLUDING the peeled
-    # Sort (dist_sort derives from it); keying on the ir.Expr objects
-    # themselves would identity-compare and defeat the executable cache
-    aff_key = tuple(sorted((t, tuple(c)) for t, c in affinity.items()))
-    cache_key = (plan.fingerprint(), aff_key)
-    misses0 = _px_compiled.cache_info().misses
-    run = _px_compiled(
-        cache_key,
-        _Holder(droot, partial_specs, elide, dist_sort, cache_key),
-        mesh, axis, ndev, budget_factor, tuple(sorted(needed)))
-    if _px_compiled.cache_info().misses > misses0:
-        # a fresh shard_map program traces+compiles on first dispatch:
-        # mark the statement so the plan-regression watchdog excludes
-        # this compile-inflated latency sample (exec/plan.py contract)
-        from oceanbase_tpu.exec.plan import mark_compiled
-
-        mark_compiled()
-    out, overflow = run(sharded)
+        # cache key: fingerprint covers the whole plan INCLUDING the
+        # peeled Sort (dist_sort derives from it); keying on the ir.Expr
+        # objects themselves would identity-compare and defeat the
+        # executable cache
+        aff_key = tuple(sorted((t, tuple(c)) for t, c in affinity.items()))
+        cache_key = (plan.fingerprint(), aff_key)
+        misses0 = _px_compiled.cache_info().misses
+        run = _px_compiled(
+            cache_key,
+            _Holder(droot, partial_specs, elide, dist_sort, cache_key),
+            mesh, axis, ndev, budget_factor, tuple(sorted(needed)))
+        if _px_compiled.cache_info().misses > misses0:
+            # a fresh shard_map program traces+compiles on first
+            # dispatch (JAX's compile events book trace_s / lower_s /
+            # compile_s / cache_lookup_s and leave this span's self time
+            # the dispatch): mark the statement so the plan-regression
+            # watchdog excludes this compile-inflated latency sample
+            # (exec/plan.py contract)
+            mark_compiled()
+            psp.tags["compiled"] = 1
+        out, overflow = run(sharded)
     # do NOT sync on the overflow scalar here: an int() at this point
     # parks the host mid-pipeline while the gather/merge/top-chain work
     # below could already be enqueued behind the shard program.  The
     # count rides along as a device scalar and is checked exactly once
     # at the result boundary.
-    rel = unshard_relation(out)
+    with qtrace.span("px.unshard"):
+        rel = unshard_relation(out)
 
-    if scalar_agg is not None:
-        # final merge of the gathered per-shard partials
-        rel = ops.scalar_agg(rel, final_specs)
-        rel = ops.project(rel, dict(post))
+    with qtrace.span("px.merge"):
+        if scalar_agg is not None:
+            # final merge of the gathered per-shard partials
+            rel = ops.scalar_agg(rel, final_specs)
+            rel = ops.project(rel, dict(post))
 
-    # re-apply the coordinator-side top chain, innermost first
-    for node in reversed(top):
-        if isinstance(node, pp.Sort):
-            rel = ops.sort_rows(rel, node.keys, node.ascending)
-        elif isinstance(node, pp.Limit):
-            rel = ops.limit(rel, node.k, node.offset)
-        elif isinstance(node, pp.Project):
-            rel = ops.project(rel, node.outputs)
+        # re-apply the coordinator-side top chain, innermost first
+        for node in reversed(top):
+            if isinstance(node, pp.Sort):
+                rel = ops.sort_rows(rel, node.keys, node.ascending)
+            elif isinstance(node, pp.Limit):
+                rel = ops.limit(rel, node.k, node.offset)
+            elif isinstance(node, pp.Project):
+                rel = ops.project(rel, node.outputs)
 
     # audited result-boundary sync: the one host read that decides
-    # whether the (fully enqueued) result is valid or must be re-planned
-    n_over = int(overflow)  # obcheck: ok(trace.host-sync)
+    # whether the (fully enqueued) result is valid or must be re-planned.
+    # It is also where the statement waits for the device: device_s
+    with qtrace.span("px.device_wait"):
+        n_over = int(overflow)  # obcheck: ok(trace.host-sync)
+    # the legacy aggregate and the launch count, as execute_plan books
+    add_exec_times(host_s=psp.self_s, calls=1)
     if n_over > 0:
         raise diag.CapacityOverflow(
             f"PX exchange overflow: {n_over} rows dropped")

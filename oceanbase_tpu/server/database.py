@@ -113,6 +113,11 @@ class Database:
         # full-link trace ring (gv$trace / SHOW TRACE; server/trace.py)
         self.trace_registry = TraceRegistry(
             int(self.config["trace_ring_spans"]))
+        # JAX's compile events and the collector's pauses, booked to the
+        # statement that paid them (one listener for the process)
+        from oceanbase_tpu.server.trace import install_runtime_hooks
+
+        install_runtime_hooks()
         self.ash = AshSampler(
             interval_s=int(self.config["ash_sample_interval_ms"]) / 1000.0)
         self.wait_events = WaitEvents()

@@ -19,6 +19,8 @@ from __future__ import annotations
 import threading
 import time
 
+from oceanbase_tpu.server import trace as qtrace
+
 
 class JobScheduler:
     def __init__(self, db, tick_s: float = 1.0):
@@ -106,7 +108,8 @@ class JobScheduler:
                 t0 = time.monotonic()  # elapsed source (step-proof)
                 ok, err = True, ""
                 try:
-                    j["fn"]()
+                    with qtrace.span("job.run", job=name):
+                        j["fn"]()
                 except Exception as e:  # noqa: BLE001 — record + continue
                     ok, err = False, f"{type(e).__name__}: {e}"
                     j["failures"] += 1

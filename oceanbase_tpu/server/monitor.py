@@ -20,6 +20,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
+from oceanbase_tpu.server import trace as qtrace
+
 
 def _tail(ring: collections.deque, n: int | None) -> list:
     """Last ``n`` entries (``None`` = all) without materializing the
@@ -60,6 +62,11 @@ class AuditRecord:
     #                            # its legacy bind-window meaning
     dispatch_s: float = 0.0
     merge_s: float = 0.0
+    # elapsed_s - queue_s - the sum of every phase: what no span owns
+    other_s: float = 0.0
+    # the statement's whole ExecTimes accumulator (every phase of
+    # trace.PHASES + close_s): gv$sql_audit's further phase columns
+    times: object = None
 
 
 class SqlAudit:
@@ -331,6 +338,11 @@ class PlanHistory:
                 ent["regressed"] = now_regressed
             return transitioned
 
+    def baseline_s(self, logical_hash: str) -> float:
+        """The frozen baseline latency of a plan (0.0 until it froze)."""
+        ent = self._store.get(logical_hash)  # one atomic dict read
+        return ent["baseline_s"] if ent is not None else 0.0
+
     def rows(self) -> list:
         """Flat gv$plan_history rows (percentiles from the bucket
         counts, never stored samples)."""
@@ -571,16 +583,19 @@ class TimeModel:
     """
 
     #: pipeline-ordered phase names; ``elapsed_s`` is appended as its
-    #: own row so phase-sum-vs-wall reconciliation is a single query
-    PHASES = ("queue_s", "bind_s", "sidecar_build_s", "lower_s",
-              "compile_s", "dispatch_s", "merge_s", "device_s")
+    #: own row so phase-sum-vs-wall reconciliation is a single query.
+    #: ``other_s`` is the wall no phase owns; ``close_s`` (after the
+    #: statement's root span closed) lies outside ``elapsed_s``
+    PHASES = ("queue_s",) + qtrace.PHASES + ("device_s", "other_s",
+                                              "close_s")
 
     def __init__(self):
         self._tenants: dict[str, dict] = {}
         self._lock = threading.Lock()
 
     def observe(self, tenant: str, times, elapsed_s: float = 0.0,
-                queue_s: float = 0.0):
+                queue_s: float = 0.0, other_s: float = 0.0,
+                close_s: float = 0.0):
         """Fold one statement's ExecTimes into the tenant account."""
         with self._lock:
             acc = self._tenants.get(tenant)
@@ -588,11 +603,12 @@ class TimeModel:
                 acc = self._tenants[tenant] = {p: 0.0 for p in self.PHASES}
                 acc["elapsed_s"] = 0.0
                 acc["statements"] = 0
-            for phase in self.PHASES:
-                if phase == "queue_s":
-                    continue
-                acc[phase] += float(getattr(times, phase, 0.0) or 0.0)
+            for phase in qtrace.PHASES:
+                acc[phase] += getattr(times, phase, 0.0)
+            acc["device_s"] += getattr(times, "device_s", 0.0)
             acc["queue_s"] += float(queue_s)
+            acc["other_s"] += float(other_s)
+            acc["close_s"] += float(close_s)
             acc["elapsed_s"] += float(elapsed_s)
             acc["statements"] += 1
 
@@ -671,7 +687,11 @@ class AshSampler:
 
         def loop():
             while not self._stop.wait(self.interval_s):
-                self.sample_once()
+                # one span per round: with no statement context it is the
+                # profiler annotation alone (ob:ash.sample), which puts a
+                # sampler round beside the statement it may have delayed
+                with qtrace.span("ash.sample"):
+                    self.sample_once()
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="ash-sampler")

@@ -10,15 +10,13 @@ PROFILE`` renders the session's most recent capture.
 
 The capture degrades gracefully everywhere the backend can't profile:
 the statement always executes; a profiler failure just yields a note
-instead of rows.  The parser reads the Chrome-trace export
-(``*.trace.json.gz``) with nothing but stdlib — no tensorflow /
-tensorboard dependency — and classifies events into
+instead of rows.  The parser reads the capture's ``.xplane.pb`` through
+``jax.profiler.ProfileData`` (nothing but JAX) into
 
-- ``kernel``  — XLA computation events (fusions, reductions, ...): the
-  rows the roofline plane cares about;
-- ``runtime`` — executor machinery (TfrtCpuExecutable, ThunkExecutor,
-  thread-pool listeners);
-- ``host``    — python-side TraceMe frames (``$file.py:line``).
+- ``kernel`` — the executed XLA ops, named as the device plane names
+  them (the rows the roofline plane cares about);
+- ``host``   — the program's own spans (``ob:<name>``): the statement's
+  phases on the same timeline.
 
 Only one trace can be active per process (a jax.profiler constraint):
 concurrent PROFILEs serialize on a non-blocking lock — the loser runs
@@ -29,8 +27,6 @@ from __future__ import annotations
 
 import collections
 import glob
-import gzip
-import json
 import os
 import shutil
 import tempfile
@@ -39,16 +35,6 @@ import time
 from dataclasses import dataclass, field
 
 _PROFILE_LOCK = threading.Lock()
-
-#: event-name prefixes that are executor/compiler machinery, not kernels
-_RUNTIME_PREFIXES = (
-    "TfrtCpu", "PjitFunction", "ThunkExecutor", "ThreadpoolListener",
-    "ParseArguments", "ExecuteHelper", "PjRt", "CopyToDevice",
-    "TransferTo", "BufferFromHost", "Execute", "program_shape",
-    "backend_compile", "CpuCompiler", "Codegen", "TaskDispatcher",
-    "XlaCompile", "ThreadPool", "BufferAllocations", "Stream",
-    "RunBackend", "optimization", "HloPass",
-)
 
 MAX_ROWS_PER_PROFILE = 256
 
@@ -108,7 +94,11 @@ def profile_statement(run):
             try:
                 import jax
 
-                cm = jax.profiler.trace(tmpdir)
+                # the statement's phases come from the program's own
+                # spans, not from a frame per Python call
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                cm = jax.profiler.trace(tmpdir, profiler_options=options)
                 cm.__enter__()
             except Exception as e:  # noqa: BLE001 — no profiler on
                 # this backend: the statement still runs
@@ -134,66 +124,63 @@ def profile_statement(run):
 
 
 # ---------------------------------------------------------------------------
-# parse (stdlib only: the Chrome-trace export)
+# parse (the capture's .xplane.pb, through jax.profiler.ProfileData)
 # ---------------------------------------------------------------------------
 
-
-def _classify(plane: str, name: str) -> str:
-    if name.startswith("$") or ".py:" in name:
-        return "host"
-    if plane.startswith("/device:"):
-        return "kernel"
-    if any(name.startswith(p) for p in _RUNTIME_PREFIXES):
-        return "runtime"
-    return "kernel"
+#: lines of a device plane that hold one event per executed HLO op
+_OP_LINES = ("XLA Ops",)
+_MAX_NAME = 256
 
 
 def parse_trace_dir(tmpdir: str) -> list:
-    """Newest ``*.trace.json.gz`` under a jax.profiler log dir ->
-    aggregated per-kernel rows (sorted by total time, bounded)."""
-    pats = (os.path.join(tmpdir, "plugins", "profile", "*",
-                         "*.trace.json.gz"),
-            os.path.join(tmpdir, "plugins", "profile", "*",
-                         "*.trace.json"))
-    files = sorted(f for p in pats for f in glob.glob(p))
+    """Newest ``*.xplane.pb`` under a jax.profiler log dir -> aggregated
+    rows (sorted by total time, bounded):
+
+    - ``kernel`` — one per executed XLA op, named as the device plane
+      names it: the ``XLA Ops`` line of each ``/device:*`` plane, or, on
+      a backend without device planes (the CPU), the host events that
+      carry an ``hlo_op`` stat;
+    - ``host`` — the program's own spans (``ob:<name>``, server/trace.py)
+      inside the capture: the statement's phases on the same clock.
+    """
+    files = sorted(glob.glob(os.path.join(
+        tmpdir, "plugins", "profile", "*", "*.xplane.pb")))
     if not files:
         return []
-    path = files[-1]
     try:
-        if path.endswith(".gz"):
-            with gzip.open(path, "rb") as fh:
-                doc = json.loads(fh.read())
-        else:
-            with open(path) as fh:
-                doc = json.load(fh)
-    except (OSError, json.JSONDecodeError, EOFError):
+        from jax.profiler import ProfileData
+
+        profile = ProfileData.from_file(files[-1])
+    except Exception:  # noqa: BLE001 — an unreadable capture is no rows
         return []
-    events = doc.get("traceEvents", []) or []
-    planes: dict[int, str] = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            planes[e.get("pid")] = (e.get("args") or {}).get("name", "")
+    from oceanbase_tpu.server.trace import ANNOTATION_PREFIX
+
     agg: dict[tuple, list] = {}
-    for e in events:
-        if e.get("ph") != "X":
+
+    def add(plane: str, name: str, kind: str, dur_ns: float):
+        cur = agg.setdefault((plane, name[:_MAX_NAME], kind), [0, 0.0])
+        cur[0] += 1
+        cur[1] += dur_ns * 1e-9
+
+    device_planes = [p for p in profile.planes
+                     if p.name.startswith("/device:")]
+    for plane in device_planes:
+        for line in plane.lines:
+            if line.name in _OP_LINES:
+                for e in line.events:
+                    add(plane.name, e.name, "kernel", e.duration_ns)
+    for plane in profile.planes:
+        if not plane.name.startswith("/host:"):
             continue
-        name = e.get("name", "")
-        if not name:
-            continue
-        plane = planes.get(e.get("pid"), "")
-        kind = _classify(plane, name)
-        if kind == "host":
-            continue  # python frames: gv$trace already covers the host
-        dur_s = float(e.get("dur", 0)) * 1e-6  # chrome trace: µs
-        k = (plane, name, kind)
-        cur = agg.get(k)
-        if cur is None:
-            agg[k] = [1, dur_s]
-        else:
-            cur[0] += 1
-            cur[1] += dur_s
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(ANNOTATION_PREFIX):
+                    add(plane.name, e.name, "host", e.duration_ns)
+                elif not device_planes and e.duration_ns > 0 and \
+                        any(k == "hlo_op" for k, _ in e.stats):
+                    add(plane.name, e.name, "kernel", e.duration_ns)
     kernel_total = sum(v[1] for (_pl, _n, kind), v in agg.items()
-                      if kind == "kernel") or 0.0
+                       if kind == "kernel")
     rows = []
     for (plane, name, kind), (occ, total) in agg.items():
         rows.append({

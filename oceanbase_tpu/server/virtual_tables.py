@@ -18,6 +18,16 @@ import time
 
 import numpy as np
 
+from oceanbase_tpu.server import trace as qtrace
+
+#: gv$sql_audit's phase columns beyond the six that predate the spans
+#: (those keep their AuditRecord fields; ``compile_s`` there is the bind
+#: window, ExecTimes.compile_s is ``xla_compile_s``)
+_AUDIT_PHASE_COLUMNS = tuple(
+    p for p in qtrace.PHASES + ("close_s",)
+    if p not in ("bind_s", "sidecar_build_s", "lower_s", "compile_s",
+                 "dispatch_s", "merge_s"))
+
 
 def _obj(xs):
     return np.array(list(xs), dtype=object)
@@ -117,6 +127,13 @@ class VirtualTables:
                                     for r in recs], np.float64),
             "merge_s": np.array([getattr(r, "merge_s", 0.0)
                                  for r in recs], np.float64),
+            # every further phase of the statement's ExecTimes (the
+            # self time of its span, trace.PHASE_OF), the wall no phase
+            # owns, and the work after the root span closed
+            **{col: np.array([getattr(r.times, col, 0.0) for r in recs],
+                             np.float64)
+               for col in _AUDIT_PHASE_COLUMNS},
+            "other_s": np.array([r.other_s for r in recs], np.float64),
         }
 
     def time_model(self):

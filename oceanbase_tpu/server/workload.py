@@ -44,6 +44,7 @@ import threading
 import time
 
 from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.storage.integrity import (
     CorruptionError,
     bytes_crc,
@@ -521,7 +522,8 @@ class WorkloadRepository:
                 continue
             last = time.monotonic()
             try:
-                self.snapshot(cluster=True)
+                with qtrace.span("workload.snapshot"):
+                    self.snapshot(cluster=True)
             except Exception:  # noqa: BLE001 — diagnostics must never
                 # take the node down; the next round retries
                 pass

@@ -145,38 +145,42 @@ def scan_filter_ranges(plan, engine):
     other scan of that table."""
     out: dict[str, dict] = {}
     scan_counts: dict[str, int] = {}
-
-    def visit(node, preds):
-        if isinstance(node, pp.Filter):
-            visit(node.child, preds + [node.pred])
-            return
-        if isinstance(node, pp.TableScan):
-            scan_counts[node.table] = scan_counts.get(node.table, 0) + 1
-            ts = engine.tables.get(node.table) if engine else None
-            if ts is None or not preds:
-                return
-            inv = {cid: base
-                   for base, cid in (node.rename or {}).items()} or \
-                {c: c for c in ts.tablet.columns}
-            coltypes = ts.tablet.types
-            ranges = out.setdefault(node.table, {})
-            for p in preds:
-                for c in _conjuncts(p):
-                    r = _range_of(c, inv, coltypes)
-                    if r is None:
-                        continue
-                    col, lo, hi = r
-                    ranges[col] = _intersect(ranges.get(col), lo, hi)
-            return
-        for fname in ("child", "left", "right"):
-            kid = getattr(node, fname, None)
-            if kid is not None:
-                visit(kid, [])
-        for kid in getattr(node, "inputs", []) or []:
-            visit(kid, [])
-
-    visit(plan, [])
+    _visit_ranges(plan, [], engine, out, scan_counts)
     return {t: r for t, r in out.items() if scan_counts.get(t, 0) == 1}
+
+
+def _visit_ranges(node, preds, engine, out, scan_counts):
+    """``scan_filter_ranges``'s walk: a module-level function, not a
+    closure that names itself (that is a reference cycle per call, on
+    every statement, for the collector to find)."""
+    if isinstance(node, pp.Filter):
+        _visit_ranges(node.child, preds + [node.pred], engine, out,
+                      scan_counts)
+        return
+    if isinstance(node, pp.TableScan):
+        scan_counts[node.table] = scan_counts.get(node.table, 0) + 1
+        ts = engine.tables.get(node.table) if engine else None
+        if ts is None or not preds:
+            return
+        inv = {cid: base
+               for base, cid in (node.rename or {}).items()} or \
+            {c: c for c in ts.tablet.columns}
+        coltypes = ts.tablet.types
+        ranges = out.setdefault(node.table, {})
+        for p in preds:
+            for c in _conjuncts(p):
+                r = _range_of(c, inv, coltypes)
+                if r is None:
+                    continue
+                col, lo, hi = r
+                ranges[col] = _intersect(ranges.get(col), lo, hi)
+        return
+    for fname in ("child", "left", "right"):
+        kid = getattr(node, fname, None)
+        if kid is not None:
+            _visit_ranges(kid, [], engine, out, scan_counts)
+    for kid in getattr(node, "inputs", []) or []:
+        _visit_ranges(kid, [], engine, out, scan_counts)
 
 
 @dataclass

@@ -20,10 +20,12 @@ import numpy as np
 from oceanbase_tpu.catalog import Catalog, ColumnDef, TableDef
 from oceanbase_tpu.datatypes import SqlType, TypeKind, days_to_date
 from oceanbase_tpu.exec.diag import CapacityOverflow
+from oceanbase_tpu.exec import plan as qplan
 from oceanbase_tpu.exec.plan import execute_plan
 from oceanbase_tpu.expr import ir
 from oceanbase_tpu.expr.compile import literal_value
 from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.sql import ast
 from oceanbase_tpu.sql.binder import Binder
 from oceanbase_tpu.sql.optimizer import scale_capacities
@@ -36,8 +38,6 @@ qmetrics.declare("sql.statements", "counter",
                  "statements executed (labels: tenant, ok)")
 qmetrics.declare("sql.statement_s", "histogram",
                  "end-to-end statement latency", unit="s")
-qmetrics.declare("sql.rows_returned", "counter",
-                 "result rows returned to clients")
 qmetrics.declare("plan_cache.hits", "counter",
                  "session plan-cache hits")
 qmetrics.declare("plan_cache.misses", "counter",
@@ -192,7 +192,6 @@ class Session:
         bounded queue is full) and run under a StmtCtx whose deadline
         and KILL flag the result-boundary checkpoints observe."""
         from oceanbase_tpu.server import admission as qadmission
-        from oceanbase_tpu.server import trace as qtrace
 
         start = time.time()        # wall ts for the audit record
         t0 = time.monotonic()      # duration source (step-proof)
@@ -200,18 +199,17 @@ class Session:
         out = None
         # host/device split accumulator: statement-scoped, so audit and
         # plan-monitor rows attribute exactly this statement's work
-        from oceanbase_tpu.exec import plan as qplan
-
-        qplan.reset_exec_times()
-        tctx = qtrace.start_trace(self.db)
-        self._ash_state.update(
-            active=True, sql=sql, state="executing",
-            trace_id=tctx.trace_id if tctx is not None else "")
+        times = qplan.reset_exec_times()
+        qtrace.begin_statement()
         self._last_compile_s = 0.0
         self._stmt_is_show_trace = False  # set by _show_trace()
+        tctx = qtrace.start_trace(self.db)
         admission = (getattr(self.db, "admission", None)
                      if self.db is not None else None)
         ctx: qadmission.StmtCtx | None = None
+        # every host phase below is a span whose self time is a column
+        # of this statement's audit row (trace.PHASE_OF); what lies
+        # between them is ``other_s``
         try:
             if admission is not None:
                 # a session evicted by plain KILL <id> takes no more
@@ -220,92 +218,109 @@ class Session:
             with qtrace.activate(tctx):
                 with qtrace.span("statement", sql=sql[:200],
                                  session=self.session_id):
-                    stmt = parse_sql(sql)
-                    if admission is not None and \
-                            self._needs_admission(stmt):
-                        ctx = qadmission.StmtCtx(
-                            session_id=self.session_id,
-                            tenant=getattr(self.tenant, "name", "sys"),
-                            sql=sql,
-                            timeout_s=self._stmt_timeout_s(),
-                            controller=admission,
-                            ash_state=self._ash_state)
-                        self._ash_state["state"] = "queued"
-                        try:
-                            admission.acquire(ctx)
-                        finally:
-                            if self._ash_state.get("state") == "queued":
-                                self._ash_state["state"] = "executing"
-                        if ctx.queue_s > 0:
-                            # queued time is a first-class wait: a span
-                            # in the statement tree + gv$sql_audit's
-                            # queue_s column (emitted only when the
-                            # statement actually waited)
-                            qtrace.add_span("admission.wait",
-                                            ctx.queue_s,
-                                            tenant=ctx.tenant)
+                    with qtrace.span("parse"):
+                        stmt = parse_sql(sql)
+                    with qtrace.span("admission"):
+                        # the statement's own bookkeeping lives here,
+                        # so that it has an owner on the timeline
+                        self._ash_state.update(
+                            active=True, sql=sql, state="executing",
+                            trace_id=tctx.trace_id
+                            if tctx is not None else "")
+                        if admission is not None and \
+                                self._needs_admission(stmt):
+                            ctx = qadmission.StmtCtx(
+                                session_id=self.session_id,
+                                tenant=getattr(self.tenant, "name", "sys"),
+                                sql=sql,
+                                timeout_s=self._stmt_timeout_s(),
+                                controller=admission,
+                                ash_state=self._ash_state)
+                            self._ash_state["state"] = "queued"
+                            try:
+                                admission.acquire(ctx)
+                            finally:
+                                if self._ash_state.get("state") == \
+                                        "queued":
+                                    self._ash_state["state"] = "executing"
+                            if ctx.queue_s > 0:
+                                # queued time is a first-class wait: a
+                                # span in the statement tree +
+                                # gv$sql_audit's queue_s column (emitted
+                                # only when the statement actually
+                                # waited; the admission span's self time
+                                # gives it up)
+                                qtrace.add_span("admission.wait",
+                                                ctx.queue_s,
+                                                tenant=ctx.tenant)
                     with qadmission.activate(ctx):
-                        self._materialize_virtuals(stmt)
+                        with qtrace.span("virtuals"):
+                            self._materialize_virtuals(stmt)
                         out = self.execute_stmt(stmt, params)
                         return out
         except Exception as e:
             err = f"{type(e).__name__}: {e}"
             raise
         finally:
-            if ctx is not None:
-                admission.release(ctx)
             elapsed = time.monotonic() - t0
-            self._ash_state.update(active=False, state="idle",
-                                   trace_id="")
-            tname = getattr(self.tenant, "name", "sys")
-            qmetrics.inc("sql.statements", tenant=tname,
-                         ok=0 if err else 1)
-            qmetrics.observe("sql.statement_s", elapsed, tenant=tname)
-            if out is not None and out.rowcount > 0:
-                qmetrics.inc("sql.rows_returned", out.rowcount,
-                             tenant=tname)
-            trace_id = ""
-            if tctx is not None:
-                kept = qtrace.finish_trace(self.db, tctx, elapsed,
-                                           error=err)
-                if kept:
-                    trace_id = tctx.trace_id
-                    # SHOW TRACE reads the LAST statement's tree — a
-                    # SHOW TRACE must not clobber what it displays
-                    if not self._stmt_is_show_trace:
-                        self._last_trace_id = trace_id
-                elif not self._stmt_is_show_trace:
-                    # sampled away: SHOW TRACE must come up empty, not
-                    # silently attribute an OLDER statement's tree
-                    self._last_trace_id = ""
-            if self.db is not None and \
-                    getattr(self.db, "audit", None) is not None:
-                from oceanbase_tpu.server.monitor import AuditRecord
+            # the root span has closed: what follows is on the
+            # profiler's timeline (ob:statement.close) and in close_s,
+            # outside elapsed_s
+            with qtrace.span("statement.close") as csp:
+                if ctx is not None:
+                    admission.release(ctx)
+                self._ash_state.update(active=False, state="idle",
+                                       trace_id="")
+                tname = getattr(self.tenant, "name", "sys")
+                qmetrics.inc("sql.statements", tenant=tname,
+                             ok=0 if err else 1)
+                qmetrics.observe("sql.statement_s", elapsed, tenant=tname)
+                trace_id = ""
+                if tctx is not None:
+                    kept = qtrace.finish_trace(self.db, tctx, elapsed,
+                                               error=err)
+                    if kept:
+                        trace_id = tctx.trace_id
+                        # SHOW TRACE reads the LAST statement's tree — a
+                        # SHOW TRACE must not clobber what it displays
+                        if not self._stmt_is_show_trace:
+                            self._last_trace_id = trace_id
+                    elif not self._stmt_is_show_trace:
+                        # sampled away: SHOW TRACE must come up empty,
+                        # not silently attribute an OLDER statement's
+                        # tree
+                        self._last_trace_id = ""
+                if self.db is not None and \
+                        getattr(self.db, "audit", None) is not None:
+                    from oceanbase_tpu.server.monitor import AuditRecord
 
-                times = qplan.exec_times()
-                self.db.audit.record(AuditRecord(
-                    sql=sql, session_id=self.session_id,
-                    tenant=getattr(self.tenant, "name", ""),
-                    start_ts=start, elapsed_s=elapsed,
-                    rows=out.rowcount if out is not None else 0,
-                    error=err,
-                    compile_s=self._last_compile_s,
-                    trace_id=trace_id,
-                    queue_s=ctx.queue_s if ctx is not None else 0.0,
-                    host_s=times.host_s, device_s=times.device_s,
-                    bind_s=times.bind_s,
-                    sidecar_build_s=times.sidecar_build_s,
-                    lower_s=times.lower_s,
-                    xla_compile_s=times.compile_s,
-                    dispatch_s=times.dispatch_s,
-                    merge_s=times.merge_s,
-                ))
-                tm = getattr(self.db, "time_model", None)
-                if tm is not None:
-                    tm.observe(getattr(self.tenant, "name", "sys"),
-                               times, elapsed_s=elapsed,
-                               queue_s=ctx.queue_s if ctx is not None
-                               else 0.0)
+                    queue_s = ctx.queue_s if ctx is not None else 0.0
+                    other_s = elapsed - queue_s - times.phase_sum()
+                    # the row keeps the statement's accumulator itself,
+                    # so close_s (booked when this span closes) shows
+                    self.db.audit.record(AuditRecord(
+                        sql=sql, session_id=self.session_id,
+                        tenant=getattr(self.tenant, "name", ""),
+                        start_ts=start, elapsed_s=elapsed,
+                        rows=out.rowcount if out is not None else 0,
+                        error=err,
+                        compile_s=self._last_compile_s,
+                        trace_id=trace_id,
+                        queue_s=queue_s,
+                        host_s=times.host_s, device_s=times.device_s,
+                        bind_s=times.bind_s,
+                        sidecar_build_s=times.sidecar_build_s,
+                        lower_s=times.lower_s,
+                        xla_compile_s=times.compile_s,
+                        dispatch_s=times.dispatch_s,
+                        merge_s=times.merge_s,
+                        other_s=other_s, times=times,
+                    ))
+                    tm = getattr(self.db, "time_model", None)
+                    if tm is not None:
+                        tm.observe(tname, times, elapsed_s=elapsed,
+                                   queue_s=queue_s, other_s=other_s,
+                                   close_s=csp.so_far_s())
 
     def _materialize_virtuals(self, stmt):
         """Refresh any referenced gv$/v$ virtual tables as transient
@@ -319,70 +334,72 @@ class Session:
         if vt is None:
             return
 
+        # the walk is made of methods, not of closures that name each
+        # other: those are reference cycles, made anew by every
+        # statement, that only the collector can free
         seen_views: set = set()
-
-        def refresh(name):
-            arrays = vt.provide(name)
-            if arrays is not None:
-                self.catalog.register_transient(name, arrays)
-                return
-            # a view body may reference gv$/v$ tables too — walk it so
-            # they refresh per statement like direct references
-            vdef = self.catalog.view_def(name)
-            if vdef is None or name in seen_views:
-                return
-            seen_views.add(name)
-            try:
-                body = parse_sql(vdef["sql"])
-            except Exception:
-                return
-            if isinstance(body, ast.SelectStmt):
-                walk_sel(body)
-
-        def walk_expr(e):
-            if e is None or not isinstance(e, ir.Expr):
-                return
-            if isinstance(e, ast.Subquery) and e.select is not None:
-                walk_sel(e.select)
-            for c in e.children():
-                walk_expr(c)
-
-        def walk_from(items):
-            for t in items:
-                if isinstance(t, ast.TableRef):
-                    refresh(t.name)
-                elif isinstance(t, ast.JoinRef):
-                    walk_from([t.left, t.right])
-                    if isinstance(t.on, ir.Expr):
-                        walk_expr(t.on)
-                elif isinstance(t, ast.SubqueryRef):
-                    walk_sel(t.select)
-
-        def walk_sel(s):
-            walk_from(s.from_)
-            for e, _ in s.items:
-                walk_expr(e)
-            walk_expr(s.where)
-            walk_expr(s.having)
-            for _, sub in s.ctes:
-                walk_sel(sub)
-            for _, _, rhs in s.setops:
-                walk_sel(rhs)
-
         if isinstance(stmt, ast.ProfileStmt):
             stmt = stmt.stmt
         if isinstance(stmt, ast.ExplainStmt):
             stmt = stmt.stmt
         if isinstance(stmt, ast.SelectStmt):
-            walk_sel(stmt)
+            self._virtuals_in_select(stmt, vt, seen_views)
         elif isinstance(stmt, ast.InsertStmt) and stmt.select is not None:
-            walk_sel(stmt.select)
+            self._virtuals_in_select(stmt.select, vt, seen_views)
         elif isinstance(stmt, (ast.UpdateStmt, ast.DeleteStmt)):
-            walk_expr(stmt.where)
+            self._virtuals_in_expr(stmt.where, vt, seen_views)
         elif isinstance(stmt, ast.DescribeStmt):
             # DESCRIBE on a gv$ table or on a view whose body reads one
             # must materialize it before the binder expands the name
-            refresh(stmt.table)
+            self._refresh_virtual(stmt.table, vt, seen_views)
+
+    def _refresh_virtual(self, name, vt, seen_views):
+        arrays = vt.provide(name)
+        if arrays is not None:
+            self.catalog.register_transient(name, arrays)
+            return
+        # a view body may reference gv$/v$ tables too — walk it so
+        # they refresh per statement like direct references
+        vdef = self.catalog.view_def(name)
+        if vdef is None or name in seen_views:
+            return
+        seen_views.add(name)
+        try:
+            body = parse_sql(vdef["sql"])
+        except Exception:
+            return
+        if isinstance(body, ast.SelectStmt):
+            self._virtuals_in_select(body, vt, seen_views)
+
+    def _virtuals_in_expr(self, e, vt, seen_views):
+        if e is None or not isinstance(e, ir.Expr):
+            return
+        if isinstance(e, ast.Subquery) and e.select is not None:
+            self._virtuals_in_select(e.select, vt, seen_views)
+        for c in e.children():
+            self._virtuals_in_expr(c, vt, seen_views)
+
+    def _virtuals_in_from(self, items, vt, seen_views):
+        for t in items:
+            if isinstance(t, ast.TableRef):
+                self._refresh_virtual(t.name, vt, seen_views)
+            elif isinstance(t, ast.JoinRef):
+                self._virtuals_in_from([t.left, t.right], vt, seen_views)
+                if isinstance(t.on, ir.Expr):
+                    self._virtuals_in_expr(t.on, vt, seen_views)
+            elif isinstance(t, ast.SubqueryRef):
+                self._virtuals_in_select(t.select, vt, seen_views)
+
+    def _virtuals_in_select(self, s, vt, seen_views):
+        self._virtuals_in_from(s.from_, vt, seen_views)
+        for e, _ in s.items:
+            self._virtuals_in_expr(e, vt, seen_views)
+        self._virtuals_in_expr(s.where, vt, seen_views)
+        self._virtuals_in_expr(s.having, vt, seen_views)
+        for _, sub in s.ctes:
+            self._virtuals_in_select(sub, vt, seen_views)
+        for _, _, rhs in s.setops:
+            self._virtuals_in_select(rhs, vt, seen_views)
 
     def execute_stmt(self, stmt, params=None) -> Result:
         if isinstance(stmt, ast.SelectStmt):
@@ -1097,7 +1114,6 @@ class Session:
         statement's own result (and errors) pass through unchanged;
         backends without a profiler degrade to a note."""
         from oceanbase_tpu.server import profiler as qprofiler
-        from oceanbase_tpu.server import trace as qtrace
 
         store = (getattr(self.db, "device_profiles", None)
                  if self.db is not None else None)
@@ -1316,7 +1332,6 @@ class Session:
 
     def _execute_select(self, stmt: ast.SelectStmt, params) -> Result:
         from oceanbase_tpu.exec.plan import referenced_tables
-        from oceanbase_tpu.server import trace as qtrace
 
         use_cache = (self.db is not None
                      and bool(self.db.config["enable_plan_cache"])
@@ -1328,41 +1343,59 @@ class Session:
                     self._ash_state["sql"], stmt, params)
             else:
                 plan, outputs, _est = self._plan_select(stmt, params)
-        self._last_compile_s = time.monotonic() - tb0
         # the bind window (parse → logical plan → CBO) is the first
-        # host phase of the statement's time model
-        from oceanbase_tpu.exec.plan import add_exec_times as _add_times
-        _add_times(bind_s=self._last_compile_s)
+        # host phase of the statement's time model: the compile span's
+        # self time is bind_s (trace.PHASE_OF)
+        self._last_compile_s = time.monotonic() - tb0
         from oceanbase_tpu.exec.plan import logical_hash as _lhash_of
         from oceanbase_tpu.sql.optimizer import apply_feedback
 
-        # cardinality feedback (gv$plan_feedback): a logical plan whose
-        # operators were observed bigger than their static budgets starts
-        # at the observed capacity bucket instead of re-riding the
-        # CapacityOverflow retry ladder (≙ plan evolution consulting
-        # measured stats).  Keyed by the capacity-insensitive hash so the
-        # corrected plan keeps matching its own history.
-        lhash = _lhash_of(plan) if self.db is not None else ""
-        if lhash and getattr(self.db, "plan_choice", None) is not None \
-                and getattr(self, "_last_cbo_choices", None):
-            # bind-time CBO beliefs land in gv$plan_choice; the measured
-            # device seconds fold in below once the plan has run
-            self.db.plan_choice.record(lhash, self._last_cbo_choices)
-        feedback_on = (
-            self.db is not None
-            and getattr(self.db, "plan_feedback", None) is not None
-            and bool(self.db.config["enable_plan_feedback"]))
-        if feedback_on:
-            corr = self.db.plan_feedback.corrections(lhash)
-            if corr:
-                qmetrics.inc("plan.feedback_hits")
-                plan, n_fixed = apply_feedback(plan, corr)
-                if n_fixed:
-                    qmetrics.inc("plan.feedback_corrections", n_fixed)
-        # estimate-driven spill route (≙ the SQL memory manager deciding
-        # spill from work-area estimates BEFORE execution): over-budget
-        # inputs never materialize whole on device
-        big = self._spill_candidates(plan)
+        monitor = None
+        mon_collect = True
+        with qtrace.span("plan.prepare"):
+            # cardinality feedback (gv$plan_feedback): a logical plan
+            # whose operators were observed bigger than their static
+            # budgets starts at the observed capacity bucket instead of
+            # re-riding the CapacityOverflow retry ladder (≙ plan
+            # evolution consulting measured stats).  Keyed by the
+            # capacity-insensitive hash so the corrected plan keeps
+            # matching its own history.
+            lhash = _lhash_of(plan) if self.db is not None else ""
+            if lhash and \
+                    getattr(self.db, "plan_choice", None) is not None \
+                    and getattr(self, "_last_cbo_choices", None):
+                # bind-time CBO beliefs land in gv$plan_choice; the
+                # measured device seconds fold in below once the plan
+                # has run
+                self.db.plan_choice.record(lhash, self._last_cbo_choices)
+            feedback_on = (
+                self.db is not None
+                and getattr(self.db, "plan_feedback", None) is not None
+                and bool(self.db.config["enable_plan_feedback"]))
+            if feedback_on:
+                corr = self.db.plan_feedback.corrections(lhash)
+                if corr:
+                    qmetrics.inc("plan.feedback_hits")
+                    plan, n_fixed = apply_feedback(plan, corr)
+                    if n_fixed:
+                        qmetrics.inc("plan.feedback_corrections", n_fixed)
+            # estimate-driven spill route (≙ the SQL memory manager
+            # deciding spill from work-area estimates BEFORE execution):
+            # over-budget inputs never materialize whole on device
+            big = self._spill_candidates(plan)
+            if self.db is not None and \
+                    getattr(self.db, "plan_monitor", None) is not None \
+                    and self.db.config["enable_sql_plan_monitor"]:
+                # sampled ledger collection: every execution runs the
+                # SAME monitored executable (the variant is part of the
+                # compile key — alternating it would double the plan's
+                # XLA trace count and break the shape-bucket
+                # amortization invariant); unsampled executions merely
+                # skip the host transfer and the ledger record
+                monitor = []
+                mon_collect = self.db.plan_monitor.should_record(
+                    lhash,
+                    int(self.db.config["plan_monitor_sample_every"]))
         if big:
             res = self._try_spilled(plan, outputs, big)
             if res is not None:
@@ -1375,31 +1408,17 @@ class Session:
             # query must not pay the full host->device materialization
             nonlocal tables
             if tables is None:
-                tables = {t: self._table_snapshot(t)
-                          for t in referenced_tables(plan)
-                          if self.catalog.has_table(t)}
-                self._try_ann_prefilter(plan, tables)
-                self._last_access_paths = self._index_prefilter(
-                    plan, tables)
-                self._prepare_index_probes(plan, tables)
+                with qtrace.span("tables"):
+                    tables = {t: self._table_snapshot(t)
+                              for t in referenced_tables(plan)
+                              if self.catalog.has_table(t)}
+                    self._try_ann_prefilter(plan, tables)
+                    self._last_access_paths = self._index_prefilter(
+                        plan, tables)
+                    self._prepare_index_probes(plan, tables)
             return tables
 
         self._last_access_paths = {}
-        monitor = None
-        mon_collect = True
-        if self.db is not None and \
-                getattr(self.db, "plan_monitor", None) is not None and \
-                self.db.config["enable_sql_plan_monitor"]:
-            # sampled ledger collection: every execution runs the SAME
-            # monitored executable (the variant is part of the compile
-            # key — alternating it would double the plan's XLA trace
-            # count and break the shape-bucket amortization invariant);
-            # unsampled executions merely skip the host transfer and
-            # the ledger record
-            monitor = []
-            mon_collect = self.db.plan_monitor.should_record(
-                lhash,
-                int(self.db.config["plan_monitor_sample_every"]))
         dop = self._px_dop()
         factor = 1
         from oceanbase_tpu.exec.plan import (
@@ -1483,56 +1502,73 @@ class Session:
                 # the satellite: a px_admission denial is visible on
                 # the statement's trace span, not silently serial
                 xsp.tags["px_downgrade"] = 1
-        if factor > 1 and use_cache:
-            # evolve the cached plan: a plan bound against a smaller
-            # table keeps overflowing its stale capacity budgets, which
-            # would replay the whole (device-executing) retry ladder on
-            # EVERY later execution — cache the successfully scaled plan
-            # in its place so the next run starts where this one ended
-            key = (self._ash_state["sql"], tuple(params or []),
-                   self.catalog.schema_version)
-            if key in self.plan_cache:
-                self._plan_cache_put(key, (p, outputs, _est))
         exec_elapsed = time.monotonic() - t0
-        path = ("dtl" if self._last_dtl
-                else "px" if self._last_px else "serial")
-        if monitor is not None and mon_collect:
-            # roofline prediction vs the measured device half of this
-            # statement (server/calibrate.py): the TIME q-error beside
-            # the cardinality one, aggregated per root-operator type
-            # into gv$time_calibration for the CBO arc
-            times, pred_s, time_q = self._roofline(plan)
-            self.db.plan_monitor.record(
-                plan.fingerprint()[:64] if hasattr(plan, "fingerprint")
-                else "", monitor, exec_elapsed,
-                logical_hash=lhash, retries=attempt, path=path,
-                host_s=times.host_s, device_s=times.device_s,
-                pred_s=pred_s, time_q=time_q)
-            if getattr(self.db, "plan_choice", None) is not None:
-                # validate the CHOICE, not just the plan: measured
-                # device seconds against the bind-time prediction
-                self.db.plan_choice.observe(lhash, times.device_s)
-            if feedback_on and monitor and path == "serial":
-                # teach the feedback store from the serial ledger only:
-                # PX/DTL rows are positioned against rewritten plans, so
-                # their postorder would not line up with future binds
-                self.db.plan_feedback.observe(lhash, monitor)
-        if self.db is not None and \
-                getattr(self.db, "plan_history", None) is not None and \
-                attempt == 0 and not compile_flag():
-            # plan-regression watchdog: latency baselines per logical
-            # hash, independent of the plan-monitor knob (a regression
-            # must be visible even when per-op collection is off).
-            # Samples that paid an XLA compile or a CapacityOverflow
-            # retry replay are excluded — they measure one-time plan
-            # work, not the plan's steady-state latency, and would
-            # inflate the frozen baseline (blinding the watchdog) or
-            # spike the EWMA into a false regressed flag
-            if self.db.plan_history.record(
-                    lhash, exec_elapsed,
-                    float(self.db.config["plan_regress_threshold"])):
-                qmetrics.inc("plan.regressions")
-        return self._materialize(rel, outputs)
+        with qtrace.span("plan.record"):
+            if factor > 1 and use_cache:
+                # evolve the cached plan: a plan bound against a smaller
+                # table keeps overflowing its stale capacity budgets,
+                # which would replay the whole (device-executing) retry
+                # ladder on EVERY later execution — cache the
+                # successfully scaled plan in its place so the next run
+                # starts where this one ended
+                key = (self._ash_state["sql"], tuple(params or []),
+                       self.catalog.schema_version)
+                if key in self.plan_cache:
+                    self._plan_cache_put(key, (p, outputs, _est))
+            path = ("dtl" if self._last_dtl
+                    else "px" if self._last_px else "serial")
+            if monitor is not None and mon_collect:
+                # roofline prediction vs the measured device half of
+                # this statement (server/calibrate.py): the TIME q-error
+                # beside the cardinality one, aggregated per
+                # root-operator type into gv$time_calibration for the
+                # CBO arc
+                times, pred_s, time_q = self._roofline(plan)
+                self.db.plan_monitor.record(
+                    plan.fingerprint()[:64]
+                    if hasattr(plan, "fingerprint")
+                    else "", monitor, exec_elapsed,
+                    logical_hash=lhash, retries=attempt, path=path,
+                    host_s=times.host_s, device_s=times.device_s,
+                    pred_s=pred_s, time_q=time_q)
+                if getattr(self.db, "plan_choice", None) is not None:
+                    # validate the CHOICE, not just the plan: measured
+                    # device seconds against the bind-time prediction
+                    self.db.plan_choice.observe(lhash, times.device_s)
+                if feedback_on and monitor and path == "serial":
+                    # teach the feedback store from the serial ledger
+                    # only: PX/DTL rows are positioned against rewritten
+                    # plans, so their postorder would not line up with
+                    # future binds
+                    self.db.plan_feedback.observe(lhash, monitor)
+            if self.db is not None and \
+                    getattr(self.db, "plan_history", None) is not None:
+                base = self.db.plan_history.baseline_s(lhash)
+                if base > 0.0 and \
+                        exec_elapsed > base * qtrace.SLOW_FACTOR:
+                    # far over its own baseline (a stall, not a slow
+                    # plan): the tree goes to the slow ring, which fast
+                    # statements cannot evict
+                    tctx = qtrace.current()
+                    if tctx is not None:
+                        tctx.slow = True
+                if attempt == 0 and not compile_flag() and \
+                        self.db.plan_history.record(
+                            lhash, exec_elapsed,
+                            float(self.db.config[
+                                "plan_regress_threshold"])):
+                    # plan-regression watchdog: latency baselines per
+                    # logical hash, independent of the plan-monitor knob
+                    # (a regression must be visible even when per-op
+                    # collection is off).  Samples that paid an XLA
+                    # compile or a CapacityOverflow retry replay are
+                    # excluded — they measure one-time plan work, not
+                    # the plan's steady-state latency, and would inflate
+                    # the frozen baseline (blinding the watchdog) or
+                    # spike the EWMA into a false regressed flag
+                    qmetrics.inc("plan.regressions")
+        with qtrace.span("materialize"):
+            return self._materialize(rel, outputs)
 
     # -- ANN top-k access path (vector index) ---------------------------
     _ANN_FETCH_FACTOR = 4
@@ -1787,7 +1823,8 @@ class Session:
             from oceanbase_tpu.exec.plan import q_error as _qe
 
             est = getattr(plan, "est_rows", None)
-            act = int(rel.count())
+            with qtrace.span("plan.monitor"):
+                act = int(rel.count())
             monitor.append({"op": f"PxExecute(dop={dop})",
                             "pos": len(monitor), "est": est,
                             "rows": act, "q_error": _qe(est, act),

@@ -29,8 +29,17 @@ import numpy as np
 
 from oceanbase_tpu.catalog import Catalog, ColumnDef, TableDef
 from oceanbase_tpu.datatypes import SqlType, TypeKind
+from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.storage.segment import Segment
 from oceanbase_tpu.storage.tablet import Tablet
+
+qmetrics.declare("storage.device_copy_builds", "counter",
+                 "device relations built from the store (cache misses "
+                 "of StorageCatalog.table_data)")
+qmetrics.declare("storage.device_copy_ns", "counter",
+                 "time spent building device relations: snapshot "
+                 "decode + host->device copy + bucket padding", unit="ns")
 
 log = logging.getLogger("oceanbase_tpu.storage.engine")
 
@@ -1368,8 +1377,6 @@ class StorageCatalog(Catalog):
         return rel.pad_to(bucket_capacity(rel.capacity, floor, growth))
 
     def table_data(self, name):
-        from oceanbase_tpu.vector import from_numpy
-
         if name in self._externals:
             return self._external_data(name)
         with self._lock:
@@ -1384,17 +1391,7 @@ class StorageCatalog(Catalog):
             if hit is not None and hit[0] == ver:
                 return hit[1]
             snap = self.snapshot_fn()
-            arrays, valids = ts.tablet.snapshot_arrays(snap)
-            n = len(next(iter(arrays.values()))) if arrays else 0
-            if n == 0:
-                # static shapes need capacity >= 1: one all-dead row
-                rel = self._empty_rel(ts)
-            else:
-                rel = self._bucketed(from_numpy(
-                    arrays,
-                    types={c.name: c.dtype for c in ts.tdef.columns},
-                    valids={k: v for k, v in valids.items() if v is not None},
-                ))
+            rel, n = self._device_copy(ts, snap, 0)
             # only cache snapshots that cover every persisted segment —
             # a snapshot below a segment's max_version would pin a
             # partial view that later (larger) snapshots must not reuse.
@@ -1418,8 +1415,6 @@ class StorageCatalog(Catalog):
     def table_data_at(self, name, snapshot: int, tx_id: int = 0):
         """Snapshot read at an explicit version (+ own-tx writes) — the
         read path active transactions use."""
-        from oceanbase_tpu.vector import from_numpy
-
         if name in self._externals:
             return self._external_data(name)
         with self._lock:
@@ -1438,17 +1433,39 @@ class StorageCatalog(Catalog):
             rel = self.table_data(name)
             if snapshot >= ts.tablet.max_commit_version():
                 return rel
-        arrays, valids = ts.tablet.snapshot_arrays(snapshot, tx_id)
-        n = len(next(iter(arrays.values()))) if arrays else 0
-        if n == 0:
-            return self._empty_rel(ts)
         # snapshot reads pad to the SAME bucket ladder: a transaction
         # re-reading a table it is growing keeps hitting one compiled
         # shape per bucket instead of one per statement
-        return self._bucketed(from_numpy(
-            arrays, types={c.name: c.dtype for c in ts.tdef.columns},
-            valids={k: v for k, v in valids.items() if v is not None},
-        ))
+        return self._device_copy(ts, snapshot, tx_id)[0]
+
+    def _device_copy(self, ts, snapshot: int, tx_id: int):
+        """Build a table's device relation from the store: decode the
+        snapshot on the host, copy it to the device, pad it to its
+        capacity bucket.  -> (relation, live rows).  The cache miss a
+        statement pays after a commit or a reopen; timed as the span
+        ``storage.device_copy`` (the statement's device_copy_s) and the
+        counters ``storage.device_copy_builds`` / ``_ns``."""
+        from oceanbase_tpu.share.kvcache import relation_bytes
+        from oceanbase_tpu.vector import from_numpy
+
+        with qtrace.span("storage.device_copy",
+                         table=ts.tdef.name) as sp:
+            arrays, valids = ts.tablet.snapshot_arrays(snapshot, tx_id)
+            n = len(next(iter(arrays.values()))) if arrays else 0
+            if n == 0:
+                # static shapes need capacity >= 1: one all-dead row
+                rel = self._empty_rel(ts)
+            else:
+                rel = self._bucketed(from_numpy(
+                    arrays,
+                    types={c.name: c.dtype for c in ts.tdef.columns},
+                    valids={k: v for k, v in valids.items()
+                            if v is not None},
+                ))
+            sp.tags.update(rows=n, bytes=relation_bytes(rel))
+        qmetrics.inc("storage.device_copy_builds")
+        qmetrics.inc("storage.device_copy_ns", int(sp.elapsed_s * 1e9))
+        return rel, n
 
     def _empty_rel(self, ts):
         import jax.numpy as jnp

@@ -27,6 +27,7 @@ from oceanbase_tpu.bench.oracle import (  # noqa: E402
 from oceanbase_tpu.bench.tpch import (  # noqa: E402
     TPCH_PRIMARY_KEYS, gen_tpch)
 from oceanbase_tpu.bench.tpch_queries import QUERIES  # noqa: E402
+from oceanbase_tpu.exec.plan import exec_times  # noqa: E402
 from oceanbase_tpu.server import metrics as qmetrics  # noqa: E402
 from oceanbase_tpu.sql import Session  # noqa: E402
 
@@ -77,11 +78,6 @@ def main():
         t0 = time.monotonic()
         want = run_oracle(conn, sql)
         oracle_s = time.monotonic() - t0
-        # per-query device attribution: the XLA cost_analysis counters
-        # (exec/plan.py) delta'd across the query — measured flops and
-        # bytes-accessed the cost-based-optimizer arc prices against
-        f0 = qmetrics.counter_value("plan.flops_executed")
-        b0 = qmetrics.counter_value("plan.bytes_executed")
         t0 = time.monotonic()
         try:
             got = sess.execute(sql).rows()
@@ -93,8 +89,10 @@ def main():
             ok, why = False, f"{type(e).__name__}: {e}"
             got = []
         n_ok += bool(ok)
-        flops = qmetrics.counter_value("plan.flops_executed") - f0
-        nbytes = qmetrics.counter_value("plan.bytes_executed") - b0
+        # per-query device attribution: the XLA cost_analysis totals of
+        # the programs this statement ran (its ExecTimes accumulator)
+        times = exec_times()
+        flops, nbytes = times.flops, times.bytes
         results[f"q{qnum}"] = {
             "ok": bool(ok), "rows": len(got), "oracle_rows": len(want),
             "engine_s": round(engine_s, 3), "oracle_s": round(oracle_s, 3),

@@ -255,7 +255,6 @@ def test_plan_metrics_counters_flow(db):
     s.execute("select count(*) from t")
     assert qmetrics.counter_value("plan.compiles") >= 1
     assert qmetrics.counter_value("plan.executions") >= 1
-    assert qmetrics.counter_value("plan.flops_executed") > 0
     assert qmetrics.counter_value("sql.statements", tenant="sys") >= 3
 
 

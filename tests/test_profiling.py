@@ -288,8 +288,15 @@ def test_profile_statement_and_device_profile_rows(db):
         f" gv$device_profile where trace_id = '{tid}'").rows()
     assert len(dp) >= 1, "PROFILE must yield >=1 gv$device_profile row"
     for _k, kind, occ, total in dp:
-        assert kind in ("kernel", "runtime")
+        assert kind in ("kernel", "host")
         assert occ >= 1 and total >= 0.0
+    # kernels as the capture names them, and the profiled statement's
+    # own phases (the program's ob: spans opened inside the capture) on
+    # the same timeline
+    assert any(kind == "kernel" for _k, kind, _o, _t in dp)
+    hosts = {k for k, kind, _o, _t in dp if kind == "host"}
+    assert {"ob:plan.dispatch", "ob:plan.device_wait",
+            "ob:materialize"} <= hosts
     # SHOW PROFILE renders the same capture
     sp = s.execute("show profile").rows()
     assert len(sp) >= 1

@@ -69,11 +69,24 @@ def test_serial_span_tree_shape(db):
     assert by_name["compile"][2] == root_id
     assert by_name["execute"][2] == root_id
     assert by_name["plan.execute"][2] == by_name["execute"][1]
-    # plan-monitor operator breakdown rides under plan.execute
-    ops = [r for r in spans if r[4].startswith("op.")]
-    assert ops and all(r[2] == by_name["plan.execute"][1] for r in ops)
-    # first execution of this fingerprint traced XLA
-    assert "xla.compile" in names
+    # every host phase of the statement is a child of the root ...
+    for phase in ("parse", "admission", "virtuals", "plan.prepare",
+                  "plan.record", "materialize"):
+        assert by_name[phase][2] == root_id, phase
+    assert by_name["tables"][2] == by_name["execute"][1]
+    # ... and plan.execute's own phases ride under it (the zero-duration
+    # op.<Name> rows are gone: gv$sql_plan_monitor has them)
+    pe = by_name["plan.execute"][1]
+    for phase in ("plan.dispatch", "plan.device_wait", "plan.monitor"):
+        assert by_name[phase][2] == pe, phase
+    assert not [n for n in names if n.startswith("op.")]
+    assert json.loads(by_name["plan.execute"][6])["plan_hash"]
+    # first execution of this fingerprint traced XLA, inside the dispatch
+    assert by_name["xla.compile"][2] == by_name["plan.dispatch"][1]
+    assert json.loads(by_name["xla.compile"][6])["bytes_accessed"] > 0
+    # the first read of t built its device copy, inside `tables`
+    assert by_name["storage.device_copy"][2] == by_name["tables"][1]
+    assert json.loads(by_name["storage.device_copy"][6])["rows"] == 100
 
 
 def test_show_trace_renders_last_statement(db):
@@ -222,7 +235,12 @@ def test_ring_recent_slices_tail():
 # ---------------------------------------------------------------------------
 
 
-def test_tracing_never_changes_results_poisoned(poison):
+@pytest.mark.parametrize("mode", ["context", "no_context", "capture"])
+def test_tracing_never_changes_results_poisoned(poison, mode, tmp_path):
+    """The spans (records in a context; annotations + phase booking with
+    none; events of a running profiler capture) never change a result."""
+    import jax
+
     from oceanbase_tpu.catalog import Catalog
     from oceanbase_tpu.exec.plan import execute_plan, referenced_tables
     from oceanbase_tpu.server import trace as qtrace
@@ -245,11 +263,21 @@ def test_tracing_never_changes_results_poisoned(poison):
                 for t, rel in tables.items()}
     clean = to_numpy(execute_plan(plan, tables))
     ctx = qtrace.TraceCtx("poisontest", node=0)
-    with qtrace.activate(ctx):
-        traced = to_numpy(execute_plan(plan, poisoned))
+    if mode == "capture":
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        with qtrace.activate(ctx if mode != "no_context" else None):
+            traced = to_numpy(execute_plan(plan, poisoned))
+    finally:
+        if mode == "capture":
+            jax.profiler.stop_trace()
     ok, why = poison.results_identical(clean, traced)
     assert ok, f"tracing + poisoned pad lanes changed results: {why}"
-    assert ctx.spans, "no spans collected under the activated context"
+    if mode == "no_context":
+        assert not ctx.spans
+    else:
+        assert {"plan.execute", "plan.dispatch", "plan.device_wait"} <= \
+            {sp.name for sp in ctx.spans}
 
 
 # ---------------------------------------------------------------------------
