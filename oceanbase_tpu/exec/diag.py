@@ -24,14 +24,19 @@ _collector: contextvars.ContextVar[list | None] = contextvars.ContextVar(
 
 
 @contextlib.contextmanager
-def collect():
-    """Activate a collector; yields the list that traced entries land in."""
-    entries: list[tuple[str, object]] = []
-    tok = _collector.set(entries)
+def _collecting(var: contextvars.ContextVar):
+    """Activate ``var`` with a fresh list; yields the list."""
+    entries: list = []
+    tok = var.set(entries)
     try:
         yield entries
     finally:
-        _collector.reset(tok)
+        var.reset(tok)
+
+
+def collect():
+    """Activate a collector; yields the list that traced entries land in."""
+    return _collecting(_collector)
 
 
 def push(name: str, scalar, capacity: int | None = None) -> None:
@@ -71,14 +76,8 @@ _monitor: contextvars.ContextVar[list | None] = contextvars.ContextVar(
 )
 
 
-@contextlib.contextmanager
 def monitor_collect():
-    entries: list[tuple[str, object]] = []
-    tok = _monitor.set(entries)
-    try:
-        yield entries
-    finally:
-        _monitor.reset(tok)
+    return _collecting(_monitor)
 
 
 def monitor_push(op_name: str, count_scalar, est: int | None = None) -> None:
@@ -88,3 +87,27 @@ def monitor_push(op_name: str, count_scalar, est: int | None = None) -> None:
     entries = _monitor.get()
     if entries is not None:
         entries.append((op_name, est, count_scalar))
+
+
+# ---------------------------------------------------------------------------
+# join-probe lane: which way each probe of the program ranks its keys
+# (ops._probe_ranges picks from static shapes, so the kinds are a fact of
+# the traced program, known when lowering ends; nothing is traced).
+# ---------------------------------------------------------------------------
+
+_probes: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "ob_tpu_probes", default=None
+)
+
+
+def probe_collect():
+    """Activate the lane; yields the list of kinds, in program order."""
+    return _collecting(_probes)
+
+
+def note_probe(kind: str) -> None:
+    """Record one probe's kind, ``merge`` or ``search`` (no-op outside a
+    collector)."""
+    kinds = _probes.get()
+    if kinds is not None:
+        kinds.append(kind)

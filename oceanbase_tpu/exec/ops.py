@@ -12,8 +12,11 @@ Design notes (tpu-first re-imaginations of the reference components):
   sort network but fully fused, static-shaped, MXU/VPU friendly.
 - ``join``           ≙ ObHashJoinVecOp build/probe
   (src/sql/engine/join/hash_join/ob_hash_join_vec_op.h:342).  Implemented as
-  sort + searchsorted (binary search is the TPU's "probe"): build side is
-  sorted by key; probe rows binary-search their candidate range; expansion
+  sort + rank: build side is sorted by key; every probe row gets the range
+  of equal build keys (``_probe_ranges``: one sort of build and probe keys
+  together and streaming scans, since random gathers are what the TPU does
+  worst; a binary search only where the probe is too small to pay for a
+  sort's compile); expansion
   to a static output capacity via jnp.repeat(total_repeat_length=...);
   multi-column keys go through a 64-bit mix with exact-key verification
   (false positives masked, ≙ the reference's normalized-key fast path in
@@ -122,17 +125,22 @@ def compact(rel: Relation, capacity: int | None = None,
 # ---------------------------------------------------------------------------
 
 
-def _lexsort(keys: Sequence[jax.Array]) -> jax.Array:
-    """``jnp.lexsort``'s permutation (last key primary), from an UNSTABLE
-    sort that takes the row number as its last key.  Ties break exactly
-    as the stable sort breaks them, so the answer is the same, and the
-    TPU compiler takes about half as long over it: a stable lexsort of
-    three int64 keys at 1M rows compiled for a v5e in 448 s, this form
-    in 243 s; a one-key argsort in 106 s against 43 s (PR 22)."""
+def _sort_with_rows(keys: Sequence[jax.Array]) -> tuple[jax.Array, ...]:
+    """The keys sorted (last key primary, returned first) and, last, the
+    int32 row numbers in that order: an UNSTABLE sort that takes the row
+    number as its last key.  Ties break exactly as the stable sort
+    breaks them, so the answer is the same, and the TPU compiler takes
+    about half as long over it: a stable lexsort of three int64 keys at
+    1M rows compiled for a v5e in 448 s, this form in 243 s; a one-key
+    argsort in 106 s against 43 s (PR 22)."""
     iota = lax.iota(jnp.int32, keys[0].shape[0])
-    order = lax.sort((*reversed(tuple(keys)), iota),
-                     num_keys=len(keys) + 1, is_stable=False)[-1]
-    return order.astype(jnp.int64)
+    return lax.sort((*reversed(tuple(keys)), iota),
+                    num_keys=len(keys) + 1, is_stable=False)
+
+
+def _lexsort(keys: Sequence[jax.Array]) -> jax.Array:
+    """``jnp.lexsort``'s permutation (last key primary)."""
+    return _sort_with_rows(keys)[-1].astype(jnp.int64)
 
 
 def _sort_key_arrays(rel: Relation, keys: Sequence[ir.Expr],
@@ -635,6 +643,70 @@ def _keys_valid(cols: Sequence[Column], mask):
     return v
 
 
+# A probe merges when ``ln * ceil(log2(rn))`` (the gathers one binary
+# search makes) exceeds this, and searches below it.  On a TPU v5e (PR 25,
+# int64 keys, rn = 262,144) the merge RUNS faster at every size tried:
+# 1.9 ms against the one search's 5.7 at 16,384 lanes, 2.3 / 39.7 at
+# 131,072, 6.7 / 484 at 1,048,576, 64.9 / 4,107 at 8,388,608 (the two
+# searches it replaces: 7,586).  What it costs is the compiler: its two
+# sorts take 24-47 s a probe, cold, where the search takes 5-9 s.  At this
+# constant (233,000 lanes into 262,144 keys) the search is 0.07-0.11 s of
+# an execution (17-27 ns a gather): under it a program keeps its compile
+# time, over it the probe would be most of the statement.
+_MERGE_PROBE_MIN_GATHERS = 1 << 22
+
+
+def _probe_ranges(build_sorted: jax.Array, probe_keys: jax.Array,
+                  _path: str | None = None):
+    """For every probe key the range ``[lo, hi)`` of equal keys in the
+    sorted build side: exactly ``jnp.searchsorted(build_sorted,
+    probe_keys, side="left")`` and ``side="right"``, lane for lane.
+
+    Two ways to compute the one answer, chosen from the static shapes
+    (``_path`` is for the tests that hold both to the contract):
+
+    - ``merge``: sort build and probe keys together by (key, position),
+      build rows first, so a build row precedes every probe row of equal
+      key; ``hi`` is then the running count of build rows and ``lo`` that
+      count at the start of the run of equal keys; one more sort by
+      position brings both back to probe order (21.5 ms at 8.4M lanes
+      where a permutation scatter takes 107; PR 25).  Sequential access
+      only.
+    - ``search``: one binary search for ``lo`` (``log2(rn)`` dependent
+      gathers a lane, cheap to compile), and ``hi`` read off the end of
+      the build side's run of equal keys where the key is there.
+    """
+    rn, ln = build_sorted.shape[0], probe_keys.shape[0]
+    if _path is None:
+        work = ln * max(rn - 1, 1).bit_length()
+        _path = ("merge" if work > _MERGE_PROBE_MIN_GATHERS
+                 and rn + ln < 2 ** 31 else "search")
+    diag.note_probe(_path)
+    if _path == "search":
+        lo = jnp.searchsorted(build_sorted, probe_keys, side="left")
+        idx = lax.iota(lo.dtype, rn)
+        last = jnp.concatenate([build_sorted[1:] != build_sorted[:-1],
+                                jnp.ones(1, jnp.bool_)])
+        # a forward scan over the flipped rows: ``reverse=True`` costs the
+        # TPU compiler 49 s at 262,144 rows, this form 6 (compiled for a
+        # described v5e in the sandbox, PR 25)
+        run_end = jnp.flip(lax.cummin(jnp.flip(
+            jnp.where(last, idx + 1, rn))))
+        at = jnp.minimum(lo, rn - 1)
+        found = (lo < rn) & (jnp.take(build_sorted, at) == probe_keys)
+        return lo, jnp.where(found, jnp.take(run_end, at), lo)
+    keys, pos = _sort_with_rows(
+        (jnp.concatenate([build_sorted, probe_keys]),))
+    is_build = (pos < rn).astype(jnp.int32)
+    hi_all = jnp.cumsum(is_build)
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), keys[1:] != keys[:-1]])
+    # the count before a run of equal keys never decreases along the
+    # sorted rows, so a running maximum carries it through the run
+    lo_all = lax.cummax(jnp.where(first, hi_all - is_build, 0))
+    _, lo, hi = lax.sort((pos, lo_all, hi_all), num_keys=1, is_stable=False)
+    return lo[rn:], hi[rn:]
+
+
 def join(
     left: Relation,
     right: Relation,
@@ -685,8 +757,7 @@ def join(
 
     lkey_p = jnp.where(lvalid, lkey, BIG - 1)
     with jax.named_scope("join.probe"):
-        lo = jnp.searchsorted(rkey_sorted, lkey_p, side="left")
-        hi = jnp.searchsorted(rkey_sorted, lkey_p, side="right")
+        lo, hi = _probe_ranges(rkey_sorted, lkey_p)
     # lo/hi ∈ [0, rn] so counts <= rn always — no clamp needed
     counts = jnp.where(lvalid, hi - lo, 0)
 
@@ -803,7 +874,7 @@ def index_probe(
     rename: dict[str, str] | None,
     out_capacity: int | None = None,
 ) -> Relation:
-    """Index nested-loop join: searchsorted probe of ``key`` into a
+    """Index nested-loop join: ``_probe_ranges`` of ``key`` into a
     PRE-SORTED index sidecar, then a positional gather of the base
     table's rows — the build-side argsort a hash join pays every
     execution is amortized into the (cached, host-built) sidecar.
@@ -830,8 +901,7 @@ def index_probe(
     # sentinel must sort strictly below them to report zero matches
     lkey_p = jnp.where(lvalid, lkey, BIG - 1)
     with jax.named_scope("join.probe"):
-        lo = jnp.searchsorted(skey, lkey_p, side="left")
-        hi = jnp.searchsorted(skey, lkey_p, side="right")
+        lo, hi = _probe_ranges(skey, lkey_p)
     counts = jnp.where(lvalid, hi - lo, 0)
 
     cap = out_capacity if out_capacity is not None else max(ln, sn)

@@ -33,6 +33,7 @@ host; the session's retry loop re-plans with bigger budgets.
 from __future__ import annotations
 
 import functools
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +54,7 @@ from oceanbase_tpu.px.exchange import (
     shard_relation_by_hash,
     unshard_relation,
 )
+from oceanbase_tpu.server import metrics as qmetrics
 from oceanbase_tpu.vector.column import Relation
 
 BROADCAST_THRESHOLD_BYTES = 4 << 20  # build sides smaller than this replicate
@@ -580,9 +582,12 @@ def _px_compiled(plan_key, holder, mesh, axis, ndev, factor, table_names):
     partial_specs = holder.partial_specs
     elide = holder.elide
     dist_sort = holder.dist_sort
+    # probes of the shard program by kind, as its last trace left them
+    # (exec/plan.py's executable keeps the same per signature)
+    probes: Counter = Counter()
 
     def shard_body(shtables):
-        with diag.collect() as entries:
+        with diag.collect() as entries, diag.probe_collect() as kinds:
             rel = _dlower(droot, shtables, ndev, axis, factor, elide)
             if getattr(rel, "_px_replicated", False):
                 # a replicated ROOT would gather ndev duplicate copies
@@ -607,13 +612,15 @@ def _px_compiled(plan_key, holder, mesh, axis, ndev, factor, table_names):
             total_ovf = jnp.zeros((), dtype=jnp.int64)
             for _name, v, _cap in entries:
                 total_ovf = total_ovf + jnp.asarray(v, dtype=jnp.int64)
+        probes.clear()
+        probes.update(kinds)
         return rel, jax.lax.psum(total_ovf, axis)
 
     return jax.jit(jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=({t: P(axis) for t in table_names},),
         out_specs=(P(axis), P()), check_vma=False,
-    ))
+    )), probes
 
 
 def execute_plan_distributed(plan: pp.PlanNode, tables: dict,
@@ -682,7 +689,7 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
         aff_key = tuple(sorted((t, tuple(c)) for t, c in affinity.items()))
         cache_key = (plan.fingerprint(), aff_key)
         misses0 = _px_compiled.cache_info().misses
-        run = _px_compiled(
+        run, probes = _px_compiled(
             cache_key,
             _Holder(droot, partial_specs, elide, dist_sort, cache_key),
             mesh, axis, ndev, budget_factor, tuple(sorted(needed)))
@@ -726,6 +733,8 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
         n_over = int(overflow)  # obcheck: ok(trace.host-sync)
     # the legacy aggregate and the launch count, as execute_plan books
     add_exec_times(host_s=psp.self_s, calls=1)
+    for kind, n in probes.items():
+        qmetrics.inc("plan.join_probes", n, kind=kind)
     if n_over > 0:
         raise diag.CapacityOverflow(
             f"PX exchange overflow: {n_over} rows dropped")

@@ -391,3 +391,57 @@ def test_repo_metric_family_clean():
     findings = run_all(files, (check_metric_rules,))
     new = diff_findings(findings, load_baseline())
     assert not new, "\n".join(f.render() for f in new)
+
+
+# ---------------------------------------------------------------------------
+# plan.join_probes: the executable's static probe counts, added per execution
+# ---------------------------------------------------------------------------
+
+
+def _probe_counts():
+    return {kind: qmetrics.counter_value("plan.join_probes", kind=kind)
+            for kind in ("merge", "search")}
+
+
+@pytest.mark.parametrize("path", ["serial", "px"])
+def test_join_probes_rise_by_the_executables_counts(path, monkeypatch):
+    from oceanbase_tpu.exec import ops
+    from oceanbase_tpu.exec.plan import _compiled
+    from oceanbase_tpu.px.planner import _px_compiled
+    from oceanbase_tpu.sql import Session
+
+    r = np.random.default_rng(5)
+    s = Session()
+    s.catalog.load_numpy("jp_a", {"ak": np.arange(400),
+                                  "aj": r.integers(0, 50, 400)},
+                         primary_key=["ak"])
+    s.catalog.load_numpy("jp_b", {"bk": np.arange(300),
+                                  "bj": r.integers(0, 50, 300)},
+                         primary_key=["bk"])
+    s.catalog.load_numpy("jp_c", {"ck": np.arange(50),
+                                  "cv": r.integers(0, 9, 50)},
+                         primary_key=["ck"])
+    if path == "px":
+        s.variables["px_dop"] = 4
+    sql = ("select count(*), sum(cv) from jp_a join jp_b on aj = bj "
+           "join jp_c on bj = ck")
+    want = None
+    # the same statement compiled on each side of the shape rule: two
+    # joins, so two probes of one kind per execution
+    for floor, kind, other in ((0, "merge", "search"),
+                               (ops._MERGE_PROBE_MIN_GATHERS, "search",
+                                "merge")):
+        monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", floor)
+        _compiled.cache_clear()
+        _px_compiled.cache_clear()
+        for _ in range(3):
+            before = _probe_counts()
+            rows = s.execute(sql).rows()
+            assert bool(s._last_px) == (path == "px")
+            after = _probe_counts()
+            assert after[kind] - before[kind] == 2, (kind, before, after)
+            assert after[other] == before[other], (kind, before, after)
+            want = want or rows
+            assert rows == want
+    _compiled.cache_clear()
+    _px_compiled.cache_clear()

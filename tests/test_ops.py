@@ -192,3 +192,174 @@ def test_join_string_keys_different_dicts():
     res = to_numpy(out)
     pairs = sorted(zip(res["name"].tolist(), res["rv"].tolist()))
     assert pairs == [("de", 10), ("us", 20)]
+
+
+# ---------------------------------------------------------------------------
+# _probe_ranges: one contract (searchsorted left and right, lane for lane),
+# two ways to compute it
+# ---------------------------------------------------------------------------
+
+_BIG = np.iinfo(np.int64).max  # ops._INT_MAX: a dead build row
+_LOW = np.iinfo(np.int64).min
+
+
+def _probe_case(name):
+    """-> (sorted build keys, probe keys), both int64."""
+    r = np.random.default_rng(11)
+    if name == "duplicates":
+        live = np.sort(r.integers(0, 40, 300))
+        probe = r.integers(0, 40, 1000)
+    elif name == "absent_keys":
+        live = np.sort(r.choice(np.arange(0, 2000, 2), 257, replace=False))
+        probe = r.integers(-50, 2100, 777)  # odd keys and both ends miss
+    elif name == "empty_live_build":
+        live = np.zeros(0, np.int64)
+        probe = r.integers(-5, 5, 100)
+    elif name == "negative_and_sentinel_neighbours":
+        live = np.sort(np.array(
+            [_LOW, _LOW, _LOW + 1, -7, -7, -1, 0, 1, _BIG - 3, _BIG - 2,
+             _BIG - 2, _BIG - 1], np.int64))
+        probe = np.array(
+            [_LOW, _LOW + 1, _LOW + 2, -8, -7, -6, -1, 0, 1, 2, _BIG - 4,
+             _BIG - 3, _BIG - 2, _BIG - 1, _BIG], np.int64)
+    elif name == "odd_sizes":
+        live = np.sort(r.integers(-1000, 1000, 37))
+        probe = r.integers(-1000, 1000, 11)
+    elif name == "one_build_row":
+        live = np.array([5], np.int64)
+        probe = np.array([4, 5, 6, 5], np.int64)
+    else:
+        raise ValueError(name)
+    live, probe = live.astype(np.int64), probe.astype(np.int64)
+    if name != "one_build_row":
+        # dead build rows sort last, dead and NULL-key probe lanes just
+        # below them (join's and index_probe's sentinels)
+        live = np.concatenate([live, np.full(13, _BIG)])
+        probe[r.random(len(probe)) < 0.15] = _BIG - 1
+    return live, probe
+
+
+@pytest.mark.parametrize("path", ["merge", "search"])
+@pytest.mark.parametrize("case", [
+    "duplicates", "absent_keys", "empty_live_build",
+    "negative_and_sentinel_neighbours", "odd_sizes", "one_build_row"])
+def test_probe_ranges_equal_searchsorted(case, path):
+    import jax
+    import jax.numpy as jnp
+
+    from oceanbase_tpu.exec import ops
+
+    build, probe = map(jnp.asarray, _probe_case(case))
+    got = jax.jit(lambda b, p: ops._probe_ranges(b, p, _path=path))(
+        build, probe)
+    for side, have in zip(("left", "right"), got):
+        want = jnp.searchsorted(build, probe, side=side)
+        assert have.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(have), np.asarray(want),
+                                      err_msg=side)
+
+
+def test_probe_path_follows_static_shapes():
+    """The helper picks from shapes alone, and notes the kind it picked."""
+    import jax
+    import jax.numpy as jnp
+
+    from oceanbase_tpu.exec import diag, ops
+
+    rn = 1 << 10
+    above = ops._MERGE_PROBE_MIN_GATHERS // 10 + 1
+    for ln, want in ((above, "merge"), (above - 2, "search")):
+        with diag.probe_collect() as kinds:
+            jax.eval_shape(ops._probe_ranges,
+                           jax.ShapeDtypeStruct((rn,), jnp.int64),
+                           jax.ShapeDtypeStruct((ln,), jnp.int64))
+        assert kinds == [want], (ln, kinds)
+
+
+def _outer_join_relations(masked):
+    """The shapes of tests/test_outer_joins.py (aj in 0..80, bj in
+    40..120: both sides have unmatched rows and duplicates), with NULL
+    keys and, when ``masked``, dead lanes on both sides."""
+    from oceanbase_tpu.vector.column import Relation
+
+    r = np.random.default_rng(3)
+    na, nb = 300, 200
+    left = from_numpy({"ak": np.arange(na), "aj": r.integers(0, 80, na),
+                       "a2": r.integers(0, 3, na),
+                       "av": r.integers(0, 1000, na)},
+                      valids={"aj": r.random(na) > 0.05})
+    right = from_numpy({"bk": np.arange(nb), "bj": r.integers(40, 120, nb),
+                        "b2": r.integers(0, 3, nb),
+                        "bv": r.integers(0, 1000, nb)},
+                       valids={"bj": r.random(nb) > 0.05})
+    if masked:
+        import jax.numpy as jnp
+
+        left = Relation(left.columns, jnp.asarray(
+            np.arange(left.capacity) % 7 != 0) & left.mask_or_true())
+        right = Relation(right.columns, jnp.asarray(
+            np.arange(right.capacity) % 5 != 0) & right.mask_or_true())
+    return left, right
+
+
+def _relation_arrays(rel):
+    out = {"__mask__": np.asarray(rel.mask_or_true())}
+    for name, c in rel.columns.items():
+        out[name] = np.asarray(c.data)
+        out[name + ".valid"] = np.asarray(c.valid_or_true())
+    return out
+
+
+@pytest.mark.parametrize("keys", ["exact", "hash_combined"])
+@pytest.mark.parametrize("how", ["inner", "left", "semi", "anti", "full"])
+def test_join_same_relation_above_and_below_threshold(how, keys,
+                                                      monkeypatch):
+    """A join whose probe merges and one whose probe searches give the
+    same relation, array for array (row order included)."""
+    from oceanbase_tpu.exec import diag, ops
+
+    left, right = _outer_join_relations(masked=True)
+    if keys == "exact":
+        lk, rk = [ir.col("aj")], [ir.col("bj")]
+    else:
+        lk, rk = ([ir.col("aj"), ir.col("a2")],
+                  [ir.col("bj"), ir.col("b2")])
+    got = {}
+    for kind, floor in (("search", ops._MERGE_PROBE_MIN_GATHERS),
+                        ("merge", 0)):
+        monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", floor)
+        with diag.probe_collect() as kinds:
+            rel = join(left, right, lk, rk, how=how, out_capacity=4096)
+        assert kinds == [kind]
+        got[kind] = _relation_arrays(rel)
+    assert sorted(got["merge"]) == sorted(got["search"])
+    for name, want in got["search"].items():
+        np.testing.assert_array_equal(got["merge"][name], want,
+                                      err_msg=name)
+    assert got["merge"]["__mask__"].sum() > 0
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "full", "semi", "anti"])
+def test_merge_probe_poison_parity(how, poison, monkeypatch):
+    """The merge path is a data-reading operator's inside: garbage in
+    masked-dead lanes of either side must not reach the result."""
+    from oceanbase_tpu.exec import ops
+
+    monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", 0)
+    left, right = _outer_join_relations(masked=True)
+
+    def live_values(rel):
+        # what a client can see: a NULL's payload is not part of the
+        # answer (a NULL-extended lane carries some build row's payload,
+        # on either probe path)
+        res = to_numpy(rel)
+        for name in [n for n in res if not n.startswith("__valid__")]:
+            v = res.get("__valid__" + name)
+            if v is not None:
+                res[name] = np.where(v, res[name], 0)
+        return res
+
+    poison.assert_poison_invariant(
+        lambda t: join(t["a"], t["b"], [ir.col("aj")], [ir.col("bj")],
+                       how=how, out_capacity=4096),
+        {"a": left, "b": right}, materialize=live_values)

@@ -119,6 +119,20 @@ def test_plan_program_compiles_for_v5e(qnum, tpch_session, one_chip,
         _fits(run.lower(shapes).compile())
 
 
+@pytest.mark.parametrize("path", ["merge", "search"])
+def test_probe_ranges_compile_for_v5e(path, one_chip, no_persistent_cache):
+    """Both ways ``ops._probe_ranges`` ranks probe keys, accepted by the
+    chip's compiler (an SF0.01 plan is all below the shape rule, so the
+    plan programs above never hold the merge).  The sorts of the merge
+    cost the compiler the same at any size (ROADMAP S0): a small one."""
+    from oceanbase_tpu.exec import ops
+
+    shapes = [jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
+              for n in (4096, 65536)]
+    _fits(jax.jit(lambda b, p: ops._probe_ranges(b, p, _path=path))
+          .lower(*shapes).compile())
+
+
 def test_px_groupby_exchange_compiles_for_four_chips(topo,
                                                      no_persistent_cache):
     """One PX program on the 2x2 mesh: partial agg -> all_to_all by key
