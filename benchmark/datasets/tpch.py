@@ -10,7 +10,8 @@ int64 (cents), dates are int32 days since 1970-01-01.
 A dataset module gives the harness ``generate(scale, seed) -> (tables,
 types)`` and ``PRIMARY_KEYS``; ``types`` maps a column to ``("decimal",
 precision, scale)`` or ``("date",)``, every other column is what its
-NumPy dtype says.
+NumPy dtype says.  A write statement names one of its ``rows`` functions
+(``harness/writes.py``): here the two refresh functions, at the end.
 """
 
 from __future__ import annotations
@@ -110,6 +111,12 @@ _END = D("1998-08-02")
 _CURRENT = D("1995-06-17")
 
 
+def _retail_price(partkey: np.ndarray) -> np.ndarray:
+    """p_retailprice in cents: a function of the part's key alone."""
+    return (90000 + ((partkey // 10) % 20001)
+            + 100 * (partkey % 1000)).astype(np.int64)
+
+
 def generate(scale: float, seed: int):
     """All 8 tables -> (tables, types): table -> {column -> array}, and
     column -> type tuple for decimals and dates."""
@@ -178,8 +185,7 @@ def generate(scale: float, seed: int):
         np.char.add(rng.choice(np.array(CONTAINER_S1), n_part).astype("U8"), " "),
         rng.choice(np.array(CONTAINER_S2), n_part).astype("U8"),
     ).astype(object)
-    p_retail = (90000 + ((np.arange(1, n_part + 1) // 10) % 20001)
-                + 100 * (np.arange(1, n_part + 1) % 1000)).astype(np.int64)
+    p_retail = _retail_price(np.arange(1, n_part + 1))
     tables["part"] = {
         "p_partkey": np.arange(1, n_part + 1, dtype=np.int64),
         "p_name": p_name,
@@ -330,3 +336,120 @@ PRIMARY_KEYS = {
     "orders": ["o_orderkey"],
     "lineitem": ["l_orderkey", "l_linenumber"],
 }
+
+
+# ---------------------------------------------------------------------------
+# refresh functions (TPC-H rev 3, clauses 2.5-2.7 as remembered: the
+# configuration that uses them lists what could not be confirmed)
+# ---------------------------------------------------------------------------
+
+REFRESH_ORDERS_PER_SF = 1500
+
+
+def refresh(scale: float, seed: int, k: int):
+    """The k-th refresh set, a pure function of its arguments ->
+    (new orders, their lineitems, old order keys).
+
+    RF1's set is ``SF x 1500`` new orders with 1-7 lineitems each, value
+    distributions as in ``generate``, keys above the loaded population
+    (``generate``'s order keys are dense: 1..n).  RF2's set is the k-th
+    ``SF x 1500`` order keys of the loaded population in ascending order.
+    Sets of different k share no key."""
+    n_ord = int(1_500_000 * scale)
+    n_part = int(200_000 * scale)
+    n_supp = max(int(10_000 * scale), 10)
+    n_cust = int(150_000 * scale)
+    n = max(int(REFRESH_ORDERS_PER_SF * scale), 1)
+    if k < 0 or (k + 1) * n > n_ord:
+        raise ValueError(f"refresh set {k}: the loaded population has "
+                         f"{n_ord // n} sets of {n} orders")
+    old_keys = np.arange(k * n + 1, (k + 1) * n + 1, dtype=np.int64)
+
+    rng = np.random.default_rng(
+        [int(seed) & 0xFFFFFFFF, int(seed) >> 32, int(k), 0x5246])
+    o_orderkey = n_ord + old_keys
+    o_custkey = rng.integers(1, max(n_cust, 2), n, dtype=np.int64)
+    o_custkey = np.where(o_custkey % 3 == 0, np.maximum(o_custkey - 1, 1),
+                         o_custkey)
+    o_orderdate = rng.integers(_START, _END - 151, n, dtype=np.int64)
+    comments = _comment_pool(rng, 64)
+    n_lines = rng.integers(1, 8, n)
+    n_li = int(n_lines.sum())
+    row_of = np.repeat(np.arange(n), n_lines)       # lineitem -> its order
+    l_odate = o_orderdate[row_of]
+    l_partkey = rng.integers(1, max(n_part, 2), n_li, dtype=np.int64)
+    j = rng.integers(0, 4, n_li)
+    l_quantity = rng.integers(1, 51, n_li, dtype=np.int64) * 100
+    l_extendedprice = (l_quantity // 100) * _retail_price(l_partkey)
+    l_discount = rng.integers(0, 11, n_li, dtype=np.int64)
+    l_tax = rng.integers(0, 9, n_li, dtype=np.int64)
+    l_shipdate = l_odate + rng.integers(1, 122, n_li)
+    l_receiptdate = l_shipdate + rng.integers(1, 31, n_li)
+    l_linestatus = np.where(l_shipdate > _CURRENT, "O", "F").astype(object)
+    lineitem = {
+        "l_orderkey": o_orderkey[row_of],
+        "l_partkey": l_partkey,
+        "l_suppkey": ((l_partkey + j * ((n_supp // 4) + 1)) % n_supp
+                      + 1).astype(np.int64),
+        "l_linenumber": (np.arange(n_li) - np.repeat(
+            np.cumsum(n_lines) - n_lines, n_lines) + 1).astype(np.int64),
+        "l_quantity": l_quantity,
+        "l_extendedprice": l_extendedprice,
+        "l_discount": l_discount,
+        "l_tax": l_tax,
+        "l_returnflag": np.where(
+            l_receiptdate <= _CURRENT,
+            np.where(rng.integers(0, 2, n_li) == 0, "R", "A"),
+            "N").astype(object),
+        "l_linestatus": l_linestatus,
+        "l_shipdate": l_shipdate.astype(np.int32),
+        "l_commitdate": (l_odate + rng.integers(30, 91, n_li)).astype(
+            np.int32),
+        "l_receiptdate": l_receiptdate.astype(np.int32),
+        "l_shipinstruct": rng.choice(np.array(SHIPINSTRUCT),
+                                     n_li).astype(object),
+        "l_shipmode": rng.choice(np.array(SHIPMODES), n_li).astype(object),
+        "l_comment": comments[rng.integers(0, len(comments), n_li)],
+    }
+    charged = (l_extendedprice * (100 - l_discount) // 100
+               * (100 + l_tax) // 100)
+    is_f = l_linestatus == "F"
+    n_f = np.bincount(row_of, weights=is_f, minlength=n).astype(np.int64)
+    orders = {
+        "o_orderkey": o_orderkey,
+        "o_custkey": o_custkey,
+        "o_orderstatus": np.where(
+            n_f == n_lines, "F", np.where(n_f > 0, "P", "O")).astype(object),
+        "o_totalprice": np.bincount(
+            row_of, weights=charged, minlength=n).astype(np.int64),
+        "o_orderdate": o_orderdate.astype(np.int32),
+        "o_orderpriority": rng.choice(np.array(PRIORITIES),
+                                      n).astype(object),
+        "o_clerk": np.array(
+            [f"Clerk#{i:09d}" for i in
+             rng.integers(1, max(n_ord // 1000, 2), n)], dtype=object),
+        "o_shippriority": np.zeros(n, dtype=np.int64),
+        "o_comment": comments[rng.integers(0, len(comments), n)],
+    }
+    return orders, lineitem, old_keys
+
+
+def refresh_new_sales(scale: float, seed: int, k: int, batch: int):
+    """RF1's k-th set, ``batch`` orders a transaction; an order and its
+    lineitems are always in one transaction."""
+    orders, lineitem, _ = refresh(scale, seed, k)
+    keys = orders["o_orderkey"]
+    out = []
+    for lo in range(0, len(keys), batch):
+        mine = np.isin(lineitem["l_orderkey"], keys[lo:lo + batch])
+        out.append({
+            "orders": {c: v[lo:lo + batch] for c, v in orders.items()},
+            "lineitem": {c: v[mine] for c, v in lineitem.items()}})
+    return out
+
+
+def refresh_old_sales(scale: float, seed: int, k: int, batch: int):
+    """RF2's k-th set of order keys, ``batch`` a transaction."""
+    _, _, keys = refresh(scale, seed, k)
+    return [{"orders": {"o_orderkey": keys[lo:lo + batch]}}
+            for lo in range(0, len(keys), batch)]

@@ -12,9 +12,12 @@ RTOL = 1e-6
 
 
 def rows_match(got: list[tuple], want: list[tuple], ordered: bool,
-               rtol: float = RTOL) -> tuple[bool, str]:
+               rtol: float = RTOL) -> tuple[bool, str, float]:
+    """-> (equal, why not, the widest relative gap between two floats that
+    were compared)."""
+    gap = 0.0
     if len(got) != len(want):
-        return False, f"row count {len(got)} != {len(want)}"
+        return False, f"row count {len(got)} != {len(want)}", gap
 
     def key(row):
         return tuple((x is None, round(x, 6) if isinstance(x, float) else x)
@@ -24,17 +27,18 @@ def rows_match(got: list[tuple], want: list[tuple], ordered: bool,
     w = want if ordered else sorted(want, key=key)
     for i, (gr, wr) in enumerate(zip(g, w)):
         if len(gr) != len(wr):
-            return False, f"row {i} arity mismatch"
+            return False, f"row {i} arity mismatch", gap
         for j, (a, b) in enumerate(zip(gr, wr)):
             if a is None or b is None:
                 if a is not b:
-                    return False, f"row {i} col {j}: {a!r} != {b!r}"
+                    return False, f"row {i} col {j}: {a!r} != {b!r}", gap
                 continue
             if isinstance(a, float) or isinstance(b, float):
                 fa, fb = float(a), float(b)
-                if abs(fa - fb) > rtol * max(1.0, abs(fa), abs(fb)):
-                    return False, f"row {i} col {j}: {fa} != {fb}"
+                gap = max(gap, abs(fa - fb) / max(1.0, abs(fa), abs(fb)))
+                if gap > rtol:
+                    return False, f"row {i} col {j}: {fa} != {fb}", gap
                 continue
             if a != b:
-                return False, f"row {i} col {j}: {a!r} != {b!r}"
-    return True, ""
+                return False, f"row {i} col {j}: {a!r} != {b!r}", gap
+    return True, "", gap

@@ -1,5 +1,6 @@
 """One run of one cell: set-up, warm-up, the measured window, the traced
-captures, the comparison with the reference, and the last line.
+captures, the read-back, the comparison with the reference, and the last
+line; all of it under the run's own time limit (``budget.py``).
 
 The phases print one JSON line each as they end; the LAST line of the
 standard output is the result object the driver reads.  Everything here
@@ -18,11 +19,13 @@ import sys
 import time
 import traceback
 
+from . import budget as budgetmod
 from . import spec as specmod
 from . import stats
 from . import traffic as trafficmod
-from .compare import rows_match
-from .reference import ReferenceChild
+from . import writes as writesmod
+from .compare import RTOL, rows_match
+from .reference import ReferenceChild, read_back_columns
 
 #: a template that still compiles after this many warm-up runs fails the run
 MAX_WARMUP_RUNS = 4
@@ -65,6 +68,13 @@ class Run:
         self.round = trafficmod.schedule(
             self.cell.traffic, self.cell.statements, args.seed)
         self.templates = list(dict.fromkeys(it.template for it in self.round))
+        #: template -> its distinct (template, parameter set)s, in order
+        self.items = {t: list(dict.fromkeys(
+            it for it in self.round if it.template == t))
+            for t in self.templates}
+        self.replays = self.cell.writes()  # the reference replays the log
+        self.next_set = dict.fromkeys(self.templates, 0)
+        self.read_back: dict[str, dict] = {}
         self.phases: dict[str, float] = {}
         self.notes: list[str] = []
         self.log: list[dict] = []       # every workload statement, in order
@@ -72,8 +82,32 @@ class Run:
         self.system = None
         self.adapter = None
         self.reference = None
+        self.watch = None
+        self.budget = budgetmod.Budget(
+            t_start, os.path.join(
+                specmod.SCRATCH_DIR, "started", self.cell.name
+                + (".rehearsal" if self.rehearsal else "")),
+            on_expire=self.stop_reference, detail=self.so_far)
 
     # -- plumbing -----------------------------------------------------------
+    def enter(self, name: str) -> float:
+        """The phase in flight, for the line of a run that ends itself."""
+        self.budget.phase = name
+        return time.monotonic()
+
+    def stop_reference(self):
+        if self.reference is not None:
+            self.reference.stop()
+
+    def so_far(self) -> dict:
+        """What a run that ends itself adds to its line: the phases that
+        ended, and JAX's own compile seconds of the whole process (the
+        ``warmup.<template>`` lines hold them per template)."""
+        if self.watch is None:
+            return {"phases": self.phases}
+        return {"phases": self.phases, "compile_s": self.watch.seconds(),
+                "compile_cache": self.watch.cache_events()}
+
     def phase(self, name: str, t0: float, **more):
         self.phases[name] = time.monotonic() - t0
         emit({"phase": name, "seconds": self.phases[name], **more})
@@ -90,9 +124,44 @@ class Run:
 
         return span(kind, label)
 
+    def _write(self, item, phase: str, spans: bool) -> dict:
+        """Send the template's next set, transaction by transaction; the
+        latency is the client's, from each ``begin`` to its ``commit``'s
+        return (rendering the rows as SQL text is not the system's time).
+        A transaction that raises is rolled back and ends the set."""
+        k = self.next_set[item.template]
+        self.next_set[item.template] = k + 1
+        rec = {"phase": phase, "template": item.template,
+               "key": writesmod.set_key(item.template, k), "sql": None,
+               "k": k, "acks": [], "tx_latency_s": [], "error": None}
+        for sqls in writesmod.transactions(
+                self.cell.statements[item.template], self.dataset,
+                self.types, self.scale, self.args.seed, k):
+            t0 = time.perf_counter()
+            try:
+                with self._span(spans, "execute", item.template):
+                    for sql in ["begin"] + sqls + ["commit"]:
+                        self.system.execute(sql)
+            except Exception as e:  # noqa: BLE001 — counted as a failed set
+                rec["error"] = f"{type(e).__name__}: {e}"[:400]
+                try:
+                    self.system.execute("rollback")
+                except Exception:  # noqa: BLE001 — nothing was open
+                    pass
+            rec["tx_latency_s"].append(time.perf_counter() - t0)
+            rec["acks"].append(rec["error"] is None)
+            if rec["error"]:
+                break
+        rec["latency_s"] = sum(rec["tx_latency_s"])
+        self.log.append(rec)
+        self.answers.append(None)
+        return rec
+
     def _statement(self, item, phase: str, spans: bool = False) -> dict:
         """Execute one workload statement and fetch its rows; the latency is
         the client's (``perf_counter`` around both)."""
+        if item.sql is None:
+            return self._write(item, phase, spans)
         rec = {"phase": phase, "template": item.template, "key": item.key,
                "sql": item.sql, "error": None}
         ans = None
@@ -111,24 +180,31 @@ class Run:
 
     # -- set-up -------------------------------------------------------------
     def start_reference(self):
-        items, seen = [], set()
-        for it in self.round:
-            if it.key in seen:
-                continue
-            seen.add(it.key)
-            ref = self.cell.statements[it.template]["reference"]
-            items.append({"key": it.key, "sql": it.sql, "params": it.params,
-                          "sqlite": bool(ref.get("sqlite")),
-                          "exact": ref.get("exact")})
-        self.reference = ReferenceChild({
-            "bench_dir": self.cell.bench_dir,
-            "dataset": self.cell.config["dataset"]["generator"],
-            "scale": self.scale, "seed": self.args.seed,
-            "reads": self.cell.reads(), "items": items})
+        self.enter("reference")
+        items, written = [], {}
+        for its in self.items.values():
+            for it in its:
+                st = self.cell.statements[it.template]
+                if it.sql is None:
+                    written[it.template] = st
+                    continue
+                ref = st["reference"]
+                items.append({"key": it.key, "sql": it.sql,
+                              "params": it.params,
+                              "sqlite": bool(ref.get("sqlite")),
+                              "exact": ref.get("exact")})
+        job = {"bench_dir": self.cell.bench_dir,
+               "dataset": self.cell.config["dataset"]["generator"],
+               "scale": self.scale, "seed": self.args.seed,
+               "reads": self.cell.reads(), "items": items}
+        if self.replays:
+            job["writes"] = written
+            job["read_back"] = self.cell.read_back()
+        self.reference = ReferenceChild(job)
         self.reference.start()
 
     def check_device(self) -> dict:
-        t0 = time.monotonic()
+        t0 = self.enter("device")
         from . import adapter  # the first touch of JAX, after the child
 
         from .compile_watch import CompileWatch
@@ -150,20 +226,25 @@ class Run:
 
     def boot_and_load(self):
         cfg = self.cell.config
-        t0 = time.monotonic()
+        t0 = self.enter("boot")
         self.system = self.adapter.System(os.path.join(
             specmod.SCRATCH_DIR, "db", self.cell.name))
         self.system.apply(cfg.get("system_settings", []))
         self.phase("boot", t0, cost_constants=self.system.cost_constants())
 
-        t_load = time.monotonic()
-        dataset = specmod.load_module("datasets", cfg["dataset"]["generator"])
-        tables, types = dataset.generate(self.scale, self.args.seed)
+        t_load = self.enter("generate")
+        self.dataset = dataset = specmod.load_module(
+            "datasets", cfg["dataset"]["generator"])
+        tables, self.types = dataset.generate(self.scale, self.args.seed)
         self.phase("generate", t_load)
+        self.enter("load")
+        self.read_back_columns = {
+            t: read_back_columns(dataset, tables[t], self.types, t)
+            for t in self.cell.read_back()}
         rows, table_load_s, analyze_s = {}, {}, {}
         for name in self.cell.tables():
             t0 = time.monotonic()
-            self.system.load_table(name, tables[name], types,
+            self.system.load_table(name, tables[name], self.types,
                                    dataset.PRIMARY_KEYS[name])
             table_load_s[name] = time.monotonic() - t0
             rows[name] = len(next(iter(tables[name].values())))
@@ -191,21 +272,33 @@ class Run:
         last_ts = max((r["ts"] for r in self.system.monitored_plans()),
                       default=0.0)
         for template in self.templates:
+            self.enter("warmup." + template)
             runs = 0
-            for item in (it for it in self.round if it.template == template):
-                for _attempt in range(MAX_WARMUP_RUNS):
+            mark = (self.watch.seconds(), self.watch.cache_events())
+            for item in self.items[template]:
+                # a write takes a new set at each execution: two at most,
+                # and one that still compiles is said, not refused (its
+                # compiles then show in the window's own count)
+                tries = MAX_WARMUP_RUNS if item.sql is not None else 2
+                for _attempt in range(tries):
                     before = self.watch.count()
                     rec = self._statement(item, "warmup")
                     runs += 1
                     if rec["error"]:
                         raise RuntimeError(
-                            f"warm-up of {item.key}: {rec['error']}")
+                            f"warm-up of {rec['key']}: {rec['error']}")
                     rec["compile_events"] = self.watch.count() - before
                     if rec["compile_events"] == 0:
                         break
                 else:
-                    raise RuntimeError(f"{item.key} still compiles after "
-                                       f"{MAX_WARMUP_RUNS} runs")
+                    if item.sql is not None:
+                        raise RuntimeError(f"{item.key} still compiles "
+                                           f"after {tries} runs")
+                    self.note(f"{template} still compiles after {tries} "
+                              f"sets ({rec['compile_events']} events)")
+            since = [{k: v - was[k] for k, v in now.items()}
+                     for was, now in zip(mark, (self.watch.seconds(),
+                                                self.watch.cache_events()))]
             new = sorted(h for h in self.system.plan_cache() if h not in seen)
             seen |= set(new)
             monitored = [r for r in self.system.monitored_plans()
@@ -221,7 +314,10 @@ class Run:
                                            for r in monitored})),
                 "paths": sorted({r["path"] for r in monitored})}
             emit({"phase": "warmup." + template, "runs": runs,
-                  "plan_fingerprint": self.fingerprints[template]})
+                  "plan_fingerprint": self.fingerprints[template],
+                  # JAX's own seconds: backend compiles (a persistent-cache
+                  # hit's retrieval is inside them), tracing, lowering
+                  "compile_s": since[0], "compile_cache": since[1]})
         self.phase("warmup", t_all, compile_cache=self.watch.cache_events())
 
     # -- the window ------------------------------------------------------------
@@ -232,6 +328,8 @@ class Run:
         events0 = self.watch.count()
         n, k = len(self.round), 0
         seconds = self.args.seconds
+        self.enter("window")
+        self.budget.need(seconds)   # a window that cannot fit is not spent
         self.setup_seconds = time.monotonic() - self.t_start
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
@@ -254,10 +352,10 @@ class Run:
         captures = []
         n_exec = int(self.cell.traffic.get("trace_executions", 1))
         for template in self.templates:
-            items = [it for it in self.round if it.template == template]
+            items = self.items[template]
             directory = os.path.join(specmod.SCRATCH_DIR, "trace",
                                      self.cell.name, template)
-            t0 = time.monotonic()
+            t0 = self.enter("trace." + template)
             with tracing.capture(directory):
                 for k in range(n_exec):
                     self._statement(items[k % len(items)], "trace", spans=True)
@@ -286,14 +384,36 @@ class Run:
         return captures
 
     # -- after the window -----------------------------------------------------------
+    def read_back_tables(self):
+        """A configuration with ``guarantees.read_back`` (and a mix that
+        writes): after the last statement, each table's ``count(*)``, the
+        sum of its key and the sum of one decimal, through the served
+        path; ``compare`` holds them to the replayed reference."""
+        if not self.read_back_columns:
+            return
+        t0 = self.enter("read_back")
+        for table, (key, dec) in self.read_back_columns.items():
+            rec = {"table": table, "got": None, "error": None}
+            try:
+                ans = self.system.fetch(self.system.execute(
+                    f"select count(*) as n, sum({key}) as k, sum({dec}) as d "
+                    f"from {table}"))
+                rec["got"] = [int(ans.arrays[c][0]) for c in ("n", "k", "d")]
+            except Exception as e:  # noqa: BLE001 — counted as a failure
+                rec["error"] = f"{type(e).__name__}: {e}"[:400]
+            self.read_back[table] = rec
+        self.phase("read_back", t0, tables={
+            t: r["got"] or r["error"] for t, r in self.read_back.items()})
+
     def attach_audit(self):
         """Each logged statement gets its ``gv$sql_audit`` row, aligned from
         the newest backwards (the ring may have dropped the oldest)."""
-        t0 = time.monotonic()
-        mine = {rec["sql"][:200] for rec in self.log}
+        t0 = self.enter("audit")
+        log = [rec for rec in self.log if rec["sql"] is not None]
+        mine = {rec["sql"][:200] for rec in log}
         rows = [r for r in self.system.audit_rows() if r["sql"] in mine]
-        k = min(len(rows), len(self.log))
-        pairs = list(zip(self.log[len(self.log) - k:], rows[len(rows) - k:]))
+        k = min(len(rows), len(log))
+        pairs = list(zip(log[len(log) - k:], rows[len(rows) - k:]))
         if any(rec["sql"][:200] != row["sql"] for rec, row in pairs):
             self.note("gv$sql_audit does not line up with the statements "
                       "sent: the audit-based metrics are left out")
@@ -302,45 +422,90 @@ class Run:
             rec["audit"] = {c: v for c, v in row.items() if c != "sql"}
         self.phase("audit", t0, rows=len(rows), attached=len(pairs))
 
+    def replay_log(self) -> list[dict]:
+        """What the reference replays: every write of the run, and the
+        reads that are compared, in the order they were sent."""
+        return [
+            {"at": at, "template": rec["template"], "k": rec["k"],
+             "acks": rec["acks"]} if rec["sql"] is None
+            else {"at": at, "key": rec["key"]}
+            for at, rec in enumerate(self.log)
+            if rec["sql"] is None or rec["phase"] != "warmup"]
+
     def compare(self) -> tuple[int, int]:
         """-> (attempted, failed) over the window's and the captures'
-        statements; each record gets ``correct``."""
-        t0 = time.monotonic()
-        ref = self.reference.join(
-            deadline_s=self.t_start + self.cell.config.get(
-                "reference_deadline_s", 900.0))
+        statements and the read-back; each record gets ``correct``."""
+        t0 = self.enter("compare")
+        deadline_s = self.t_start + self.cell.config.get(
+            "reference_deadline_s", 900.0)
+        ref = self.reference.replay(self.replay_log(), deadline_s) \
+            if self.replays else self.reference.join(deadline_s)
         want_path = self.cell.config.get("required_path")
         exact_mods = {}
         attempted = failed = 0
         examples = []
-        for rec, ans in zip(self.log, self.answers):
+        self.checks = {"raised": 0, "sqlite_differ": 0, "sqlite_rel_gap": 0.0,
+                       "exact_differ": 0, "off_path": 0,
+                       "read_back_differ": 0}
+
+        def fail(check: str, what: str, why: str):
+            nonlocal failed
+            failed += 1
+            self.checks[check] += 1
+            if len(examples) < 5:
+                examples.append(f"{what}: {why}"[:300])
+
+        for at, (rec, ans) in enumerate(zip(self.log, self.answers)):
             if rec["phase"] == "warmup":
                 continue
             attempted += 1
             st = self.cell.statements[rec["template"]]
-            why = rec["error"]
-            if why is None and st["reference"].get("sqlite"):
-                ok, why = rows_match(ans.rows, ref["sqlite"][rec["key"]],
-                                     ordered=bool(st["ordered"]))
-                why = None if ok else "vs sqlite: " + why
-            name = st["reference"].get("exact")
-            if why is None and name:
-                if name not in exact_mods:
-                    exact_mods[name] = specmod.load_module("references", name)
-                got = exact_mods[name].extract(ans.names, ans.arrays)
-                if got != ref["exact"][rec["key"]]:
-                    why = f"vs exact: {got!r} != {ref['exact'][rec['key']]!r}"
-            if why is None and want_path and rec["phase"] == "window" \
-                    and rec.get("path") != want_path:
-                why = f"path {rec.get('path')!r}, not {want_path!r}"
+            where = at if self.replays else rec["key"]
+            check, why = "raised", rec["error"]
+            if rec["sql"] is not None:
+                if why is None and st["reference"].get("sqlite"):
+                    ok, why, gap = rows_match(
+                        ans.rows, ref["sqlite"][where],
+                        ordered=bool(st["ordered"]))
+                    self.checks["sqlite_rel_gap"] = max(
+                        self.checks["sqlite_rel_gap"], gap)
+                    check, why = "sqlite_differ", \
+                        None if ok else "vs sqlite: " + why
+                name = st["reference"].get("exact")
+                if why is None and name:
+                    if name not in exact_mods:
+                        exact_mods[name] = specmod.load_module(
+                            "references", name)
+                    got = exact_mods[name].extract(ans.names, ans.arrays)
+                    if got != ref["exact"][where]:
+                        check = "exact_differ"
+                        why = f"vs exact: {got!r} != {ref['exact'][where]!r}"
+                if why is None and want_path and rec["phase"] == "window" \
+                        and rec.get("path") != want_path:
+                    check = "off_path"
+                    why = f"path {rec.get('path')!r}, not {want_path!r}"
             rec["correct"] = why is None
             if why is not None:
-                failed += 1
-                if len(examples) < 5:
-                    examples.append(f"{rec['key']}: {why}"[:300])
+                fail(check, rec["key"], why)
+        for table, rec in self.read_back.items():
+            attempted += 1
+            want = ref["read_back"][table]
+            rec["want"] = want
+            if rec["error"]:
+                fail("raised", "read-back of " + table, rec["error"])
+            elif rec["got"] != want:
+                fail("read_back_differ", "read-back of " + table,
+                     f"count, key sum, decimal sum {rec['got']} != {want}")
         self.phase("compare", t0, attempted=attempted, failed=failed,
                    examples=examples, reference_seconds=ref.get("seconds"))
         return attempted, failed
+
+    def compared(self) -> dict:
+        """Each number compared beside its limit, for the result's line
+        and the standard error's last lines."""
+        return {name: {"value": value,
+                       "limit": RTOL if name == "sqlite_rel_gap" else 0}
+                for name, value in self.checks.items()}
 
     # -- the record the metric readers get ---------------------------------------------
     def record(self, device: dict, captures) -> dict:
@@ -361,7 +526,7 @@ class Run:
             "counters_after": self.counters_after,
             "compile_events_in_window": self.compile_events_in_window,
             "fingerprints": self.fingerprints,
-            "layouts": self.layouts,
+            "layouts": self.layouts, "read_back": self.read_back,
             "captures": captures, "notes": self.notes,
         }
 
@@ -416,6 +581,7 @@ def main(argv, t_start: float) -> int:
         run.warm_up()
         run.window()
         captures = run.traced_captures() if args.trace else []
+        run.read_back_tables()
         run.attach_audit()
         attempted, failed = run.compare()
         device["memory_peak_bytes"] = run.adapter.memory_peak_bytes()
@@ -450,8 +616,7 @@ def main(argv, t_start: float) -> int:
         traceback.print_exc()
         return 1
     finally:
-        if run.reference is not None:
-            run.reference.stop()
+        run.stop_reference()
         if run.system is not None:
             try:
                 run.system.close()
@@ -466,7 +631,12 @@ def main(argv, t_start: float) -> int:
         bd = breakdown(captures)
         if bd:
             result["breakdown"] = bd
+    result["compared"] = run.compared()     # the last key, by contract
+    run.budget.close()      # from here on the run has its result
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
     if run.rehearsal and device["platform"] != "tpu":
         return EXIT_REHEARSED if failed == 0 and attempted > 0 else 1
     return 0

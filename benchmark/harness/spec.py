@@ -93,24 +93,49 @@ class Cell:
             raise SpecError("order must be round_robin")
         if int(self.config["chips"]) != self.chips:
             raise SpecError("the cell and its configuration disagree on chips")
+        rules = {t["statement"]: t.get("params", "validation")
+                 for t in self.traffic["templates"]}
         for s, st in self.statements.items():
-            for key in ("sql", "parameters", "reads", "ordered", "reference"):
+            write = "writes" in st
+            for key in (("writes", "transaction", "rows", "batch") if write
+                        else ("sql", "parameters", "reads", "ordered",
+                              "reference")):
                 if key not in st:
                     raise SpecError(f"statements/{s}.json lacks {key!r}")
+            if write != (rules[s] == "sequence"):
+                raise SpecError(f"{s}: a write statement takes the rule "
+                                '"sequence", and no other statement does')
+        if not set(self.read_back()) <= set(self.tables()):
+            raise SpecError("read_back names a table that no statement of "
+                            "the cell names")
 
     # -- what the cell needs --------------------------------------------
     def tables(self) -> list[str]:
         """The tables the cell's statements name, in first-use order."""
         out = []
         for st in self.statements.values():
-            out += [t for t in st["reads"] if t not in out]
+            for t in list(st.get("reads", ())) + list(st.get("writes", ())):
+                if t not in out:
+                    out.append(t)
         return out
+
+    def writes(self) -> bool:
+        """Whether some statement of the cell writes: the reference then
+        replays the run's log instead of answering beside the load."""
+        return any("writes" in st for st in self.statements.values())
+
+    def read_back(self) -> list[str]:
+        """The tables a mix that writes is read back from at the end (the
+        configuration's ``guarantees.read_back``)."""
+        if not self.writes():
+            return []
+        return list(self.config.get("guarantees", {}).get("read_back", []))
 
     def reads(self) -> dict[str, list[str]]:
         """table -> columns some statement of the cell reads."""
         out: dict[str, list[str]] = {}
         for st in self.statements.values():
-            for t, cols in st["reads"].items():
+            for t, cols in st.get("reads", {}).items():
                 have = out.setdefault(t, [])
                 have += [c for c in cols if c not in have]
         return out

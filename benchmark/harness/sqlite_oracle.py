@@ -16,28 +16,41 @@ import numpy as np
 _EPOCH = np.datetime64("1970-01-01", "D")
 
 
+def _py_columns(cols: dict, types: dict) -> list[list]:
+    """The columns as SQLite takes them: decimals as doubles, dates as
+    ISO text."""
+    pycols = []
+    for c, arr in cols.items():
+        t = types.get(c)
+        if t is not None and t[0] == "decimal":
+            pycols.append([v / (10 ** t[2]) for v in arr.tolist()])
+        elif t is not None and t[0] == "date":
+            pycols.append((_EPOCH + np.asarray(arr).astype(
+                "timedelta64[D]")).astype(str).tolist())
+        elif arr.dtype == object or arr.dtype.kind in "US":
+            pycols.append([str(v) for v in arr])
+        else:
+            pycols.append(arr.tolist())
+    return pycols
+
+
+def insert_rows(conn: sqlite3.Connection, name: str, cols: dict,
+                types: dict):
+    ph = ",".join("?" * len(cols))
+    conn.executemany(f"insert into {name} ({', '.join(cols)}) values ({ph})",
+                     list(zip(*_py_columns(cols, types))))
+
+
+def delete_rows(conn: sqlite3.Connection, name: str, column: str, values):
+    conn.executemany(f"delete from {name} where {column} = ?",
+                     [(v,) for v in np.asarray(values).tolist()])
+
+
 def load_sqlite(tables: dict, types: dict) -> sqlite3.Connection:
     conn = sqlite3.connect(":memory:")
     for name, cols in tables.items():
-        colnames = list(cols)
-        decls = ", ".join(colnames)
-        conn.execute(f"create table {name} ({decls})")
-        pycols = []
-        for c in colnames:
-            arr = cols[c]
-            t = types.get(c)
-            if t is not None and t[0] == "decimal":
-                pycols.append([v / (10 ** t[2]) for v in arr.tolist()])
-            elif t is not None and t[0] == "date":
-                pycols.append((_EPOCH + np.asarray(arr).astype(
-                    "timedelta64[D]")).astype(str).tolist())
-            elif arr.dtype == object or arr.dtype.kind in "US":
-                pycols.append([str(v) for v in arr])
-            else:
-                pycols.append(arr.tolist())
-        rows = list(zip(*pycols))
-        ph = ",".join("?" * len(colnames))
-        conn.executemany(f"insert into {name} values ({ph})", rows)
+        conn.execute(f"create table {name} ({', '.join(cols)})")
+        insert_rows(conn, name, cols, types)
     # index every *key column (PKs and FKs) so correlated subqueries and
     # joins in the ORACLE don't go quadratic at SF>=0.1 — the oracle's
     # job is to be correct AND fast enough to produce SF1 evidence

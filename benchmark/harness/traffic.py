@@ -2,9 +2,13 @@
 
 A mix names statement templates and, for each, a parameter rule:
 ``"validation"`` (the one set the statement file gives as its validation
-values) or ``{"pool": N}`` (N distinct sets drawn from ``--seed`` inside
-the ranges of the statement file).  Statements are sent round-robin over
-(template, parameter set) by one client that waits for each answer.
+values), ``{"pool": N}`` (N distinct sets drawn from ``--seed`` inside
+the ranges of the statement file) or ``"sequence"`` (a write statement:
+every execution takes the next set of its ``rows`` function, and no set
+is used twice in a run).  Statements are sent round-robin over the
+templates by one client that waits for each answer; a template with
+``"every": N`` takes N statements in a row at each of its turns
+(default 1), cycling its own parameter sets.
 
 Parameter kinds a statement file may use:
 
@@ -19,6 +23,7 @@ and ``derived`` values: ``{"from": <parameter>, "add": <decimal>}``.
 
 from __future__ import annotations
 
+import math
 import re
 import zlib
 from decimal import Decimal
@@ -115,11 +120,13 @@ def render(statement: dict, params: dict) -> str:
 
 
 class Item:
-    """One (template, parameter set) of a run, with its SQL text."""
+    """One (template, parameter set) of a run, with its SQL text.  A
+    ``"sequence"`` template's item has no ``sql``: it is a slot, and
+    whoever executes it binds the next set number (``writes.set_key``)."""
 
     __slots__ = ("template", "params", "sql", "key")
 
-    def __init__(self, template: str, params: dict, sql: str):
+    def __init__(self, template: str, params: dict, sql: str | None):
         self.template = template
         self.params = params
         self.sql = sql
@@ -128,20 +135,30 @@ class Item:
 
 
 def schedule(traffic: dict, statements: dict, seed: int) -> list[Item]:
-    """One round of the mix: every (template, parameter set) once, in the
-    order the client cycles through them."""
+    """One round of the mix, in the order the client cycles through it: as
+    many turns as it takes every template to come back to its first
+    parameter set, each template sending ``every`` statements a turn."""
     per_template = []
     for t in traffic["templates"]:
         name = t["statement"]
         st = statements[name]
         rule = t.get("params", "validation")
-        if rule == "validation":
-            sets = [validation_params(st)]
-        elif isinstance(rule, dict) and "pool" in rule:
-            sets = draw_pool(name, st, int(rule["pool"]), seed)
+        if rule == "sequence":
+            items = [Item(name, {}, None)]
         else:
-            raise ValueError(f"unknown parameter rule {rule!r}")
-        per_template.append([Item(name, p, render(st, p)) for p in sets])
-    # round-robin over templates, each template cycling its own sets
-    n = max(len(sets) for sets in per_template)
-    return [sets[i % len(sets)] for i in range(n) for sets in per_template]
+            if rule == "validation":
+                sets = [validation_params(st)]
+            elif isinstance(rule, dict) and "pool" in rule:
+                sets = draw_pool(name, st, int(rule["pool"]), seed)
+            else:
+                raise ValueError(f"unknown parameter rule {rule!r}")
+            items = [Item(name, p, render(st, p)) for p in sets]
+        every = int(t.get("every", 1))
+        if every < 1:
+            raise ValueError(f"{name}: every must be 1 or more")
+        per_template.append((items, every))
+    turns = math.lcm(*(len(items) // math.gcd(len(items), every)
+                       for items, every in per_template))
+    return [items[(turn * every + j) % len(items)]
+            for turn in range(turns)
+            for items, every in per_template for j in range(every)]

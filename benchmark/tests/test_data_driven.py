@@ -159,7 +159,8 @@ def _data_names() -> set:
     # parameter names and range ends of the statement files
     for f in os.listdir(os.path.join(spec.BENCH_DIR, "statements")):
         st = spec.read_json(os.path.join(spec.BENCH_DIR, "statements", f))
-        names |= set(st["parameters"]) | set(st.get("derived", {}))
+        names |= set(st.get("parameters", {})) | set(st.get("derived", {}))
+        names |= set(st.get("writes", ()))  # the tables a statement writes
     return names
 
 
