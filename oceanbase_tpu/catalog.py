@@ -62,6 +62,14 @@ class TableDef:
     mcv: dict = field(default_factory=dict)
     # range partitioning: (column, [upper-exclusive split points]) or None
     partition: tuple | None = None
+    # hash / key partitioning: (method, [columns], partitions) or None.
+    # A row lives in partition ``storage.partition.hash_partition_of(its
+    # key values) ``; partition i's device copy lives on device i
+    hash_partition: tuple | None = None
+    # the tablegroup the table was created in (≙ __all_tablegroup): its
+    # tables share method, partition count and key types, so equal keys
+    # lie in equal partitions
+    tablegroup: str | None = None
     auto_increment_cols: list = field(default_factory=list)
     indexes: list = field(default_factory=list)  # list[IndexDef]
     # vector/fulltext indexes: name -> {"kind", "column", "metric"...}
@@ -81,6 +89,14 @@ class TableDef:
     @property
     def column_names(self) -> list[str]:
         return [c.name for c in self.columns]
+
+    @property
+    def partition_columns(self) -> list[str]:
+        """The columns a row's partition follows from (range, hash or
+        key); empty for an unpartitioned table."""
+        if self.partition is not None:
+            return [self.partition[0]]
+        return list(self.hash_partition[1]) if self.hash_partition else []
 
 
 def sampled_ndv(arr, n: int, sample: int = 8192) -> int:

@@ -111,3 +111,38 @@ def note_probe(kind: str) -> None:
     kinds = _probes.get()
     if kinds is not None:
         kinds.append(kind)
+
+
+# ---------------------------------------------------------------------------
+# PX lane: what the distributed lowering decided (a join's distribution
+# method, an exchange buffer's static capacity).  Facts of the traced
+# shard program like the probe kinds above; the executable keeps them and
+# every execution adds them to ``gv$sysstat``.
+# ---------------------------------------------------------------------------
+
+_px_notes: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "ob_tpu_px_notes", default=None
+)
+
+
+def px_collect():
+    """Activate the lane; yields the list of (what, value, n): a join by
+    its distribution method, an exchange buffer's lanes by its kind."""
+    return _collecting(_px_notes)
+
+
+def _note_px(what: str, value: str, n: int) -> None:
+    notes = _px_notes.get()
+    if notes is not None:
+        notes.append((what, value, n))
+
+
+def note_join(dist: str) -> None:
+    """One join of the program being lowered, by distribution method
+    (no-op outside a collector)."""
+    _note_px("join", dist, 1)
+
+
+def note_lanes(kind: str, lanes: int) -> None:
+    """One exchange buffer of ``lanes`` lanes a shard, by kind."""
+    _note_px("lanes", kind, lanes)

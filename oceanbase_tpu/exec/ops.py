@@ -42,6 +42,8 @@ from oceanbase_tpu.datatypes import SqlType, TypeKind
 from oceanbase_tpu.exec import diag
 from oceanbase_tpu.expr import ir
 from oceanbase_tpu.expr.compile import cast_column, eval_expr, eval_predicate
+from oceanbase_tpu.share import keyhash
+from oceanbase_tpu.share.keyhash import mix64 as _mix64
 from oceanbase_tpu.vector.column import Column, Relation, StringDict
 
 # ---------------------------------------------------------------------------
@@ -603,36 +605,18 @@ def scalar_agg(rel: Relation, aggs: Sequence[AggSpec]) -> Relation:
 # join
 # ---------------------------------------------------------------------------
 
-_M1 = np.uint64(0xBF58476D1CE4E5B9)
-_M2 = np.uint64(0x94D049BB133111EB)
-
-
-def _mix64(x):
-    x = x.astype(jnp.uint64)
-    x = (x ^ (x >> 30)) * _M1
-    x = (x ^ (x >> 27)) * _M2
-    return x ^ (x >> 31)
-
-
 def _combined_key(cols: Sequence[Column]):
     """Combine join key columns into one sortable int64.
 
     Single int-like key -> raw value (exact, no verification needed).
     Multi-key / string-pairs -> 64-bit mix; caller must verify candidates.
     """
-    if len(cols) == 1 and cols[0].dtype.kind in (
+    exact = len(cols) == 1 and cols[0].dtype.kind in (
         TypeKind.INT, TypeKind.DATE, TypeKind.DATETIME, TypeKind.DECIMAL,
         TypeKind.BOOL, TypeKind.STRING,
-    ):
-        return cols[0].data.astype(jnp.int64), True
-    h = jnp.zeros(cols[0].capacity, dtype=jnp.uint64)
-    for c in cols:
-        if jnp.issubdtype(c.data.dtype, jnp.floating):
-            k = c.data.astype(jnp.float64).view(jnp.int64)
-        else:
-            k = c.data.astype(jnp.int64)
-        h = _mix64(h ^ _mix64(k.astype(jnp.uint64)))
-    return h.astype(jnp.int64), False
+    )
+    return keyhash.combine([c.data for c in cols], jnp,
+                           raw_single=exact), exact
 
 
 def _keys_valid(cols: Sequence[Column], mask):

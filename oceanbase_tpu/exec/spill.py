@@ -18,17 +18,9 @@ from __future__ import annotations
 import numpy as np
 
 from oceanbase_tpu.exec import diag, ops
-from oceanbase_tpu.exec.ops import _M1, _M2  # one source for hash constants
 from oceanbase_tpu.expr import ir
+from oceanbase_tpu.share.keyhash import mix64 as _mix64_np  # the one mixer
 from oceanbase_tpu.vector import Relation, from_numpy, to_numpy
-
-
-def _mix64_np(x: np.ndarray) -> np.ndarray:
-    x = x.astype(np.uint64)
-    with np.errstate(over="ignore"):
-        x = (x ^ (x >> np.uint64(30))) * np.uint64(_M1)
-        x = (x ^ (x >> np.uint64(27))) * np.uint64(_M2)
-        return x ^ (x >> np.uint64(31))
 
 
 def _partition_of(arrays: dict, keys: list[str], n_parts: int) -> np.ndarray:

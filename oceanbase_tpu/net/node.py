@@ -37,6 +37,7 @@ from oceanbase_tpu.share.location import LocationCache
 from oceanbase_tpu.storage.integrity import CorruptionError, arrays_crc
 
 _DDL_KINDS = {"create_view", "drop_view",
+              "create_tablegroup", "drop_tablegroup",
               "create_table", "drop_table", "truncate", "alter_add",
               "alter_drop", "create_index", "drop_index", "aux_index",
               "drop_aux_index"}

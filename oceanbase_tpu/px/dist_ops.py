@@ -233,11 +233,9 @@ def dist_join_shard_hybrid(
     r_hot = classify(rk, rm)
 
     def hash_dest(k, m, is_hot):
-        from oceanbase_tpu.exec.ops import _mix64
+        from oceanbase_tpu.share.keyhash import dest_of
 
-        h = _mix64(k.astype(jnp.uint64))
-        d = (h % jnp.uint64(ndev)).astype(jnp.int32)
-        return jnp.where(m & ~is_hot, d, ndev)  # hot/dead -> drop
+        return jnp.where(m & ~is_hot, dest_of(k, ndev), ndev)  # hot/dead
 
     l_cap = (probe_cap_per_dest if probe_cap_per_dest is not None
              else cap_per_dest)

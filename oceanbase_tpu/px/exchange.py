@@ -28,9 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from oceanbase_tpu.datatypes import SqlType
-from oceanbase_tpu.exec.ops import _combined_key, _mix64  # shared key mixers
+from oceanbase_tpu.exec.ops import _combined_key
 from oceanbase_tpu.expr import ir
 from oceanbase_tpu.expr.compile import eval_expr
+from oceanbase_tpu.share import keyhash
 from oceanbase_tpu.vector.column import Column, Relation
 
 PX_AXIS = "px"
@@ -81,20 +82,6 @@ def shard_relation(rel: Relation, mesh, axis: str = PX_AXIS) -> Relation:
     return Relation(columns=cols, mask=jax.device_put(pad_mask, sharding))
 
 
-_M1 = np.uint64(0xBF58476D1CE4E5B9)
-_M2 = np.uint64(0x94D049BB133111EB)
-
-
-def _np_mix64(x: np.ndarray) -> np.ndarray:
-    """Host mirror of exec.ops._mix64 — MUST stay bit-identical so a
-    host-side hash shard co-locates with device-side hash exchanges."""
-    with np.errstate(over="ignore"):
-        x = x.astype(np.uint64)
-        x = (x ^ (x >> np.uint64(30))) * _M1
-        x = (x ^ (x >> np.uint64(27))) * _M2
-        return x ^ (x >> np.uint64(31))
-
-
 def shard_relation_by_hash(rel: Relation, key_cols: Sequence[str], mesh,
                            axis: str = PX_AXIS) -> Relation:
     """Hash-shard a device relation by key columns: rows with equal keys
@@ -102,10 +89,10 @@ def shard_relation_by_hash(rel: Relation, key_cols: Sequence[str], mesh,
     their join keys needs NO exchange (partition-wise join / PKEY
     distribution, ≙ ob_pwj_comparer.h matching + PKEY slice routing).
 
-    Mirrors the device hash exactly for the single-int fast path and the
-    multi-key mix; key columns must be non-string (dict codes are
-    relation-local).  NULL-key rows hash on 0 — they never match an
-    equi-join, any placement works."""
+    The destinations are ``share/keyhash.py``'s, the function the
+    in-program exchanges and the storage router use; key columns must be
+    non-string (dict codes are relation-local).  NULL-key rows hash on 0
+    — they never match an equi-join, any placement works."""
     ndev = mesh.devices.size
     datas = []
     for c in key_cols:
@@ -114,15 +101,7 @@ def shard_relation_by_hash(rel: Relation, key_cols: Sequence[str], mesh,
         if col.valid is not None:
             d = np.where(np.asarray(col.valid), d, 0)
         datas.append(d)
-    if len(datas) == 1:
-        k = datas[0]
-    else:
-        h = np.zeros(len(datas[0]), dtype=np.uint64)
-        for d in datas:
-            h = _np_mix64(h ^ _np_mix64(d.astype(np.uint64)))
-        k = h.astype(np.int64)
-    dest = (_np_mix64(k.astype(np.uint64)) % np.uint64(ndev)).astype(
-        np.int64)
+    dest = keyhash.partition_of(datas, ndev).astype(np.int64)
     n = rel.capacity
     mask = np.ones(n, dtype=bool) if rel.mask is None \
         else np.asarray(rel.mask)
@@ -180,8 +159,7 @@ def unshard_relation(rel: Relation) -> Relation:
 def _hash_dest(rel: Relation, keys: Sequence[ir.Expr], ndev: int):
     cols = [eval_expr(e, rel) for e in keys]
     k, _ = _combined_key(cols)
-    h = _mix64(k.astype(jnp.uint64))
-    return (h % jnp.uint64(ndev)).astype(jnp.int32)
+    return keyhash.dest_of(k, ndev)
 
 
 def exchange_by_dest(

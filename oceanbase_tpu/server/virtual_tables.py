@@ -71,6 +71,7 @@ class VirtualTables:
             "v$parameters": self.parameters,
             "v$tenants": self.tenants,
             "v$tables": self.tables,
+            "gv$table_locations": self.table_locations,
             "v$palf": self.palf,
             "v$wait_events": self.wait_events,
             "v$sql_workarea": self.sql_workarea,
@@ -864,6 +865,21 @@ class VirtualTables:
             "segment_bytes": np.array([r[4] for r in rows], np.int64),
             "memtable_rows": np.array([r[5] for r in rows], np.int64),
         }
+
+    def table_locations(self):
+        """Where each partition of a hash-partitioned table lies
+        (≙ DBA_OB_TABLE_LOCATIONS): partition ``i``'s device copy is on
+        device ``i`` once a PX statement has read the table; ``device``
+        is empty and ``capacity`` 0 before."""
+        rows = [dict(r, tenant=tname)
+                for tname, tenant in self.db.tenants.items()
+                for r in tenant.catalog.table_locations()]
+        ints = ("partition_id", "rows", "capacity")
+        return {c: (np.array([r[c] for r in rows], np.int64) if c in ints
+                    else _obj(r[c] for r in rows))
+                for c in ("tenant", "table_name", "tablegroup",
+                          "partition_id", "method", "partition_key", "rows",
+                          "device", "capacity")}
 
     def palf(self):
         rows = []

@@ -133,6 +133,19 @@ class CreateTableStmt:
     as_select: object = None  # CREATE TABLE ... AS SELECT
     # inline secondary indexes: list[(name|None, [cols], unique)]
     indexes: list = field(default_factory=list)
+    # PARTITION BY HASH(col) | KEY(cols) PARTITIONS n:
+    # (method, [cols], n) or None
+    hash_partition: tuple | None = None
+    tablegroup: str | None = None  # TABLEGROUP = name
+
+
+@dataclass
+class TablegroupStmt:
+    """CREATE TABLEGROUP [IF NOT EXISTS] name / DROP TABLEGROUP [IF EXISTS]
+    name (``flag`` is the IF clause)."""
+    op: str  # create | drop
+    name: str
+    flag: bool = False
 
 
 @dataclass

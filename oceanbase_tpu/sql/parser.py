@@ -1300,6 +1300,14 @@ class Parser:
                 self.peek().value == "procedure":
             self.next()
             return self.parse_create_procedure()
+        if self._accept_word("tablegroup"):
+            if_not_exists = False
+            if self.accept_kw("if"):
+                self.expect_kw("not")
+                self.expect_kw("exists")
+                if_not_exists = True
+            return ast.TablegroupStmt("create", self.expect_ident(),
+                                      if_not_exists)
         or_replace = False
         if self.at_kw("or"):
             self.next()
@@ -1382,48 +1390,82 @@ class Parser:
             if not self.accept_op(","):
                 break
         self.expect_op(")")
-        partition = None
-        if self.accept_kw("partition"):
-            self.expect_kw("by")
-            if self.expect_ident() != "range":
-                raise ParseError("only PARTITION BY RANGE is supported")
-            self.expect_op("(")
-            pcol = self.expect_ident()
-            self.expect_op(")")
-            self.expect_op("(")
-            bounds = []
-            saw_maxvalue = False
-            while True:
-                if saw_maxvalue:
-                    raise ParseError(
-                        "MAXVALUE partition must be last")
-                self.expect_kw("partition")
-                self.expect_ident()  # partition name (unused)
-                self.expect_kw("values")
-                if self.expect_ident() != "less":
-                    raise ParseError("expected VALUES LESS THAN")
-                if self.expect_ident() != "than":
-                    raise ParseError("expected VALUES LESS THAN")
-                if self.peek().kind == "ident" and \
-                        self.peek().value == "maxvalue":
-                    self.next()
-                    saw_maxvalue = True
+        # table options, in either order: TABLEGROUP [=] name and one
+        # PARTITION BY clause
+        tablegroup = partition = hash_partition = None
+        while True:
+            if tablegroup is None and self._accept_word("tablegroup"):
+                self.accept_op("=")
+                tablegroup = self.expect_ident()
+            elif partition is None and hash_partition is None and \
+                    self.accept_kw("partition"):
+                self.expect_kw("by")
+                method = self.next().value
+                if method in ("hash", "key"):
+                    hash_partition = self._parse_hash_partition(method)
+                elif method == "range":
+                    partition = self._parse_range_partition()
                 else:
-                    self.expect_op("(")
-                    b = self._signed_int()
-                    if bounds and b <= bounds[-1]:
-                        raise ParseError(
-                            "partition bounds must be increasing")
-                    bounds.append(b)
-                    self.expect_op(")")
-                if not self.accept_op(","):
-                    break
-            self.expect_op(")")
-            partition = (pcol, bounds)
+                    raise ParseError("PARTITION BY takes RANGE, HASH or "
+                                     f"KEY, not {method!r}")
+            else:
+                break
         stmt = ast.CreateTableStmt(name, cols, pk, if_not_exists,
                                    partition)
+        stmt.hash_partition = hash_partition
+        stmt.tablegroup = tablegroup
         stmt.indexes = inline_indexes
         return stmt
+
+    def _parse_range_partition(self):
+        """``(col) (PARTITION p VALUES LESS THAN (n) | MAXVALUE, ...)`` ->
+        (col, [upper-exclusive bounds])."""
+        self.expect_op("(")
+        pcol = self.expect_ident()
+        self.expect_op(")")
+        self.expect_op("(")
+        bounds = []
+        saw_maxvalue = False
+        while True:
+            if saw_maxvalue:
+                raise ParseError("MAXVALUE partition must be last")
+            self.expect_kw("partition")
+            self.expect_ident()  # partition name (unused)
+            self.expect_kw("values")
+            if self.expect_ident() != "less":
+                raise ParseError("expected VALUES LESS THAN")
+            if self.expect_ident() != "than":
+                raise ParseError("expected VALUES LESS THAN")
+            if self.peek().kind == "ident" and \
+                    self.peek().value == "maxvalue":
+                self.next()
+                saw_maxvalue = True
+            else:
+                self.expect_op("(")
+                b = self._signed_int()
+                if bounds and b <= bounds[-1]:
+                    raise ParseError("partition bounds must be increasing")
+                bounds.append(b)
+                self.expect_op(")")
+            if not self.accept_op(","):
+                break
+        self.expect_op(")")
+        return (pcol, bounds)
+
+    def _parse_hash_partition(self, method: str):
+        """``(col[, col ...]) PARTITIONS n`` -> (method, [cols], n).  HASH
+        takes one column (MySQL's takes an integer expression: a plain
+        column is the form upstream's own DDL uses), KEY a list."""
+        cols = self._parse_paren_idents()
+        if method == "hash" and len(cols) != 1:
+            raise ParseError("PARTITION BY HASH takes one column "
+                             "(KEY takes a list)")
+        if not self._accept_word("partitions"):
+            raise ParseError("expected PARTITIONS n")
+        n = self._signed_int()
+        if n < 1:
+            raise ParseError("PARTITIONS must be at least 1")
+        return (method, cols, n)
 
     def parse_create_view(self, or_replace: bool):
         """CREATE [OR REPLACE] VIEW name [(cols)] AS select — the body is
@@ -1452,6 +1494,13 @@ class Parser:
                 self.expect_kw("exists")
                 if_exists = True
             return ast.DropViewStmt(self.expect_ident(), if_exists)
+        if self._accept_word("tablegroup"):
+            if_exists = False
+            if self.accept_kw("if"):
+                self.expect_kw("exists")
+                if_exists = True
+            return ast.TablegroupStmt("drop", self.expect_ident(),
+                                      if_exists)
         if self.accept_kw("index"):
             # DROP INDEX [IF EXISTS] name ON table
             if_exists = False

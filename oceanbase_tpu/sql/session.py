@@ -416,6 +416,16 @@ class Session:
                 return _ok()
             self.catalog.drop_table(stmt.name, if_exists=stmt.if_exists)
             return _ok()
+        if isinstance(stmt, ast.TablegroupStmt):
+            if self.db is None:
+                raise NotImplementedError(
+                    "tablegroups need the storage engine")
+            if stmt.op == "create":
+                self._engine.create_tablegroup(stmt.name, stmt.flag)
+            else:
+                self._engine.drop_tablegroup(stmt.name, stmt.flag)
+            self.catalog.schema_version += 1
+            return _ok()
         if isinstance(stmt, ast.CreateViewStmt):
             self.catalog.create_view(stmt.name, stmt.sql_text,
                                      cols=stmt.columns,
@@ -555,6 +565,12 @@ class Session:
                              ", ".join(ix.columns) + ")")
             text = (f"CREATE TABLE {td.name} (\n" + ",\n".join(parts) +
                     "\n)")
+            if td.tablegroup:
+                text += f" TABLEGROUP = {td.tablegroup}"
+            if td.hash_partition:
+                method, pcols, nparts = td.hash_partition
+                text += (f" PARTITION BY {method.upper()} ("
+                         + ", ".join(pcols) + f") PARTITIONS {nparts}")
             if td.partition:
                 pcol, bounds = td.partition
                 ps = [f"PARTITION p{i} VALUES LESS THAN ({b})"
@@ -2263,6 +2279,8 @@ class Session:
                      if getattr(c, "auto_increment", False)]
         tdef = TableDef(stmt.name, cols, primary_key=stmt.primary_key,
                         partition=getattr(stmt, "partition", None),
+                        hash_partition=stmt.hash_partition,
+                        tablegroup=stmt.tablegroup,
                         auto_increment_cols=auto_cols)
         if getattr(stmt, "indexes", None) and self.db is None:
             # capability check BEFORE create_table: a failure must not
@@ -2983,9 +3001,8 @@ class Session:
         key_changed = any(c in tablet.key_cols for c, _ in stmt.assignments)
         # an update that moves a row across range partitions must also be
         # delete+insert (the versions live in different tablets)
-        part_col = getattr(tablet, "part_col", None)
-        part_changed = part_col is not None and \
-            any(c == part_col for c, _ in stmt.assignments)
+        part_cols = getattr(tablet, "part_cols", ())
+        part_changed = any(c in part_cols for c, _ in stmt.assignments)
 
         def op(tx):
             keyed = []

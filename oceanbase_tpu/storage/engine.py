@@ -57,6 +57,23 @@ class TableStore:
 # ---------------------------------------------------------------------------
 
 
+def _layout_record(tdef: TableDef) -> dict:
+    """A table's hash partitioning and tablegroup as the manifest and the
+    slog keep them (absent keys read back as None: older files)."""
+    hp = tdef.hash_partition
+    return {"hash_partition": [hp[0], list(hp[1]), int(hp[2])] if hp
+            else None,
+            "tablegroup": tdef.tablegroup}
+
+
+def _layout_of(rec: dict) -> dict:
+    """``_layout_record``'s inverse, as ``TableDef`` keywords."""
+    hp = rec.get("hash_partition")
+    return {"hash_partition": (hp[0], list(hp[1]), int(hp[2])) if hp
+            else None,
+            "tablegroup": rec.get("tablegroup")}
+
+
 def load_manifest(path: str) -> dict:
     """Read + verify a checkpoint manifest.  New files are
     {"crc", "m"} with the crc over the sorted-key serialization of the
@@ -286,6 +303,7 @@ class StorageEngine:
                     "primary_key": ts.tdef.primary_key,
                     "partition": (list(ts.tdef.partition)
                                   if ts.tdef.partition else None),
+                    **_layout_record(ts.tdef),
                     "auto_increment": list(ts.tdef.auto_increment_cols),
                     "indexes": [[ix.name, list(ix.columns), ix.unique]
                                 for ix in ts.tdef.indexes],
@@ -346,7 +364,8 @@ class StorageEngine:
                 tdef = TableDef(name, cols, primary_key=t["primary_key"],
                                 partition=tuple(part) if part else None,
                                 auto_increment_cols=t.get("auto_increment",
-                                                          []))
+                                                          []),
+                                **_layout_of(t))
                 self._install_table(tdef, log=False)
                 ts = self.tables[name]
                 from oceanbase_tpu.catalog import IndexDef
@@ -406,8 +425,13 @@ class StorageEngine:
             self._install_table(
                 TableDef(op["name"], cols, primary_key=op["primary_key"],
                          partition=tuple(part) if part else None,
-                         auto_increment_cols=op.get("auto_increment", [])),
+                         auto_increment_cols=op.get("auto_increment", []),
+                         **_layout_of(op)),
                 log=False)
+        elif kind == "create_tablegroup":
+            self.meta.setdefault("tablegroups", {})[op["name"]] = {}
+        elif kind == "drop_tablegroup":
+            self.meta.get("tablegroups", {}).pop(op["name"], None)
         elif kind == "drop_table":
             self.tables.pop(op["name"], None)
         elif kind == "truncate":
@@ -527,13 +551,19 @@ class StorageEngine:
             columns.append("__rowid__")
             types["__rowid__"] = SqlType.int_()
             key_cols = ["__rowid__"]
-        if tdef.partition is not None:
-            from oceanbase_tpu.storage.partition import PartitionedTablet
+        from oceanbase_tpu.storage.partition import PartitionedTablet
 
+        if tdef.partition is not None:
             part_col, bounds = tdef.partition
             tablet = PartitionedTablet(len(self.tables) + 1, columns,
                                        types, key_cols, part_col,
                                        list(bounds))
+        elif tdef.hash_partition is not None:
+            _method, hash_cols, nparts = tdef.hash_partition
+            tablet = PartitionedTablet(len(self.tables) + 1, columns,
+                                       types, key_cols,
+                                       hash_cols=list(hash_cols),
+                                       nparts=int(nparts))
         else:
             tablet = Tablet(len(self.tables) + 1, columns, types, key_cols)
         self.tables[tdef.name] = TableStore(tdef, tablet)
@@ -548,6 +578,7 @@ class StorageEngine:
                     "primary_key": tdef.primary_key,
                     "partition": (list(tdef.partition)
                                   if tdef.partition else None),
+                    **_layout_record(tdef),
                     "auto_increment": list(tdef.auto_increment_cols),
                 })
             except Exception:
@@ -561,15 +592,85 @@ class StorageEngine:
         with self._lock:
             if tdef.name in self.tables:
                 raise ValueError(f"table {tdef.name} exists")
-            if tdef.partition is not None and tdef.primary_key and \
-                    tdef.partition[0] not in tdef.primary_key:
+            if tdef.primary_key and any(c not in tdef.primary_key
+                                        for c in tdef.partition_columns):
                 # MySQL/OceanBase rule: every unique key (incl. the PK)
                 # must contain all partitioning columns — otherwise
                 # uniqueness could only be checked across partitions
                 raise ValueError(
                     "a PRIMARY KEY must include all columns in the "
                     "table's partitioning function")
+            self._check_layout(tdef)
             self._install_table(tdef)
+
+    def _check_layout(self, tdef: TableDef):
+        """A hash / key partitioning takes integer-like columns (their
+        stored value is what ``share/keyhash.py`` hashes; a string's
+        dictionary code means nothing outside one relation), and a
+        tablegroup's tables share method, partition count and key types
+        (≙ the tablegroup's partition-consistency check), so that equal
+        keys lie in equal partitions."""
+        from oceanbase_tpu.share.keyhash import HASHABLE_KINDS
+
+        if tdef.hash_partition is not None:
+            for c in tdef.hash_partition[1]:
+                if not tdef.has_column(c):
+                    raise ValueError(
+                        f"partition column {c!r} is not a table column")
+                if tdef.column(c).dtype.kind not in HASHABLE_KINDS:
+                    raise ValueError(
+                        f"PARTITION BY {tdef.hash_partition[0].upper()} "
+                        f"on {c!r}: only integer, decimal, date and "
+                        "boolean columns can be hashed")
+        if tdef.tablegroup is None:
+            return
+        if tdef.tablegroup not in self.meta.get("tablegroups", {}):
+            raise ValueError(f"unknown tablegroup {tdef.tablegroup!r}")
+
+        def shape(td):
+            if td.hash_partition is None:
+                return None
+            method, cols, n = td.hash_partition
+            return (method, int(n),
+                    [td.column(c).dtype.kind for c in cols])
+
+        for other in self.tables.values():
+            if other.tdef.tablegroup == tdef.tablegroup and \
+                    shape(other.tdef) != shape(tdef):
+                raise ValueError(
+                    f"tablegroup {tdef.tablegroup!r}: {tdef.name} is not "
+                    f"partitioned as {other.tdef.name} is (method, "
+                    "partition count and key types must be equal)")
+
+    def create_tablegroup(self, name: str, if_not_exists: bool = False):
+        """≙ CREATE TABLEGROUP: a named set of tables partitioned alike,
+        whose equal partitions live together."""
+        with self._lock:
+            groups = self.meta.setdefault("tablegroups", {})
+            if name in groups:
+                if if_not_exists:
+                    return
+                raise ValueError(f"tablegroup {name} exists")
+            groups[name] = {}
+            try:
+                self._log_meta({"op": "create_tablegroup", "name": name})
+            except Exception:
+                groups.pop(name, None)
+                raise
+
+    def drop_tablegroup(self, name: str, if_exists: bool = False):
+        with self._lock:
+            if name not in self.meta.get("tablegroups", {}):
+                if if_exists:
+                    return
+                raise KeyError(f"unknown tablegroup {name}")
+            used = sorted(t.tdef.name for t in self.tables.values()
+                          if t.tdef.tablegroup == name)
+            if used:
+                raise ValueError(f"tablegroup {name} is not empty: "
+                                 + ", ".join(used))
+            self._log_meta({"op": "drop_tablegroup", "name": name})
+            del self.meta["tablegroups"][name]
 
     def alter_table(self, name: str, action: str, column, log=True):
         """Online schema change: ADD COLUMN (old segments serve NULLs for
@@ -605,7 +706,7 @@ class StorageEngine:
                         raise ValueError(
                             f"cannot drop column {cname!r}: used by "
                             f"index {ix.name} (drop the index first)")
-                if getattr(tab, "part_col", None) == cname:
+                if cname in getattr(tab, "part_cols", ()):
                     raise ValueError("cannot drop the partition column")
                 if not any(c.name == cname for c in tdef.columns):
                     raise KeyError(f"unknown column {cname!r}")
@@ -892,7 +993,7 @@ class StorageEngine:
             from oceanbase_tpu.storage.partition import PartitionedTablet
 
             if isinstance(ts.tablet, PartitionedTablet):
-                parts = ts.tablet.split_arrays_by_partition(arrays)
+                parts = ts.tablet.split_arrays_by_partition(arrays, valids)
                 targets = [(i, pa,
                             {k: v[sel] for k, v in (valids or {}).items()
                              if v is not None})
@@ -1167,6 +1268,11 @@ class StorageCatalog(Catalog):
         from oceanbase_tpu.share.kvcache import KvCache
 
         self._cache = KvCache(limit_bytes=2 << 30, name="relation")
+        # table -> the newest relation's partition layout, for as long as
+        # that relation lives (hash-partitioned tables only)
+        import weakref
+
+        self._layouts = weakref.WeakValueDictionary()
         # surface engine-persisted tables in the catalog
         for name, ts in engine.tables.items():
             self._defs[name] = ts.tdef
@@ -1448,9 +1554,14 @@ class StorageCatalog(Catalog):
         from oceanbase_tpu.share.kvcache import relation_bytes
         from oceanbase_tpu.vector import from_numpy
 
+        hashed = ts.tdef.hash_partition is not None
         with qtrace.span("storage.device_copy",
                          table=ts.tdef.name) as sp:
-            arrays, valids = ts.tablet.snapshot_arrays(snapshot, tx_id)
+            if hashed:
+                arrays, valids, part_rows = \
+                    ts.tablet.snapshot_arrays_counted(snapshot, tx_id)
+            else:
+                arrays, valids = ts.tablet.snapshot_arrays(snapshot, tx_id)
             n = len(next(iter(arrays.values()))) if arrays else 0
             if n == 0:
                 # static shapes need capacity >= 1: one all-dead row
@@ -1465,7 +1576,52 @@ class StorageCatalog(Catalog):
             sp.tags.update(rows=n, bytes=relation_bytes(rel))
         qmetrics.inc("storage.device_copy_builds")
         qmetrics.inc("storage.device_copy_ns", int(sp.elapsed_s * 1e9))
+        if hashed:
+            rel.partitions = self._partitions_of(ts, part_rows, rel)
         return rel, n
+
+    def _partitions_of(self, ts, part_rows, rel):
+        """The declared layout of a hash-partitioned table's relation:
+        which lanes are which partition, and (built when a PX plan first
+        asks) each partition's copy on its own device, at one ladder
+        capacity for all of them.  A statement finds it on the relation
+        (``rel.partitions``), ``gv$table_locations`` here."""
+        from oceanbase_tpu.storage.device_partitions import DevicePartitions
+        from oceanbase_tpu.vector.column import bucket_capacity
+
+        enabled, floor, growth = self._bucket_policy()
+        most = max(max(part_rows), 1)
+        layout = DevicePartitions(
+            ts.tdef.name, ts.tdef.hash_partition[1], ts.tdef.tablegroup,
+            part_rows, rel,
+            bucket_capacity(most, floor, growth) if enabled else most)
+        self._layouts[ts.tdef.name] = layout
+        return layout
+
+    def table_locations(self) -> list[dict]:
+        """One row per partition of every hash-partitioned table: where
+        it lies (≙ DBA_OB_TABLE_LOCATIONS).  ``device`` is empty and
+        ``capacity`` 0 until a PX statement has read the table."""
+        out = []
+        with self._lock:
+            tables = sorted(self.engine.tables.items())
+        for name, ts in tables:
+            if ts.tdef.hash_partition is None:
+                continue
+            method, cols, _n = ts.tdef.hash_partition
+            lay = self._layouts.get(name)
+            devs = lay.devices() if lay is not None else []
+            for i, part in enumerate(ts.tablet.partitions):
+                out.append({
+                    "table_name": name,
+                    "tablegroup": ts.tdef.tablegroup or "",
+                    "partition_id": i,
+                    "method": method, "partition_key": ",".join(cols),
+                    "rows": lay.rows[i] if lay is not None
+                    else part.row_count_estimate(),
+                    "device": devs[i] if devs else "",
+                    "capacity": lay.capacity if devs else 0})
+        return out
 
     def _empty_rel(self, ts):
         import jax.numpy as jnp

@@ -1,4 +1,4 @@
-"""Range-partitioned tables: multiple tablets per table.
+"""Partitioned tables (range, hash, key): multiple tablets per table.
 
 Reference analog: partitioned tables mapping to multiple tablets hosted
 by log streams (src/storage/tablet + the partition routing the DAS layer
@@ -6,13 +6,17 @@ performs).  A PartitionedTablet keeps the single-tablet interface the
 rest of the engine uses (write/commit/abort/freeze/compact/snapshot) and
 routes internally:
 
-- writes route by the partition key's range (≙ PKEY slice routing)
+- writes route by the partition key's range, or by the hash of the key
+  columns (``share/keyhash.py``: the function the PX exchanges use, so a
+  partition is also a shard; ≙ PKEY slice routing)
 - snapshot reads concatenate per-partition arrays (scans parallelize
   naturally — each partition is an independent granule source)
 - freeze/compaction iterate partitions (≙ per-tablet merge DAGs)
 
 Bounds are upper-exclusive split points: bounds [10, 20] makes partitions
-(-inf,10), [10,20), [20,+inf).
+(-inf,10), [10,20), [20,+inf).  A hash or key partitioning has
+``hash_cols`` and a partition count instead, and no ``part_col``: nothing
+prunes it by range.
 """
 
 from __future__ import annotations
@@ -22,25 +26,32 @@ import threading
 
 import numpy as np
 
+from oceanbase_tpu.share import keyhash
 from oceanbase_tpu.storage.tablet import Tablet
 
 
 class PartitionedTablet:
     def __init__(self, tablet_id: int, columns, types, key_cols,
-                 part_col: str, bounds: list):
-        if part_col not in columns:
-            raise ValueError(
-                f"partition column {part_col!r} is not a table column")
+                 part_col: str | None = None, bounds: list | None = None,
+                 hash_cols: list | None = None, nparts: int = 0):
+        """Range: ``part_col`` and ``bounds``.  Hash / key: ``hash_cols``
+        and ``nparts``."""
+        for c in hash_cols or [part_col]:
+            if c not in columns:
+                raise ValueError(
+                    f"partition column {c!r} is not a table column")
+        bounds = list(bounds or [])
         if any(bounds[i] >= bounds[i + 1] for i in range(len(bounds) - 1)):
             raise ValueError("partition bounds must be strictly increasing")
         self.part_col = part_col
-        self.bounds = list(bounds)
+        self.bounds = bounds
+        self.hash_cols = list(hash_cols or [])
         self.columns = list(columns)
         self.types = dict(types)
         self.key_cols = list(key_cols)
         self.partitions = [
             Tablet(tablet_id * 1000 + i, columns, types, key_cols)
-            for i in range(len(bounds) + 1)
+            for i in range(nparts if self.hash_cols else len(bounds) + 1)
         ]
         # one segment-id space across partitions (filenames stay unique;
         # add_segment bumps it past recovered ids — see SegIdAlloc)
@@ -88,18 +99,34 @@ class PartitionedTablet:
             out.extend(p.segments)
         return out
 
+    @property
+    def part_cols(self) -> list:
+        """The columns a row's partition follows from."""
+        return self.hash_cols or [self.part_col]
+
+    def _hash_route(self, vals) -> Tablet:
+        """One row's key values (a NULL hashes as 0) -> its partition."""
+        datas = [np.array([0 if v is None else int(v)], dtype=np.int64)
+                 for v in vals]
+        return self.partitions[int(
+            keyhash.partition_of(datas, len(self.partitions))[0])]
+
     def _route(self, values: dict) -> Tablet:
+        if self.hash_cols:
+            return self._hash_route([values.get(c) for c in self.hash_cols])
         v = values.get(self.part_col)
         if v is None:
             return self.partitions[0]  # NULLs live in the first partition
         return self.partitions[bisect.bisect_right(self.bounds, v)]
 
     def _route_key(self, key: tuple) -> Tablet | None:
-        """Route by key when the partition column is part of the key."""
-        if self.part_col in self.key_cols:
-            v = key[self.key_cols.index(self.part_col)]
-            return self.partitions[bisect.bisect_right(self.bounds, v)]
-        return None
+        """Route by key when the partition columns are part of the key."""
+        if not all(c in self.key_cols for c in self.part_cols):
+            return None
+        vals = [key[self.key_cols.index(c)] for c in self.part_cols]
+        if self.hash_cols:
+            return self._hash_route(vals)
+        return self.partitions[bisect.bisect_right(self.bounds, vals[0])]
 
     # ------------------------------------------------------------------
     def make_key(self, values: dict) -> tuple:
@@ -153,6 +180,13 @@ class PartitionedTablet:
 
     # ------------------------------------------------------------------
     def snapshot_arrays(self, snapshot: int, tx_id: int = 0, prune=None):
+        return self.snapshot_arrays_counted(snapshot, tx_id, prune)[:2]
+
+    def snapshot_arrays_counted(self, snapshot: int, tx_id: int = 0,
+                                prune=None):
+        """-> (arrays, valids, rows of each partition read): the rows of
+        partition ``i`` are one contiguous run of the arrays, in partition
+        order (a device copy per partition slices them there)."""
         live = self.partitions
         if prune and self.part_col in prune:
             lo, hi = prune[self.part_col]
@@ -186,7 +220,8 @@ class PartitionedTablet:
                      for (a, v), x in zip(parts, vs)])
             else:
                 valids[c] = None
-        return arrays, valids
+        counts = [len(next(iter(a.values()))) if a else 0 for a, _v in parts]
+        return arrays, valids, counts
 
     def row_count_estimate(self) -> int:
         return sum(p.row_count_estimate() for p in self.partitions)
@@ -205,10 +240,19 @@ class PartitionedTablet:
             out.extend((s, i) for s in p.segments)
         return out
 
-    def split_arrays_by_partition(self, arrays: dict):
-        """Bulk-load routing: -> [(part_idx, {col -> rows})] per range."""
-        col = arrays[self.part_col]
-        idx = np.searchsorted(np.asarray(self.bounds), col, side="right")
+    def split_arrays_by_partition(self, arrays: dict, valids=None):
+        """Bulk-load routing: -> [(part_idx, {col -> rows}, selector)] per
+        partition that gets rows (``valids``: a NULL hash key is 0)."""
+        if self.hash_cols:
+            datas = []
+            for c in self.hash_cols:
+                d = np.asarray(arrays[c]).astype(np.int64)
+                v = (valids or {}).get(c)
+                datas.append(d if v is None else np.where(v, d, 0))
+            idx = keyhash.partition_of(datas, len(self.partitions))
+        else:
+            idx = np.searchsorted(np.asarray(self.bounds),
+                                  arrays[self.part_col], side="right")
         out = []
         for i in range(len(self.partitions)):
             sel = idx == i

@@ -176,3 +176,69 @@ def test_px_groupby_exchange_compiles_for_four_chips(topo,
         check_vma=False)).lower(shapes).compile()
     assert "all-to-all" in compiled.as_text()
     _fits(compiled)
+
+
+def test_px_plan_over_declared_partitions_compiles_for_four_chips(
+        topo, no_persistent_cache, monkeypatch, tmp_path):
+    """TPC-H Q3 at ``px_dop = 4`` over tables hash-partitioned by DDL: the
+    shard program the planner derives from the declared layout (customer
+    broadcast, lineitem partition-wise, the group-by local, the range
+    sort's exchange), built again for the described 2x2 mesh and fed the
+    partitions' shapes.  It runs on four virtual CPU devices first: that
+    is where the program's arguments come from."""
+    from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
+    from oceanbase_tpu.bench.tpch_queries import QUERIES
+    from oceanbase_tpu.px import planner
+    from oceanbase_tpu.server import Database
+
+    tables, types = gen_tpch(sf=0.01)
+    db = Database(str(tmp_path))
+    s = db.session()
+    for name, key in (("lineitem", "l_orderkey"), ("orders", "o_orderkey"),
+                      ("customer", "c_custkey")):
+        cols = ", ".join(
+            f"{c} " + (str(types[c]) if c in types else
+                       "varchar(200)" if a.dtype == object else "bigint")
+            for c, a in tables[name].items())
+        s.execute(f"create table {name} ({cols}, primary key "
+                  f"({', '.join(TPCH_PRIMARY_KEYS[name])})) partition by "
+                  f"key ({key}) partitions 4")
+        s.catalog.load_numpy(
+            name, tables[name],
+            types={k: v for k, v in types.items() if k in tables[name]},
+            primary_key=TPCH_PRIMARY_KEYS[name])
+    built = []
+    compiled_for = planner._px_compiled
+
+    def spy(*args):
+        run, probes, notes = compiled_for(*args)
+
+        def call(sharded):
+            built.append((args, sharded, notes))
+            return run(sharded)
+
+        return call, probes, notes
+
+    spy.cache_info = compiled_for.cache_info
+    monkeypatch.setattr(planner, "_px_compiled", spy)
+    s.execute("set px_dop = 4")
+    assert s.execute(QUERIES[3]).rowcount > 0 and s._last_px
+    db.close()
+    (key, holder, _mesh, axis, ndev, factor, names), sharded, notes = \
+        built[-1]
+    assert dict(holder.declared) == {"customer": ("c_custkey",),
+                                     "lineitem": ("l_orderkey",),
+                                     "orders": ("o_orderkey",)}
+    assert notes["join", "partition_wise"] == 1
+    assert notes["join", "broadcast"] == 1
+    assert not any(k == ("lanes", "groupby") for k in notes)
+    mesh = Mesh(np.array(topo.devices), (axis,))
+    run, _probes, _notes = compiled_for.__wrapped__(
+        key, holder, mesh, axis, ndev, factor, names)
+    on_mesh = NamedSharding(mesh, P(axis))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_mesh),
+        sharded)
+    compiled = run.lower(shapes).compile()
+    assert "all-gather" in compiled.as_text()
+    _fits(compiled)
