@@ -114,6 +114,31 @@ def note_probe(kind: str) -> None:
 
 
 # ---------------------------------------------------------------------------
+# group-by lane: which way each group-by of the program took
+# (ops.hash_groupby picks from the keys' static types and code space: a
+# fact of the traced program like the probe kinds above).
+# ---------------------------------------------------------------------------
+
+_groupbys: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "ob_tpu_groupbys", default=None
+)
+
+
+def groupby_collect():
+    """Activate the lane; yields the list of kinds, in program order."""
+    return _collecting(_groupbys)
+
+
+def note_groupby(kind: str) -> None:
+    """Record one group-by's kind: ``masked`` (dictionary / bool keys, no
+    sort, masked streaming reductions) or ``sort`` (no-op outside a
+    collector)."""
+    kinds = _groupbys.get()
+    if kinds is not None:
+        kinds.append(kind)
+
+
+# ---------------------------------------------------------------------------
 # PX lane: what the distributed lowering decided (a join's distribution
 # method, an exchange buffer's static capacity).  Facts of the traced
 # shard program like the probe kinds above; the executable keeps them and

@@ -39,6 +39,11 @@ qmetrics.declare("plan.join_probes", "counter",
                  "join / index probes executed, by how the program ranks "
                  "the probe keys (kind=merge|search: ops._probe_ranges "
                  "picks from the static shapes at trace time)")
+qmetrics.declare("plan.groupby_reduces", "counter",
+                 "group-bys executed, by the way the program takes "
+                 "(kind=masked: dictionary / bool keys, no sort, masked "
+                 "streaming reductions; kind=sort: sort + segment "
+                 "reduce; ops.hash_groupby picks at trace time)")
 qmetrics.declare("plan.compiles", "counter",
                  "XLA trace+compile events (per plan x input signature)")
 qmetrics.declare("plan.capacity_retries", "counter",
@@ -798,20 +803,23 @@ class _PlanExecutable:
     MAX_SIGNATURES = 64  # >> the bucket-ladder rungs a table ever visits
 
     __slots__ = ("stats", "diag_names", "monitor_names", "probe_kinds",
-                 "_run", "_execs", "_lock")
+                 "groupby_kinds", "_run", "_execs", "_lock")
 
     def __init__(self, plan: PlanNode, plan_key: str, with_monitor: bool):
         self.stats = _stats_for(plan_key)
         self.diag_names: list[str] = []     # filled at trace time
         self.monitor_names: list[str] = []
         self.probe_kinds: list[str] = []    # filled at trace time
+        self.groupby_kinds: list[str] = []  # filled at trace time
         diag_names = self.diag_names
         monitor_names = self.monitor_names
         probe_kinds = self.probe_kinds
+        groupby_kinds = self.groupby_kinds
 
         @jax.jit
         def run(tables):
-            with diag.collect() as entries, diag.probe_collect() as kinds:
+            with diag.collect() as entries, diag.probe_collect() as kinds, \
+                    diag.groupby_collect() as reduces:
                 if with_monitor:
                     with diag.monitor_collect() as mons:
                         out = _lower(plan, tables)
@@ -836,6 +844,7 @@ class _PlanExecutable:
             # (lane name, static capacity) pairs for the overflow report
             diag_names.extend((n, cap) for n, _, cap in entries)
             probe_kinds[:] = kinds
+            groupby_kinds[:] = reduces
             # fold the per-operator overflow lanes into ONE scalar on
             # device: the per-execute host check reads a single value
             # instead of syncing once per diagnostic lane (obcheck
@@ -853,7 +862,8 @@ class _PlanExecutable:
         # `run` as a traced root), its dispatch cache stays empty
         self._run = run
         #: signature -> (compiled executable, flops, bytes, peak,
-        #: probes by kind: the shapes of the signature pick each kind)
+        #: (probes by kind, group-bys by kind): the
+        #: shapes of the signature pick each kind)
         self._execs: dict[tuple, tuple] = {}
         self._lock = threading.Lock()
 
@@ -874,7 +884,7 @@ class _PlanExecutable:
             t0 = time.perf_counter()
             lowered = self._run.lower(tables)
             lower_s = time.perf_counter() - t0 - (acc.gc_s - g0)
-            probes = tuple(sorted(Counter(self.probe_kinds).items()))
+            noted = (Counter(self.probe_kinds), Counter(self.groupby_kinds))
             exe = lowered.compile()
             flops, nbytes, peak = _xla_analysis(exe)
             csp.tags.update(flops=flops, bytes_accessed=nbytes,
@@ -890,14 +900,14 @@ class _PlanExecutable:
         qmetrics.inc("plan.compiles")
         if len(self._execs) >= self.MAX_SIGNATURES:
             self._execs.pop(next(iter(self._execs)))
-        entry = (exe, flops, nbytes, peak, probes)
+        entry = (exe, flops, nbytes, peak, noted)
         self._execs[sig] = entry
         return entry
 
     def call(self, tables):
         """-> ((out, diag_vals, diag_total, mon_vals), compiled_now,
-        flops, bytes_accessed, probes) — the cost-analysis pair and the
-        probe counts by kind are the executed SIGNATURE's, so callers
+        flops, bytes_accessed, noted) — the cost-analysis pair and the
+        noted kinds' counts are the executed SIGNATURE's, so callers
         can attribute measured device time to the program that actually
         ran."""
         sig = _input_signature(tables)
@@ -909,8 +919,8 @@ class _PlanExecutable:
                 if entry is None:
                     entry = self._compile(tables, sig)
                     compiled_now = True
-        exe, flops, nbytes, _peak, probes = entry
-        return exe(tables), compiled_now, flops, nbytes, probes
+        exe, flops, nbytes, _peak, noted = entry
+        return exe(tables), compiled_now, flops, nbytes, noted
 
 
 # per-thread statement-scoped compile marker: the session resets it
@@ -1129,7 +1139,7 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             bundle = _compiled(key, _PlanHolder(plan, key), with_monitor)
             stats = bundle.stats
             (out, diag_vals, diag_total, mon_vals), compiled_now, flops, \
-                nbytes, probes = bundle.call(
+                nbytes, noted = bundle.call(
                     {k: v for k, v in tables.items() if k in needed})
             stats.executions += 1
         # a first execution at a signature pays lower()+compile() inside
@@ -1178,8 +1188,11 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             acc.calls += 1
             plan_elapsed = dsp.elapsed_s + device_s
             qmetrics.inc("plan.executions", op=root_op)
-            for kind, n in probes:
+            probes, groupbys = noted
+            for kind, n in probes.items():
                 qmetrics.inc("plan.join_probes", n, kind=kind)
+            for kind, n in groupbys.items():
+                qmetrics.inc("plan.groupby_reduces", n, kind=kind)
             if compiled_now:
                 _exec_flags.compiled = True
                 tsp.tags["compiled"] = 1

@@ -736,12 +736,14 @@ def _px_compiled(plan_key, holder, mesh, axis, ndev, factor, table_names):
     lowering = _Lowering(ndev, axis, factor, holder.elide, holder.declared)
     # what the shard program's last trace noted: its probes by kind
     # (exec/plan.py's executable keeps the same per signature), its joins
-    # by distribution method and its exchange buffers' lanes by kind
+    # by distribution method, its exchange buffers' lanes by kind and its
+    # group-bys by kind
     probes: Counter = Counter()
     notes: Counter = Counter()
 
     def shard_body(shtables):
         with diag.collect() as entries, diag.probe_collect() as kinds, \
+                diag.groupby_collect() as reduces, \
                 diag.px_collect() as noted:
             rel = _dlower(droot, shtables, lowering)
             if getattr(rel, "_px_replicated", False):
@@ -773,6 +775,8 @@ def _px_compiled(plan_key, holder, mesh, axis, ndev, factor, table_names):
         notes.clear()
         for what, value, n in noted:
             notes[what, value] += n
+        for kind in reduces:
+            notes["groupby", kind] += 1
         return rel, jax.lax.psum(total_ovf, axis)
 
     return jax.jit(jax.shard_map(
@@ -911,6 +915,8 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
     for (what, value), n in notes.items():
         if what == "join":
             qmetrics.inc("px.joins", n, dist=value)
+        elif what == "groupby":
+            qmetrics.inc("plan.groupby_reduces", n, kind=value)
         else:
             qmetrics.inc("px.exchange_lanes", n, kind=value)
     if n_over > 0:

@@ -66,3 +66,179 @@ def test_lowcard_respects_capacity_fallback(rng):
                                 out_capacity=8))
     # truncated sort-path output of 8 groups (overflow handled upstream)
     assert len(out["s"]) <= 8
+
+
+# ---------------------------------------------------------------------------
+# the masked streaming reductions against the sort path and against the
+# scatters of jax.ops.segment_*: one answer three ways
+# ---------------------------------------------------------------------------
+
+_ALL_AGGS = [AggSpec("sm", "sum", ir.col("v")),
+             AggSpec("ct", "count", ir.col("v")),
+             AggSpec("n", "count_star"),
+             AggSpec("av", "avg", ir.col("v")),
+             AggSpec("lo", "min", ir.col("v")),
+             AggSpec("hi", "max", ir.col("v"))]
+
+
+def _groups(res, keys):
+    """{key tuple (None for NULL): {aggregate: value or None}} of a
+    ``to_numpy`` result."""
+    def cell(name, i):
+        valid = res.get("__valid__" + name)
+        return None if valid is not None and not valid[i] else res[name][i]
+
+    names = [n for n in res if not n.startswith("__valid__")]
+    n = len(res[names[0]]) if names else 0
+    return {tuple(cell(k, i) for k in keys):
+            {a: cell(a, i) for a in names if a not in keys}
+            for i in range(n)}
+
+
+def _case(name):
+    """-> (relation, group keys) of one named input."""
+    import jax.numpy as jnp
+
+    r = np.random.default_rng(11)
+    # (most inputs are one block of the masked reduce)
+    n = 2 * ops._MASKED_REDUCE_BLOCK if name == "two_blocks" else 3000
+    flag = r.choice(np.array(["A", "N", "R"]), n)
+    status = r.choice(np.array(["F", "O"]), n)
+    v = r.integers(-1000, 1000, n)
+    cols, valids, mask = {"f": flag, "s": status, "v": v}, {}, None
+    keys = ("f", "s")
+    if name == "nullable_keys":
+        valids = {"f": r.random(n) > 0.1, "s": r.random(n) > 0.2}
+    elif name == "nullable_arguments":
+        valids = {"v": r.random(n) > 0.3}
+        # one whole group's argument is NULL: its SUM/MIN/MAX/AVG are NULL
+        valids["v"] &= ~((flag == "N") & (status == "O"))
+    elif name in ("dead_lanes", "two_blocks"):
+        valids = {"v": r.random(n) > 0.1, "s": r.random(n) > 0.1}
+        mask = r.random(n) > 0.4
+    elif name == "all_dead":
+        mask = np.zeros(n, bool)
+    elif name == "zero_lanes":
+        # an empty table that arrives unpadded, with its dictionaries
+        rel = from_numpy(cols, valids={"v": r.random(n) > 0.1})
+        return rel.gather(jnp.zeros(0, jnp.int32)), keys
+    elif name == "bool_keys":
+        cols = {"f": r.integers(0, 2, n).astype(bool),
+                "s": r.integers(0, 2, n).astype(bool), "v": v}
+        valids = {"s": r.random(n) > 0.1}
+    elif name == "int64_near_2_62":
+        # every group's sum wraps several times; the masked sums and the
+        # sort path's scatter must wrap alike
+        cols["v"] = r.integers(2 ** 62 - 10 ** 6, 2 ** 62, n) \
+            * r.choice(np.array([-1, 1]), n)
+    elif name == "float_values":
+        cols["v"] = r.normal(0.0, 1e3, n)
+        valids = {"v": r.random(n) > 0.1}
+    elif name == "forty_codes":
+        cols["f"] = r.choice(np.array([f"k{i:02d}" for i in range(40)]), n)
+        keys = ("f",)
+        del cols["s"]
+        valids = {"f": r.random(n) > 0.05}
+    else:
+        raise ValueError(name)
+    rel = from_numpy(cols, valids=valids)
+    if mask is not None:
+        rel = rel.with_mask(jnp.asarray(mask))
+    return rel, keys
+
+
+@pytest.mark.parametrize("case", [
+    "nullable_keys", "nullable_arguments", "dead_lanes", "all_dead",
+    "bool_keys", "int64_near_2_62", "float_values", "forty_codes",
+    "two_blocks", "zero_lanes"])
+def test_masked_reduce_matches_sort_path(case):
+    from oceanbase_tpu.exec import diag
+
+    rel, keys = _case(case)
+    group_by = {k: ir.col(k) for k in keys}
+    with diag.groupby_collect() as kinds:
+        fast = _groups(_run(rel, group_by, _ALL_AGGS), keys)
+    assert kinds == ["masked"]
+    with diag.groupby_collect() as kinds:
+        slow = _groups(_run(rel, group_by, _ALL_AGGS, force_sort=True), keys)
+    assert kinds == ["sort"]
+    assert fast.keys() == slow.keys()
+    assert (len(fast) == 0) == (case in ("all_dead", "zero_lanes"))
+    for key, want in slow.items():
+        for agg, w in want.items():
+            g = fast[key][agg]
+            if w is None or g is None:
+                assert g is None and w is None, (key, agg, g, w)
+            elif agg == "av" or case == "float_values":
+                assert g == pytest.approx(w, rel=1e-9, abs=1e-9), (key, agg)
+            else:
+                assert g == w and type(g) is type(w), (key, agg, g, w)
+
+
+@pytest.mark.parametrize("lanes", [0, 3000, 2 * ops._MASKED_REDUCE_BLOCK])
+@pytest.mark.parametrize("fn", ["sum", "min", "max"])
+def test_lowcard_reduce_is_bit_equal_to_the_scatter(fn, lanes):
+    """``_lowcard_reduce`` against ``jax.ops.segment_<fn>``, which it
+    replaced: int64 values that wrap in a sum, 7 and 4,097 segments (the
+    most ``LOWCARD_GROUP_LIMIT`` admits), no lanes at all."""
+    import jax
+    import jax.numpy as jnp
+
+    r = np.random.default_rng(14)
+    d = jnp.asarray(r.integers(-2 ** 62, 2 ** 62, lanes))
+    scatter = getattr(jax.ops, "segment_" + fn)
+    for nseg in (7, ops.LOWCARD_GROUP_LIMIT + 1):
+        gid = jnp.asarray(r.integers(0, nseg, lanes).astype(np.int32))
+        got = ops._lowcard_reduce(fn, d, gid, nseg)
+        want = scatter(d, gid, num_segments=nseg)[:nseg - 1]
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_groupby_kind_follows_the_code_space(monkeypatch):
+    """``hash_groupby`` reads the keys' static code space alone: a
+    dictionary on each side of ``LOWCARD_GROUP_LIMIT`` (brought down for
+    this), the same answers either way."""
+    from oceanbase_tpu.exec import diag
+
+    monkeypatch.setattr(ops, "LOWCARD_GROUP_LIMIT", 32)
+    r = np.random.default_rng(12)
+    n = 2000
+    v = r.integers(-50, 50, n)
+    for codes, want in ((32, "masked"), (33, "sort")):
+        names = np.array([f"k{i:02d}" for i in range(codes)])
+        k = names[r.integers(0, codes, n)]
+        k[:codes] = names
+        rel = from_numpy({"k": k, "v": v})
+        with diag.groupby_collect() as kinds:
+            got = _groups(_run(rel, {"k": ir.col("k")}, _ALL_AGGS), ("k",))
+        assert kinds == [want], (codes, kinds)
+        slow = _groups(_run(rel, {"k": ir.col("k")}, _ALL_AGGS,
+                            force_sort=True), ("k",))
+        assert got.keys() == slow.keys() and len(got) == codes
+        for key, w in slow.items():
+            assert got[key] == pytest.approx(w), key
+
+
+def test_q1_shaped_groupby_lowers_without_a_scatter():
+    """Two dictionary keys (3 x 2 codes), sums, averages and a count over
+    the lanes: no scatter in the lowered program (what the TPU's compiler
+    keeps in memory for it: tests/test_tpu_compile.py)."""
+    import jax
+
+    r = np.random.default_rng(13)
+    n = 4096
+    rel = from_numpy({"f": r.choice(np.array(["A", "N", "R"]), n),
+                      "s": r.choice(np.array(["F", "O"]), n),
+                      "q": r.integers(0, 50, n),
+                      "p": r.integers(0, 10 ** 6, n)})
+    aggs = [AggSpec("sq", "sum", ir.col("q")),
+            AggSpec("sp", "sum", ir.col("p")),
+            AggSpec("sd", "sum", ir.col("p") * ir.col("q")),
+            AggSpec("aq", "avg", ir.col("q")),
+            AggSpec("ap", "avg", ir.col("p")),
+            AggSpec("n", "count_star")]
+    keys = {"f": ir.col("f"), "s": ir.col("s")}
+    lowered = jax.jit(lambda rel: hash_groupby(rel, keys, aggs)).lower(rel)
+    assert "stablehlo.scatter" not in lowered.as_text()
+    assert " scatter(" not in lowered.compile().as_text()

@@ -445,3 +445,49 @@ def test_join_probes_rise_by_the_executables_counts(path, monkeypatch):
             assert rows == want
     _compiled.cache_clear()
     _px_compiled.cache_clear()
+
+
+# ---------------------------------------------------------------------------
+# plan.groupby_reduces: which way the program's group-bys take (a static
+# choice like the probes' kinds), added per execution
+# ---------------------------------------------------------------------------
+
+
+def _groupby_counts():
+    return {kind: qmetrics.counter_value("plan.groupby_reduces", kind=kind)
+            for kind in ("masked", "sort")}
+
+
+@pytest.mark.parametrize("path", ["serial", "px"])
+@pytest.mark.parametrize("key, kind", [
+    ("k", "masked"),    # a dictionary key: no sort, masked reductions
+    ("g", "sort"),      # an integer key
+])
+def test_groupby_reduces_rise_by_the_programs_kinds(key, kind, path):
+    from oceanbase_tpu.sql import Session
+
+    r = np.random.default_rng(6)
+    s = Session()
+    s.catalog.load_numpy(
+        "gr_t", {"id": np.arange(600),
+                 "k": r.choice(np.array(["a", "b", "c"]), 600),
+                 "g": r.integers(0, 3, 600),
+                 "v": r.integers(0, 100, 600)}, primary_key=["id"])
+    if path == "px":
+        s.variables["px_dop"] = 4
+    # the shard program holds the per-shard partial and the final
+    # aggregate over the exchanged partials
+    per_execution = 2 if path == "px" else 1
+    other = "sort" if kind == "masked" else "masked"
+    sql = (f"select {key}, sum(v), count(*), min(v) from gr_t "
+           f"group by {key} order by {key}")
+    want = None
+    for _ in range(3):
+        before = _groupby_counts()
+        rows = s.execute(sql).rows()
+        assert bool(s._last_px) == (path == "px")
+        after = _groupby_counts()
+        assert after[kind] - before[kind] == per_execution, (before, after)
+        assert after[other] == before[other], (before, after)
+        want = want or rows
+        assert rows == want

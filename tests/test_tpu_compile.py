@@ -242,3 +242,23 @@ def test_px_plan_over_declared_partitions_compiles_for_four_chips(
     compiled = run.lower(shapes).compile()
     assert "all-gather" in compiled.as_text()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("fn", ["sum", "min"])
+def test_lowcard_reduce_compiles_for_v5e(fn, one_chip, no_persistent_cache):
+    """``ops._lowcard_reduce`` over SF1's ``lineitem`` bucket into 7
+    segments holds no scatter and writes nothing of (segments, lanes):
+    what it keeps beside its arguments is under one int64 column (the two
+    halves the TPU splits the column into), where the broadcast would be
+    six."""
+    from oceanbase_tpu.exec import ops
+
+    lanes, nseg = 8_388_608, 7
+    compiled = jax.jit(
+        lambda d, gid: ops._lowcard_reduce(fn, d, gid, nseg)).lower(
+            jax.ShapeDtypeStruct((lanes,), jnp.int64, sharding=one_chip),
+            jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip),
+        ).compile()
+    _fits(compiled)
+    assert " scatter(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= lanes * 8
