@@ -4,9 +4,9 @@ Default (one chip): device -> boot (``Database``) -> load (TPC-H through
 ``catalog.load_numpy`` + ``ANALYZE``) -> six queries through
 ``Session.execute``, each twice, each compared with references that share
 no code with the engine (SQLite, and exact NumPy integers for Q1/Q6) ->
-a committed write read back before and after a reopen -> the Pallas Q6
-kernel, compiled -> a summary.  ``--px`` (four chips) runs Q1/Q3/Q6 with
-``px_dop = 4`` against the same three run serially, and nothing else.
+a committed write read back before and after a reopen -> a summary.
+``--px`` (four chips) runs Q1/Q3/Q6 with ``px_dop = 4`` against the same
+three run serially, and nothing else.
 
 Every phase prints one JSON line as it ends.  The LAST line is
 ``{"ok": ..., "device": {"platform", "kind", "count"}}`` and the exit
@@ -199,7 +199,6 @@ class Smoke:
         self.types = None            # column -> SqlType, from the generator
         self.reference = None        # the child's answers, once joined
         self._ref_proc = self._ref_queue = None
-        self.q6_sql_raw = None       # newest SQL answer of Q6, scaled int
         self.cache_events = {"hits": 0, "misses": 0}
         self.t_start = time.monotonic()
 
@@ -406,7 +405,6 @@ class Smoke:
             got = int(res.arrays["revenue"][0])
             if got != ref[key]:
                 failed.append(f"q6 exact: {got} != {ref[key]}")
-            self.q6_sql_raw = got
         elif qnum == 1:
             a = res.arrays
             want = ref["q1_exact"]
@@ -493,36 +491,6 @@ class Smoke:
         r, f2 = self._q6_and_readback(ref, "after_reopen")
         rec.update(r)
         return {**rec, "failed": failed + f2}
-
-    def p_pallas(self):
-        import jax
-        import jax.numpy as jnp
-
-        from oceanbase_tpu.datatypes import date_to_days
-        from oceanbase_tpu.ops import q6_filter_sum
-
-        s = self.need_db()
-        rel = s.catalog.table_data("lineitem")
-        c = rel.columns
-        live = rel.mask if rel.mask is not None \
-            else jnp.ones(rel.capacity, dtype=bool)
-        d0, d1 = (date_to_days(d) for d in Q6_DATES)
-        times = []
-        for _ in range(2):
-            t0 = time.monotonic()
-            # never interpreted: off the TPU this phase fails
-            got = int(jax.block_until_ready(q6_filter_sum(
-                c["l_shipdate"].data, c["l_discount"].data,
-                c["l_quantity"].data, c["l_extendedprice"].data, live,
-                ship_lo=d0, ship_hi=d1, disc_lo=5, disc_hi=7, qty_hi=2400,
-                interpret=False)))
-            times.append(round(time.monotonic() - t0, 4))
-        failed = []
-        if got != self.q6_sql_raw:
-            failed.append(f"kernel {got} != SQL {self.q6_sql_raw}")
-        return {"rows": int(rel.capacity), "interpret": False,
-                "first_s": times[0], "second_s": times[1],
-                "equal_to_sql_q6": not failed, "failed": failed}
 
     def p_summary(self):
         import jax
@@ -625,7 +593,6 @@ class Smoke:
         else:
             self.phase("queries", self.p_queries)
             self.phase("writes", self.p_writes)
-            self.phase("pallas", self.p_pallas)
         self.phase("summary", self.p_summary)
 
 

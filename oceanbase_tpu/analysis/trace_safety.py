@@ -36,6 +36,9 @@ from oceanbase_tpu.analysis.core import (
 
 # call names that trace their function argument
 JIT_NAMES = {"jit", "shard_map", "pmap"}
+# ... and those that hand it to the one place that does: a ``Program``'s
+# body is what exec/plan.py's executable traces (serial and PX plans)
+ROOT_NAMES = JIT_NAMES | {"Program"}
 # numpy module aliases whose asarray/array force device->host transfer
 NP_ALIASES = {"np", "numpy"}
 SYNC_BUILTINS = {"int", "float", "bool"}
@@ -153,9 +156,9 @@ class _Index:
         return []
 
 
-def _is_jit_call(call: ast.Call) -> bool:
+def _is_jit_call(call: ast.Call, names=JIT_NAMES) -> bool:
     d = dotted_name(call.func)
-    return d is not None and d.split(".")[-1] in JIT_NAMES
+    return d is not None and d.split(".")[-1] in names
 
 
 def _has_jit_decorator(fnode) -> bool:
@@ -179,7 +182,7 @@ def _traced_roots(idx: _Index) -> set[tuple[str, str]]:
     # functions passed (positionally) to jit/shard_map call sites
     for (path, _qual), info in idx.funcs.items():
         for call in info.calls:
-            if not _is_jit_call(call):
+            if not _is_jit_call(call, ROOT_NAMES):
                 continue
             for a in call.args[:1]:  # the traced callable is arg 0
                 if isinstance(a, ast.Name):

@@ -18,6 +18,8 @@ from __future__ import annotations
 import contextlib
 import contextvars
 
+from oceanbase_tpu.server import metrics as qmetrics
+
 _collector: contextvars.ContextVar[list | None] = contextvars.ContextVar(
     "ob_tpu_diag", default=None
 )
@@ -90,84 +92,50 @@ def monitor_push(op_name: str, count_scalar, est: int | None = None) -> None:
 
 
 # ---------------------------------------------------------------------------
-# join-probe lane: which way each probe of the program ranks its keys
-# (ops._probe_ranges picks from static shapes, so the kinds are a fact of
-# the traced program, known when lowering ends; nothing is traced).
+# note lane: facts of the traced program that an operator picks from
+# static shapes and types (which way a probe ranks its keys, which way a
+# group-by reduces, how a PX join is distributed, an exchange buffer's
+# lanes).  Nothing is traced: the notes are known when lowering ends, the
+# executable keeps their counts per input signature, and every execution
+# adds them to ``gv$sysstat`` (``book_notes``).  A new operator's counter
+# is a ``declare()`` of its series, a row here and a ``note()`` call.
 # ---------------------------------------------------------------------------
 
-_probes: contextvars.ContextVar[list | None] = contextvars.ContextVar(
-    "ob_tpu_probes", default=None
+#: what -> (series, label) the note's value is booked under
+NOTE_SERIES = {
+    "probe": ("plan.join_probes", "kind"),        # merge | search
+    "groupby": ("plan.groupby_reduces", "kind"),  # masked | sort
+    "join": ("px.joins", "dist"),    # partition_wise|broadcast|pkey|hash
+    "lanes": ("px.exchange_lanes", "kind"),  # n = lanes a shard
+}
+
+_notes: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "ob_tpu_notes", default=None
 )
 
 
-def probe_collect():
-    """Activate the lane; yields the list of kinds, in program order."""
-    return _collecting(_probes)
+def note_collect():
+    """Activate the lane; yields the list of (what, value, n), in program
+    order."""
+    return _collecting(_notes)
 
 
-def note_probe(kind: str) -> None:
-    """Record one probe's kind, ``merge`` or ``search`` (no-op outside a
-    collector)."""
-    kinds = _probes.get()
-    if kinds is not None:
-        kinds.append(kind)
-
-
-# ---------------------------------------------------------------------------
-# group-by lane: which way each group-by of the program took
-# (ops.hash_groupby picks from the keys' static types and code space: a
-# fact of the traced program like the probe kinds above).
-# ---------------------------------------------------------------------------
-
-_groupbys: contextvars.ContextVar[list | None] = contextvars.ContextVar(
-    "ob_tpu_groupbys", default=None
-)
-
-
-def groupby_collect():
-    """Activate the lane; yields the list of kinds, in program order."""
-    return _collecting(_groupbys)
-
-
-def note_groupby(kind: str) -> None:
-    """Record one group-by's kind: ``masked`` (dictionary / bool keys, no
-    sort, masked streaming reductions) or ``sort`` (no-op outside a
-    collector)."""
-    kinds = _groupbys.get()
-    if kinds is not None:
-        kinds.append(kind)
-
-
-# ---------------------------------------------------------------------------
-# PX lane: what the distributed lowering decided (a join's distribution
-# method, an exchange buffer's static capacity).  Facts of the traced
-# shard program like the probe kinds above; the executable keeps them and
-# every execution adds them to ``gv$sysstat``.
-# ---------------------------------------------------------------------------
-
-_px_notes: contextvars.ContextVar[list | None] = contextvars.ContextVar(
-    "ob_tpu_px_notes", default=None
-)
-
-
-def px_collect():
-    """Activate the lane; yields the list of (what, value, n): a join by
-    its distribution method, an exchange buffer's lanes by its kind."""
-    return _collecting(_px_notes)
-
-
-def _note_px(what: str, value: str, n: int) -> None:
-    notes = _px_notes.get()
+def note(what: str, value: str, n: int = 1) -> None:
+    """Record one fact of the program being lowered (no-op outside a
+    collector); a ``what`` with no row in ``NOTE_SERIES`` raises here, at
+    trace time, not at the first execution's booking."""
+    if what not in NOTE_SERIES:
+        raise KeyError(f"diag.note: no series for {what!r}")
+    notes = _notes.get()
     if notes is not None:
         notes.append((what, value, n))
 
 
-def note_join(dist: str) -> None:
-    """One join of the program being lowered, by distribution method
-    (no-op outside a collector)."""
-    _note_px("join", dist, 1)
-
-
-def note_lanes(kind: str, lanes: int) -> None:
-    """One exchange buffer of ``lanes`` lanes a shard, by kind."""
-    _note_px("lanes", kind, lanes)
+def book_notes(noted) -> None:
+    """One execution of a program whose trace noted ``noted`` (a Counter
+    of (what, value)): add each to its series."""
+    for (what, value), n in noted.items():
+        series, label = NOTE_SERIES[what]
+        # a name out of the table above, each declare()d where its
+        # operator lives  # obcheck: ok(metric.dynamic-name)
+        qmetrics.inc(series, n, **{label: value})

@@ -336,7 +336,7 @@ def hash_groupby(
             return fast, jnp.zeros((), dtype=jnp.int64)
         return fast
 
-    diag.note_groupby("sort")
+    diag.note("groupby", "sort")
     key_cols = {name: eval_expr(e, rel) for name, e in group_by.items()}
     # canonicalize NULL payloads so all NULLs of a key share one group
     # (GROUP BY treats NULLs as equal; the validity lane separates them
@@ -550,7 +550,7 @@ def _lowcard_groupby(rel, group_by, aggs, out_capacity, n, m):
             continue
         return None  # unsupported agg: caller falls back to sort path
 
-    diag.note_groupby("masked")
+    diag.note("groupby", "masked")
     return Relation(columns=out_cols, mask=occupied)
 
 
@@ -705,7 +705,7 @@ def _probe_ranges(build_sorted: jax.Array, probe_keys: jax.Array,
         work = ln * max(rn - 1, 1).bit_length()
         _path = ("merge" if work > _MERGE_PROBE_MIN_GATHERS
                  and rn + ln < 2 ** 31 else "search")
-    diag.note_probe(_path)
+    diag.note("probe", _path)
     if _path == "search":
         lo = jnp.searchsorted(build_sorted, probe_keys, side="left")
         idx = lax.iota(lo.dtype, rn)

@@ -740,13 +740,18 @@ def prepare_index_probes(catalog, plan: PlanNode,
         qtrace.book_owned("sidecar_build_s", int(dt * 1e9))
 
 
-def _input_signature(tables: dict[str, Relation]) -> tuple:
+def _input_signature(tables: dict[str, Relation],
+                     placed: bool = False) -> tuple:
     """Hashable signature equivalent to jit's dispatch key for a
     {name -> Relation} input: table/column names, leaf shapes + dtypes
     (+ weak_type), validity/mask presence, and the static aux metadata
     (SqlType, content-hashed StringDict).  Two inputs with equal
     signatures lower to the same XLA program; a cheaper hand-rolled walk
-    than ``jax.tree_util.tree_flatten`` + abstractify on the hot path."""
+    than ``jax.tree_util.tree_flatten`` + abstractify on the hot path.
+    ``placed``: the program runs over a mesh, so where a table lies is
+    part of the key too (a compiled executable refuses inputs whose
+    shardings differ from those it was lowered for); one sharding a
+    table, as every PX input is placed whole by one call."""
     parts = []
     for tname in sorted(tables):
         rel = tables[tname]
@@ -762,6 +767,8 @@ def _input_signature(tables: dict[str, Relation]) -> tuple:
                       bool(getattr(d, "weak_type", False)),
                       None if v is None else (v.shape, str(v.dtype)),
                       c.dtype, c.sdict))
+        if placed and cols:
+            p.append(d.sharding)  # the last column's stands for all
         parts.append(tuple(p))
     return tuple(parts)
 
@@ -792,8 +799,37 @@ def _xla_analysis(exe) -> tuple[float, float, int]:
     return flops, nbytes, peak
 
 
+class Program:
+    """What an executable is traced from, and the key it is cached under.
+
+    ``body(*args, tables) -> Relation`` is the function to trace: the
+    serial lowering of a plan (``_lower``), or one shard's half of a PX
+    plan (``px/planner.py``).  ``shard`` = (mesh, axis, table names) wraps
+    the traced function in ``jax.shard_map`` over the mesh, every table
+    on ``P(axis)``, the result relation on ``P(axis)`` and the overflow
+    total ``psum``med over the axis.  ``stats_key`` names the program's
+    ``gv$plan_cache`` row.  Only ``key`` is hashed and compared: the
+    rest rides along to the cache miss that builds the executable."""
+
+    __slots__ = ("body", "args", "key", "stats_key", "shard")
+
+    def __init__(self, body, args: tuple, key, stats_key: str,
+                 shard: tuple | None = None):
+        self.body = body
+        self.args = args
+        self.key = key
+        self.stats_key = stats_key
+        self.shard = shard
+
+    def __hash__(self):
+        return hash(self.key)
+
+    def __eq__(self, other):
+        return isinstance(other, Program) and other.key == self.key
+
+
 class _PlanExecutable:
-    """AOT compile cache for one (plan fingerprint, monitor flag):
+    """AOT compile cache for one (program, monitor flag), serial or PX:
     explicit ``lower().compile()`` per input signature instead of jit's
     implicit dispatch, so every compile event is observed exactly once —
     counted, timed, and cost/memory-attributed — with no second
@@ -802,34 +838,35 @@ class _PlanExecutable:
 
     MAX_SIGNATURES = 64  # >> the bucket-ladder rungs a table ever visits
 
-    __slots__ = ("stats", "diag_names", "monitor_names", "probe_kinds",
-                 "groupby_kinds", "_run", "_execs", "_lock")
+    __slots__ = ("program", "stats", "diag_names", "monitor_names",
+                 "_noted", "_run", "_execs", "_lock")
 
-    def __init__(self, plan: PlanNode, plan_key: str, with_monitor: bool):
-        self.stats = _stats_for(plan_key)
+    def __init__(self, program: Program, with_monitor: bool = False):
+        self.program = program
+        self.stats = _stats_for(program.stats_key)
         self.diag_names: list[str] = []     # filled at trace time
         self.monitor_names: list[str] = []
-        self.probe_kinds: list[str] = []    # filled at trace time
-        self.groupby_kinds: list[str] = []  # filled at trace time
+        self._noted: Counter = Counter()    # the last trace's notes
+        last_noted = self._noted
+        body, args, shard = program.body, program.args, program.shard
+        if with_monitor and shard:
+            # its per-operator counts would be one shard's
+            raise ValueError("a shard program carries no monitor lanes")
         diag_names = self.diag_names
         monitor_names = self.monitor_names
-        probe_kinds = self.probe_kinds
-        groupby_kinds = self.groupby_kinds
 
-        @jax.jit
         def run(tables):
-            with diag.collect() as entries, diag.probe_collect() as kinds, \
-                    diag.groupby_collect() as reduces:
+            with diag.collect() as entries, diag.note_collect() as noted:
                 if with_monitor:
                     with diag.monitor_collect() as mons:
-                        out = _lower(plan, tables)
+                        out = body(*args, tables)
                     monitor_names.clear()
                     # (op name, static est) pairs; only the count lane
                     # is traced
                     monitor_names.extend((n, e) for n, e, _ in mons)
                     mvals = [v for _, _, v in mons]
                 else:
-                    out = _lower(plan, tables)
+                    out = body(*args, tables)
                     mvals = []
                 import jax.numpy as _jnp
 
@@ -843,8 +880,9 @@ class _PlanExecutable:
             diag_names.clear()
             # (lane name, static capacity) pairs for the overflow report
             diag_names.extend((n, cap) for n, _, cap in entries)
-            probe_kinds[:] = kinds
-            groupby_kinds[:] = reduces
+            last_noted.clear()
+            for what, value, n in noted:
+                last_noted[what, value] += n
             # fold the per-operator overflow lanes into ONE scalar on
             # device: the per-execute host check reads a single value
             # instead of syncing once per diagnostic lane (obcheck
@@ -855,15 +893,27 @@ class _PlanExecutable:
             for _n, v, _cap in entries:
                 total = total + jnp.maximum(
                     jnp.asarray(v, dtype=jnp.int64), 0)
-            return out, [v for _, v, _ in entries], total, mon_vec
+            lanes = [v for _, v, _ in entries]
+            if shard is not None:
+                # the total over the mesh decides; a lane's detail would
+                # be one shard's
+                lanes, total = [], jax.lax.psum(total, shard[1])
+            return out, lanes, total, mon_vec
 
+        if shard is not None:
+            from jax.sharding import PartitionSpec as P
+
+            mesh, axis, names = shard
+            run = jax.shard_map(
+                run, mesh=mesh, in_specs=({t: P(axis) for t in names},),
+                out_specs=(P(axis), P(), P(), P()), check_vma=False)
         # only ever driven through .lower()/.compile(): the jit wrapper
         # exists for the lowering machinery (and so obcheck keeps seeing
         # `run` as a traced root), its dispatch cache stays empty
-        self._run = run
-        #: signature -> (compiled executable, flops, bytes, peak,
-        #: (probes by kind, group-bys by kind): the
-        #: shapes of the signature pick each kind)
+        self._run = jax.jit(run)
+        #: signature -> (compiled executable, flops, bytes, peak, notes
+        #: of the trace by (what, value): the shapes of the signature
+        #: pick each)
         self._execs: dict[tuple, tuple] = {}
         self._lock = threading.Lock()
 
@@ -873,7 +923,7 @@ class _PlanExecutable:
         # the time model attributes them separately (lower_s/compile_s)
         # while last_compile_s stays their sum for the existing
         # gv$plan_cache column.  This bracket is the ONE source of both
-        # phases on the serial path: JAX's own compile events inside it
+        # phases, serial and PX: JAX's own compile events inside it
         # count into jax.compile_ns but book nothing (bracketed_compile),
         # and a collector pause inside is gc_s, not lowering.
         acc = _exec_acc()
@@ -884,7 +934,7 @@ class _PlanExecutable:
             t0 = time.perf_counter()
             lowered = self._run.lower(tables)
             lower_s = time.perf_counter() - t0 - (acc.gc_s - g0)
-            noted = (Counter(self.probe_kinds), Counter(self.groupby_kinds))
+            noted = Counter(self._noted)
             exe = lowered.compile()
             flops, nbytes, peak = _xla_analysis(exe)
             csp.tags.update(flops=flops, bytes_accessed=nbytes,
@@ -898,6 +948,9 @@ class _PlanExecutable:
         st.bytes_accessed = nbytes
         st.peak_memory = peak
         qmetrics.inc("plan.compiles")
+        # the statement's wall time holds a compile: the plan-regression
+        # watchdog leaves it out (reset_compile_flag)
+        _exec_flags.compiled = True
         if len(self._execs) >= self.MAX_SIGNATURES:
             self._execs.pop(next(iter(self._execs)))
         entry = (exe, flops, nbytes, peak, noted)
@@ -907,10 +960,10 @@ class _PlanExecutable:
     def call(self, tables):
         """-> ((out, diag_vals, diag_total, mon_vals), compiled_now,
         flops, bytes_accessed, noted) — the cost-analysis pair and the
-        noted kinds' counts are the executed SIGNATURE's, so callers
-        can attribute measured device time to the program that actually
-        ran."""
-        sig = _input_signature(tables)
+        trace's notes are the executed SIGNATURE's, so callers can
+        attribute measured device time to the program that actually
+        ran and book what it noted (``diag.book_notes``)."""
+        sig = _input_signature(tables, self.program.shard is not None)
         entry = self._execs.get(sig)
         compiled_now = False
         if entry is None:
@@ -939,12 +992,6 @@ def compile_flag() -> bool:
     """Did any plan compilation happen on this thread since the last
     reset_compile_flag()?"""
     return bool(getattr(_exec_flags, "compiled", False))
-
-
-def mark_compiled():
-    """For non-execute_plan compile paths (PX shard_map programs) to
-    join the same statement-scoped exclusion."""
-    _exec_flags.compiled = True
 
 
 # ---------------------------------------------------------------------------
@@ -981,10 +1028,11 @@ class ExecTimes:
     the SELF time of the span of that boundary (``trace.PHASE_OF``:
     ``parse`` -> ``parse_s`` ... ``materialize`` -> ``materialize_s``),
     booked when the span closes; ``sidecar_build_s``, and ``lower_s`` /
-    ``compile_s`` on the serial AOT path, are bracketed by their owner;
-    ``trace_s`` / ``cache_lookup_s`` (and ``lower_s`` / ``compile_s``
-    wherever jit dispatch compiles implicitly: the PX program, eager
-    ops) come from JAX's own compile events; ``gc_s`` from the
+    ``compile_s`` of a plan program (serial or PX: the executable's AOT
+    bracket), are bracketed by their owner; ``trace_s`` /
+    ``cache_lookup_s`` (and ``lower_s`` / ``compile_s`` wherever jit
+    dispatch compiles implicitly: eager ops, the chunk programs of the
+    spill tier) come from JAX's own compile events; ``gc_s`` from the
     collector's callbacks.  Whoever books time inside an open span
     charges it to that span as child time, so no second is owned twice
     and ``elapsed_s - queue_s - phase_sum()`` is the unowned rest
@@ -1066,27 +1114,13 @@ def _book_phase(name: str, seconds: float):
 
 
 @functools.lru_cache(maxsize=256)
-def _compiled(plan_key, plan_holder, with_monitor=False):
-    # the stats object rides along with the executable bundle: callers
-    # must count executions on the same one (a fresh _stats_for lookup
-    # could return a new entry after registry eviction and desync the
-    # counters)
-    return _PlanExecutable(plan_holder.plan, plan_key, with_monitor)
-
-
-class _PlanHolder:
-    """Hashable wrapper so lru_cache can key on the fingerprint while the
-    plan object rides along."""
-
-    def __init__(self, plan: PlanNode, key: str):
-        self.plan = plan
-        self.key = key
-
-    def __hash__(self):
-        return hash(self.key)
-
-    def __eq__(self, other):
-        return isinstance(other, _PlanHolder) and other.key == self.key
+def executable_for(program: Program,
+                   with_monitor: bool = False) -> _PlanExecutable:
+    """THE executable cache, serial and PX plans alike (≙ ObPlanCache).
+    The stats object rides along with the executable: callers must count
+    executions on the same one (a fresh _stats_for lookup could return a
+    new entry after registry eviction and desync the counters)."""
+    return _PlanExecutable(program, with_monitor)
 
 
 def execute_plan(plan: PlanNode, tables: dict[str, Relation],
@@ -1136,7 +1170,8 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
                 if isinstance(n, IndexProbe):
                     needed.add(IndexProbe.sidecar_name(n.table, n.index))
                 stack.extend(n.children())
-            bundle = _compiled(key, _PlanHolder(plan, key), with_monitor)
+            bundle = executable_for(Program(_lower, (plan,), key, key),
+                                    with_monitor)
             stats = bundle.stats
             (out, diag_vals, diag_total, mon_vals), compiled_now, flops, \
                 nbytes, noted = bundle.call(
@@ -1188,13 +1223,8 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             acc.calls += 1
             plan_elapsed = dsp.elapsed_s + device_s
             qmetrics.inc("plan.executions", op=root_op)
-            probes, groupbys = noted
-            for kind, n in probes.items():
-                qmetrics.inc("plan.join_probes", n, kind=kind)
-            for kind, n in groupbys.items():
-                qmetrics.inc("plan.groupby_reduces", n, kind=kind)
+            diag.book_notes(noted)
             if compiled_now:
-                _exec_flags.compiled = True
                 tsp.tags["compiled"] = 1
             if with_monitor and monitor_collect:
                 # audited: opt-in plan-monitor collection materializes

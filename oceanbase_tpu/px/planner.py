@@ -55,12 +55,6 @@ on the host; the session's retry loop re-plans with bigger budgets.
 from __future__ import annotations
 
 import dataclasses
-import functools
-from collections import Counter
-
-import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from oceanbase_tpu.exec import diag, ops
 from oceanbase_tpu.exec import plan as pp
@@ -426,11 +420,11 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
                 recv, ovf = all_to_all_repartition(
                     child, list(node.keys.values()), ndev, per_dest,
                     axis)
-                diag.note_lanes("groupby", ndev * per_dest)
+                diag.note("lanes", "groupby", ndev * per_dest)
                 diag.push("px_exchange_overflow", ovf)
             else:
                 recv = broadcast_gather(child, axis)
-                diag.note_lanes("groupby", ndev * child.capacity)
+                diag.note("lanes", "groupby", ndev * child.capacity)
             rel = ops.hash_groupby(recv, node.keys, node.aggs,
                                    out_capacity=local_cap)
             if not node.keys:
@@ -439,7 +433,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
         rel, ovf = dist_groupby_shard(
             child, node.keys, node.aggs, ndev=ndev,
             local_cap=local_cap, out_cap=local_cap, axis_name=axis)
-        diag.note_lanes("groupby", ndev * local_cap)
+        diag.note("lanes", "groupby", ndev * local_cap)
         diag.push("px_exchange_overflow", ovf)
         return rel
     if isinstance(node, pp.HashJoin):
@@ -448,7 +442,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
         if id(node) in lo.elide:
             # both inputs were co-hash-sharded on the join key at granule
             # assignment (choose_affinity): already co-located
-            diag.note_join("partition_wise")
+            diag.note("join", "partition_wise")
             return ops.join(left, right, node.left_keys, node.right_keys,
                             how=node.how,
                             out_capacity=_local_cap(node.out_capacity,
@@ -469,7 +463,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
             partial_specs, final_specs, post = split_aggs(node.aggs)
             part = ops.scalar_agg(child, partial_specs)
             gathered = broadcast_gather(part, axis)
-            diag.note_lanes("datahub", ndev * part.capacity)
+            diag.note("lanes", "datahub", ndev * part.capacity)
             rel = ops.scalar_agg(gathered, final_specs)
             rel = ops.project(rel, dict(post))
         rel._px_replicated = True
@@ -498,7 +492,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
             (child.capacity + ndev - 1) // ndev * 2) * factor
         recv, ovf = all_to_all_repartition(child, keys, ndev, per_dest,
                                            axis)
-        diag.note_lanes("window", ndev * per_dest)
+        diag.note("lanes", "window", ndev * per_dest)
         diag.push("px_exchange_overflow", ovf)
         return exec_window(recv, node.specs)
     if isinstance(node, pp.SemiJoinResidual):
@@ -521,7 +515,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
                 left, node.left_keys, ndev, per_dest, axis)
             rrecv, rov = all_to_all_repartition(
                 right, node.right_keys, ndev, per_dest, axis)
-            diag.note_lanes("hash", 2 * ndev * per_dest)
+            diag.note("lanes", "hash", 2 * ndev * per_dest)
             diag.push("px_exchange_overflow", lov + rov)
             return ops.semi_join_residual(
                 lrecv, rrecv, node.left_keys, node.right_keys,
@@ -530,7 +524,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
         # keyless (pure residual) or small inner: replicate it — the
         # complete candidate set must be visible to every probe row
         bright = broadcast_gather(right, axis)
-        diag.note_lanes("broadcast", ndev * right.capacity)
+        diag.note("lanes", "broadcast", ndev * right.capacity)
         return _placed(ops.semi_join_residual(
             left, bright, node.left_keys, node.right_keys, node.residual,
             anti=node.anti, out_capacity=node.out_capacity), _dist(left))
@@ -586,7 +580,7 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         if how == "full":
             # unmatched-build emission would repeat once per shard
             raise NotDistributable("full join with a replicated build")
-        diag.note_join("broadcast")
+        diag.note("join", "broadcast")
         out = ops.join(left, right, lkeys, rkeys, how=how,
                        out_capacity=cap)
         if lrep:
@@ -600,7 +594,7 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         if how != "inner":
             raise NotDistributable(
                 f"replicated probe side with {how} join")
-        diag.note_join("broadcast")
+        diag.note("join", "broadcast")
         return _placed(ops.join(left, right, lkeys, rkeys, how=how,
                                 out_capacity=cap), _dist(right))
     # where each side lies by (some of) its join keys, as key positions
@@ -611,7 +605,7 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
     if {p for p, _ in lpos} & {p for p, _ in rpos}:
         # PARTITION-WISE: both sides lie by the same join-key pairs under
         # one hash, so matching rows are already on one shard
-        diag.note_join("partition_wise")
+        diag.note("join", "partition_wise")
         out = ops.join(left, right, lkeys, rkeys, how=how,
                        out_capacity=_local_cap(cap, ndev))
         return _placed(out, _dist(left) | _dist(right) if how == "inner"
@@ -631,8 +625,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
             left, right, lkeys, rkeys, ndev=ndev, cap_per_dest=per_dest,
             probe_cap_per_dest=per_dest,
             out_capacity=_local_cap(cap, ndev), how=how, axis_name=axis)
-        diag.note_join("hash")
-        diag.note_lanes("hash", 2 * ndev * per_dest)
+        diag.note("join", "hash")
+        diag.note("lanes", "hash", 2 * ndev * per_dest)
         diag.push("px_exchange_overflow", ovf)
         return out
     if right.capacity * _row_bytes(right) <= BROADCAST_THRESHOLD_BYTES \
@@ -641,8 +635,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         # small build side, keyless, or hash-unsafe key representation:
         # replicate it (BROADCAST dist); the probe rows stay where they lie
         bright = broadcast_gather(right, axis)
-        diag.note_join("broadcast")
-        diag.note_lanes("broadcast", ndev * right.capacity)
+        diag.note("join", "broadcast")
+        diag.note("lanes", "broadcast", ndev * right.capacity)
         return _placed(ops.join(left, bright, lkeys, rkeys, how=how,
                                 out_capacity=cap), _dist(left))
     if lpos or rpos:
@@ -657,8 +651,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
             (moved.capacity + ndev - 1) // ndev * 2) * factor
         recv, ovf = all_to_all_repartition(
             moved, [keys[i] for i in pos], ndev, per_dest, axis)
-        diag.note_join("pkey")
-        diag.note_lanes("pkey", ndev * per_dest)
+        diag.note("join", "pkey")
+        diag.note("lanes", "pkey", ndev * per_dest)
         diag.push("px_exchange_overflow", ovf)
         out = ops.join(recv if move_left else left,
                        right if move_left else recv, lkeys, rkeys,
@@ -697,8 +691,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         left, right, lkeys, rkeys, ndev=ndev, cap_per_dest=per_dest,
         probe_cap_per_dest=l_per_dest,
         out_capacity=_local_cap(cap, ndev), how=how, axis_name=axis)
-    diag.note_join("hash")
-    diag.note_lanes("hash", ndev * (per_dest + l_per_dest))
+    diag.note("join", "hash")
+    diag.note("lanes", "hash", ndev * (per_dest + l_per_dest))
     diag.push("px_exchange_overflow", ovf)
     return out
 
@@ -708,82 +702,33 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
 # ---------------------------------------------------------------------------
 
 
-class _Holder:
-    """Hashable wrapper keying the PX compile cache on the plan
-    fingerprint (≙ exec.plan._PlanHolder)."""
+def _shard_program(droot, partial_specs, dist_sort, lowering, shtables):
+    """One shard's half of a PX plan: the body ``exec/plan.py``'s
+    executable traces under ``jax.shard_map``.  The exchanges push their
+    overflow counts (``diag.push``): the executable sums them over the
+    mesh."""
+    ndev, axis, factor = lowering.ndev, lowering.axis, lowering.factor
+    rel = _dlower(droot, shtables, lowering)
+    if getattr(rel, "_px_replicated", False):
+        # a replicated ROOT would gather ndev duplicate copies (or
+        # ndev-overcounted partials) — run such (tiny, scalar-only) plans
+        # serially instead
+        raise NotDistributable("replicated distributed root")
+    if partial_specs is not None:
+        rel = ops.scalar_agg(rel, partial_specs)
+    if dist_sort is not None:
+        from oceanbase_tpu.px.range_sort import dist_sort_shard
 
-    def __init__(self, droot, partial_specs, elide, dist_sort, declared,
-                 key):
-        self.droot = droot
-        self.partial_specs = partial_specs
-        self.elide = elide
-        self.dist_sort = dist_sort  # (keys tuple, ascending tuple) | None
-        self.declared = declared  # ((table, key cols), ...), in the key
-        self.key = key
-
-    def __hash__(self):
-        return hash(self.key)
-
-    def __eq__(self, other):
-        return isinstance(other, _Holder) and other.key == self.key
-
-
-@functools.lru_cache(maxsize=64)
-def _px_compiled(plan_key, holder, mesh, axis, ndev, factor, table_names):
-    droot = holder.droot
-    partial_specs = holder.partial_specs
-    dist_sort = holder.dist_sort
-    lowering = _Lowering(ndev, axis, factor, holder.elide, holder.declared)
-    # what the shard program's last trace noted: its probes by kind
-    # (exec/plan.py's executable keeps the same per signature), its joins
-    # by distribution method, its exchange buffers' lanes by kind and its
-    # group-bys by kind
-    probes: Counter = Counter()
-    notes: Counter = Counter()
-
-    def shard_body(shtables):
-        with diag.collect() as entries, diag.probe_collect() as kinds, \
-                diag.groupby_collect() as reduces, \
-                diag.px_collect() as noted:
-            rel = _dlower(droot, shtables, lowering)
-            if getattr(rel, "_px_replicated", False):
-                # a replicated ROOT would gather ndev duplicate copies
-                # (or ndev-overcounted partials) — run such (tiny,
-                # scalar-only) plans serially instead
-                raise NotDistributable("replicated distributed root")
-            if partial_specs is not None:
-                rel = ops.scalar_agg(rel, partial_specs)
-            if dist_sort is not None:
-                from oceanbase_tpu.px.range_sort import dist_sort_shard
-
-                keys, asc = dist_sort
-                # per-(sender,dest) budget: local rows average out at
-                # capacity/ndev per destination; skew overflows are
-                # counted and the session retry loop scales ``factor``
-                cap = _snap_budget(
-                    max(rel.capacity * 2 // ndev, 128)) * factor
-                rel, s_ovf = dist_sort_shard(
-                    rel, list(keys), list(asc) if asc else None,
-                    ndev, cap, axis)
-                diag.note_lanes("sort", ndev * cap)
-                diag.push("px_exchange_overflow", s_ovf)
-            total_ovf = jnp.zeros((), dtype=jnp.int64)
-            for _name, v, _cap in entries:
-                total_ovf = total_ovf + jnp.asarray(v, dtype=jnp.int64)
-        probes.clear()
-        probes.update(kinds)
-        notes.clear()
-        for what, value, n in noted:
-            notes[what, value] += n
-        for kind in reduces:
-            notes["groupby", kind] += 1
-        return rel, jax.lax.psum(total_ovf, axis)
-
-    return jax.jit(jax.shard_map(
-        shard_body, mesh=mesh,
-        in_specs=({t: P(axis) for t in table_names},),
-        out_specs=(P(axis), P()), check_vma=False,
-    )), probes, notes
+        keys, asc = dist_sort
+        # per-(sender,dest) budget: local rows average out at
+        # capacity/ndev per destination; skew overflows are counted and
+        # the session retry loop scales ``factor``
+        cap = _snap_budget(max(rel.capacity * 2 // ndev, 128)) * factor
+        rel, s_ovf = dist_sort_shard(
+            rel, list(keys), list(asc) if asc else None, ndev, cap, axis)
+        diag.note("lanes", "sort", ndev * cap)
+        diag.push("px_exchange_overflow", s_ovf)
+    return rel
 
 
 def execute_plan_distributed(plan: pp.PlanNode, tables: dict,
@@ -809,7 +754,7 @@ def execute_plan_distributed(plan: pp.PlanNode, tables: dict,
 
 def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
                          top, scalar_agg, droot) -> Relation:
-    from oceanbase_tpu.exec.plan import add_exec_times, mark_compiled
+    from oceanbase_tpu.exec.plan import add_exec_times
     from oceanbase_tpu.server import trace as qtrace
     from oceanbase_tpu.share.kvcache import relation_bytes
 
@@ -863,23 +808,27 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
         # objects themselves would identity-compare and defeat the
         # executable cache
         aff_key = tuple(sorted((t, tuple(c)) for t, c in affinity.items()))
-        cache_key = (plan.fingerprint(), aff_key, declared)
-        misses0 = _px_compiled.cache_info().misses
-        run, probes, notes = _px_compiled(
-            cache_key,
-            _Holder(droot, partial_specs, elide, dist_sort, declared,
-                    cache_key),
-            mesh, axis, ndev, budget_factor, tuple(sorted(needed)))
-        if _px_compiled.cache_info().misses > misses0:
-            # a fresh shard_map program traces+compiles on first
-            # dispatch (JAX's compile events book trace_s / lower_s /
-            # compile_s / cache_lookup_s and leave this span's self time
-            # the dispatch): mark the statement so the plan-regression
-            # watchdog excludes this compile-inflated latency sample
-            # (exec/plan.py contract)
-            mark_compiled()
+        names = tuple(sorted(needed))
+        fingerprint = plan.fingerprint()
+        exe = pp.executable_for(pp.Program(
+            _shard_program,
+            (droot, partial_specs, dist_sort,
+             _Lowering(ndev, axis, budget_factor, elide, declared)),
+            (fingerprint, aff_key, declared, mesh, axis, ndev,
+             budget_factor, names),
+            # the shard program's gv$plan_cache row, apart from the
+            # serial plan's of the same fingerprint
+            f"px(dop={ndev},factor={budget_factor},by={aff_key + declared})"
+            f" {fingerprint}",
+            shard=(mesh, axis, names)))
+        # a first execution at a signature lowers and compiles inside the
+        # call, as the xla.compile child span (lower_s / compile_s from
+        # its bracket): this span's SELF time stays the dispatch
+        (out, _lanes, overflow, _mon), compiled_now, _flops, _nbytes, \
+            noted = exe.call(sharded)
+        exe.stats.executions += 1
+        if compiled_now:
             psp.tags["compiled"] = 1
-        out, overflow = run(sharded)
     # do NOT sync on the overflow scalar here: an int() at this point
     # parks the host mid-pipeline while the gather/merge/top-chain work
     # below could already be enqueued behind the shard program.  The
@@ -910,15 +859,7 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
         n_over = int(overflow)  # obcheck: ok(trace.host-sync)
     # the legacy aggregate and the launch count, as execute_plan books
     add_exec_times(host_s=psp.self_s, calls=1)
-    for kind, n in probes.items():
-        qmetrics.inc("plan.join_probes", n, kind=kind)
-    for (what, value), n in notes.items():
-        if what == "join":
-            qmetrics.inc("px.joins", n, dist=value)
-        elif what == "groupby":
-            qmetrics.inc("plan.groupby_reduces", n, kind=value)
-        else:
-            qmetrics.inc("px.exchange_lanes", n, kind=value)
+    diag.book_notes(noted)
     if n_over > 0:
         raise diag.CapacityOverflow(
             f"PX exchange overflow: {n_over} rows dropped")

@@ -584,7 +584,7 @@ _CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 class bracketed_compile:
     """``with bracketed_compile():`` — the owner times ``lower()`` /
-    ``compile()`` itself (the serial AOT cache), so JAX's events inside
+    ``compile()`` itself (exec/plan.py's executable), so JAX's events inside
     still count into ``jax.compile_ns`` but book no phase: one source
     per phase."""
 

@@ -477,8 +477,9 @@ class VirtualTables:
         amortizes — and the wall time of the last traced execution.
 
         Entries are PROCESS-wide, mirroring the process-global XLA
-        executable cache they instrument (exec.plan._compiled) — in a
-        multi-tenant process the view spans tenants, like the gv$
+        executable cache they instrument (exec.plan.executable_for:
+        serial plans, and PX shard programs as ``px(dop=...) ...``) — in
+        a multi-tenant process the view spans tenants, like the gv$
         prefix advertises."""
         from oceanbase_tpu.exec.plan import plan_cache_stats
 

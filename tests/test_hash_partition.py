@@ -15,6 +15,7 @@ import pytest
 from oceanbase_tpu.bench.oracle import load_sqlite, rows_match, run_oracle
 from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
 from oceanbase_tpu.bench.tpch_queries import QUERIES
+from oceanbase_tpu.exec.plan import executable_for
 from oceanbase_tpu.px import planner as px_planner
 from oceanbase_tpu.px.exchange import default_mesh
 from oceanbase_tpu.server import Database
@@ -287,7 +288,7 @@ def test_a_large_side_moves_to_the_others_partitions(partitioned, sqlite,
     # nothing is small enough to broadcast: customer lies by the join key
     # already, so orders alone moves, to customer's partitions (PKEY)
     monkeypatch.setattr(px_planner, "BROADCAST_THRESHOLD_BYTES", 0)
-    px_planner._px_compiled.cache_clear()
+    executable_for.cache_clear()
     try:
         _run_px(partitioned, MOVE)
         joins, lanes = _joins(), _lanes()
@@ -301,7 +302,7 @@ def test_a_large_side_moves_to_the_others_partitions(partitioned, sqlite,
         assert rows_match(got, run_oracle(sqlite, QUERIES[3]),
                           ordered=True)[0]
     finally:
-        px_planner._px_compiled.cache_clear()
+        executable_for.cache_clear()
 
 
 def test_q3_takes_one_partition_wise_join_and_one_exchange(partitioned):

@@ -156,12 +156,12 @@ def test_masked_reduce_matches_sort_path(case):
 
     rel, keys = _case(case)
     group_by = {k: ir.col(k) for k in keys}
-    with diag.groupby_collect() as kinds:
+    with diag.note_collect() as notes:
         fast = _groups(_run(rel, group_by, _ALL_AGGS), keys)
-    assert kinds == ["masked"]
-    with diag.groupby_collect() as kinds:
+    assert notes == [("groupby", "masked", 1)]
+    with diag.note_collect() as notes:
         slow = _groups(_run(rel, group_by, _ALL_AGGS, force_sort=True), keys)
-    assert kinds == ["sort"]
+    assert notes == [("groupby", "sort", 1)]
     assert fast.keys() == slow.keys()
     assert (len(fast) == 0) == (case in ("all_dead", "zero_lanes"))
     for key, want in slow.items():
@@ -210,9 +210,9 @@ def test_groupby_kind_follows_the_code_space(monkeypatch):
         k = names[r.integers(0, codes, n)]
         k[:codes] = names
         rel = from_numpy({"k": k, "v": v})
-        with diag.groupby_collect() as kinds:
+        with diag.note_collect() as notes:
             got = _groups(_run(rel, {"k": ir.col("k")}, _ALL_AGGS), ("k",))
-        assert kinds == [want], (codes, kinds)
+        assert notes == [("groupby", want, 1)], (codes, notes)
         slow = _groups(_run(rel, {"k": ir.col("k")}, _ALL_AGGS,
                             force_sort=True), ("k",))
         assert got.keys() == slow.keys() and len(got) == codes

@@ -406,8 +406,7 @@ def _probe_counts():
 @pytest.mark.parametrize("path", ["serial", "px"])
 def test_join_probes_rise_by_the_executables_counts(path, monkeypatch):
     from oceanbase_tpu.exec import ops
-    from oceanbase_tpu.exec.plan import _compiled
-    from oceanbase_tpu.px.planner import _px_compiled
+    from oceanbase_tpu.exec.plan import executable_for
     from oceanbase_tpu.sql import Session
 
     r = np.random.default_rng(5)
@@ -432,8 +431,7 @@ def test_join_probes_rise_by_the_executables_counts(path, monkeypatch):
                                (ops._MERGE_PROBE_MIN_GATHERS, "search",
                                 "merge")):
         monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", floor)
-        _compiled.cache_clear()
-        _px_compiled.cache_clear()
+        executable_for.cache_clear()
         for _ in range(3):
             before = _probe_counts()
             rows = s.execute(sql).rows()
@@ -443,8 +441,7 @@ def test_join_probes_rise_by_the_executables_counts(path, monkeypatch):
             assert after[other] == before[other], (kind, before, after)
             want = want or rows
             assert rows == want
-    _compiled.cache_clear()
-    _px_compiled.cache_clear()
+    executable_for.cache_clear()
 
 
 # ---------------------------------------------------------------------------

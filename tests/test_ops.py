@@ -269,11 +269,11 @@ def test_probe_path_follows_static_shapes():
     rn = 1 << 10
     above = ops._MERGE_PROBE_MIN_GATHERS // 10 + 1
     for ln, want in ((above, "merge"), (above - 2, "search")):
-        with diag.probe_collect() as kinds:
+        with diag.note_collect() as notes:
             jax.eval_shape(ops._probe_ranges,
                            jax.ShapeDtypeStruct((rn,), jnp.int64),
                            jax.ShapeDtypeStruct((ln,), jnp.int64))
-        assert kinds == [want], (ln, kinds)
+        assert notes == [("probe", want, 1)], (ln, notes)
 
 
 def _outer_join_relations(masked):
@@ -328,9 +328,9 @@ def test_join_same_relation_above_and_below_threshold(how, keys,
     for kind, floor in (("search", ops._MERGE_PROBE_MIN_GATHERS),
                         ("merge", 0)):
         monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", floor)
-        with diag.probe_collect() as kinds:
+        with diag.note_collect() as notes:
             rel = join(left, right, lk, rk, how=how, out_capacity=4096)
-        assert kinds == [kind]
+        assert notes == [("probe", kind, 1)]
         got[kind] = _relation_arrays(rel)
     assert sorted(got["merge"]) == sorted(got["search"])
     for name, want in got["search"].items():

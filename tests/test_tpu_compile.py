@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from oceanbase_tpu.datatypes import date_to_days
-
 V5E_HBM_BYTES = 16 * 10**9
 
 
@@ -60,23 +58,6 @@ def _fits(compiled):
     ma = compiled.memory_analysis()
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes) < V5E_HBM_BYTES, ma
-
-
-@pytest.mark.parametrize("rows", [6_002_357, 8_193])
-def test_q6_kernel_compiles_for_v5e(rows, one_chip, no_persistent_cache):
-    """The Pallas Q6 kernel through Mosaic (interpret=False) at TPC-H
-    SF1's lineitem length and at a ragged one."""
-    from oceanbase_tpu.ops import q6_filter_sum
-
-    col = jax.ShapeDtypeStruct((rows,), jnp.int64, sharding=one_chip)
-    live = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
-    compiled = q6_filter_sum.lower(
-        col, col, col, col, live,
-        ship_lo=date_to_days("1994-01-01"),
-        ship_hi=date_to_days("1995-01-01"),
-        disc_lo=5, disc_hi=7, qty_hi=2400, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits(compiled)
 
 
 @pytest.fixture(scope="module")
@@ -188,7 +169,7 @@ def test_px_plan_over_declared_partitions_compiles_for_four_chips(
     is where the program's arguments come from."""
     from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
     from oceanbase_tpu.bench.tpch_queries import QUERIES
-    from oceanbase_tpu.px import planner
+    from oceanbase_tpu.exec import plan as qplan
     from oceanbase_tpu.server import Database
 
     tables, types = gen_tpch(sf=0.01)
@@ -208,33 +189,31 @@ def test_px_plan_over_declared_partitions_compiles_for_four_chips(
             types={k: v for k, v in types.items() if k in tables[name]},
             primary_key=TPCH_PRIMARY_KEYS[name])
     built = []
-    compiled_for = planner._px_compiled
+    call = qplan._PlanExecutable.call
 
-    def spy(*args):
-        run, probes, notes = compiled_for(*args)
+    def spy(self, sharded):
+        if self.program.shard is not None:
+            built.append((self, sharded))
+        return call(self, sharded)
 
-        def call(sharded):
-            built.append((args, sharded, notes))
-            return run(sharded)
-
-        return call, probes, notes
-
-    spy.cache_info = compiled_for.cache_info
-    monkeypatch.setattr(planner, "_px_compiled", spy)
+    monkeypatch.setattr(qplan._PlanExecutable, "call", spy)
     s.execute("set px_dop = 4")
     assert s.execute(QUERIES[3]).rowcount > 0 and s._last_px
     db.close()
-    (key, holder, _mesh, axis, ndev, factor, names), sharded, notes = \
-        built[-1]
-    assert dict(holder.declared) == {"customer": ("c_custkey",),
-                                     "lineitem": ("l_orderkey",),
-                                     "orders": ("o_orderkey",)}
+    exe, sharded = built[-1]
+    program = exe.program
+    _mesh, axis, names = program.shard
+    assert dict(program.args[-1].declared) == {
+        "customer": ("c_custkey",), "lineitem": ("l_orderkey",),
+        "orders": ("o_orderkey",)}
+    (_compiled, _flops, _bytes, _peak, notes), = exe._execs.values()
     assert notes["join", "partition_wise"] == 1
     assert notes["join", "broadcast"] == 1
     assert not any(k == ("lanes", "groupby") for k in notes)
     mesh = Mesh(np.array(topo.devices), (axis,))
-    run, _probes, _notes = compiled_for.__wrapped__(
-        key, holder, mesh, axis, ndev, factor, names)
+    run = qplan._PlanExecutable(qplan.Program(
+        program.body, program.args, "described", "described",
+        shard=(mesh, axis, names)))._run
     on_mesh = NamedSharding(mesh, P(axis))
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_mesh),

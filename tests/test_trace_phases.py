@@ -97,9 +97,9 @@ def test_px_phases_sum_to_elapsed(db):
         s.execute("set px_dop = 1")
     rows = _audit(s, "select b.v, sum(d.w)")
     first, warm = rows[0], rows[1:]
-    # the shard_map program's first dispatch traced, lowered and compiled
-    # inside one jit call: JAX's own events split it
-    for col in ("trace_s", "lower_s", "xla_compile_s"):
+    # the shard program's first execution lowered and compiled inside the
+    # executable's bracket, as a serial plan's does
+    for col in ("lower_s", "xla_compile_s"):
         assert first[col] > 0, col
     assert _owned_share(first) >= 0.90
     for row in warm:
@@ -295,7 +295,8 @@ def test_scopes_change_hlo_metadata_only(db, monkeypatch):
     alone (which the persistent cache leaves out of its key); and the
     plan compiles once."""
     from oceanbase_tpu.exec.plan import (
-        _compiled, _input_signature, _PlanHolder, referenced_tables)
+        Program, _input_signature, _lower, executable_for,
+        referenced_tables)
     from oceanbase_tpu.sql.binder import Binder
     from oceanbase_tpu.sql.parser import parse_sql
 
@@ -306,8 +307,8 @@ def test_scopes_change_hlo_metadata_only(db, monkeypatch):
     sig = _input_signature(tables)
 
     def lowered_text(debug):
-        _compiled.cache_clear()
-        bundle = _compiled(key, _PlanHolder(plan, key), False)
+        executable_for.cache_clear()
+        bundle = executable_for(Program(_lower, (plan,), key, key))
         low = bundle._run.lower(tables)
         return bundle.stats.plan_hash, low.as_text(debug_info=debug)
 
@@ -318,7 +319,7 @@ def test_scopes_change_hlo_metadata_only(db, monkeypatch):
     hash_plain, plain = lowered_text(False)
     _h, plain_debug = lowered_text(True)
     monkeypatch.undo()
-    _compiled.cache_clear()
+    executable_for.cache_clear()
     assert plan.fingerprint() == key and _input_signature(tables) == sig
     assert hash_scoped == hash_plain
     assert scoped == plain
