@@ -335,6 +335,14 @@ class Catalog:
                 raise KeyError(f"unknown table {name}")
             return self._defs[name]
 
+    def scan_lanes(self, name: str) -> int:
+        """The lanes a scan of ``name`` presents to a plan program (its
+        relation's static capacity), from the definition alone: where
+        the planner reads how many lanes a filter chain over the table
+        arrives on.  Loaded relations are not padded here, so it is the
+        loaded row count; the storage catalog pads to its bucket ladder."""
+        return max(int(self.table_def(name).row_count), 1)
+
     def table_data(self, name: str) -> Relation:
         with self._lock:
             t = self._transients.get(name)

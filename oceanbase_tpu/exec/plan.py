@@ -44,6 +44,13 @@ qmetrics.declare("plan.groupby_reduces", "counter",
                  "(kind=masked: dictionary / bool keys, no sort, masked "
                  "streaming reductions; kind=sort: sort + segment "
                  "reduce; ops.hash_groupby picks at trace time)")
+qmetrics.declare("plan.join_inputs", "counter",
+                 "inputs of the joins and index probes executed, by "
+                 "the lanes they arrive on (kind=compacted: densified "
+                 "to the estimate's bucket by a Compact the planner "
+                 "put under the join; kind=whole: on the lanes of what "
+                 "lies under it; sql/optimizer.py::compact_join_input "
+                 "decides at bind time)")
 qmetrics.declare("plan.compiles", "counter",
                  "XLA trace+compile events (per plan x input signature)")
 qmetrics.declare("plan.capacity_retries", "counter",
@@ -565,6 +572,15 @@ def _lower(node: PlanNode, tables: dict[str, Relation],
     return rel
 
 
+def note_join_inputs(node: PlanNode) -> None:
+    """One note per input of a join being lowered (serial or PX): did the
+    planner compact it to its estimate's bucket, or does the join run
+    over the lanes of what lies under it."""
+    for child in node.children():
+        diag.note("join_input", "compacted" if isinstance(child, Compact)
+                  else "whole")
+
+
 def _lower_inner(node: PlanNode, tables: dict[str, Relation]) -> Relation:
     if isinstance(node, TableScan):
         rel = tables[node.table]
@@ -591,6 +607,7 @@ def _lower_inner(node: PlanNode, tables: dict[str, Relation]) -> Relation:
         return ops.scalar_agg(_lower(node.child, tables, node),
                               node.aggs)
     if isinstance(node, HashJoin):
+        note_join_inputs(node)
         return ops.join(
             _lower(node.left, tables, node),
             _lower(node.right, tables, node),
@@ -598,6 +615,7 @@ def _lower_inner(node: PlanNode, tables: dict[str, Relation]) -> Relation:
             out_capacity=node.out_capacity,
         )
     if isinstance(node, IndexProbe):
+        note_join_inputs(node)
         return ops.index_probe(
             _lower(node.child, tables, node),
             tables[IndexProbe.sidecar_name(node.table, node.index)],

@@ -437,6 +437,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
         diag.push("px_exchange_overflow", ovf)
         return rel
     if isinstance(node, pp.HashJoin):
+        pp.note_join_inputs(node)
         left = _dlower(node.left, tables, lo)
         right = _dlower(node.right, tables, lo)
         if id(node) in lo.elide:

@@ -17,6 +17,7 @@ Subquery rewrites implemented (≙ ObTransformerImpl rules):
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 from dataclasses import dataclass, field
 from typing import Optional
@@ -112,6 +113,9 @@ class Fragment:
     hist: dict = field(default_factory=dict)
     # colid -> (mcv values, frequency fractions) from ANALYZE (strings)
     mcv: dict = field(default_factory=dict)
+    # colid -> (lo, hi, selectivity charged): the range bounds the
+    # fragment's filters already priced (_and_selectivity)
+    ranges: dict = field(default_factory=dict)
 
     def __post_init__(self):
         if not self.colids:
@@ -700,7 +704,13 @@ class Binder:
         if sub is not None:
             self._rewrite_subquery_pred(conj, sub, qb, scope)
             return
-        bound = self.bind_expr(conj, scope)
+        self._bind_conjunct_bound(self.bind_expr(conj, scope), qb)
+
+    def _bind_conjunct_bound(self, bound: ir.Expr, qb: QueryBlock):
+        """Place one bound conjunct: an equality between two fragments is
+        a join edge, a predicate over one fragment filters it (and
+        re-prices the fragment's estimate), anything else waits until
+        after the joins."""
         used = {n.name for n in ir.walk(bound) if isinstance(n, ir.ColumnRef)}
         homes = [i for i, f in enumerate(qb.fragments)
                  if used & f.colids]
@@ -717,22 +727,17 @@ class Binder:
             if ru <= ci and lu.isdisjoint(ci):
                 qb.join_edges.append((fj, fi, bound.left, bound.right))
                 return
-        if len(homes) <= 1:
-            if homes:
-                i = homes[0]
-                f = qb.fragments[i]
-                new_est = max(1, int(f.est_rows * _selectivity(
-                    bound, f.hist, f.mcv, f.ndv)))
-                qb.fragments[i] = Fragment(
-                    pp.Filter(f.plan, bound, est_rows=new_est), f.cols,
-                    new_est,
-                    f.unique_cols, colids=f.colids, ndv=f.ndv,
-                    hist=f.hist, mcv=f.mcv,
-                )
-            else:
-                qb.post_preds.append(bound)  # constant predicate
+        if len(homes) != 1:
+            qb.post_preds.append(bound)  # constant, or spans fragments
             return
-        qb.post_preds.append(bound)
+        i = homes[0]
+        f = qb.fragments[i]
+        sel, ranges = _and_selectivity([bound], f.hist, f.mcv, f.ndv,
+                                       f.ranges)
+        new_est = max(1, int(f.est_rows * sel))
+        qb.fragments[i] = dataclasses.replace(
+            f, plan=pp.Filter(f.plan, bound, est_rows=new_est),
+            est_rows=new_est, ranges=ranges)
 
     # ------------------------------------------------------------------
     # subquery rewrites
@@ -1346,40 +1351,123 @@ def _erepr(e) -> str:
     return "(" + "|".join(parts) + ")"
 
 
-def _hist_selectivity(pred: ir.Cmp, hist: dict):
-    """Range selectivity from an equi-height histogram, or None when
-    the predicate/column has no histogram (≙ ObOptSelectivity range
-    selectivity over ObOptColumnStat buckets)."""
-    import numpy as np
+_FLIP = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
 
+
+def _range_bound(pred, hist: dict):
+    """``column <op> literal`` (either way round) over a column with a
+    histogram -> (colid, op, value in the storage domain), the column on
+    the left; None for anything else (=, != keep the NDV-based
+    defaults, strings have no histogram)."""
+    if not isinstance(pred, ir.Cmp):
+        return None
     l, r, op = pred.left, pred.right, pred.op
     if isinstance(l, ir.Literal) and isinstance(r, ir.ColumnRef):
         l, r = r, l
-        op = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}.get(op, op)
+        op = _FLIP.get(op, op)
     if not (isinstance(l, ir.ColumnRef) and isinstance(r, ir.Literal)):
         return None
-    if op not in ("<", "<=", ">", ">="):
-        return None  # =, != keep the NDV-based defaults
+    if op not in _FLIP:
+        return None
     entry = (hist or {}).get(l.name)
     if entry is None:
         return None
-    edges, null_frac, coltype = entry
     try:
         from oceanbase_tpu.expr.compile import literal_value
         from oceanbase_tpu.sql.session import _coerce_value
 
         v, t = literal_value(r)
-        v = _coerce_value(v, t, coltype)
+        v = _coerce_value(v, t, entry[2])
     except Exception:
         return None
     if v is None or isinstance(v, str):
         return None
-    k = len(edges) - 1
+    return l.name, op, v
+
+
+def _floored(frac: float, null_frac: float) -> float:
+    return float(min(max(frac * (1.0 - null_frac), 0.001), 1.0))
+
+
+def _one_sided_selectivity(entry, op: str, v) -> float:
+    """One bound against an equi-height histogram, to the bucket (≙
+    ObOptSelectivity range selectivity over ObOptColumnStat buckets)."""
+    import numpy as np
+
+    edges, null_frac, _coltype = entry
     frac = float(np.searchsorted(
-        edges, v, side="right" if op in ("<=", ">") else "left")) / k
+        edges, v, side="right" if op in ("<=", ">") else "left")) \
+        / (len(edges) - 1)
     if op in (">", ">="):
         frac = 1.0 - frac
-    return float(min(max(frac * (1.0 - null_frac), 0.001), 1.0))
+    return _floored(frac, null_frac)
+
+
+def _hist_cdf(edges, v, inclusive: bool) -> float:
+    """Share of the non-null rows under ``v`` (``<= v`` when
+    ``inclusive``): the bucket from the edges, the place inside it by
+    linear interpolation between the bucket's two edges."""
+    import numpy as np
+
+    k = len(edges) - 1
+    i = int(np.searchsorted(edges, v, side="right" if inclusive else "left"))
+    if i == 0:
+        return 0.0
+    if i > k:
+        return 1.0
+    lo, hi = float(edges[i - 1]), float(edges[i])
+    inside = (float(v) - lo) / (hi - lo) if hi > lo else 0.0
+    return (i - 1 + inside) / k
+
+
+def _interval_selectivity(entry, lo, hi) -> float:
+    """Two bounds on one column are ONE interval, F(hi) - F(lo) (≙ the
+    reference merging a column's range predicates into one query range
+    before it prices them); ``lo`` / ``hi`` are (op, value) or None.  A
+    one-month interval lies inside one of the 64 buckets, so the edges
+    are interpolated.  One bound alone prices to the bucket, as ever."""
+    if lo is None or hi is None:
+        op, v = lo or hi
+        return _one_sided_selectivity(entry, op, v)
+    edges, null_frac, _coltype = entry
+    frac = _hist_cdf(edges, hi[1], hi[0] == "<=") \
+        - _hist_cdf(edges, lo[1], lo[0] == ">")
+    return _floored(max(frac, 0.0), null_frac)
+
+
+def _tighter(old, new, is_lo: bool):
+    """The tighter of two bounds (op, value) on one side of an interval."""
+    if old is None:
+        return new
+    if new[1] == old[1]:
+        return new if new[0] in ("<", ">") else old
+    return new if (new[1] > old[1]) == is_lo else old
+
+
+def _and_selectivity(preds, hist, mcv, ndv, ranges: dict | None = None):
+    """Selectivity of a conjunction, given the range bounds already
+    priced on the same rows: ``ranges`` maps colid -> (lo, hi, the
+    selectivity those bounds were charged).  A further bound on a column
+    re-prices the column's interval and charges the difference, instead
+    of multiplying two dependent conjuncts.  -> (selectivity, ranges
+    with this conjunction's bounds)."""
+    ranges = dict(ranges or {})
+    sel = 1.0
+    for p in preds:
+        rb = _range_bound(p, hist)
+        if rb is None:
+            sel *= _selectivity(p, hist, mcv, ndv)
+            continue
+        col, op, v = rb
+        lo, hi, charged = ranges.get(col, (None, None, 1.0))
+        if op in (">", ">="):
+            lo = _tighter(lo, (op, v), True)
+        else:
+            hi = _tighter(hi, (op, v), False)
+        now = _interval_selectivity(hist[col], lo, hi)
+        sel *= now / charged
+        ranges[col] = (lo, hi, now)
+    return sel, ranges
 
 
 def _mcv_selectivity(col: str, value, op: str, mcv: dict,
@@ -1409,9 +1497,9 @@ def _selectivity(pred: ir.Expr, hist: dict | None = None,
                  mcv: dict | None = None,
                  ndv: dict | None = None) -> float:
     if isinstance(pred, ir.Cmp):
-        hs = _hist_selectivity(pred, hist)
-        if hs is not None:
-            return hs
+        rb = _range_bound(pred, hist)
+        if rb is not None:
+            return _one_sided_selectivity(hist[rb[0]], rb[1], rb[2])
         if pred.op in ("=", "!="):
             l, r = pred.left, pred.right
             if isinstance(l, ir.Literal) and isinstance(r, ir.ColumnRef):
@@ -1431,14 +1519,10 @@ def _selectivity(pred: ir.Expr, hist: dict | None = None,
     if isinstance(pred, ir.Like):
         return 0.1
     if isinstance(pred, ir.Logic):
-        s = 1.0
         if pred.op == "and":
-            for a in pred.args:
-                s *= _selectivity(a, hist, mcv, ndv)
-        else:
-            s = min(1.0, sum(_selectivity(a, hist, mcv, ndv)
-                             for a in pred.args))
-        return s
+            return _and_selectivity(_conjuncts(pred), hist, mcv, ndv)[0]
+        return min(1.0, sum(_selectivity(a, hist, mcv, ndv)
+                            for a in pred.args))
     return 0.5
 
 
@@ -1474,39 +1558,3 @@ def _fold_date_arith(fn: str, base: ir.Expr, n: int, unit: str) -> ir.Expr:
     if unit == "day":
         return ir.Arith("+" if sign > 0 else "-", base, ir.lit(n))
     return ir.FuncCall("add_months", [base, ir.lit(sign * n)])
-
-
-# late-bound helper used by _CorrelationCollector
-def _bind_conjunct_bound(self: Binder, bound: ir.Expr, qb: QueryBlock):
-    used = {n.name for n in ir.walk(bound) if isinstance(n, ir.ColumnRef)}
-    homes = [i for i, f in enumerate(qb.fragments)
-             if used & f.colids]
-    if isinstance(bound, ir.Cmp) and bound.op == "=" and len(homes) == 2:
-        lu = {n.name for n in ir.walk(bound.left)
-              if isinstance(n, ir.ColumnRef)}
-        fi, fj = homes
-        ci = set(qb.fragments[fi].colids)
-        ru = {n.name for n in ir.walk(bound.right)
-              if isinstance(n, ir.ColumnRef)}
-        if lu <= ci and ru.isdisjoint(ci):
-            qb.join_edges.append((fi, fj, bound.left, bound.right))
-            return
-        if ru <= ci and lu.isdisjoint(ci):
-            qb.join_edges.append((fj, fi, bound.left, bound.right))
-            return
-    if len(homes) == 1:
-        i = homes[0]
-        f = qb.fragments[i]
-        new_est = max(1, int(f.est_rows * _selectivity(
-            bound, f.hist, f.mcv, f.ndv)))
-        qb.fragments[i] = Fragment(
-            pp.Filter(f.plan, bound, est_rows=new_est), f.cols,
-            new_est,
-            f.unique_cols, colids=f.colids, ndv=f.ndv, hist=f.hist,
-            mcv=f.mcv,
-        )
-    else:
-        qb.post_preds.append(bound)
-
-
-Binder._bind_conjunct_bound = _bind_conjunct_bound

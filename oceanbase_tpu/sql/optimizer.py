@@ -67,6 +67,12 @@ def _pow2(n: int) -> int:
     return min(p, CAP_MAX)
 
 
+def _bucket(rows: int, slack: float) -> int:
+    """The static budget for ``rows`` estimated or observed rows: the
+    power of two over rows x slack (+16 so that tiny estimates keep room)."""
+    return _pow2(int(min(rows, CAP_MAX) * slack) + 16)
+
+
 # ---------------------------------------------------------------------------
 # cost model: operators priced in predicted seconds
 # ---------------------------------------------------------------------------
@@ -368,6 +374,33 @@ def _frag_scan_chain(plan):
     return None
 
 
+# a join input is compacted when its estimate's bucket is at most this
+# share of the lanes it arrives on: the compaction sorts those lanes
+# once (4 ns a lane), every gather and sort of the join then runs over
+# the bucket
+_COMPACT_LANE_SHARE = 8
+
+
+def compact_join_input(f, catalog, capacity_factor: float = 1.5):
+    """A join pays by the LANES of its inputs (the build side sorts them,
+    the probe ranks and expands them), the cost model prices their
+    estimated ROWS.  Where a filter chain over a scan is estimated to
+    leave at most 1/8 of the scan's lanes, densify it to the estimate's
+    bucket before the join, so that both agree.  ``strict``: a row that
+    does not fit is reported on the ``compact_overflow`` lane and the
+    session re-plans with scaled budgets, as for a join's out_capacity.
+    -> the fragment, compacted or as it was."""
+    chain = _frag_scan_chain(f.plan)
+    if chain is None or not isinstance(f.plan, pp.Filter):
+        return f
+    bucket = _bucket(f.est_rows, capacity_factor)
+    if bucket * _COMPACT_LANE_SHARE > catalog.scan_lanes(chain[0].table):
+        return f
+    return _clone_fragment(
+        f, pp.Compact(f.plan, capacity=bucket, strict=True,
+                      est_rows=f.est_rows), f.est_rows)
+
+
 def _index_for(catalog, table: str, base_col: str):
     """Leading-column secondary index on ``table.base_col`` -> index
     name, or None.  Only int-like columns qualify (the searchsorted
@@ -429,7 +462,7 @@ def _build_plan(item: _Item, frags, edges, model: CostModel, catalog,
                         capacity_factor, stats)
     keys = _edge_keys(edges, li.members, ri.members)
     out_est = item.est
-    cap = _pow2(int(min(out_est, CAP_MAX) * capacity_factor) + 16)
+    cap = _bucket(out_est, capacity_factor)
     ncols = item.ncols
     hash_s = min(model.hash_join_s(li.est, ri.est, out_est, ncols),
                  model.hash_join_s(ri.est, li.est, out_est, ncols))
@@ -461,7 +494,7 @@ def _build_plan(item: _Item, frags, edges, model: CostModel, catalog,
         # variant of the same order becomes the runner-up.
         stats["probe_saving_s"] = (stats.get("probe_saving_s", 0.0)
                                    + (hash_s - inl_s))
-        icap = _pow2(int(min(exp_est, CAP_MAX) * capacity_factor) + 16)
+        icap = _bucket(exp_est, capacity_factor)
         node = pp.IndexProbe(
             probe_p, table=scan.table, index=iname,
             key=oriented[0][0], columns=scan.columns,
@@ -543,6 +576,11 @@ def build_join_tree(qb, catalog, capacity_factor: float = 1.5,
         for c in f.colids:
             colid_frag[c] = i
 
+    if n > 1 or semi_edges:
+        # before any join is priced: the enumeration then prices rows
+        # that the program's lanes match
+        frags = [compact_join_input(f, catalog, capacity_factor)
+                 for f in frags]
     stats: dict = {}
     if n == 1:
         f = frags[0]
@@ -686,15 +724,17 @@ def apply_feedback(plan: pp.PlanNode, corrections: dict,
     CapacityOverflow retry ladder, and its ``est_rows`` is re-seeded to
     the observation so every downstream consumer (spill candidates, px
     budget snapping, the roofline's q-error ledger) prices against
-    measured reality instead of the compounding misestimate.  The
-    op-name check guards against postorder drift (e.g. the fused top-N
-    path).  -> (plan, number of capacities raised)."""
+    measured reality instead of the compounding misestimate.  A Compact
+    (a pass-through with no ledger row) takes the observation of its
+    child.  The op-name check guards against postorder drift (e.g. the
+    fused top-N path).  -> (plan, number of capacities raised)."""
     import dataclasses
 
     from oceanbase_tpu.exec.plan import monitored_op
 
     counter = [0]
     n_fixed = [0]
+    observed: dict = {}  # id(node) -> rows the ledger saw it put out
 
     def walk(node, parent=None):
         kids = {}
@@ -715,15 +755,20 @@ def apply_feedback(plan: pp.PlanNode, corrections: dict,
             hit = corrections.get(counter[0])
             counter[0] += 1
         updates = dict(kids) if changed else {}
-        if hit is not None:
-            op_name, rows = hit
-            if op_name == type(node).__name__ and \
-                    getattr(node, "out_capacity", None) is not None:
-                want = _pow2(int(rows * slack) + 16)
-                if want > node.out_capacity:
-                    updates["out_capacity"] = min(want, CAP_MAX)
-                    updates["est_rows"] = int(rows)
-                    n_fixed[0] += 1
+        rows = budget = None
+        if hit is not None and hit[0] == type(node).__name__:
+            rows, budget = hit[1], "out_capacity"
+            observed[id(node)] = rows
+        elif isinstance(node, pp.Compact):
+            # a compacted join input (compact_join_input) keeps no ledger
+            # row of its own: its budget follows the filter under it
+            rows, budget = observed.get(id(node.child)), "capacity"
+        if rows is not None and getattr(node, budget, None) is not None:
+            want = _bucket(rows, slack)
+            if want > getattr(node, budget):
+                updates[budget] = want
+                updates["est_rows"] = int(rows)
+                n_fixed[0] += 1
         if not updates:
             return node
         return dataclasses.replace(node, **updates)

@@ -1482,6 +1482,16 @@ class StorageCatalog(Catalog):
             return rel
         return rel.pad_to(bucket_capacity(rel.capacity, floor, growth))
 
+    def scan_lanes(self, name: str) -> int:
+        from oceanbase_tpu.vector.column import bucket_capacity
+
+        rows = super().scan_lanes(name)
+        enabled, floor, growth = self._bucket_policy()
+        with self._lock:
+            padded = enabled and name in self.engine.tables \
+                and name not in self._transients
+        return bucket_capacity(rows, floor, growth) if padded else rows
+
     def table_data(self, name):
         if name in self._externals:
             return self._external_data(name)

@@ -60,27 +60,32 @@ def _fits(compiled):
             + ma.temp_size_in_bytes) < V5E_HBM_BYTES, ma
 
 
-@pytest.fixture(scope="module")
-def tpch_session():
+def _tpch_session(names=None, analyze=False):
+    """An SF0.01 load of ``names`` (all eight tables by default)."""
     from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
     from oceanbase_tpu.sql import Session
 
     tables, types = gen_tpch(sf=0.01)
     sess = Session()
-    for name, arrays in tables.items():
+    for name in names or tables:
+        arrays = tables[name]
         sess.catalog.load_numpy(
             name, arrays,
             types={k: v for k, v in types.items() if k in arrays},
             primary_key=TPCH_PRIMARY_KEYS[name])
+        if analyze:
+            sess.execute(f"analyze table {name}")
     return sess
 
 
-@pytest.mark.parametrize("qnum", [1, 6, 3, 14])
-def test_plan_program_compiles_for_v5e(qnum, tpch_session, one_chip,
-                                       no_persistent_cache, monkeypatch):
-    """The plan programs Session.execute builds for a smoke query, at the
-    shapes of an SF0.01 load, accepted by the chip's compiler."""
-    from oceanbase_tpu.bench.tpch_queries import QUERIES
+@pytest.fixture(scope="module")
+def tpch_session():
+    return _tpch_session()
+
+
+def _compile_programs_of(session, sql, one_chip, monkeypatch):
+    """Execute ``sql`` on the CPU, then compile every plan program the
+    execution called, at the shapes it was called with, for the chip."""
     from oceanbase_tpu.exec import plan as qplan
 
     programs = []
@@ -91,13 +96,42 @@ def test_plan_program_compiles_for_v5e(qnum, tpch_session, one_chip,
         return call(self, tables)
 
     monkeypatch.setattr(qplan._PlanExecutable, "call", spy)
-    assert tpch_session.execute(QUERIES[qnum]).rowcount > 0
+    assert session.execute(sql).rowcount > 0
     assert programs
     for run, tables in programs:
         shapes = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=one_chip), tables)
         _fits(run.lower(shapes).compile())
+
+
+@pytest.mark.parametrize("qnum", [1, 6, 3, 14])
+def test_plan_program_compiles_for_v5e(qnum, tpch_session, one_chip,
+                                       no_persistent_cache, monkeypatch):
+    """The plan programs Session.execute builds for a smoke query, at the
+    shapes of an SF0.01 load, accepted by the chip's compiler."""
+    from oceanbase_tpu.bench.tpch_queries import QUERIES
+
+    _compile_programs_of(tpch_session, QUERIES[qnum], one_chip, monkeypatch)
+
+
+def test_compacted_join_input_compiles_for_v5e(one_chip,
+                                               no_persistent_cache,
+                                               monkeypatch):
+    """Q14 as the benchmark runs it, with ANALYZE'd statistics: the
+    filtered ``lineitem`` is compacted to its estimate's bucket under the
+    join (the case above loads without statistics and goes on compiling
+    the uncompacted plan)."""
+    from oceanbase_tpu.bench.tpch_queries import QUERIES
+    from oceanbase_tpu.exec import plan as qplan
+    from oceanbase_tpu.sql.parser import parse_sql
+
+    sess = _tpch_session(("lineitem", "part"), analyze=True)
+    plan, _outs, _est = sess._plan_select(parse_sql(QUERIES[14]), None)
+    compacts = [n for n in qplan._postorder(plan)
+                if isinstance(n, qplan.Compact)]
+    assert len(compacts) == 1 and compacts[0].strict
+    _compile_programs_of(sess, QUERIES[14], one_chip, monkeypatch)
 
 
 @pytest.mark.parametrize("path", ["merge", "search"])
