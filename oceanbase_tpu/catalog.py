@@ -70,6 +70,12 @@ class TableDef:
     # tables share method, partition count and key types, so equal keys
     # lie in equal partitions
     tablegroup: str | None = None
+    # WITH COLUMN GROUP (all columns | each column [, ...]) as the DDL
+    # declared it (≙ OceanBase 4.3's column-group clause).  Both groups
+    # always exist here (the host LSM is the row store, the device
+    # relation the column store), so this records the schema author's
+    # declaration and selects no path
+    column_groups: list | None = None
     auto_increment_cols: list = field(default_factory=list)
     indexes: list = field(default_factory=list)  # list[IndexDef]
     # vector/fulltext indexes: name -> {"kind", "column", "metric"...}

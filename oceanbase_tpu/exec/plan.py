@@ -670,6 +670,84 @@ def referenced_tables(node: PlanNode) -> set[str]:
     return out
 
 
+def _narrows(node: PlanNode) -> bool:
+    """Whether every column of ``node``'s output is named by a node of
+    its subtree (Project, GroupBy, ScalarAgg define their outputs; the
+    rest pass their inputs' columns through)."""
+    if isinstance(node, (Project, GroupBy, ScalarAgg)):
+        return True
+    kids = node.children()
+    return bool(kids) and all(_narrows(c) for c in kids)
+
+
+def _strings(x, out: set) -> None:
+    """Every string anywhere inside a plan: column ids in expressions, key
+    lists and output maps among them.  A scan's ``rename`` is left out:
+    it lists every column whether anything reads it or not."""
+    if isinstance(x, str):
+        out.add(x)
+    elif isinstance(x, dict):
+        for k, v in x.items():
+            _strings(k, out)
+            _strings(v, out)
+    elif isinstance(x, (list, tuple, set, frozenset)):
+        for v in x:
+            _strings(v, out)
+    elif isinstance(x, (TableScan, IndexProbe)):
+        for f, v in vars(x).items():
+            if f != "rename":
+                _strings(v, out)
+    elif hasattr(x, "__dict__"):
+        _strings(vars(x), out)
+    elif hasattr(x, "__slots__"):
+        for f in x.__slots__:
+            _strings(getattr(x, f, None), out)
+
+
+def scan_columns(plan: PlanNode):
+    """Which columns of its tables a plan can reach -> (names mentioned
+    anywhere in the plan, {table: the renames of its scans}); ``None``
+    where some column may pass through to the output unnamed.  A table
+    behind an IndexProbe keeps every column.  The executable is handed
+    the columns this names and no others: what a plan never reads (a
+    string column whose dictionary a commit grew) is no part of its input
+    signature, so it does not compile again."""
+    if not _narrows(plan):
+        return None
+    mentioned: set = set()
+    _strings(plan, mentioned)
+    renames: dict = {}
+    stack = [plan]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, TableScan):
+            got = renames.setdefault(node.table, [])
+            if got is not None and node.columns is None:
+                got.append(node.rename or {})
+            else:
+                renames[node.table] = None
+        elif isinstance(node, IndexProbe):
+            renames[node.table] = None
+        stack.extend(node.children())
+    return mentioned, renames
+
+
+def narrowed(rel: Relation, mentioned: set, renames) -> Relation:
+    """``rel`` with the columns some scan hands on under a mentioned
+    name; one column at least (the lanes' count rides on it)."""
+    if renames is None:
+        return rel
+    keep = {n: c for n, c in rel.columns.items()
+            if any(r.get(n, n) in mentioned for r in renames)}
+    if len(keep) == len(rel.columns):
+        return rel
+    if not keep:
+        n = min(rel.columns, key=lambda n: (rel.columns[n].sdict is not None,
+                                            n))
+        keep = {n: rel.columns[n]}
+    return Relation(columns=keep, mask=rel.mask)
+
+
 def prepare_index_probes(catalog, plan: PlanNode,
                          tables: dict[str, Relation]) -> None:
     """Host-build (and cache) the sorted index sidecar every IndexProbe
@@ -857,10 +935,14 @@ class _PlanExecutable:
     MAX_SIGNATURES = 64  # >> the bucket-ladder rungs a table ever visits
 
     __slots__ = ("program", "stats", "diag_names", "monitor_names",
-                 "_noted", "_run", "_execs", "_lock")
+                 "_noted", "_run", "_execs", "_lock", "scan_columns")
 
     def __init__(self, program: Program, with_monitor: bool = False):
         self.program = program
+        #: a serial plan's ``scan_columns`` (execute_plan narrows its
+        #: tables by it); None: the tables go in whole
+        self.scan_columns = scan_columns(program.args[0]) \
+            if program.body is _lower else None
         self.stats = _stats_for(program.stats_key)
         self.diag_names: list[str] = []     # filled at trace time
         self.monitor_names: list[str] = []
@@ -1069,6 +1151,7 @@ class ExecTimes:
     bind_s = sidecar_build_s = lower_s = compile_s = dispatch_s = 0.0
     merge_s = parse_s = admission_s = virtuals_s = prepare_s = 0.0
     tables_s = device_copy_s = trace_s = cache_lookup_s = shard_s = 0.0
+    delta_apply_s = 0.0
     unshard_s = monitor_s = record_s = materialize_s = gc_s = 0.0
     close_s = 0.0
 
@@ -1191,9 +1274,13 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             bundle = executable_for(Program(_lower, (plan,), key, key),
                                     with_monitor)
             stats = bundle.stats
+            given = {k: v for k, v in tables.items() if k in needed}
+            if bundle.scan_columns is not None:
+                mentioned, renames = bundle.scan_columns
+                given = {k: narrowed(v, mentioned, renames.get(k))
+                         for k, v in given.items()}
             (out, diag_vals, diag_total, mon_vals), compiled_now, flops, \
-                nbytes, noted = bundle.call(
-                    {k: v for k, v in tables.items() if k in needed})
+                nbytes, noted = bundle.call(given)
             stats.executions += 1
         # a first execution at a signature pays lower()+compile() inside
         # the window above as the xla.compile child span: the dispatch

@@ -239,6 +239,16 @@ def civil_from_days(z):
 # main evaluator
 # ---------------------------------------------------------------------------
 
+def _in_operand(vals: list) -> list:
+    """An IN list's values at a ladder length (the last one repeated): the
+    membership test is a program per (lanes, list length), and a DELETE or
+    UPDATE ... WHERE key IN (...) evaluates it eagerly, statement after
+    statement with lists of any length."""
+    from oceanbase_tpu.vector import bucket_capacity
+
+    return vals + [vals[-1]] * (bucket_capacity(len(vals)) - len(vals))
+
+
 def eval_expr(e: ir.Expr, rel: Relation) -> Column:
     n = rel.capacity
 
@@ -287,7 +297,8 @@ def eval_expr(e: ir.Expr, rel: Relation) -> Column:
             if not codes:
                 val = jnp.zeros(n, dtype=jnp.bool_)
             else:
-                val = jnp.isin(c.data, jnp.asarray(codes, dtype=c.data.dtype))
+                val = jnp.isin(c.data, jnp.asarray(_in_operand(codes),
+                                                   dtype=c.data.dtype))
         else:
             vals = []
             for v in e.values:
@@ -312,7 +323,7 @@ def eval_expr(e: ir.Expr, rel: Relation) -> Column:
             if not vals:
                 val = jnp.zeros(n, dtype=jnp.bool_)
             else:
-                val = jnp.isin(c.data, jnp.asarray(vals))
+                val = jnp.isin(c.data, jnp.asarray(_in_operand(vals)))
         if e.negated:
             val = ~val
         return Column(data=val, valid=c.valid, dtype=SqlType.bool_())

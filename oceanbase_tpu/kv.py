@@ -51,7 +51,6 @@ class KvTable:
             raise
         if own:
             svc.commit(tx)
-        self.tenant.catalog.invalidate(self.table)
 
     def get(self, key, columns: Optional[list] = None,
             snapshot: int | None = None, tx_id: int = 0) -> Optional[dict]:
@@ -93,7 +92,6 @@ class KvTable:
             raise
         if own:
             svc.commit(tx)
-        self.tenant.catalog.invalidate(self.table)
         return True
 
     def scan(self, limit: int | None = None, snapshot: int | None = None):

@@ -192,7 +192,6 @@ class Tenant:
         try:
             self.engine.freeze_and_flush(
                 table, snapshot=self.tx.flush_snapshot())
-            self.catalog.invalidate(table)
         except KeyError:
             self.throttle.drop_table(table)  # dropped mid-pressure
 

@@ -80,6 +80,8 @@ PHASE_OF = {
     "plan.prepare": "prepare_s",
     "tables": "tables_s",
     "storage.device_copy": "device_copy_s",
+    "storage.delta_apply": "delta_apply_s",
+    "storage.delta_read": "delta_apply_s",
     "plan.dispatch": "dispatch_s",
     "plan.device_wait": "device_s",
     "plan.monitor": "monitor_s",
@@ -100,7 +102,8 @@ PHASE_OF = {
 #: closed, does not), so with ``queue_s`` and ``device_s`` they sum to it
 #: up to ``other_s``
 PHASES = ("parse_s", "admission_s", "virtuals_s", "bind_s", "prepare_s",
-          "tables_s", "device_copy_s", "sidecar_build_s", "trace_s",
+          "tables_s", "device_copy_s", "delta_apply_s", "sidecar_build_s",
+          "trace_s",
           "lower_s", "compile_s", "cache_lookup_s", "dispatch_s",
           "shard_s", "unshard_s", "merge_s", "monitor_s", "record_s",
           "materialize_s", "gc_s")

@@ -137,6 +137,8 @@ class CreateTableStmt:
     # (method, [cols], n) or None
     hash_partition: tuple | None = None
     tablegroup: str | None = None  # TABLEGROUP = name
+    # WITH COLUMN GROUP (...): ["all columns", "each column"] as declared
+    column_groups: list | None = None
 
 
 @dataclass

@@ -1390,13 +1390,15 @@ class Parser:
             if not self.accept_op(","):
                 break
         self.expect_op(")")
-        # table options, in either order: TABLEGROUP [=] name and one
-        # PARTITION BY clause
-        tablegroup = partition = hash_partition = None
+        # table options, in any order: TABLEGROUP [=] name, one
+        # PARTITION BY clause, one WITH COLUMN GROUP clause
+        tablegroup = partition = hash_partition = column_groups = None
         while True:
             if tablegroup is None and self._accept_word("tablegroup"):
                 self.accept_op("=")
                 tablegroup = self.expect_ident()
+            elif column_groups is None and self._accept_word("with"):
+                column_groups = self._parse_column_groups()
             elif partition is None and hash_partition is None and \
                     self.accept_kw("partition"):
                 self.expect_kw("by")
@@ -1414,8 +1416,31 @@ class Parser:
                                    partition)
         stmt.hash_partition = hash_partition
         stmt.tablegroup = tablegroup
+        stmt.column_groups = column_groups
         stmt.indexes = inline_indexes
         return stmt
+
+    def _parse_column_groups(self):
+        """``COLUMN GROUP (ALL COLUMNS | EACH COLUMN [, ...])`` (after
+        WITH) -> the groups declared, in order, each once."""
+        if not (self._accept_word("column") and self._accept_word("group")):
+            raise ParseError("expected WITH COLUMN GROUP (...)")
+        self.expect_op("(")
+        groups = []
+        while True:
+            first = self.next().value
+            second = self.next().value
+            spec = {("all", "columns"): "all columns",
+                    ("each", "column"): "each column"}.get((first, second))
+            if spec is None or spec in groups:
+                raise ParseError("a column group is ALL COLUMNS or EACH "
+                                 f"COLUMN, each once; not {first!r} "
+                                 f"{second!r}")
+            groups.append(spec)
+            if not self.accept_op(","):
+                break
+        self.expect_op(")")
+        return groups
 
     def _parse_range_partition(self):
         """``(col) (PARTITION p VALUES LESS THAN (n) | MAXVALUE, ...)`` ->

@@ -579,6 +579,9 @@ class Session:
                           f"MAXVALUE")
                 text += (f" PARTITION BY RANGE ({pcol}) (" +
                          ", ".join(ps) + ")")
+            if td.column_groups:
+                text += (" WITH COLUMN GROUP ("
+                         + ", ".join(td.column_groups) + ")")
             return Result(
                 ["table", "create_table"],
                 {"table": np.array([td.name], dtype=object),
@@ -752,7 +755,6 @@ class Session:
             eng.freeze_and_flush(name, snapshot=snap)
             if stmt.action == "major_freeze":
                 eng.major_compact(name)
-            self.catalog.invalidate(name)
         return _ok()
 
     def _load_data(self, stmt: ast.LoadDataStmt) -> Result:
@@ -972,7 +974,6 @@ class Session:
             # horizon-clamped: see _alter_system major_freeze
             self._engine.freeze_and_flush(
                 table, snapshot=self._txsvc.flush_snapshot())
-            self.catalog.invalidate(table)
             l0 = sum(1 for s in ts.tablet.segments if s.level == 0)
             if l0 >= int(self.tenant.config["minor_compact_trigger"]):
                 self._engine.minor_compact(table)
@@ -1788,11 +1789,23 @@ class Session:
         from oceanbase_tpu.vector import bucket_capacity
 
         n = len(next(iter(arrays.values()))) if arrays else 0
+        # padded on the HOST: a device-side pad is one small program per
+        # (row count, pad) pair, i.e. per statement
+        pad = bucket_capacity(n) - n
+
+        def padded(a, fill):
+            a = np.asarray(a)
+            tail = np.full((pad,) + a.shape[1:], fill, dtype=a.dtype)
+            return np.concatenate([a, tail])
+
         rel = from_numpy(
-            arrays,
+            {c: padded(a, "" if np.asarray(a).dtype.kind in "OUS" else 0)
+             for c, a in arrays.items()},
             types={c.name: c.dtype for c in ts.tdef.columns},
-            valids={k: v for k, v in valids.items() if v is not None})
-        return rel.pad_to(bucket_capacity(n))
+            valids={k: padded(v, False) for k, v in valids.items()
+                    if v is not None})
+        return Relation(columns=rel.columns,
+                        mask=jnp.asarray(np.arange(n + pad) < n))
 
     def _px_dop(self) -> int:
         """Effective degree of parallelism.  A session px_dop wins over the
@@ -2281,6 +2294,7 @@ class Session:
                         partition=getattr(stmt, "partition", None),
                         hash_partition=stmt.hash_partition,
                         tablegroup=stmt.tablegroup,
+                        column_groups=stmt.column_groups,
                         auto_increment_cols=auto_cols)
         if getattr(stmt, "indexes", None) and self.db is None:
             # capability check BEFORE create_table: a failure must not
@@ -2501,8 +2515,6 @@ class Session:
         # savepoints created after this one are destroyed (MySQL)
         tx.savepoints = {n: v for n, v in tx.savepoints.items()
                          if v[0] <= sp_seq}
-        for t in stmt_writes:
-            self.catalog.invalidate(t)
         return _ok()
 
     # ------------------------------------------------------------------
@@ -2561,8 +2573,6 @@ class Session:
         else:
             self._txsvc.xa_rollback_prepared(tx)
         store.pop(stmt.xid, None)
-        for t in list(tx.participants):
-            self.catalog.invalidate(t)
         return _ok()
 
     # ------------------------------------------------------------------
@@ -2855,7 +2865,6 @@ class Session:
                                   values)
 
         self._run_in_tx(op)
-        self.catalog.invalidate(stmt.table)
         # keep the binder's est_rows current: a plan bound while the
         # table looked empty would budget capacities for one row and
         # ride the CapacityOverflow retry ladder on every execution
@@ -3050,7 +3059,6 @@ class Session:
                                   values)
 
         self._run_in_tx(op, tx_hint=tx_hint)
-        self.catalog.invalidate(stmt.table)
         self._maybe_freeze(stmt.table)
         return _ok(rowcount=n_upd)
 
@@ -3090,7 +3098,6 @@ class Session:
                                   values)
 
         self._run_in_tx(op, tx_hint=tx_hint)
-        self.catalog.invalidate(stmt.table)
         self._maybe_freeze(stmt.table)
         return _ok(rowcount=n_del)
 

@@ -31,6 +31,12 @@ from oceanbase_tpu.catalog import Catalog, ColumnDef, TableDef
 from oceanbase_tpu.datatypes import SqlType, TypeKind
 from oceanbase_tpu.server import metrics as qmetrics
 from oceanbase_tpu.server import trace as qtrace
+from oceanbase_tpu.storage.device_delta import (
+    DeviceCopy,
+    PadExhausted,
+    apply_delta,
+    delta_bytes,
+)
 from oceanbase_tpu.storage.segment import Segment
 from oceanbase_tpu.storage.tablet import Tablet
 
@@ -40,6 +46,21 @@ qmetrics.declare("storage.device_copy_builds", "counter",
 qmetrics.declare("storage.device_copy_ns", "counter",
                  "time spent building device relations: snapshot "
                  "decode + host->device copy + bucket padding", unit="ns")
+qmetrics.declare("storage.device_copy_fallbacks", "counter",
+                 "device relations built whole where no delta could bring "
+                 "a cached one up, by {reason}: no_entry (first read, "
+                 "reopen, eviction), pad_exhausted, delta_unavailable "
+                 "(the baseline was rewritten, the schema changed, the "
+                 "commit log no longer reaches back), partitioned")
+qmetrics.declare("storage.delta_applies", "counter",
+                 "cached device relations brought up to the newest commit "
+                 "by applying the committed delta (no rebuild)")
+qmetrics.declare("storage.delta_apply_ns", "counter",
+                 "time spent reading committed deltas and applying them "
+                 "to cached device relations", unit="ns")
+qmetrics.declare("storage.delta_rows", "counter",
+                 "rows a delta apply wrote into pad lanes {op=insert} and "
+                 "lanes it cleared {op=delete}; an update is one of each")
 
 log = logging.getLogger("oceanbase_tpu.storage.engine")
 
@@ -58,12 +79,15 @@ class TableStore:
 
 
 def _layout_record(tdef: TableDef) -> dict:
-    """A table's hash partitioning and tablegroup as the manifest and the
-    slog keep them (absent keys read back as None: older files)."""
+    """A table's hash partitioning, tablegroup and declared column groups
+    as the manifest and the slog keep them (absent keys read back as
+    None: older files)."""
     hp = tdef.hash_partition
     return {"hash_partition": [hp[0], list(hp[1]), int(hp[2])] if hp
             else None,
-            "tablegroup": tdef.tablegroup}
+            "tablegroup": tdef.tablegroup,
+            "column_groups": list(tdef.column_groups)
+            if tdef.column_groups else None}
 
 
 def _layout_of(rec: dict) -> dict:
@@ -71,7 +95,8 @@ def _layout_of(rec: dict) -> dict:
     hp = rec.get("hash_partition")
     return {"hash_partition": (hp[0], list(hp[1]), int(hp[2])) if hp
             else None,
-            "tablegroup": rec.get("tablegroup")}
+            "tablegroup": rec.get("tablegroup"),
+            "column_groups": rec.get("column_groups")}
 
 
 def load_manifest(path: str) -> dict:
@@ -751,7 +776,7 @@ class StorageEngine:
             else:
                 raise ValueError(action)
             for t in tablets:
-                t.data_version += 1
+                t.rebase()
 
     # ------------------------------------------------------------------
     # secondary indexes (≙ index tables, src/share/schema index DDL +
@@ -967,7 +992,7 @@ class StorageEngine:
             for t in getattr(tab, "partitions", [tab]):
                 t.active = MemTable(next(t._next_mt))
                 t.frozen = []
-                t.data_version += 1
+                t.rebase()
 
     def drop_table(self, name: str):
         with self._lock:
@@ -1502,12 +1527,25 @@ class StorageCatalog(Catalog):
             ts = self.engine.tables.get(name)
             if ts is None:
                 raise KeyError(f"table {name} has no data")
-            ver = ts.tablet.data_version
+            tablet = ts.tablet
+            ver = tablet.data_version
             hit = self._cache.get(name)
-            if hit is not None and hit[0] == ver:
-                return hit[1]
+            if hit is not None and hit.tablet is not tablet:
+                hit = None      # TRUNCATE installed a new tablet
+            if hit is not None and hit.version == ver:
+                return hit.rel
             snap = self.snapshot_fn()
-            rel, n = self._device_copy(ts, snap, 0)
+            copy, reason = None, "no_entry"
+            if hit is not None:
+                copy, reason = self._delta_copy(ts, hit, ver, snap)
+            if copy is None:
+                qmetrics.inc("storage.device_copy_fallbacks", reason=reason)
+                # the mark BEFORE the read: a commit landing during it is
+                # listed again by the next delta, never lost
+                mark = tablet.delta_mark() \
+                    if isinstance(tablet, Tablet) else None
+                rel, n = self._device_copy(ts, snap, 0)
+                copy = DeviceCopy(ver, rel, tablet, snap, mark, n, n)
             # only cache snapshots that cover every persisted segment —
             # a snapshot below a segment's max_version would pin a
             # partial view that later (larger) snapshots must not reuse.
@@ -1515,18 +1553,51 @@ class StorageCatalog(Catalog):
             # snapshot read inside the bucket (table_data_at included)
             # reuses one HBM-resident copy AND one compiled shape.
             seg_max = max((s.max_version
-                           for s, _ in ts.tablet.segment_locations()),
+                           for s, _ in tablet.segment_locations()),
                           default=0)
             if snap >= seg_max:
                 from oceanbase_tpu.share.kvcache import relation_bytes
 
-                self._cache.put(name, (ver, rel),
-                                nbytes=relation_bytes(rel))
+                self._cache.put(name, copy,
+                                nbytes=relation_bytes(copy.rel))
             # record the LIVE row count, not the padded capacity: the
             # binder's est_rows drives join/groupby capacity budgets and
             # spill decisions, which must not drift with pad lanes
-            ts.tdef.row_count = n
-            return rel
+            ts.tdef.row_count = copy.live
+            return copy.rel
+
+    def _delta_copy(self, ts, hit, ver: int, snap: int):
+        """The cached copy ``hit`` brought up to ``snap`` by what was
+        committed since it was built -> (DeviceCopy, None), or (None,
+        reason) where only a rebuild will do.  Timed as the span
+        ``storage.delta_apply`` (the statement's ``delta_apply_s``), the
+        host's read of the delta as its child ``storage.delta_read``."""
+        if not isinstance(ts.tablet, Tablet) or hit.mark is None:
+            # per-partition copies keep their rebuild (ROADMAP S4, second
+            # half)
+            return None, "partitioned"
+        with qtrace.span("storage.delta_apply", table=ts.tdef.name) as sp:
+            with qtrace.span("storage.delta_read"):
+                delta = ts.tablet.delta_since(hit.mark, hit.snapshot, snap)
+            if delta is None or set(delta.arrays) != set(hit.rel.columns):
+                return None, "delta_unavailable"
+            try:
+                rel, high, live, n_rows, n_cleared = apply_delta(
+                    hit, delta, ts.tablet.key_cols)
+            except PadExhausted:
+                return None, "pad_exhausted"
+            except Exception:  # noqa: BLE001 — the rebuild is always right
+                log.warning("delta apply of %s failed; rebuilding",
+                            ts.tdef.name, exc_info=True)
+                return None, "delta_unavailable"
+            sp.tags.update(rows_inserted=n_rows, lanes_cleared=n_cleared,
+                           bytes=delta_bytes(rel, n_rows, n_cleared))
+        qmetrics.inc("storage.delta_applies")
+        qmetrics.inc("storage.delta_apply_ns", int(sp.elapsed_s * 1e9))
+        qmetrics.inc("storage.delta_rows", n_rows, op="insert")
+        qmetrics.inc("storage.delta_rows", n_cleared, op="delete")
+        return DeviceCopy(ver, rel, ts.tablet, max(snap, hit.snapshot),
+                          delta.mark, high, live, hit.index), None
 
     def table_data_at(self, name, snapshot: int, tx_id: int = 0):
         """Snapshot read at an explicit version (+ own-tx writes) — the

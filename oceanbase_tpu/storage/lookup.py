@@ -56,7 +56,9 @@ def estimate_rows_in_ranges(tablet, ranges: dict) -> int:
                 continue
             any_col = next(iter(seg.columns.values()))
             total += sum(any_col[i].n for i in np.nonzero(cm)[0])
-        total += len(t.active) + sum(len(m) for m in t.frozen)
+        within = t.key_ranges(sub)
+        total += sum(len(m.keys_within(within)) if within else len(m)
+                     for m in [t.active] + t.frozen)
     return total
 
 

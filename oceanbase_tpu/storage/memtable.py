@@ -40,6 +40,11 @@ class MemTable:
     def __init__(self, mt_id: int = 0):
         self.mt_id = mt_id
         self._rows: dict[tuple, Version] = {}
+        # first key part -> the keys that start with it: a read by a narrow
+        # range of the leading key column (DELETE ... WHERE key IN (...))
+        # looks its keys up instead of walking every row written since the
+        # last freeze
+        self._by_first: dict = {}
         self._lock = threading.RLock()
         self.frozen = False
         self.min_version = 2**63
@@ -78,8 +83,16 @@ class MemTable:
                     raise DuplicateKey(f"duplicate key {key}")
             v = Version(0, tx_id, op, dict(values), prev=head,
                         stmt_seq=stmt_seq)
-            self._rows[key] = v
+            self.adopt(key, v)
             return v
+
+    def adopt(self, key: tuple, head: Version):
+        """``head`` becomes ``key``'s chain (a write, or a chain a flush
+        carried over)."""
+        with self._lock:
+            if key not in self._rows:
+                self._by_first.setdefault(key[0], []).append(key)
+            self._rows[key] = head
 
     def commit(self, tx_id: int, commit_version: int, keys):
         with self._lock:
@@ -102,10 +115,13 @@ class MemTable:
                 while head is not None and head.commit_version == 0 and \
                         head.tx_id == tx_id and head.stmt_seq >= min_stmt_seq:
                     head = head.prev
-                if head is None:
-                    self._rows.pop(key, None)
-                else:
+                if head is not None:
                     self._rows[key] = head
+                elif self._rows.pop(key, None) is not None:
+                    group = self._by_first[key[0]]
+                    group.remove(key)
+                    if not group:
+                        del self._by_first[key[0]]
 
     # ------------------------------------------------------------------
     # read path
@@ -122,11 +138,33 @@ class MemTable:
             v = v.prev
         return None
 
-    def snapshot_rows(self, snapshot: int, tx_id: int = 0) -> dict:
-        """-> {key: Version} of all visible versions at ``snapshot``."""
+    def keys_within(self, within) -> list:
+        """The keys whose parts lie in ``within``: [(position in the key,
+        lo | None, hi | None)], inclusive; every key when it is empty."""
+        with self._lock:
+            span = next(((lo, hi) for i, lo, hi in within or ()
+                         if i == 0 and isinstance(lo, int)
+                         and isinstance(hi, int)), None)
+            if span is not None and span[1] - span[0] < len(self._by_first):
+                keys = [k for first in range(span[0], span[1] + 1)
+                        for k in self._by_first.get(first, ())]
+            else:
+                keys = list(self._rows)
+        for i, lo, hi in within or ():
+            # a NULL key part (an index entry's) lies in no range
+            if lo is not None:
+                keys = [k for k in keys if k[i] is not None and k[i] >= lo]
+            if hi is not None:
+                keys = [k for k in keys if k[i] is not None and k[i] <= hi]
+        return keys
+
+    def snapshot_rows(self, snapshot: int, tx_id: int = 0,
+                      within=None) -> dict:
+        """-> {key: Version} of all visible versions at ``snapshot``,
+        of the keys ``within`` (``keys_within``) when given."""
         out = {}
         with self._lock:
-            for key in self._rows:
+            for key in self.keys_within(within):
                 v = self.visible_version(key, snapshot, tx_id)
                 if v is not None:
                     out[key] = v

@@ -60,6 +60,7 @@ class PartitionedTablet:
         shared = SegIdAlloc(1)
         for p in self.partitions:
             p._next_seg = shared
+            p.logs_commits = False
         self._lock = threading.RLock()
 
     # ------------------------------------------------------------------

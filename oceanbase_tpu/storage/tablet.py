@@ -42,6 +42,23 @@ class SegIdAlloc:
         self.n = max(self.n, int(seg_id) + 1)
 
 
+#: keys the commit log holds before it drops its oldest entries (a device
+#: copy further behind than the log reaches is rebuilt)
+COMMIT_LOG_KEYS = 4_000_000
+
+
+@dataclass
+class TabletDelta:
+    """What was committed to a tablet since a mark: the newest committed
+    version of every key touched, as ``delta_since`` found it."""
+
+    keys: list                    # every key touched (deleted or written)
+    row_keys: list                # the keys whose newest version is live
+    arrays: dict                  # column -> values of those rows
+    valids: dict                  # column -> validity, None = all valid
+    mark: tuple                   # the mark a copy holds once applied
+
+
 class Tablet:
     def __init__(self, tablet_id: int, columns: list[str],
                  types: dict[str, SqlType], key_cols: list[str]):
@@ -57,6 +74,18 @@ class Tablet:
         self._lock = threading.RLock()
         self._auto_key = itertools.count()  # rowid for keyless tables
         self.data_version = 0               # bumps on any visible change
+        # what delta_since answers from: every commit applied here, in
+        # the order applied, as (sequence, commit version, keys)
+        # (a PartitionedTablet turns it off for its partitions: their
+        # copies are rebuilt, and every partition is told every key)
+        self.logs_commits = True
+        self._commit_log: list[tuple] = []
+        self._commit_seq = 0
+        self._log_keys = 0
+        self._log_floor = (0, 0)    # newest (sequence, version) dropped
+        # bumps whenever segments are rewritten or the schema changes:
+        # a copy built before then cannot be brought up by a delta
+        self.baseline_epoch = 0
 
     # ------------------------------------------------------------------
     def make_key(self, values: dict) -> tuple:
@@ -117,6 +146,111 @@ class Tablet:
             for mt in self.frozen:
                 mt.commit(tx_id, commit_version, keys)
             self.data_version += 1
+            if self.logs_commits:
+                self._log_commit(commit_version, tuple(keys))
+
+    def _log_commit(self, commit_version: int, keys: tuple):
+        with self._lock:
+            self._commit_seq += 1
+            self._commit_log.append((self._commit_seq, commit_version, keys))
+            self._log_keys += len(keys)
+            while self._log_keys > COMMIT_LOG_KEYS and \
+                    len(self._commit_log) > 1:
+                seq, version, dropped = self._commit_log.pop(0)
+                self._log_keys -= len(dropped)
+                self._log_floor = (seq, max(self._log_floor[1], version))
+
+    def rebase(self):
+        """The baseline was rewritten (compaction above L0, a segment
+        installed or removed, a schema change, the memtables reset): a
+        visible change no delta describes."""
+        self.data_version += 1
+        self.baseline_epoch += 1
+
+    # ------------------------------------------------------------------
+    # committed deltas (the device copy's maintenance reads these)
+    # ------------------------------------------------------------------
+    def delta_mark(self) -> tuple:
+        """Where a copy built from a snapshot read that STARTS now stands:
+        (baseline epoch, commit-log sequence).  Taken before the read, so
+        a commit that lands during it is listed again, never lost."""
+        with self._lock:
+            return (self.baseline_epoch, self._commit_seq)
+
+    def delta_since(self, mark: tuple, after: int, upto: int):
+        """The newest committed version <= ``upto`` of every key whose
+        commit was applied after ``mark`` or carries a version above
+        ``after`` -> ``TabletDelta``; ``None`` where that cannot be
+        answered exactly (the baseline was rewritten, or the log no
+        longer reaches back to the mark): the caller rebuilds.
+
+        Reads the memtables (active and frozen) and, for a key a freeze
+        and mini-compaction moved meanwhile, the L0 segments that hold
+        versions as new as the commits asked for."""
+        epoch, seq = mark
+        with self._lock:
+            if epoch != self.baseline_epoch or seq < self._log_floor[0] \
+                    or after < self._log_floor[1]:
+                return None
+            keys: dict = {}
+            oldest = upto
+            for s, version, ks in self._commit_log:
+                if (s > seq or version > after) and version <= upto:
+                    oldest = min(oldest, version)
+                    for k in ks:
+                        keys[k] = None
+            new_mark = (self.baseline_epoch, self._commit_seq)
+            found: dict = {}
+            missing = []
+            tables = [self.active] + self.frozen[::-1]
+            for key in keys:
+                for mt in tables:
+                    v = mt.visible_version(key, upto)
+                    if v is not None:
+                        found[key] = (v.op == "delete", v.values)
+                        break
+                else:
+                    missing.append(key)
+            if missing:
+                found.update(self._segment_versions(missing, oldest, upto))
+        # a key with no committed version at all was written and rolled
+        # back by its statement: nothing changed for it
+        touched = [k for k in keys if k in found]
+        row_keys = [k for k in touched if not found[k][0]]
+        arrays, valids = _values_to_arrays(
+            [found[k][1] for k in row_keys], self.columns, self.types)
+        return TabletDelta(touched, row_keys, arrays, valids, new_mark)
+
+    def _segment_versions(self, keys: list, oldest: int, upto: int) -> dict:
+        """{key: (deleted, values)} of the newest version <= ``upto`` of
+        each of ``keys`` in the L0 segments a mini-compaction wrote since
+        ``oldest`` (their rows carry ``__version__``)."""
+        want = set(keys)
+        first = np.array([k[0] for k in keys])
+        best: dict = {}
+        for seg in self.segments:
+            if seg.level != 0 or seg.max_version < oldest \
+                    or seg.min_version > upto:
+                continue
+            a, v = seg.decode()
+            vers = a.get("__version__")
+            sel = np.isin(a[self.key_cols[0]], first)
+            if vers is not None:
+                sel &= vers <= upto
+            for i in np.nonzero(sel)[0]:
+                key = tuple(_item(a[k][i]) for k in self.key_cols)
+                if key not in want:
+                    continue
+                ver = int(vers[i]) if vers is not None else seg.max_version
+                if key in best and best[key][0] > ver:
+                    continue
+                values = {c: (None if v.get(c) is not None and not v[c][i]
+                              else _item(a[c][i]))
+                          for c in self.columns if c in a}
+                best[key] = (ver, bool(a["__deleted__"][i])
+                             if "__deleted__" in a else False, values)
+        return {k: (deleted, values)
+                for k, (_ver, deleted, values) in best.items()}
 
     def abort(self, tx_id: int, keys, min_stmt_seq: int = 0):
         with self._lock:
@@ -183,7 +317,7 @@ class Tablet:
         for key, head in chains.items():
             cur = self.active._rows.get(key)
             if cur is None:
-                self.active._rows[key] = head
+                self.active.adopt(key, head)
             else:
                 tail = cur
                 while tail.prev is not None:
@@ -203,7 +337,7 @@ class Tablet:
                                     self.key_cols, drop_tombstones=False)
             # place after existing L1/L2 so order stays oldest-first
             self.segments = keep + [merged]
-            self.data_version += 1
+            self.rebase()
             return merged
 
     def major_compact(self):
@@ -215,7 +349,7 @@ class Tablet:
             merged = merge_segments(next(self._next_seg), 2, self.segments,
                                     self.key_cols, drop_tombstones=True)
             self.segments = [merged]
-            self.data_version += 1
+            self.rebase()
             return merged
 
     # ------------------------------------------------------------------
@@ -256,8 +390,12 @@ class Tablet:
                          for k, vv in v.items()}
                 seg_parts.append((a, v, None))
             mt_parts = []
+            # the same ranges prune the memtables: sound for the reason
+            # above, and a DELETE by key then decodes the rows it may
+            # touch, not every row written since the last freeze
+            within = self.key_ranges(prune)
             for mt in self.frozen + [self.active]:
-                rows = mt.snapshot_rows(snapshot, tx_id)
+                rows = mt.snapshot_rows(snapshot, tx_id, within)
                 if rows:
                     a, v = _rows_to_arrays(rows, self.columns, self.types)
                     mt_parts.append((a, v, None))
@@ -271,20 +409,20 @@ class Tablet:
         n = len(next(iter(arrays.values())))
         keep = np.ones(n, dtype=bool)
         if self.key_cols and n:
-            key_arrays = [arrays[k] for k in self.key_cols]
-            seen: set = set()
-            for idx in range(n - 1, -1, -1):  # newest last -> wins
-                key = tuple(a[idx] for a in key_arrays)
-                if key in seen:
-                    keep[idx] = False
-                else:
-                    seen.add(key)
+            # newest last -> wins: of the rows with one key, the last
+            keep = _last_of_each_key([arrays[k] for k in self.key_cols])
         if "__deleted__" in arrays:
             keep &= ~arrays["__deleted__"].astype(bool)
         out_a = {c: arrays[c][keep] for c in self.columns}
         out_v = {c: (valids[c][keep] if valids.get(c) is not None else None)
                  for c in self.columns}
         return out_a, out_v
+
+    def key_ranges(self, ranges) -> list:
+        """{key column: (lo, hi)} -> ``MemTable.keys_within``'s form."""
+        return [(self.key_cols.index(c), lo, hi)
+                for c, (lo, hi) in (ranges or {}).items()
+                if c in self.key_cols]
 
     def row_count_estimate(self) -> int:
         return sum(s.n_rows for s in self.segments) + len(self.active) + \
@@ -302,14 +440,14 @@ class Tablet:
         with self._lock:
             self.segments.append(seg)
             self._next_seg.bump_past(seg.segment_id)
-            self.data_version += 1
+            self.rebase()
 
     def remove_segments(self, ids):
         ids = set(ids)
         with self._lock:
             self.segments = [s for s in self.segments
                              if s.segment_id not in ids]
-            self.data_version += 1
+            self.rebase()
 
     def segment_locations(self):
         """-> [(Segment, partition_idx|None)] for manifest checkpoints."""
@@ -324,27 +462,57 @@ class Tablet:
         return v
 
 
-def _rows_to_arrays(rows: dict, columns, types):
-    n = len(rows)
-    arrays = {c: [] for c in columns}
-    valids = {c: np.ones(n, dtype=bool) for c in columns}
-    deleted = np.zeros(n, dtype=bool)
-    for i, (key, v) in enumerate(sorted(rows.items())):
-        deleted[i] = v.op == "delete"
-        for c in columns:
-            val = v.values.get(c)
-            if val is None:
-                valids[c][i] = False
-                arrays[c].append("" if types[c].is_string else 0)
-            else:
-                arrays[c].append(val)
-    out = {}
+def _last_of_each_key(key_arrays: list) -> np.ndarray:
+    """keep[i]: no later row has row i's key.  One stable sort by the key
+    columns (rows of one key stay in their order, so the last of each run
+    is the newest), no per-row work."""
+    n = len(key_arrays[0])
+    order = np.lexsort(key_arrays[::-1])
+    same_as_next = np.ones(n - 1, dtype=bool)
+    for a in key_arrays:
+        s = a[order]
+        same_as_next &= s[1:] == s[:-1]
+    keep = np.ones(n, dtype=bool)
+    keep[order[:-1][same_as_next]] = False
+    return keep
+
+
+def _item(x):
+    return x.item() if hasattr(x, "item") else x
+
+
+def _values_to_arrays(rows: list, columns, types):
+    """[{column: python value | None}] -> (arrays, valids), a column at a
+    time; ``valids[c]`` is None where no row is NULL there."""
+    arrays, valids = {}, {}
     for c in columns:
-        if types[c].is_string:
-            out[c] = np.array(arrays[c], dtype=object)
+        vals = [r.get(c) for r in rows]
+        null = np.fromiter((x is None for x in vals), dtype=bool,
+                           count=len(vals))
+        if null.any():
+            fill = "" if types[c].is_string else 0
+            vals = [fill if x is None else x for x in vals]
+            valids[c] = ~null
         else:
-            out[c] = np.asarray(arrays[c], dtype=types[c].np_dtype)
-    out["__deleted__"] = deleted
+            valids[c] = None
+        if types[c].is_string:
+            arrays[c] = np.array(vals, dtype=object)
+        else:
+            arrays[c] = np.asarray(vals, dtype=types[c].np_dtype)
+    return arrays, valids
+
+
+def _rows_to_arrays(rows: dict, columns, types):
+    """{key: Version} -> (arrays with ``__deleted__``, valids), in key
+    order; every column gets a validity array."""
+    versions = [v for _key, v in sorted(rows.items())]
+    out, valids = _values_to_arrays([v.values for v in versions], columns,
+                                    types)
+    n = len(versions)
+    valids = {c: np.ones(n, dtype=bool) if v is None else v
+              for c, v in valids.items()}
+    out["__deleted__"] = np.fromiter((v.op == "delete" for v in versions),
+                                     dtype=bool, count=n)
     return out, valids
 
 

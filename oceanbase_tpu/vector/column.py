@@ -133,6 +133,37 @@ class StringDict:
         """
         return np.array([fn(v) for v in self.values])
 
+    def merged(self, strings: np.ndarray):
+        """This dictionary with the values of ``strings`` it lacks merged
+        in, still sorted -> (dictionary, positions): ``positions`` are the
+        places in THIS dictionary before which the new values went, in
+        ascending order, so a code ``c`` of this dictionary becomes
+        ``c + count(positions <= c)`` in the new one.  ``(self, None)``
+        when nothing is new.  The new dictionary's digest is chained from
+        this one's and what was merged, not hashed over all the values."""
+        new = np.unique(np.asarray(strings, dtype=object))
+        at = np.searchsorted(self.values, new)
+        have = at < self.size
+        have[have] = self.values[at[have]] == new[have]
+        new, at = new[~have], at[~have]
+        if len(new) == 0:
+            return self, None
+        out = StringDict(np.insert(self.values.astype(object), at, new))
+        import hashlib
+
+        h = hashlib.blake2b(digest_size=8)
+        h.update(self._content_digest().to_bytes(8, "little"))
+        h.update(at.astype(np.int64).tobytes())
+        h.update("\0".join(new.tolist()).encode())
+        object.__setattr__(out, "_digest",
+                           int.from_bytes(h.digest(), "little"))
+        return out, at.astype(np.int32)
+
+    def codes_of(self, strings: np.ndarray) -> np.ndarray:
+        """int32 codes of values this dictionary holds."""
+        return np.searchsorted(self.values, np.asarray(
+            strings, dtype=object)).astype(np.int32)
+
     @staticmethod
     def encode(strings: np.ndarray) -> tuple[np.ndarray, "StringDict"]:
         """Encode raw strings -> (int32 codes, dict)."""

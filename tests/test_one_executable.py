@@ -36,6 +36,9 @@ def pair(tmp_path):
     s.execute("insert into b values " + ", ".join(
         f"({k}, {k * 2})" for k in range(40)))
     qplan.executable_for.cache_clear()
+    # gv$plan_cache's rows are the process's: a file that ran before on
+    # this worker must not lend its PX rows to the counts below
+    qplan.reset_plan_cache_stats()
     yield s
     db.close()
     qplan.executable_for.cache_clear()
