@@ -30,7 +30,7 @@ from oceanbase_tpu.exec.ops import AggSpec
 from oceanbase_tpu.expr import ir
 from oceanbase_tpu.server import metrics as qmetrics
 from oceanbase_tpu.server import trace as qtrace
-from oceanbase_tpu.vector.column import Relation
+from oceanbase_tpu.vector.column import Relation, prefetch
 
 # device attribution + per-plan wall time (host-side, result boundary)
 qmetrics.declare("plan.executions", "counter",
@@ -1282,6 +1282,16 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             (out, diag_vals, diag_total, mon_vals), compiled_now, flops, \
                 nbytes, noted = bundle.call(given)
             stats.executions += 1
+            # what the host will read is asked for now, behind the
+            # program, so that it arrives with the wait's end instead of
+            # one round trip each after it: a small result's arrays
+            # (to_numpy finds them there), the overflow total, a sampled
+            # execution's counts
+            prefetch(out)
+            if check_overflow and diag_vals:
+                diag_total.copy_to_host_async()
+            if with_monitor and monitor_collect:
+                mon_vals.copy_to_host_async()
         # a first execution at a signature pays lower()+compile() inside
         # the window above as the xla.compile child span: the dispatch
         # span's SELF time is the per-execution dispatch, and the

@@ -73,7 +73,7 @@ from oceanbase_tpu.px.exchange import (
     unshard_relation,
 )
 from oceanbase_tpu.server import metrics as qmetrics
-from oceanbase_tpu.vector.column import Relation
+from oceanbase_tpu.vector.column import Relation, prefetch
 
 qmetrics.declare("px.joins", "counter",
                  "joins of executed shard programs by distribution method "
@@ -853,6 +853,10 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
             elif isinstance(node, pp.Project):
                 rel = ops.project(rel, node.outputs)
 
+    # the coordinator's relation, if small, and the overflow total start
+    # for the host behind the merge, as execute_plan's do
+    prefetch(rel)
+    overflow.copy_to_host_async()
     # audited result-boundary sync: the one host read that decides
     # whether the (fully enqueued) result is valid or must be re-planned.
     # It is also where the statement waits for the device: device_s

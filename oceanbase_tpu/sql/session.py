@@ -1584,8 +1584,8 @@ class Session:
                     # the frozen baseline (blinding the watchdog) or
                     # spike the EWMA into a false regressed flag
                     qmetrics.inc("plan.regressions")
-        with qtrace.span("materialize"):
-            return self._materialize(rel, outputs)
+        with qtrace.span("materialize") as msp:
+            return self._materialize(rel, outputs, msp.tags)
 
     # -- ANN top-k access path (vector index) ---------------------------
     _ANN_FETCH_FACTOR = 4
@@ -1860,8 +1860,8 @@ class Session:
                             "elapsed_s": 0.0})
         return rel
 
-    def _materialize(self, rel: Relation, outputs) -> Result:
-        raw = to_numpy(rel)
+    def _materialize(self, rel: Relation, outputs, tags=None) -> Result:
+        raw = to_numpy(rel, tags=tags)
         names, arrays, valids, dtypes = [], {}, {}, {}
         for cid, name in outputs:
             col = rel.columns[cid]

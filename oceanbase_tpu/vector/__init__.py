@@ -20,6 +20,7 @@ from oceanbase_tpu.vector.column import (
     bucket_capacity,
     empty_relation,
     from_numpy,
+    prefetch,
     to_numpy,
 )
 
@@ -30,5 +31,6 @@ __all__ = [
     "bucket_capacity",
     "empty_relation",
     "from_numpy",
+    "prefetch",
     "to_numpy",
 ]

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from oceanbase_tpu.datatypes import SqlType, TypeKind
+from oceanbase_tpu.server import metrics as qmetrics
 
 # ---------------------------------------------------------------------------
 # capacity bucket ladder (the static-shape policy)
@@ -408,37 +409,233 @@ def empty_relation(types: dict[str, "SqlType"]) -> Relation:
                     mask=jnp.zeros(1, dtype=jnp.bool_))
 
 
-def to_numpy(rel: Relation, limit: int | None = None) -> dict[str, np.ndarray]:
+# ---------------------------------------------------------------------------
+# the result boundary: device relation -> host columns
+# ---------------------------------------------------------------------------
+
+qmetrics.declare("sql.result_fetches", "counter",
+                 "relations brought to the host by to_numpy (labels: kind "
+                 "= packed | dense | columns)")
+qmetrics.declare("sql.result_fetch_bytes", "counter",
+                 "bytes those fetches moved from the device to the host")
+
+# Shape rules of the fetch, from what a TPU v5e and its host measured
+# (PR 34; no knob sets them): a transfer that is waited for alone takes
+# 0.42 ms however small, one of k requested together 0.07 ms more; a
+# program, however small, is done 0.75 ms after its call; bytes cross at
+# 0.3 GB/s one array at a time (1 GB/s requested together).
+#
+# A relation of at most this many lanes crosses as it lies, dead lanes
+# and all, every copy requested at once: counting its live rows first is
+# a program and a transfer (1.19 ms), more than its dead lanes cost.
+_AS_IT_LIES_MAX_LANES = 4096
+# Above it the live rows are counted, and brought to the front of their
+# count's bucket when that bucket is at most this share of the capacity
+# (``1 / n``).  The densify is a prefix sum over the capacity, a binary
+# search of ``bucket`` lanes in it and ``bucket`` gathered elements a leaf
+# (524,288 lanes of four columns to 64: 1.0 ms, to 16,384: 4.5, to 65,536:
+# 14.4, 0.2 us a bucket lane; the same lanes crossing whole: 0.1 us each,
+# 51 ms).  A denser relation crosses as it lies.
+_DENSIFY_MAX_SHARE = 8
+
+_RAW = SqlType.int_()  # the fetch programs see arrays, not SQL types
+
+
+def _leaves(rel: Relation, names) -> list:
+    """``[mask, data, valid, data, valid, ...]`` in the order of ``names``
+    (``None`` where a relation has no mask or a column no validity)."""
+    out = [rel.mask]
+    for n in names:
+        c = rel.columns[n]
+        out += [c.data, c.valid]
+    return out
+
+
+def _plane_rows(leaves) -> list:
+    """Where each leaf of a packed relation travels: ``(plane, row)``,
+    ``None`` for an absent leaf.  Booleans and integers ride widened in
+    ONE int64 plane (exact both ways; a 64-bit bit-cast does not lower on
+    the TPU, which holds int64 as pairs of 32-bit words); every other
+    element type has a plane of its own (named by the type), so no value
+    passes through a float or a narrower type; a 2-D leaf (a VECTOR
+    column's rows) is its own plane.  The traced pack and the host's split both read this, so
+    they cannot disagree."""
+    rows: dict = {}
+    where = []
+    for i, x in enumerate(leaves):
+        if x is None:
+            where.append(None)
+            continue
+        dt = np.dtype(x.dtype)
+        if x.ndim > 1:
+            plane = f"leaf{i}"
+        elif dt.kind in "bi" or (dt.kind == "u" and dt.itemsize < 8):
+            plane = "int64"
+        else:
+            plane = dt.name
+        row = rows.get(plane, 0)
+        rows[plane] = row + 1
+        where.append((plane, row))
+    return where
+
+
+def _live_through(mask):
+    """Traced: for every lane, the live lanes up to and including it.  A
+    prefix sum in two levels, within rows of 1,024 lanes and over the
+    rows' totals: the TPU compiler takes 0.2 s over it at 524,288 lanes
+    where one flat ``cumsum`` costs it 7.7 s (compiled for a described
+    v5e in the sandbox, PR 34; 21 s of a warm-up on the chip)."""
+    n = mask.shape[0]
+    rows = jnp.pad(mask.astype(jnp.int32), (0, -n % 1024)).reshape(-1, 1024)
+    within = jnp.cumsum(rows, axis=1)
+    totals = within[:, -1]
+    before = jnp.cumsum(totals) - totals
+    return (within + before[:, None]).reshape(-1)[:n]
+
+
+def _pack_body(bucket, tables):
+    """Traced: the first ``bucket`` live rows brought to the front, in
+    lane order, as planes (``_plane_rows``): a prefix sum of the mask, a
+    binary search of ``1..bucket`` in it and one gather a leaf (no
+    scatter, no sort over the capacity's lanes)."""
+    rel = tables["r"]
+    leaves = _leaves(rel, sorted(rel.columns))
+    seen = _live_through(leaves[0])
+    lanes = jnp.searchsorted(
+        seen, jnp.arange(1, bucket + 1, dtype=jnp.int32))
+    leaves = [jnp.arange(bucket, dtype=jnp.int32) < seen[-1]] + [
+        None if x is None else jnp.take(x, lanes, axis=0, mode="clip")
+        for x in leaves[1:]]
+    planes: dict = {}
+    for x, at in zip(leaves, _plane_rows(leaves)):
+        if at is not None:
+            planes.setdefault(at[0], []).append(x)
+    return {plane: xs[0] if xs[0].ndim > 1
+            else jnp.stack([x.astype(plane) for x in xs])
+            for plane, xs in planes.items()}
+
+
+def _count_body(tables):
+    """Traced: the live rows of a mask."""
+    return jnp.sum(tables["r"].mask, dtype=jnp.int32)
+
+
+def _on_device(body, args, leaves):
+    """Run a fetch program over the leaves, compiled once per input
+    signature and placement like any plan program (``executable_for``):
+    it has a ``gv$plan_cache`` row and its compiles count.  The program
+    sees positional names and no SQL types or dictionaries, so every
+    relation of the same shapes shares it."""
+    # exec sits above vector: imported where it is called
+    from oceanbase_tpu.exec.plan import Program, executable_for
+
+    name = f"result.{body.__name__.strip('_')}{args}"
+    placed = tuple(x.sharding for x in leaves if x is not None)
+    exe = executable_for(Program(body, args, (name, placed), name))
+    cols = {f"c{i:04d}": Column(leaves[j], leaves[j + 1], _RAW)
+            for i, j in enumerate(range(1, len(leaves), 2))}
+    (out, _lanes, _total, _mon), *_ = exe.call(
+        {"r": Relation(columns=cols, mask=leaves[0])})
+    exe.stats.executions += 1
+    return out
+
+
+def prefetch(rel: Relation) -> None:
+    """Ask for the host copies of a small relation's arrays, and wait for
+    nothing: called between a program's dispatch and the wait for it, the
+    copies follow the computation and ``to_numpy`` finds them there."""
+    if rel.capacity <= _AS_IT_LIES_MAX_LANES:
+        for x in _leaves(rel, rel.columns):
+            if isinstance(x, jax.Array):
+                x.copy_to_host_async()
+
+
+def to_numpy(rel: Relation, limit: int | None = None,
+             tags: dict | None = None) -> dict[str, np.ndarray]:
     """Materialize live rows back to host (decoding string dictionaries).
 
     This is the result-set boundary (≙ result drivers serializing rows to
-    MySQL packets, src/observer/mysql/ob_sync_plan_driver.cpp) — the one
-    place dynamic shapes are allowed, because we are leaving the device.
+    MySQL packets, src/observer/mysql/ob_sync_plan_driver.cpp).  Dynamic
+    shape begins ON the device, at the bucket of the live count, not on
+    the host at the capacity, and every transfer is requested before the
+    first is read.  One algorithm; the regime follows from what the
+    relation shows (``sql.result_fetches{kind}``):
+
+    - ``dense``: at most ``_AS_IT_LIES_MAX_LANES`` lanes, or more lanes
+      most of which are live (or no mask): the arrays cross as they lie,
+      with no copy on the device.
+    - ``packed``: more lanes, few of them live: one round trip reads the
+      live count, a small cached program brings the live rows to the front
+      of the count's bucket (``bucket_capacity``) and stacks mask, data
+      and validity into one plane an element type (``_plane_rows``), and
+      that bucket crosses: transfers grow with neither the columns nor the
+      capacity.
+    - ``columns``: a leaf that already lies on the host: nothing to fetch.
+
+    ``tags`` (the ``materialize`` span's) takes ``kind``, ``rows``,
+    ``capacity``, ``bytes`` and ``transfers``.
     """
-    mask = np.asarray(rel.mask_or_true())
-    out: dict[str, np.ndarray] = {}
-    idx = np.nonzero(mask)[0]
+    names = list(rel.columns)
+    if not names:
+        return {}
+    capacity = rel.capacity
+    leaves = _leaves(rel, names)
+    fetched = []
+    kind = "dense"
+    if not all(isinstance(x, jax.Array) for x in leaves if x is not None):
+        kind = "columns"
+    elif capacity > _AS_IT_LIES_MAX_LANES and rel.mask is not None:
+        fetched.append(jax.device_get(
+            _on_device(_count_body, (), [rel.mask])))
+        live = int(fetched[0])
+        bucket = bucket_capacity(live if limit is None
+                                 else min(live, limit))
+        if bucket * _DENSIFY_MAX_SHARE <= capacity:
+            kind = "packed"
+    if kind == "packed":
+        planes = jax.device_get(_on_device(_pack_body, (bucket,), leaves))
+        fetched += planes.values()
+        host = [None if at is None
+                else planes[at[0]] if x.ndim > 1
+                else planes[at[0]][at[1]].astype(x.dtype, copy=False)
+                for x, at in zip(leaves, _plane_rows(leaves))]
+    else:
+        host = jax.device_get(leaves)
+        fetched += [h for x, h in zip(leaves, host)
+                    if isinstance(x, jax.Array)]
+    nbytes = sum(int(x.nbytes) for x in fetched)
+
+    mask = host[0]
+    idx = np.nonzero(mask)[0] if mask is not None \
+        else np.arange(host[1].shape[0])
     if limit is not None:
         idx = idx[:limit]
-    for name, col in rel.columns.items():
-        data = np.asarray(col.data)[idx]
+    out: dict[str, np.ndarray] = {}
+    for i, name in enumerate(names):
+        col = rel.columns[name]
+        data = host[1 + 2 * i][idx]
+        v = host[2 + 2 * i]
+        if v is not None:
+            v = v[idx]
         if col.dtype.kind == TypeKind.VECTOR:
             # embeddings come back as an object array of float32 rows
             out[name] = np.array([data[i] for i in range(len(data))],
                                  dtype=object)
-            if col.valid is not None:
-                out.setdefault("__valid__" + name,
-                               np.asarray(col.valid)[idx])
+            if v is not None:
+                out.setdefault("__valid__" + name, v)
             continue
         if col.sdict is not None:
             codes = np.clip(data, 0, col.sdict.size - 1)
-            vals = col.sdict.values[codes]
-            data = vals
-        if col.valid is not None:
-            v = np.asarray(col.valid)[idx]
+            data = col.sdict.values[codes]
+        if v is not None:
             data = np.where(v, data, None) if data.dtype == object else data
             out[name] = data
             out.setdefault("__valid__" + name, v)
         else:
             out[name] = data
+    qmetrics.inc("sql.result_fetches", kind=kind)
+    qmetrics.inc("sql.result_fetch_bytes", nbytes)
+    if tags is not None:
+        tags.update(kind=kind, rows=len(idx), capacity=capacity,
+                    bytes=nbytes, transfers=len(fetched))
     return out

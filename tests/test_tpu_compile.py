@@ -275,3 +275,37 @@ def test_lowcard_reduce_compiles_for_v5e(fn, one_chip, no_persistent_cache):
     _fits(compiled)
     assert " scatter(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes <= lanes * 8
+
+
+@pytest.mark.parametrize("spread", [False, True])
+def test_result_pack_compiles_for_v5e(spread, topo, one_chip,
+                                      no_persistent_cache):
+    """The result boundary's densify + pack (``vector/column.py``) at the
+    shape of Q3's SF1 result, ten rows alive on 524,288 lanes (on one
+    chip, and on 4 x 262,144 lanes spread over the mesh as the PX
+    coordinator's relation lies): a prefix sum, a binary search and
+    gathers, with no sort and no scatter over the capacity's lanes."""
+    from oceanbase_tpu.vector import Relation, from_numpy
+    from oceanbase_tpu.vector import column as vcol
+
+    where = one_chip
+    lanes = 524288
+    if spread:
+        where = NamedSharding(Mesh(np.array(topo.devices), ("px",)),
+                              P("px"))
+        lanes = 4 * 262144
+    rel = from_numpy({"k": np.zeros(8, np.int64),
+                      "rev": np.zeros(8, np.int64),
+                      "day": np.zeros(8, np.int32),
+                      "avg": np.zeros(8, np.float64)},
+                     valids={"rev": np.ones(8, bool)})
+    rel = Relation(rel.columns, jnp.ones(8, jnp.bool_))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((lanes,), x.dtype, sharding=where),
+        rel)
+    compiled = jax.jit(lambda r: vcol._pack_body(64, {"r": r})).lower(
+        shapes).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text
+    assert set(compiled.output_shardings) == {"int64", "float64"}

@@ -273,9 +273,9 @@ def test_forced_collection_lands_in_the_statements_gc_s(db, monkeypatch):
     s.execute(Q_GROUP).rows()
     real = type(s)._materialize
 
-    def collecting(self, rel, outputs):
+    def collecting(self, rel, outputs, tags):
         gc.collect()
-        return real(self, rel, outputs)
+        return real(self, rel, outputs, tags)
 
     monkeypatch.setattr(type(s), "_materialize", collecting)
     s.execute(Q_GROUP).rows()
