@@ -12,6 +12,7 @@ hot-reloadable; persisted to the data directory; per-tenant overlay maps.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -63,10 +64,19 @@ DEF("join_capacity_factor", 1.5, "float",
     "safety multiplier over join cardinality estimates", _pos)
 DEF("max_capacity_retry", 3, "int",
     "re-plan attempts (4x budget each) after CapacityOverflow", _nonneg)
-DEF("sql_work_area_rows", 1 << 22, "int",
-    "per-query work-area row budget; inputs estimated above it stream "
-    "through the disk spill tier (≙ ObTenantSqlMemoryManager work areas)",
-    _pos)
+DEF("ob_sql_work_area_percentage", 5, "int",
+    "share of the device's memory one statement's work area may take, in "
+    "per cent (upstream's name and default; its TPC-H guide sets 80).  A "
+    "statement's inputs are priced in bytes (estimated rows reaching the "
+    "plan x the widths of the columns it reads); a table over the budget "
+    "streams through the disk spill tier (≙ ObTenantSqlMemoryManager "
+    "work areas)", lambda v: 0 < v <= 100)
+DEF("sql_work_area_rows", 0, "int",
+    "the older work-area budget, in ROWS of whatever width; 0 (the "
+    "default): ob_sql_work_area_percentage decides.  While it is not 0 "
+    "it is the budget, read as that many rows of the table being priced, "
+    "and the percentage is not consulted (ROADMAP S0b: to retire)",
+    _nonneg)
 DEF("enable_sql_spill", True, "bool",
     "route over-budget sorts/joins/group-bys through the temp-file "
     "spill tier instead of failing on CapacityOverflow")
@@ -336,9 +346,11 @@ DEF("sql_audit_queue_size", 10000, "int",
     "ring-buffer capacity of gv$sql_audit", _pos)
 DEF("enable_defensive_check", True, "bool",
     "extra engine invariant checks (≙ _enable_defensive_check)")
-DEF("kv_cache_limit_bytes", 2 << 30, "cap",
-    "device-relation (block) cache budget per tenant "
-    "(≙ ObKVGlobalCache memory limit)", _pos)
+DEF("kv_cache_limit_bytes", 0, "cap",
+    "device-relation (block) cache budget per tenant (≙ ObKVGlobalCache "
+    "memory limit); 0: half of the device's memory, the share upstream "
+    "leaves beside the memstore (memstore_limit_percentage = 50)",
+    _nonneg)
 DEF("enable_dbms_jobs", False, "bool",
     "start the DBMS job scheduler thread at boot (stats auto-gather, "
     "auto compaction — ≙ dbms_scheduler maintenance windows)")
@@ -431,6 +443,33 @@ class Config:
     @staticmethod
     def defs() -> dict[str, ParamDef]:
         return dict(_DEFS)
+
+
+#: the memory a device is taken to have where the backend reports none
+#: (the CPU): a TPU v5e chip's 16 GiB, so that a share of it means on
+#: the CPU what it means on the chip the program is written for
+DEVICE_BYTES_STAND_IN = 16 << 30
+
+
+@functools.lru_cache(maxsize=1)
+def device_bytes_limit() -> int:
+    """``bytes_limit`` of the first device as its runtime reports it."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or DEVICE_BYTES_STAND_IN)
+
+
+def kv_cache_limit(config: "Config") -> int:
+    """``kv_cache_limit_bytes`` in force: what was set, or half the
+    device's memory."""
+    return int(config["kv_cache_limit_bytes"]) or device_bytes_limit() // 2
+
+
+def work_area_bytes(config: "Config") -> int:
+    """``ob_sql_work_area_percentage`` of the device's memory, in bytes."""
+    return device_bytes_limit() \
+        * int(config["ob_sql_work_area_percentage"]) // 100
 
 
 _CAP_UNITS = {"k": 1 << 10, "m": 1 << 20, "g": 1 << 30, "t": 1 << 40}

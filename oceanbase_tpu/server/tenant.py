@@ -19,7 +19,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 from oceanbase_tpu.palf.cluster import PalfCluster
-from oceanbase_tpu.server.config import Config
+from oceanbase_tpu.server.config import Config, kv_cache_limit
 from oceanbase_tpu.storage.engine import StorageCatalog, StorageEngine
 from oceanbase_tpu.tx.service import TransService
 
@@ -122,7 +122,7 @@ class Tenant:
         self.catalog = StorageCatalog(self.engine,
                                       snapshot_fn=self.tx.gts.current,
                                       config=self.config)
-        self.catalog._cache.resize(int(self.config["kv_cache_limit_bytes"]))
+        self.catalog._cache.resize(kv_cache_limit(self.config))
 
         # satellites: sequences, table locks, KV/CDC front-ends
         from oceanbase_tpu.share.sequence import SequenceManager
@@ -138,7 +138,7 @@ class Tenant:
             if k == "lock_wait_timeout_s":
                 self.tx.lock_wait_timeout_s = float(v)
             elif k == "kv_cache_limit_bytes":
-                self.catalog._cache.resize(int(v))
+                self.catalog._cache.resize(kv_cache_limit(self.config))
             elif k in ("enable_shape_buckets", "shape_bucket_growth",
                        "shape_bucket_floor"):
                 # cached relations were padded under the old policy;

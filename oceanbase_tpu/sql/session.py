@@ -38,6 +38,18 @@ qmetrics.declare("sql.statements", "counter",
                  "statements executed (labels: tenant, ok)")
 qmetrics.declare("sql.statement_s", "histogram",
                  "end-to-end statement latency", unit="s")
+qmetrics.declare("sql.work_area_bytes", "gauge",
+                 "the work-area budget in force, in bytes: "
+                 "ob_sql_work_area_percentage of the device's memory; "
+                 "absent while sql_work_area_rows is not 0 and decides",
+                 unit="bytes")
+qmetrics.declare("sql.work_area_decisions", "counter",
+                 "statements the work-area budget kept on the device "
+                 "{kind=resident} or sent through the disk spill tier "
+                 "{kind=spill}, one per plan execution priced")
+qmetrics.declare("storage.analyze_ns", "counter",
+                 "time ANALYZE TABLE spent gathering statistics (the "
+                 "span ``analyze``)", unit="ns")
 qmetrics.declare("plan_cache.hits", "counter",
                  "session plan-cache hits")
 qmetrics.declare("plan_cache.misses", "counter",
@@ -978,9 +990,6 @@ class Session:
             if l0 >= int(self.tenant.config["minor_compact_trigger"]):
                 self._engine.minor_compact(table)
 
-    HIST_BUCKETS = 64
-    MCV_K = 16  # most-common-values kept per string column
-
     def _analyze_workload(self, stmt: ast.AnalyzeWorkloadStmt) -> Result:
         """ANALYZE WORKLOAD REPORT [FROM <id> TO <id>]: build (and
         remember) the delta report between two workload snapshots.
@@ -1024,54 +1033,36 @@ class Session:
         equi-height histograms for non-string columns, and
         most-common-values frequency lists for dict-encoded string
         columns (≙ DBMS_STATS gather, src/share/stat/
-        ob_opt_column_stat.h top-k frequency histogram)."""
-        td = self.catalog.table_def(stmt.table)
-        rel = self.catalog.table_data(stmt.table)
-        import numpy as _np
+        ob_opt_column_stat.h top-k frequency histogram).  The columns
+        stay on the device: programs over them answer (sql/table_stats
+        .py), and only counts, edges and per-code rows cross."""
+        from oceanbase_tpu.sql import table_stats as ts
 
-        mask = _np.asarray(rel.mask_or_true())
-        n = int(mask.sum())
-        td.row_count = n
-        for c in td.columns:
-            col = rel.columns.get(c.name)
-            if col is None:
-                continue
-            if col.sdict is not None:
-                codes = _np.asarray(col.data)[mask]
-                if col.valid is not None:
-                    codes = codes[_np.asarray(col.valid)[mask]]
-                codes = codes[codes >= 0]
-                uniq, counts = _np.unique(codes, return_counts=True)
-                td.ndv[c.name] = max(int(len(uniq)), 1)
-                if len(uniq):
-                    # top-k by measured frequency: string-equality
-                    # selectivity reads this instead of the 0.1 guess
-                    order = _np.argsort(counts)[::-1][:self.MCV_K]
-                    total = max(int(counts.sum()), 1)
-                    td.mcv[c.name] = (
-                        [str(col.sdict.values[int(uniq[i])])
-                         for i in order],
-                        [float(counts[i]) / total for i in order],
-                    )
+        td = self.catalog.table_def(stmt.table)
+        with qtrace.span("analyze", table=stmt.table) as sp:
+            rel = self.catalog.table_data(stmt.table)
+            valid_rows, n = ts.live_counts(rel)
+            sp.tags["rows"] = n
+            td.row_count = n
+            mask = rel.mask_or_true()
+            for c in td.columns:
+                col = rel.columns.get(c.name)
+                if col is None:
+                    continue
+                with qtrace.span("analyze.column", column=c.name,
+                                 on_device=int(ts.on_device(col))):
+                    ndv, detail = ts.column_stats(
+                        col, mask, n, valid_rows[c.name])
+                td.ndv[c.name] = ndv
+                # a dictionary column's frequency list, any other's
+                # histogram; one that no longer qualifies drops its
+                # stale one (it must not keep feeding selectivity)
+                store = td.mcv if col.sdict is not None else td.histograms
+                if detail is None:
+                    store.pop(c.name, None)
                 else:
-                    td.mcv.pop(c.name, None)
-                continue
-            data = _np.asarray(col.data)[mask]
-            if col.valid is not None:
-                v = _np.asarray(col.valid)[mask]
-                null_frac = 1.0 - (v.sum() / max(len(v), 1))
-                data = data[v]
-            else:
-                null_frac = 0.0
-            td.ndv[c.name] = int(len(_np.unique(data))) if len(data) else 1
-            if len(data) >= self.HIST_BUCKETS and data.dtype.kind in "iuf":
-                qs = _np.linspace(0, 100, self.HIST_BUCKETS + 1)
-                edges = _np.percentile(data, qs)
-                td.histograms[c.name] = (edges, float(null_frac))
-            else:
-                # the column no longer qualifies: stale edges must not
-                # keep feeding selectivity after a successful ANALYZE
-                td.histograms.pop(c.name, None)
+                    store[c.name] = detail
+        qmetrics.inc("storage.analyze_ns", int(sp.elapsed_s * 1e9))
         return _ok()
 
     def _describe_view(self, name: str) -> Result:
@@ -1883,7 +1874,7 @@ class Session:
     # ------------------------------------------------------------------
     def _spill_candidates(self, plan, force_largest: bool = False) -> set:
         """Tables whose estimated rows REACHING the plan exceed the
-        work-area budget (sql_work_area_rows).  The estimate is
+        work-area budget (``_work_area``).  The estimate is
         post-access-path (≙ deciding spill from per-operator work-area
         estimates, not base-table size): a table whose filter conjuncts
         admit a selective primary/secondary path keeps the in-memory
@@ -1906,7 +1897,7 @@ class Session:
             # path, so stay in-memory when any referenced table is dirty
             if any(t in self._tx.participants for t in refs):
                 return set()
-        budget = int(self.db.config["sql_work_area_rows"])
+        fits = self._work_area(plan)
         try:
             ranges_by_table = ap.scan_filter_ranges(plan, self._engine)
         except Exception:
@@ -1936,10 +1927,54 @@ class Session:
                 est[t] = choice.est_rows
             else:
                 est[t] = estimate_rows_in_ranges(ts.tablet, rngs)
-        big = {t for t, e in est.items() if e > budget}
+        # a table at a time, as the spill tier streams: the rows reaching
+        # the plan against the rows of it the work area holds
+        big = {t for t, e in est.items() if e > fits(t)}
+        if not force_largest:
+            qmetrics.inc("sql.work_area_decisions",
+                         kind="spill" if big else "resident")
         if not big and force_largest and est:
             big = {max(est, key=est.get)}
         return big
+
+    def _config(self):
+        """The configuration this session's statements read: the
+        tenant's overlay (SET GLOBAL) over the cluster's (ALTER SYSTEM)."""
+        return self.tenant.config if self.tenant is not None \
+            else self.db.config
+
+    def _work_area(self, plan):
+        """-> fits(table): the rows of ``table`` the work area holds.  The
+        budget is ``ob_sql_work_area_percentage`` of the device's memory,
+        in BYTES, and a row is priced at the widths of the columns
+        ``plan`` reaches (validity and row mask included); while
+        ``sql_work_area_rows`` is not 0 it is the budget instead, that
+        many rows whatever their width."""
+        from oceanbase_tpu.exec.plan import scan_columns
+        from oceanbase_tpu.server.config import work_area_bytes
+
+        cfg = self._config()
+        rows = int(cfg["sql_work_area_rows"])
+        if rows:
+            return lambda table: rows
+        nbytes = work_area_bytes(cfg)
+        qmetrics.set_gauge("sql.work_area_bytes", nbytes)
+        reach = scan_columns(plan)
+
+        def fits(table: str) -> int:
+            cols = self.catalog.table_def(table).columns
+            renames = reach[1].get(table) if reach is not None else None
+            if renames is not None:
+                cols = [c for c in cols
+                        if any(r.get(c.name, c.name) in reach[0]
+                               for r in renames)] or cols[:1]
+            row_bytes = 1 + sum(
+                c.dtype.np_dtype.itemsize * max(
+                    c.dtype.precision if c.dtype.kind == TypeKind.VECTOR
+                    else 1, 1) + bool(c.nullable) for c in cols)
+            return nbytes // row_bytes
+
+        return fits
 
     def _try_spilled(self, plan, outputs, big: set):
         """Execute through exec/spill_exec (granule streams + temp-file
@@ -1989,7 +2024,9 @@ class Session:
         try:
             arrays, valids, dtypes, stats = spill_exec.execute_spilled(
                 plan, providers, sdir,
-                int(self.db.config["sql_work_area_rows"]),
+                # its sorts and joins count rows: the fewest the work
+                # area holds of a streamed table
+                max(min(map(self._work_area(plan), big)), 1),
                 device_tables, types_by_table, big,
                 disk_budget=getattr(self.tenant, "diskmgr", None),
                 faults=getattr(self.db, "faults", None),

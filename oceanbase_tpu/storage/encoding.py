@@ -13,6 +13,10 @@ reference's encoding selector):
 - RLE       run-length (values + run lengths), good for sorted/clustered
 - DELTA     monotonic-ish int sequences -> base + small deltas (bit-width
             reduced)
+- SDICT     a string chunk as the distinct strings it holds (sorted) and
+            uint{8,16,32} codes into them: what a load that has already
+            factorised the column (``CodedStrings``) writes, a gather to
+            decode
 
 Decode happens column-at-a-time into dense arrays — on TPU the decode is a
 gather (DICT), repeat (RLE) or cumsum (DELTA), all vectorizable; round 1
@@ -77,6 +81,53 @@ def _zone(arr: np.ndarray, valid) -> ZoneMap:
         vals = live.tolist()
         return ZoneMap(min(vals), max(vals), nulls, n)
     return ZoneMap(live.min(), live.max(), nulls, n)
+
+
+@dataclass
+class CodedStrings:
+    """A string column as a load factorised it (``vector.column.
+    factorize_strings``): int32 ``codes`` into ``values``, the sorted
+    distinct strings.  Slicing and fancy indexing give the same column
+    over fewer rows, so the key sort and the partition split treat it as
+    they treat an array; the codes order as the strings do."""
+
+    codes: np.ndarray
+    values: np.ndarray            # object, sorted ascending
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    def __getitem__(self, sel) -> "CodedStrings":
+        return CodedStrings(self.codes[sel], self.values)
+
+    def strings(self) -> np.ndarray:
+        """The column as an object array of its strings."""
+        return self.values[self.codes]
+
+
+def encode_coded(col: CodedStrings, valid) -> EncodedColumn:
+    """One chunk of a factorised string column -> an ``sdict`` chunk."""
+    codes, n = col.codes, len(col.codes)
+    nulls = 0 if valid is None else int((~valid).sum())
+    live = codes if valid is None else codes[valid]
+    if len(live) == 0:
+        return EncodedColumn("plain", {"data": col.strings()}, valid,
+                             ZoneMap(None, None, nulls, n), n)
+    size = len(col.values)
+    if size <= 4 * n:
+        # a flag per dictionary entry: no sort of the chunk's codes
+        present = np.zeros(size, dtype=bool)
+        present[codes] = True
+        used = np.flatnonzero(present)
+        local = (np.cumsum(present, dtype=np.int64) - 1)[codes]
+    else:
+        used, local = np.unique(codes, return_inverse=True)
+    zone = ZoneMap(str(col.values[live.min()]), str(col.values[live.max()]),
+                   nulls, n)
+    return EncodedColumn(
+        "sdict", {"values": col.values[used],
+                  "codes": local.astype(_best_uint(len(used)))},
+        valid, zone, n)
 
 
 def _best_uint(maxval: int) -> np.dtype:
@@ -154,6 +205,8 @@ def decode_column(ec: EncodedColumn, out_dtype=None) -> np.ndarray:
     elif ec.encoding == "rle":
         data = np.repeat(ec.payload["values"], ec.payload["lengths"])
     elif ec.encoding == "dict":
+        data = ec.payload["values"][ec.payload["codes"]]
+    elif ec.encoding == "sdict":
         data = ec.payload["values"][ec.payload["codes"]]
     elif ec.encoding == "delta":
         base = ec.payload["base"]

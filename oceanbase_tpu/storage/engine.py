@@ -19,6 +19,7 @@ HBM-resident columns (≙ KV cache framework serving block cache hits).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -37,6 +38,7 @@ from oceanbase_tpu.storage.device_delta import (
     apply_delta,
     delta_bytes,
 )
+from oceanbase_tpu.storage.encoding import CodedStrings
 from oceanbase_tpu.storage.segment import Segment
 from oceanbase_tpu.storage.tablet import Tablet
 
@@ -62,7 +64,31 @@ qmetrics.declare("storage.delta_rows", "counter",
                  "rows a delta apply wrote into pad lanes {op=insert} and "
                  "lanes it cleared {op=delete}; an update is one of each")
 
+qmetrics.declare("storage.device_copy_bytes", "counter",
+                 "bytes of the device relations the relation cache holds: "
+                 "added when a copy is cached, taken off when it is "
+                 "replaced, evicted or invalidated (it moves both ways: "
+                 "read it as a gauge)", unit="bytes")
+qmetrics.declare("storage.bulk_load_rows", "counter",
+                 "rows direct loads wrote (StorageCatalog.load_numpy)")
+qmetrics.declare("storage.bulk_load_ns", "counter",
+                 "time direct loads spent, by {phase}: encode (types, "
+                 "string dictionaries), sort (key order), segment (chunk "
+                 "encodings), persist (segment file, slog record), "
+                 "device_copy (host -> device, a column at a time)",
+                 unit="ns")
+
 log = logging.getLogger("oceanbase_tpu.storage.engine")
+
+
+@contextlib.contextmanager
+def load_phase(phase: str, **tags):
+    """One phase of a direct load: the span ``load.<phase>`` and its
+    seconds in ``storage.bulk_load_ns{phase=...}``."""
+    with qtrace.span("load." + phase, **tags) as sp:
+        yield sp
+    qmetrics.inc("storage.bulk_load_ns", int(sp.elapsed_s * 1e9),
+                 phase=phase)
 
 
 @dataclass
@@ -1004,9 +1030,13 @@ class StorageEngine:
                         self.drop_table(ix.storage_table)
 
     def bulk_load(self, name: str, arrays: dict, valids: dict | None = None,
-                  version: int = 1):
+                  version: int = 1, runs: list | None = None):
         """Direct load: host arrays -> L2 baseline segment, bypassing the
-        memtable (≙ src/storage/direct_load)."""
+        memtable (≙ src/storage/direct_load).  A string column may come
+        factorised (``CodedStrings``).  ``runs``: a list that gets
+        ``(partition | None, arrays, valids)`` of every segment written,
+        in key order as the segment holds them (what the caller builds
+        the device copy from)."""
         with self._lock:
             ts = self.tables[name]
             if "__rowid__" in ts.tablet.types and "__rowid__" not in arrays:
@@ -1030,19 +1060,27 @@ class StorageEngine:
             for part_idx, pa, pv in targets:
                 tab = (ts.tablet.partitions[part_idx]
                        if part_idx is not None else ts.tablet)
+                rows = len(next(iter(pa.values()))) if pa else 0
                 if tab.key_cols != ["__rowid__"]:
-                    pa, pv = sort_rows_by_keys(pa, dict(pv or {}),
-                                               tab.key_cols)
-                seg = Segment.build(
-                    next(tab._next_seg), 2, pa, ts.tablet.types,
-                    pv or None, min_version=version, max_version=version)
+                    with load_phase("sort", rows=rows):
+                        pa, pv = sort_rows_by_keys(pa, dict(pv or {}),
+                                                   tab.key_cols)
+                if runs is not None:
+                    runs.append((part_idx, pa, pv))
+                with load_phase("segment", rows=rows):
+                    seg = Segment.build(
+                        next(tab._next_seg), 2, pa, ts.tablet.types,
+                        pv or None, min_version=version,
+                        max_version=version)
                 ts.tablet.add_segment(seg, part_idx)
                 if self.root is not None:
                     op = {"op": "add_segment", "table": name,
                           "segment_id": seg.segment_id, "part": part_idx}
                     try:
-                        self._save_segment(name, seg)
-                        self._log_meta(op)
+                        with load_phase("persist", rows=rows,
+                                        bytes=seg.nbytes()):
+                            self._save_segment(name, seg)
+                            self._log_meta(op)
                     except Exception:
                         # memory serves the loaded seg; the persist
                         # re-attempts at the next flush/checkpoint
@@ -1061,7 +1099,9 @@ class StorageEngine:
                 ev = {}
                 for c in ikey:
                     if c in arrays:
-                        entry[c] = arrays[c]
+                        entry[c] = arrays[c].strings() \
+                            if isinstance(arrays[c], CodedStrings) \
+                            else arrays[c]
                         if (valids or {}).get(c) is not None:
                             ev[c] = valids[c]
                         continue
@@ -1293,6 +1333,7 @@ class StorageCatalog(Catalog):
         from oceanbase_tpu.share.kvcache import KvCache
 
         self._cache = KvCache(limit_bytes=2 << 30, name="relation")
+        self._booked_bytes = 0      # of it in storage.device_copy_bytes
         # table -> the newest relation's partition layout, for as long as
         # that relation lives (hash-partitioned tables only)
         import weakref
@@ -1436,45 +1477,134 @@ class StorageCatalog(Catalog):
 
     def load_numpy(self, name, arrays, types=None, primary_key=None,
                    valids=None):
-        from oceanbase_tpu.vector import from_numpy
+        """Direct load of host arrays (≙ src/storage/direct_load): ONE
+        encode on the host (types, string dictionaries) feeds the
+        baseline segment (on disk, its slog record written, before this
+        returns) and the table's device copy, which is the key-sorted,
+        bucket-padded relation ``table_data`` serves from then on."""
+        from oceanbase_tpu.vector.column import encode_host
 
-        rel = from_numpy(arrays, types=types, valids=valids)
-        cols = [ColumnDef(c, rel.columns[c].dtype,
-                          nullable=rel.columns[c].valid is not None)
-                for c in arrays]
-        tdef = TableDef(name, cols, primary_key=primary_key or [],
-                        row_count=rel.capacity)
-        with self._lock:
-            if name not in self.engine.tables:
-                self.engine.create_table(tdef)
-            # store raw (pre-dict-encode) arrays; strings re-encode on read
-            store_arrays = {}
-            store_valids = {}
-            for c in arrays:
-                store_arrays[c] = np.asarray(arrays[c])
-                if rel.columns[c].dtype.kind == TypeKind.DATE:
-                    store_arrays[c] = store_arrays[c].astype(np.int32)
-                elif rel.columns[c].dtype.kind == TypeKind.DECIMAL:
-                    store_arrays[c] = store_arrays[c].astype(np.int64)
-                if valids and c in valids and valids[c] is not None:
-                    store_valids[c] = valids[c]
-            self.engine.bulk_load(name, store_arrays, store_valids or None)
-            self._defs[name] = self.engine.tables[name].tdef
-            from oceanbase_tpu.catalog import sampled_ndv
-            from oceanbase_tpu.datatypes import TypeKind as _TK
+        rows = len(next(iter(arrays.values()))) if arrays else 0
+        with qtrace.span("load", table=name, rows=rows) as sp:
+            with load_phase("encode", rows=rows):
+                host = encode_host(arrays, types=types, valids=valids)
+            cols = [ColumnDef(c, host[c].dtype,
+                              nullable=host[c].valid is not None)
+                    for c in arrays]
+            tdef = TableDef(name, cols, primary_key=primary_key or [],
+                            row_count=rows)
+            with self._lock:
+                if name not in self.engine.tables:
+                    self.engine.create_table(tdef)
+                ts = self.engine.tables[name]
+                fresh = ts.tablet.row_count_estimate() == 0
+                # the segment stores what was given (dates as int32,
+                # decimals as int64), a string column as its codes and
+                # sorted dictionary
+                store_arrays, store_valids = {}, {}
+                for c in arrays:
+                    hc = host[c]
+                    if hc.sdict is not None:
+                        store_arrays[c] = CodedStrings(
+                            hc.data, np.asarray(hc.sdict.values, object))
+                    elif hc.dtype.kind in (TypeKind.DATE,
+                                           TypeKind.DECIMAL):
+                        store_arrays[c] = hc.data
+                    else:
+                        store_arrays[c] = np.asarray(arrays[c])
+                    if hc.valid is not None:
+                        store_valids[c] = hc.valid
+                runs: list = []
+                self.engine.bulk_load(name, store_arrays,
+                                      store_valids or None, runs=runs)
+                self._defs[name] = ts.tdef
+                from oceanbase_tpu.catalog import sampled_ndv
 
-            for c in cols:
-                col = rel.columns[c.name]
-                if col.sdict is not None:
-                    nd = col.sdict.size
-                elif col.dtype.kind == _TK.VECTOR:
-                    nd = rel.capacity
+                for c in cols:
+                    hc = host[c.name]
+                    if hc.sdict is not None:
+                        nd = hc.sdict.size
+                    elif hc.dtype.kind == TypeKind.VECTOR:
+                        nd = rows
+                    else:
+                        nd = sampled_ndv(np.asarray(arrays[c.name]), rows)
+                    ts.tdef.ndv[c.name] = nd
+                self.schema_version += 1
+                self._cache.invalidate(name)
+                del host, store_arrays
+                if fresh and rows:
+                    with load_phase("device_copy", rows=rows) as dsp:
+                        nbytes = self._register_loaded(ts, runs)
+                        dsp.tags["bytes"] = nbytes
+                    sp.tags["bytes"] = nbytes
+                self._book_resident()
+        qmetrics.inc("storage.bulk_load_rows", rows)
+
+    def _register_loaded(self, ts, runs: list) -> int:
+        """The rows a direct load just wrote into an empty table become
+        its device copy: the runs as their segments hold them (key order
+        within a partition, partitions in order), copied a column at a
+        time and padded to the bucket on the host, cached under the data
+        version the segments gave the tablet.  Booked as the build it
+        replaces (``storage.device_copy``, ``source=load``): the first
+        read finds it.  -> its bytes."""
+        from oceanbase_tpu.share.kvcache import relation_bytes
+        from oceanbase_tpu.vector.column import (
+            HostColumn,
+            StringDict,
+            bucket_capacity,
+            encode_host,
+            relation_from_host,
+        )
+
+        tablet = ts.tablet
+        types = {c.name: c.dtype for c in ts.tdef.columns}
+        n = sum(len(next(iter(a.values()))) for _p, a, _v in runs)
+        with qtrace.span("storage.device_copy", table=ts.tdef.name,
+                         source="load") as sp:
+            snap = self.snapshot_fn()
+            mark = tablet.delta_mark() if isinstance(tablet, Tablet) \
+                else None
+            host = {}
+            for c in tablet.columns:
+                parts = [a[c] for _p, a, _v in runs]
+                vparts = [v.get(c) for _p, _a, v in runs]
+                valid = None
+                if any(v is not None for v in vparts):
+                    valid = np.concatenate(
+                        [v if v is not None else np.ones(len(a), bool)
+                         for a, v in zip(parts, vparts)])
+                if isinstance(parts[0], CodedStrings):
+                    codes = parts[0].codes if len(parts) == 1 else \
+                        np.concatenate([p.codes for p in parts])
+                    host[c] = HostColumn(codes, valid, types[c],
+                                         StringDict(parts[0].values))
                 else:
-                    nd = sampled_ndv(np.asarray(arrays[c.name]),
-                                     rel.capacity)
-                self._defs[name].ndv[c.name] = nd
-            self.schema_version += 1
-            self._cache.invalidate(name)
+                    data = parts[0] if len(parts) == 1 else \
+                        np.concatenate(parts)
+                    host.update(encode_host({c: data}, types, {c: valid}))
+            enabled, floor, growth = self._bucket_policy()
+            rel = relation_from_host(
+                host, bucket_capacity(n, floor, growth) if enabled else None)
+            nbytes = relation_bytes(rel)
+            sp.tags.update(rows=n, bytes=nbytes)
+        qmetrics.inc("storage.device_copy_builds")
+        qmetrics.inc("storage.device_copy_ns", int(sp.elapsed_s * 1e9))
+        if ts.tdef.hash_partition is not None:
+            part_rows = [0] * len(tablet.partitions)
+            for p, a, _v in runs:
+                part_rows[p] = len(next(iter(a.values())))
+            rel.partitions = self._partitions_of(ts, part_rows, rel)
+        self._cache.put(ts.tdef.name,
+                        DeviceCopy(tablet.data_version, rel, tablet, snap,
+                                   mark, n, n), nbytes=nbytes)
+        return nbytes
+
+    def _book_resident(self):
+        """``storage.device_copy_bytes`` follows the relation cache."""
+        now = self._cache.stats()["bytes"]
+        qmetrics.inc("storage.device_copy_bytes", now - self._booked_bytes)
+        self._booked_bytes = now
 
     # -- capacity bucketing (the static-shape policy) --------------------
     def _bucket_policy(self):
@@ -1560,6 +1690,7 @@ class StorageCatalog(Catalog):
 
                 self._cache.put(name, copy,
                                 nbytes=relation_bytes(copy.rel))
+                self._book_resident()
             # record the LIVE row count, not the padded capacity: the
             # binder's est_rows drives join/groupby capacity budgets and
             # spill decisions, which must not drift with pad lanes
@@ -1733,3 +1864,4 @@ class StorageCatalog(Catalog):
     def invalidate(self, name: str):
         with self._lock:
             self._cache.invalidate(name)
+            self._book_resident()

@@ -27,6 +27,7 @@ import threading
 import numpy as np
 
 from oceanbase_tpu.share import keyhash
+from oceanbase_tpu.storage.encoding import CodedStrings
 from oceanbase_tpu.storage.tablet import Tablet
 
 
@@ -252,8 +253,11 @@ class PartitionedTablet:
                 datas.append(d if v is None else np.where(v, d, 0))
             idx = keyhash.partition_of(datas, len(self.partitions))
         else:
-            idx = np.searchsorted(np.asarray(self.bounds),
-                                  arrays[self.part_col], side="right")
+            col = arrays[self.part_col]
+            if isinstance(col, CodedStrings):   # a load's factorised column
+                col = col.strings()
+            idx = np.searchsorted(np.asarray(self.bounds), col,
+                                  side="right")
         out = []
         for i in range(len(self.partitions)):
             sel = idx == i

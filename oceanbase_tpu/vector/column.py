@@ -168,8 +168,32 @@ class StringDict:
     @staticmethod
     def encode(strings: np.ndarray) -> tuple[np.ndarray, "StringDict"]:
         """Encode raw strings -> (int32 codes, dict)."""
-        values, codes = np.unique(np.asarray(strings), return_inverse=True)
-        return codes.astype(np.int32), StringDict(values)
+        codes, values = factorize_strings(np.asarray(strings))
+        return codes, StringDict(values)
+
+
+def factorize_strings(strings: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """-> (int32 codes, the distinct strings sorted ascending), with
+    ``values[codes]`` the input.  An array of Python strings takes ONE
+    hash pass by value (``pandas.factorize``: 60-70 ns a row, whether the
+    rows share their string objects or not) and a sort of the distinct
+    strings alone, where ``np.unique`` sorts every row's string (1.3 us a
+    row).  pandas is a requirement (``requirements.txt``): there is no
+    second way.  A fixed-width array is NumPy's own to sort."""
+    if strings.dtype != object or strings.ndim != 1:
+        values, codes = np.unique(strings, return_inverse=True)
+        return codes.astype(np.int32).reshape(-1), values
+    from pandas import factorize
+
+    first_seen, distinct = factorize(strings)
+    if len(first_seen) and first_seen.min() < 0:
+        # as ``np.unique``'s sort says it; a NULL is the validity's to say
+        raise TypeError("a NULL among the strings of a column")
+    distinct = np.asarray(distinct, dtype=object)
+    order = np.argsort(distinct, kind="stable")
+    rank = np.empty(len(order), dtype=np.int32)
+    rank[order] = np.arange(len(order), dtype=np.int32)
+    return rank[first_seen], distinct[order]
 
 
 @jax.tree_util.register_pytree_node_class
@@ -325,22 +349,34 @@ class Relation:
 # ---------------------------------------------------------------------------
 
 
-def from_numpy(
+@dataclass
+class HostColumn:
+    """One column encoded on the host as the device will hold it: what
+    ``from_numpy`` copies over, and what a direct load builds its segment
+    and its device copy from (one encode for both)."""
+
+    data: np.ndarray            # dtype.np_dtype values / int32 codes
+    valid: Optional[np.ndarray]
+    dtype: SqlType
+    sdict: Optional[StringDict] = None
+
+
+def encode_host(
     arrays: dict[str, np.ndarray],
     types: dict[str, SqlType] | None = None,
     valids: dict[str, np.ndarray] | None = None,
-    device=None,
-) -> Relation:
-    """Build a device Relation from host numpy columns.
-
-    String (object/str-dtype) columns are dictionary-encoded here.
-    """
-    cols: dict[str, Column] = {}
+) -> dict[str, HostColumn]:
+    """Host numpy columns -> ``HostColumn``s: types settled, string
+    (object/str-dtype) columns dictionary-encoded.  Touches no device."""
+    cols: dict[str, HostColumn] = {}
     for name, arr in arrays.items():
         arr = np.asarray(arr)
         sdict = None
         want = types.get(name) if types else None
         vec_t = want is not None and want.kind == TypeKind.VECTOR
+        valid = None
+        if valids and name in valids and valids[name] is not None:
+            valid = np.asarray(valids[name]).astype(np.bool_)
         if arr.dtype == object and len(arr) and \
                 isinstance(arr.reshape(-1)[0], (list, np.ndarray)) and \
                 arr.ndim == 1:
@@ -355,39 +391,75 @@ def from_numpy(
                 dim = want.precision if want is not None else 0
                 arr = np.zeros((len(arr), dim), dtype=np.float32)
             data = arr.astype(np.float32)
-            dtype = SqlType.vector(data.shape[1])
-            valid = None
-            if valids and name in valids and valids[name] is not None:
-                valid = jnp.asarray(valids[name].astype(np.bool_))
-            cols[name] = Column(jax.device_put(jnp.asarray(data), device),
-                                valid, dtype)
+            cols[name] = HostColumn(data, valid,
+                                    SqlType.vector(data.shape[1]))
             continue
         if arr.dtype.kind in ("U", "S", "O"):
-            codes, sdict = StringDict.encode(arr)
-            data = codes
+            data, sdict = StringDict.encode(arr)
             dtype = SqlType.string()
         else:
             data = arr
             if types and name in types:
                 dtype = types[name]
-                data = arr.astype(dtype.np_dtype)
+                data = arr.astype(dtype.np_dtype, copy=False)
             else:
                 if arr.dtype.kind == "f":
                     dtype = SqlType.double()
-                    data = arr.astype(np.float64)
+                    data = arr.astype(np.float64, copy=False)
                 elif arr.dtype.kind == "b":
                     dtype = SqlType.bool_()
                 else:
                     dtype = SqlType.int_()
-                    data = arr.astype(np.int64)
+                    data = arr.astype(np.int64, copy=False)
         if types and name in types and types[name].is_string:
             dtype = types[name]
-        valid = None
-        if valids and name in valids and valids[name] is not None:
-            valid = jnp.asarray(valids[name].astype(np.bool_))
-        jdata = jax.device_put(jnp.asarray(data), device)
-        cols[name] = Column(data=jdata, valid=valid, dtype=dtype, sdict=sdict)
-    return Relation(columns=cols, mask=None)
+        cols[name] = HostColumn(data, valid, dtype, sdict)
+    return cols
+
+
+def relation_from_host(cols: dict[str, HostColumn],
+                       capacity: int | None = None,
+                       device=None) -> Relation:
+    """Copy ``HostColumn``s to the device, a column at a time.  With a
+    ``capacity`` the relation comes padded to it as ``Relation.pad_to``
+    pads (zero payload, invalid, dead in a mask that is always there),
+    the padding done on the host: the device never holds a column
+    twice."""
+    def put(a: np.ndarray):
+        if capacity is None or capacity <= len(a):
+            return jax.device_put(a, device)
+        pad = np.zeros((capacity - len(a),) + a.shape[1:], a.dtype)
+        # waited for, so that the host holds one padded column at a time
+        return jax.device_put(np.concatenate([a, pad]),
+                              device).block_until_ready()
+
+    out: dict[str, Column] = {}
+    n = 0
+    for name, c in cols.items():
+        n = len(c.data)
+        out[name] = Column(data=put(c.data),
+                           valid=None if c.valid is None else put(c.valid),
+                           dtype=c.dtype, sdict=c.sdict)
+    mask = None
+    if capacity is not None:
+        if capacity < n:
+            raise ValueError(f"capacity {capacity} below the {n} rows")
+        mask = put(np.ones(n, dtype=np.bool_))
+    return Relation(columns=out, mask=mask)
+
+
+def from_numpy(
+    arrays: dict[str, np.ndarray],
+    types: dict[str, SqlType] | None = None,
+    valids: dict[str, np.ndarray] | None = None,
+    device=None,
+) -> Relation:
+    """Build a device Relation from host numpy columns.
+
+    String (object/str-dtype) columns are dictionary-encoded here.
+    """
+    return relation_from_host(encode_host(arrays, types, valids),
+                              device=device)
 
 
 def empty_relation(types: dict[str, "SqlType"]) -> Relation:

@@ -309,3 +309,69 @@ def test_result_pack_compiles_for_v5e(spread, topo, one_chip,
     text = compiled.as_text()
     assert " sort(" not in text and " scatter(" not in text
     assert set(compiled.output_shardings) == {"int64", "float64"}
+
+
+#: SF10's buckets (``benchmark/configs/tpch_sf10.json``): lineitem's 60M
+#: rows in 67,108,864 lanes, part's 2,000,000 in 2,097,152
+SF10_LANES = {"lineitem": 67_108_864, "part": 2_097_152}
+#: the compiler's own limit for one v5e chip's programs
+V5E_PROGRAM_BYTES = int(15.75 * 2**30)
+
+
+@pytest.fixture(scope="module")
+def sf10_session():
+    """An SF0.01 load whose statistics say SF10: rows and key
+    cardinalities x 1,000 (histograms and frequency lists describe
+    distributions and stay), so the binder sizes every capacity as it
+    does over the real tables, and nothing of that size exists here."""
+    sess = _tpch_session(("lineitem", "part"), analyze=True)
+    for name in SF10_LANES:
+        td = sess.catalog.table_def(name)
+        rows = td.row_count
+        for c, ndv in td.ndv.items():
+            if ndv * 10 > rows:         # a key, not a code
+                td.ndv[c] = ndv * 1000
+        td.row_count = rows * 1000
+        assert SF10_LANES[name] // 2 < td.row_count <= SF10_LANES[name]
+    return sess
+
+
+@pytest.mark.parametrize("qnum", [1, 6, 14])   # Q14: 53 s of compile here
+def test_sf10_plan_compiles_for_v5e_and_fits(qnum, sf10_session, one_chip,
+                                             no_persistent_cache):
+    """The plan program of a statement of ``tpch_sf10.q1q6q14`` lowered at
+    SF10's lanes for the described chip: arguments (the columns the plan
+    reaches) plus temporaries plus outputs under the chip's 15.75 GiB by
+    the compiler's own memory analysis."""
+    from oceanbase_tpu.bench.tpch_queries import QUERIES
+    from oceanbase_tpu.exec import plan as qplan
+    from oceanbase_tpu.sql.parser import parse_sql
+
+    sess = sf10_session
+    plan, _outs, _est = sess._plan_select(parse_sql(QUERIES[qnum]), None)
+    key = plan.fingerprint()
+    bundle = qplan.executable_for(
+        qplan.Program(qplan._lower, (plan,), key, key), True)
+    mentioned, renames = bundle.scan_columns
+    tables = {}
+    for name in qplan.referenced_tables(plan):
+        rel = qplan.narrowed(sess.catalog.table_data(name), mentioned,
+                             renames.get(name))
+        lanes = SF10_LANES[name]
+        tables[name] = jax.tree.map(
+            lambda x, lanes=lanes: jax.ShapeDtypeStruct(
+                (lanes,) + x.shape[1:], x.dtype, sharding=one_chip),
+            rel.pad_to(rel.capacity + 1))   # with the mask a load gives
+    compiled = bundle._run.lower(tables).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    print(f"Q{qnum} at SF10 lanes: arguments {ma.argument_size_in_bytes}, "
+          f"outputs {ma.output_size_in_bytes}, temporaries "
+          f"{ma.temp_size_in_bytes} bytes")
+    assert total < V5E_PROGRAM_BYTES, ma
+    if qnum == 14:
+        compacts = [n for n in qplan._postorder(plan)
+                    if isinstance(n, qplan.Compact)]
+        assert len(compacts) == 1 and compacts[0].strict
+        assert compacts[0].capacity <= SF10_LANES["lineitem"] // 8

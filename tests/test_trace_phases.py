@@ -59,6 +59,10 @@ def _owned_share(row) -> float:
 
 def test_serial_phases_sum_to_elapsed(db):
     s = db.session()
+    # the load registered big's device copy (a first read builds nothing);
+    # the miss a statement pays after an eviction or a reopen is what
+    # ``device_copy_s`` owns
+    s.catalog.invalidate("big")
     for _ in range(6):
         s.execute(Q_GROUP).rows()
     rows = _audit(s, "select v, sum(k) as s, count(*)")
