@@ -95,7 +95,8 @@ def monitor_push(op_name: str, count_scalar, est: int | None = None) -> None:
 # note lane: facts of the traced program that an operator picks from
 # static shapes and types (which way a probe ranks its keys, which way a
 # group-by reduces, whether a join's input was compacted to its estimate's
-# bucket, how a PX join is distributed, an exchange buffer's lanes).
+# bucket, whether a join emits on its probe's lanes or expands, how a PX
+# join is distributed, an exchange buffer's lanes).
 # Nothing is traced: the notes are known when lowering ends, the
 # executable keeps their counts per input signature, and every execution
 # adds them to ``gv$sysstat`` (``book_notes``).  A new operator's counter
@@ -107,6 +108,7 @@ NOTE_SERIES = {
     "probe": ("plan.join_probes", "kind"),        # merge | search
     "groupby": ("plan.groupby_reduces", "kind"),  # masked | sort
     "join_input": ("plan.join_inputs", "kind"),   # compacted | whole
+    "join_emit": ("plan.join_emits", "kind"),   # probe_lanes | expanded
     "join": ("px.joins", "dist"),    # partition_wise|broadcast|pkey|hash
     "lanes": ("px.exchange_lanes", "kind"),  # n = lanes a shard
 }

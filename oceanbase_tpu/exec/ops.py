@@ -20,7 +20,10 @@ Design notes (tpu-first re-imaginations of the reference components):
   to a static output capacity via jnp.repeat(total_repeat_length=...);
   multi-column keys go through a 64-bit mix with exact-key verification
   (false positives masked, ≙ the reference's normalized-key fast path in
-  join_hash_table.h:16 with key re-check).
+  join_hash_table.h:16 with key re-check).  A build side the planner
+  found unique on the key (a declared primary key, ``build_unique``)
+  needs none of the expansion: ``_join_on_probe_lanes`` pairs each probe
+  lane with at most one build row and emits on the probe's lanes.
 - ``sort_rows``      ≙ ObSortVecOp (src/sql/engine/sort/ob_sort_vec_op.h:62).
 - Aggregate null/valid handling ≙ IAggregate::add_batch_rows
   (src/share/aggregate/agg_ctx.h:552): dead/null lanes contribute the
@@ -680,6 +683,16 @@ def _keys_valid(cols: Sequence[Column], mask):
 _MERGE_PROBE_MIN_GATHERS = 1 << 22
 
 
+def _ranks_by_merge(rn: int, ln: int, sorts: int = 2) -> bool:
+    """The shape rule: do ``ln`` probe keys rank against ``rn`` build keys
+    by merging (or by binary searches)?  ``sorts``: how many sorts the
+    merge costs the compiler over the search; the constant above is the
+    price of two."""
+    work = ln * max(rn - 1, 1).bit_length()
+    return work * 2 > _MERGE_PROBE_MIN_GATHERS * sorts \
+        and rn + ln < 2 ** 31
+
+
 def _probe_ranges(build_sorted: jax.Array, probe_keys: jax.Array,
                   _path: str | None = None):
     """For every probe key the range ``[lo, hi)`` of equal keys in the
@@ -702,9 +715,7 @@ def _probe_ranges(build_sorted: jax.Array, probe_keys: jax.Array,
     """
     rn, ln = build_sorted.shape[0], probe_keys.shape[0]
     if _path is None:
-        work = ln * max(rn - 1, 1).bit_length()
-        _path = ("merge" if work > _MERGE_PROBE_MIN_GATHERS
-                 and rn + ln < 2 ** 31 else "search")
+        _path = "merge" if _ranks_by_merge(rn, ln) else "search"
     diag.note("probe", _path)
     if _path == "search":
         lo = jnp.searchsorted(build_sorted, probe_keys, side="left")
@@ -738,12 +749,16 @@ def join(
     right_keys: Sequence[ir.Expr],
     how: str = "inner",
     out_capacity: int | None = None,
+    build_unique: bool = False,
 ) -> Relation:
     """Sort-based equi-join; probe side = left, build side = right.
 
     how: inner | left | semi | anti.
     Column names must be disjoint (the planner qualifies them).
     NULL join keys never match (SQL equi-join semantics).
+    ``build_unique``: the planner found the build side unique on the key
+    (``HashJoin.build_unique``); an inner or left join on an exact key
+    then emits on the probe's lanes (``_join_on_probe_lanes``).
     """
     ln, rn = left.capacity, right.capacity
     lm, rm = left.mask_or_true(), right.mask_or_true()
@@ -771,6 +786,10 @@ def join(
     # build: sort right by key, dead/null-key rows pushed to the end
     BIG = jnp.asarray(_INT_MAX, dtype=jnp.int64)
     rkey_s = jnp.where(rvalid, rkey, BIG)
+    if build_unique and exact and how in ("inner", "left"):
+        return _join_on_probe_lanes(
+            left, right, jnp.where(lvalid, lkey, BIG - 1), lvalid, rkey_s,
+            how)
     # the two expensive steps carry a scope of their own: HLO metadata
     # only (device ops read "HashJoin#k/join.probe" in a profile), no
     # cache key sees it
@@ -802,6 +821,7 @@ def join(
     cap = out_capacity if out_capacity is not None else max(ln, rn)
 
     total = jnp.sum(ecounts)
+    diag.note("join_emit", "expanded")
     # static-capacity overflow is a hard error surfaced by the executor
     # (≙ DTL backpressure made compile-time; see exec/diag.py)
     diag.push("join_overflow", jnp.maximum(total - cap, 0),
@@ -887,6 +907,104 @@ def join(
                         mask=jnp.concatenate([live, app_live]))
 
     return Relation(columns=out_cols, mask=live)
+
+
+def _running_max(x: jax.Array) -> jax.Array:
+    """``lax.cummax`` of non-negative int64 lanes, in two levels (within
+    rows of 1,024 lanes, then over the rows' maxima): one flat 64-bit scan
+    costs the TPU compiler 183 s at 393,216 lanes and 146 s at 524,288
+    (11 s at 4,194,304), this form 5 s at each (compiled for a described
+    v5e in the sandbox, PR 39; ``vector/column.py::_live_through`` found
+    the same of a flat ``cumsum``)."""
+    n = x.shape[0]
+    rows = jnp.pad(x, (0, -n % 1024)).reshape(-1, 1024)
+    within = lax.cummax(rows, axis=1)
+    over_rows = lax.cummax(within[:, -1])
+    before = jnp.concatenate([jnp.zeros(1, x.dtype), over_rows[:-1]])
+    return jnp.maximum(within, before[:, None]).reshape(-1)[:n]
+
+
+def _unique_match_by_merge(rkey_s: jax.Array, lkey_p: jax.Array):
+    """For every probe key the row of the ONE build key equal to it
+    (``>= rn``: none), and how many build keys repeat another: build and
+    probe keys sorted together by (key, position), build rows first, so a
+    run of equal keys starts with its build row if it has one; that head's
+    row is carried through the run by a running maximum over (sorted
+    position, row) packed into one int64, and one more sort by position
+    brings it back to probe order.  Two sorts and a scan, sequential
+    access only: no sort of the build side alone, no ``lo`` / ``hi``, and
+    no gather through a permutation (a random gather costs the TPU 20 ns
+    an element, a sort 4 ns a lane; PERF.md section 5)."""
+    rn = rkey_s.shape[0]
+    keys, pos = _sort_with_rows((jnp.concatenate([rkey_s, lkey_p]),))
+    same = keys[1:] == keys[:-1]
+    # a build row behind an equal key follows a build row: a repeat
+    dups = jnp.sum((same & (pos[1:] < rn)
+                    & (keys[1:] != _INT_MAX)).astype(jnp.int64))
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), ~same])
+    at = lax.iota(jnp.int64, keys.shape[0])
+    head = _running_max(jnp.where(first,
+                                  (at << 32) | pos.astype(jnp.int64), 0))
+    head_row = (head & 0xFFFFFFFF).astype(jnp.int32)
+    _, row = lax.sort((pos, head_row), num_keys=1, is_stable=False)
+    return row[rn:], dups
+
+
+def _unique_match_by_search(rkey_s: jax.Array, lkey_p: jax.Array):
+    """``_unique_match_by_merge``'s answer where the probe is too small to
+    pay for the merge's compile (``_ranks_by_merge``): the build side
+    sorted alone, its sorted keys taken FROM the sort, one
+    binary search a probe key and one gather through the permutation."""
+    rn = rkey_s.shape[0]
+    with jax.named_scope("join.sort_build"):
+        rkey_sorted, border = _sort_with_rows((rkey_s,))
+    dups = jnp.sum(((rkey_sorted[1:] == rkey_sorted[:-1])
+                    & (rkey_sorted[1:] != _INT_MAX)).astype(jnp.int64))
+    at = jnp.minimum(jnp.searchsorted(rkey_sorted, lkey_p, side="left"),
+                     rn - 1)
+    found = jnp.take(rkey_sorted, at) == lkey_p
+    return jnp.where(found, jnp.take(border, at), rn), dups
+
+
+def _join_on_probe_lanes(left: Relation, right: Relation,
+                         lkey_p: jax.Array, lvalid: jax.Array,
+                         rkey_s: jax.Array, how: str) -> Relation:
+    """Inner / left join against a build side that holds each key once:
+    every probe row pairs with at most one build row, so the output stays
+    on the probe's lanes.  The probe's columns pass as they are, the
+    build's are gathered once by the matched row; no prefix sum, no
+    ``jnp.repeat``, and nothing can overflow.
+
+    ``rkey_s``: the build keys, ``_INT_MAX`` on dead / NULL-key lanes;
+    ``lkey_p``: the probe keys, ``_INT_MAX - 1`` on such lanes.
+
+    The guarantee is the planner's reading of a declared primary key; it
+    is checked here: build keys that repeat another are counted on the
+    ``join_build_dup`` lane, and a count above zero makes the session
+    re-plan with the mark off."""
+    rn, ln = right.capacity, left.capacity
+    # the merge sorts twice and the search once (the build side): one
+    # sort more to compile, not ``_probe_ranges``' two, so it pays from
+    # half the gathers (Q14 at SF1, 131,072 lanes into 262,144 keys: 2.5
+    # ms merged, about 35 searched; my chip run, PR 39)
+    path = "merge" if _ranks_by_merge(rn, ln, sorts=1) else "search"
+    diag.note("probe", path)
+    diag.note("join_emit", "probe_lanes")
+    with jax.named_scope("join.probe"):
+        row, dups = (_unique_match_by_merge if path == "merge"
+                     else _unique_match_by_search)(rkey_s, lkey_p)
+    diag.push("join_build_dup", dups)
+    matched = lvalid & (row < rn)
+    build_idx = jnp.minimum(row, rn - 1)
+    out_cols = dict(left.columns)
+    for name, c in right.columns.items():
+        g = c.gather(build_idx)
+        if how == "left":
+            g = Column(g.data, g.valid_or_true() & matched, c.dtype, c.sdict)
+        out_cols[name] = g
+    lm = left.mask_or_true()
+    return Relation(columns=out_cols,
+                    mask=lm & matched if how == "inner" else lm)
 
 
 def index_probe(

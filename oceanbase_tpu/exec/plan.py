@@ -20,6 +20,7 @@ import re
 import threading
 import time
 from collections import Counter
+import dataclasses
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -51,6 +52,14 @@ qmetrics.declare("plan.join_inputs", "counter",
                  "put under the join; kind=whole: on the lanes of what "
                  "lies under it; sql/optimizer.py::compact_join_input "
                  "decides at bind time)")
+qmetrics.declare("plan.join_emits", "counter",
+                 "joins executed that pair probe and build rows, by "
+                 "where the pairs land (kind=probe_lanes: one lane per "
+                 "probe lane, the build side unique on the key by a "
+                 "declared primary key, HashJoin.build_unique; "
+                 "kind=expanded: prefix sum + repeat into out_capacity "
+                 "lanes; a semi / anti join on an exact key only masks "
+                 "its probe and books neither)")
 qmetrics.declare("plan.compiles", "counter",
                  "XLA trace+compile events (per plan x input signature)")
 qmetrics.declare("plan.capacity_retries", "counter",
@@ -250,9 +259,25 @@ class HashJoin(PlanNode):
     how: str = "inner"
     out_capacity: Optional[int] = None
     est_rows: Optional[int] = _est_field()
+    # the build (right) side is a filter chain over a scan and the one
+    # join key its table's declared primary key, and the probe's lanes
+    # fit the out_capacity: the join emits one lane per probe lane
+    # (sql/optimizer.py::unique_build decides at bind time; ops.join
+    # checks the guarantee at run time)
+    build_unique: bool = field(default=False, repr=False)
 
     def children(self):
         return (self.left, self.right)
+
+    def __repr__(self):
+        # an unmarked join renders as it did before the mark existed, so
+        # every plan that does not take the new path keeps its fingerprint
+        # (and with it gv$plan_cache's plan_hash and the AOT cache's key)
+        parts = [f"{f.name}={getattr(self, f.name)!r}"
+                 for f in dataclasses.fields(self) if f.repr]
+        if self.build_unique:
+            parts.append("build_unique=True")
+        return f"{type(self).__qualname__}({', '.join(parts)})"
 
 
 @dataclass(repr=True)
@@ -381,7 +406,7 @@ def _logical_repr(node: PlanNode) -> str:
     parts = []
     for k, v in vars(node).items():
         if k in ("out_capacity", "capacity", "est_rows") or \
-                k.startswith("_"):
+                k.startswith("_") or (k == "build_unique" and not v):
             continue
         if isinstance(v, PlanNode) or k in ("child", "left", "right",
                                             "inputs"):
@@ -434,7 +459,6 @@ def propagate_estimates(node: PlanNode,
     else inherits a defensible bound so EVERY operator row in
     gv$sql_plan_monitor carries an estimate to q-error against.
     ``row_counts`` maps table -> live rows for un-annotated scans."""
-    import dataclasses
 
     kids: dict = {}
     changed = False
@@ -613,6 +637,7 @@ def _lower_inner(node: PlanNode, tables: dict[str, Relation]) -> Relation:
             _lower(node.right, tables, node),
             node.left_keys, node.right_keys, how=node.how,
             out_capacity=node.out_capacity,
+            build_unique=node.build_unique,
         )
     if isinstance(node, IndexProbe):
         note_join_inputs(node)

@@ -309,6 +309,13 @@ def encode_plan(node: pp.PlanNode):
     if isinstance(node, pp.ScalarAgg):
         return {"p": "scalaragg", "child": encode_plan(node.child),
                 "aggs": _enc_aggs(node.aggs)}
+    if isinstance(node, pp.HashJoin):
+        return {"p": "join", "left": encode_plan(node.left),
+                "right": encode_plan(node.right),
+                "lkeys": [encode_expr(k) for k in node.left_keys],
+                "rkeys": [encode_expr(k) for k in node.right_keys],
+                "how": node.how, "cap": node.out_capacity,
+                "unique": node.build_unique}
     raise NotPushable(type(node).__name__)
 
 
@@ -336,6 +343,12 @@ def decode_plan(d) -> pp.PlanNode:
                           _dec_aggs(d["aggs"]), out_capacity=d.get("cap"))
     if k == "scalaragg":
         return pp.ScalarAgg(decode_plan(d["child"]), _dec_aggs(d["aggs"]))
+    if k == "join":
+        return pp.HashJoin(decode_plan(d["left"]), decode_plan(d["right"]),
+                           [decode_expr(e) for e in d["lkeys"]],
+                           [decode_expr(e) for e in d["rkeys"]],
+                           how=d["how"], out_capacity=d.get("cap"),
+                           build_unique=bool(d.get("unique", False)))
     raise NotPushable(f"plan tag {k!r}")
 
 

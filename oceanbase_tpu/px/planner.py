@@ -447,9 +447,11 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
             return ops.join(left, right, node.left_keys, node.right_keys,
                             how=node.how,
                             out_capacity=_local_cap(node.out_capacity,
-                                                    ndev))
+                                                    ndev),
+                            build_unique=node.build_unique)
         return _djoin(left, right, node.left_keys, node.right_keys,
-                      node.how, node.out_capacity, ndev, axis, factor)
+                      node.how, node.out_capacity, ndev, axis, factor,
+                      node.build_unique)
     if isinstance(node, pp.ScalarAgg):
         # mid-plan scalar aggregate (a scalar-subquery fragment): local
         # partials -> all_gather (the datahub barrier) -> final merge;
@@ -568,10 +570,15 @@ def _names(keys, pos) -> tuple:
     return tuple(keys[i].name for i in pos)
 
 
-def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
+def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1,
+           build_unique=False):
     """One join of the shard program: pick its distribution method from
     where both sides lie (module docstring), note it, say where the
-    output lies."""
+    output lies.  ``build_unique`` (``HashJoin.build_unique``) holds on
+    every shard, whose build side is a subset or a copy of the whole: it
+    goes to every local ``ops.join`` but the hybrid hash join's, whose
+    probe is an exchange buffer beside the whole local side, lanes the
+    planner's comparison did not weigh."""
     lrep = getattr(left, "_px_replicated", False)
     rrep = getattr(right, "_px_replicated", False)
     if rrep:
@@ -583,7 +590,7 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
             raise NotDistributable("full join with a replicated build")
         diag.note("join", "broadcast")
         out = ops.join(left, right, lkeys, rkeys, how=how,
-                       out_capacity=cap)
+                       out_capacity=cap, build_unique=build_unique)
         if lrep:
             out._px_replicated = True
         return _placed(out, _dist(left))
@@ -597,7 +604,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
                 f"replicated probe side with {how} join")
         diag.note("join", "broadcast")
         return _placed(ops.join(left, right, lkeys, rkeys, how=how,
-                                out_capacity=cap), _dist(right))
+                                out_capacity=cap,
+                                build_unique=build_unique), _dist(right))
     # where each side lies by (some of) its join keys, as key positions
     lpos = [(p, a) for p, a in _key_positions(_dist(left), lkeys)
             if _pairs_hashable(left, right, lkeys, rkeys, p)]
@@ -608,7 +616,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         # one hash, so matching rows are already on one shard
         diag.note("join", "partition_wise")
         out = ops.join(left, right, lkeys, rkeys, how=how,
-                       out_capacity=_local_cap(cap, ndev))
+                       out_capacity=_local_cap(cap, ndev),
+                       build_unique=build_unique)
         return _placed(out, _dist(left) | _dist(right) if how == "inner"
                        else () if how == "full" else _dist(left))
     if how == "full":
@@ -639,7 +648,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         diag.note("join", "broadcast")
         diag.note("lanes", "broadcast", ndev * right.capacity)
         return _placed(ops.join(left, bright, lkeys, rkeys, how=how,
-                                out_capacity=cap), _dist(left))
+                                out_capacity=cap,
+                                build_unique=build_unique), _dist(left))
     if lpos or rpos:
         # PKEY: one side lies by its join keys already; only the other
         # moves, to those partitions (the smaller when either could)
@@ -657,7 +667,8 @@ def _djoin(left, right, lkeys, rkeys, how, cap, ndev, axis, factor=1):
         diag.push("px_exchange_overflow", ovf)
         out = ops.join(recv if move_left else left,
                        right if move_left else recv, lkeys, rkeys,
-                       how=how, out_capacity=_local_cap(cap, ndev))
+                       how=how, out_capacity=_local_cap(cap, ndev),
+                       build_unique=build_unique)
         if how == "inner":
             # the moved rows lie by their own key now: equal values
             stayed = _dist(right) if move_left else _dist(left)

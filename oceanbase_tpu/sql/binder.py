@@ -586,10 +586,14 @@ class Binder:
         cap = _pow2(int((lf.est_rows + (rf.est_rows
                                         if how == "full" else 0))
                         * 1.5) + 16)
+        from oceanbase_tpu.sql.optimizer import unique_build
+
         plan = pp.HashJoin(lf.plan, rf.plan, lkeys, rkeys, how=how,
                            out_capacity=cap,
                            est_rows=max(1, lf.est_rows + (
-                               rf.est_rows if how == "full" else 0)))
+                               rf.est_rows if how == "full" else 0)),
+                           build_unique=how == "left" and unique_build(
+                               lf.plan, rf.plan, rkeys, cap, self.catalog))
         for p in lpreds + residual:
             # ON predicates on the left side of a LEFT JOIN semantically
             # only nullify matches; approximate by post-filtering matched

@@ -401,6 +401,75 @@ def compact_join_input(f, catalog, capacity_factor: float = 1.5):
                       est_rows=f.est_rows), f.est_rows)
 
 
+def _base_column(scan: pp.TableScan, col: ir.ColumnRef) -> str:
+    """The table's own name of a scan's output column."""
+    inv = {cid: base for base, cid in (scan.rename or {}).items()}
+    return inv.get(col.name, col.name)
+
+
+def _static_lanes(plan, catalog) -> int | None:
+    """The lanes ``plan``'s output arrives on, where the plan alone says
+    so: a ``Compact``'s capacity, a scan's ``catalog.scan_lanes`` (filters
+    only mask), a join's output lanes.  None: unknown."""
+    node = plan
+    while isinstance(node, pp.Filter):
+        node = node.child
+    if isinstance(node, pp.Compact):
+        return node.capacity
+    if isinstance(node, pp.TableScan):
+        try:
+            return catalog.scan_lanes(node.table)
+        except KeyError:    # a relation the session injects, not a table
+            return None
+    if isinstance(node, pp.HashJoin) and node.how in ("inner", "left"):
+        if node.build_unique:
+            return _static_lanes(node.left, catalog)
+        return node.out_capacity
+    return None
+
+
+def unique_build(probe, build, build_keys, out_capacity, catalog) -> bool:
+    """Does a join of ``probe`` against ``build`` emit on its probe's
+    lanes (``HashJoin.build_unique``)?  Yes when the build side is
+    PROVABLY unique on the join key — a Filter*/Compact* chain over a
+    scan, one key pair, its column on that side the table's single-column
+    primary key — and the probe's static lanes are at most the
+    ``out_capacity`` the join would expand into: the output then has no
+    more lanes than today's, and none of the expansion's work.
+    ``Fragment.unique_cols`` is not used: after a join it is the union of
+    both sides' keys, good for estimates, not sound as a guarantee."""
+    if len(build_keys) != 1 or not isinstance(build_keys[0], ir.ColumnRef):
+        return False
+    chain = _frag_scan_chain(build)
+    if chain is None:
+        return False
+    scan = chain[0]
+    try:
+        pk = list(catalog.table_def(scan.table).primary_key or [])
+    except KeyError:    # a relation the session injects, not a table
+        return False
+    if pk != [_base_column(scan, build_keys[0])]:
+        return False
+    lanes = _static_lanes(probe, catalog)
+    return lanes is not None and out_capacity is not None \
+        and lanes <= out_capacity
+
+
+def without_unique_builds(node: pp.PlanNode) -> pp.PlanNode:
+    """The plan with every ``HashJoin.build_unique`` mark off, each join
+    as it is oriented: what a statement re-plans to when a build side
+    repeated its key (``join_build_dup``)."""
+    import dataclasses
+
+    updates = {f: without_unique_builds(getattr(node, f))
+               for f in ("child", "left", "right") if hasattr(node, f)}
+    if hasattr(node, "inputs"):
+        updates["inputs"] = [without_unique_builds(c) for c in node.inputs]
+    if isinstance(node, pp.HashJoin):
+        updates["build_unique"] = False
+    return dataclasses.replace(node, **updates) if updates else node
+
+
 def _index_for(catalog, table: str, base_col: str):
     """Leading-column secondary index on ``table.base_col`` -> index
     name, or None.  Only int-like columns qualify (the searchsorted
@@ -441,8 +510,7 @@ def _inl_candidate(ri: _Item, frags, keys, catalog):
     rk = keys[0][1]
     if not isinstance(rk, ir.ColumnRef):
         return None
-    inv = {cid: base for base, cid in (scan.rename or {}).items()}
-    base_col = inv.get(rk.name, rk.name)
+    base_col = _base_column(scan, rk)
     iname = _index_for(catalog, scan.table, base_col)
     if iname is None:
         return None
@@ -503,13 +571,17 @@ def _build_plan(item: _Item, frags, edges, model: CostModel, catalog,
         for pred in reversed(preds):
             node = pp.Filter(node, pred, est_rows=max(1, out_est))
         return node
-    if swap:
-        return pp.HashJoin(rplan, lplan, [k[1] for k in keys],
-                           [k[0] for k in keys], how="inner",
-                           out_capacity=cap, est_rows=max(1, out_est))
-    return pp.HashJoin(lplan, rplan, [k[0] for k in keys],
-                       [k[1] for k in keys], how="inner",
-                       out_capacity=cap, est_rows=max(1, out_est))
+    fwd = (lplan, rplan, [k[0] for k in keys], [k[1] for k in keys])
+    rev = (rplan, lplan, fwd[3], fwd[2])
+    priced, other = (rev, fwd) if swap else (fwd, rev)
+    # a side that holds the key once builds, whichever side the cost
+    # model would have sorted
+    marked = next((o for o in (priced, other)
+                   if unique_build(o[0], o[1], o[3], cap, catalog)), None)
+    probe, build, pkeys, bkeys = marked or priced
+    return pp.HashJoin(probe, build, pkeys, bkeys, how="inner",
+                       out_capacity=cap, est_rows=max(1, out_est),
+                       build_unique=marked is not None)
 
 
 # ---------------------------------------------------------------------------
@@ -708,6 +780,23 @@ def overflow_jump_factor(drops: list, slack: float = 1.5) -> int:
             f *= 4
         need = max(need, f)
     return need
+
+
+def after_overflow(plan: pp.PlanNode, drops: list,
+                   jump: bool = True) -> tuple[pp.PlanNode, int]:
+    """What a CapacityOverflow asks of the next attempt: -> (the plan to
+    scale, the factor its budgets grow by).  A build side that repeated
+    its declared key (lane ``join_build_dup`` of ``ops.join``) takes the
+    ``build_unique`` marks off; a PX program's total names no lane, so
+    an overflow without lanes takes them off as well.  Budgets grow when
+    a budget overflowed: by ``overflow_jump_factor`` with ``jump``, else
+    by the ladder's 4."""
+    dup = [d for d in drops if d[0] == "join_build_dup"]
+    if dup or not drops:
+        plan = without_unique_builds(plan)
+    if drops and len(dup) == len(drops):
+        return plan, 1
+    return plan, overflow_jump_factor(drops) if jump else 4
 
 
 def apply_feedback(plan: pp.PlanNode, corrections: dict,

@@ -1489,18 +1489,17 @@ class Session:
                             return res
                         raise
                     qmetrics.inc("plan.capacity_retries")
-                    if feedback_on:
-                        # the overflow report carries (lane, static cap,
-                        # rows dropped): jump straight to a clearing
-                        # budget instead of riding the blind 4x ladder
-                        from oceanbase_tpu.sql.optimizer import (
-                            overflow_jump_factor,
-                        )
+                    # the overflow report carries (lane, static cap,
+                    # rows dropped): with feedback on, jump straight to
+                    # a clearing budget instead of riding the blind 4x
+                    # ladder; a build side that repeated its key gives
+                    # up its joins' marks, not its budgets
+                    from oceanbase_tpu.sql.optimizer import after_overflow
 
-                        factor *= overflow_jump_factor(
-                            getattr(ovf, "drops", None))
-                    else:
-                        factor *= 4
+                    plan, step = after_overflow(
+                        plan, getattr(ovf, "drops", None) or [],
+                        jump=feedback_on)
+                    factor *= step
                     if monitor is not None:
                         monitor.clear()
             xsp.tags.update(attempts=attempt + 1, factor=factor,
@@ -1512,13 +1511,14 @@ class Session:
                 xsp.tags["px_downgrade"] = 1
         exec_elapsed = time.monotonic() - t0
         with qtrace.span("plan.record"):
-            if factor > 1 and use_cache:
+            if attempt > 0 and use_cache:
                 # evolve the cached plan: a plan bound against a smaller
-                # table keeps overflowing its stale capacity budgets,
-                # which would replay the whole (device-executing) retry
-                # ladder on EVERY later execution — cache the
-                # successfully scaled plan in its place so the next run
-                # starts where this one ended
+                # table keeps overflowing its stale capacity budgets
+                # (and one whose build side repeats its key keeps
+                # finding that out), which would replay the whole
+                # (device-executing) retry ladder on EVERY later
+                # execution — cache the successfully re-planned one in
+                # its place so the next run starts where this one ended
                 key = (self._ash_state["sql"], tuple(params or []),
                        self.catalog.schema_version)
                 if key in self.plan_cache:
@@ -2234,11 +2234,12 @@ class Session:
                                 self.variables["max_capacity_retry"]):
                             raise
                         from oceanbase_tpu.sql.optimizer import (
-                            overflow_jump_factor,
+                            after_overflow,
                         )
 
-                        factor *= overflow_jump_factor(
-                            getattr(ovf, "drops", None))
+                        plan, step = after_overflow(
+                            plan, getattr(ovf, "drops", None) or [])
+                        factor *= step
                         monitor.clear()
                 # monitor entries arrive in the executor's postorder
                 # (pass-through ops emit no lane); map them back to
@@ -3428,6 +3429,12 @@ def format_plan(node, indent: int = 0, row_counts: dict | None = None) -> str:
     for k, v in vars(node).items():
         if k == "est_rows" or k.startswith("_"):
             continue  # ledger annotation / memoized metadata
+        if k == "build_unique":
+            # the join's emit kind, readable without a trace; an
+            # unmarked join prints as it always did
+            if v:
+                attrs.insert(0, "unique build, on probe lanes")
+            continue
         if isinstance(v, pp.PlanNode) or k in ("child", "left", "right",
                                                "inputs"):
             continue

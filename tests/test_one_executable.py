@@ -144,8 +144,10 @@ def test_two_signatures_of_a_px_plan_keep_their_own_notes(pair,
 
     monkeypatch.setattr(qplan._PlanExecutable, "call", spy)
     # 64 lanes a shard probe by search, 128 by merge: the shape rule's
-    # floor between the two (lanes x bits of the build side)
-    monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", 64 * 7)
+    # floor between the two (lanes x bits of the build side; the join
+    # builds on a primary key, and a join on its probe's lanes merges
+    # from half the floor)
+    monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", 2 * 64 * 7)
     small = _px(pair, Q_JOIN)
     _grow_a(pair)
     pair.execute("insert into b values " + ", ".join(

@@ -330,7 +330,8 @@ def test_join_same_relation_above_and_below_threshold(how, keys,
         monkeypatch.setattr(ops, "_MERGE_PROBE_MIN_GATHERS", floor)
         with diag.note_collect() as notes:
             rel = join(left, right, lk, rk, how=how, out_capacity=4096)
-        assert notes == [("probe", kind, 1)]
+        # (the joins that pair rows also note how they emit: join_emit)
+        assert [n for n in notes if n[0] == "probe"] == [("probe", kind, 1)]
         got[kind] = _relation_arrays(rel)
     assert sorted(got["merge"]) == sorted(got["search"])
     for name, want in got["search"].items():

@@ -173,6 +173,12 @@ def _load(sess, n=300):
 
 
 def test_device_split_recorded(db):
+    from oceanbase_tpu.exec.plan import reset_plan_cache_stats
+
+    # gv$plan_cache is the process's: a PX shard program an earlier file
+    # of this worker executed (executions without device_executions)
+    # would tie with, or outrank, the plan this test runs
+    reset_plan_cache_stats()
     s = db.session()
     _load(s)
     for _ in range(2):

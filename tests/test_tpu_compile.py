@@ -10,6 +10,8 @@ inside a fixture, never at import: one process at a time may load the TPU
 library, and every xdist worker imports every test file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -146,6 +148,24 @@ def test_probe_ranges_compile_for_v5e(path, one_chip, no_persistent_cache):
               for n in (4096, 65536)]
     _fits(jax.jit(lambda b, p: ops._probe_ranges(b, p, _path=path))
           .lower(*shapes).compile())
+
+
+@pytest.mark.parametrize("path", ["merge", "search"])
+def test_unique_match_compiles_for_v5e(path, one_chip, no_persistent_cache):
+    """Both ways a join on its probe's lanes finds each probe key's one
+    build row (``ops._join_on_probe_lanes``; an SF0.01 plan is below the
+    shape rule, the SF10 case further down holds the merge at real size):
+    no scatter in either, and no gather in the merge."""
+    from oceanbase_tpu.exec import ops
+
+    shapes = [jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
+              for n in (4096, 65536)]
+    compiled = jax.jit(getattr(ops, "_unique_match_by_" + path)).lower(
+        *shapes).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert " scatter(" not in text
+    assert (" gather(" in text) == (path == "search")
 
 
 def test_px_groupby_exchange_compiles_for_four_chips(topo,
@@ -375,3 +395,16 @@ def test_sf10_plan_compiles_for_v5e_and_fits(qnum, sf10_session, one_chip,
                     if isinstance(n, qplan.Compact)]
         assert len(compacts) == 1 and compacts[0].strict
         assert compacts[0].capacity <= SF10_LANES["lineitem"] // 8
+        # the join emits on the compacted probe's lanes: no scatter-add,
+        # and beside the compaction's own gathers out of the scan's lanes
+        # only p_type's codes (by the matched row) and the dictionary
+        # predicate's are gathered; no compacted lineitem column is
+        (join,) = [n for n in qplan._postorder(plan)
+                   if isinstance(n, qplan.HashJoin)]
+        assert join.build_unique and join.left is compacts[0]
+        text = compiled.as_text()
+        assert " scatter(" not in text
+        gathers = re.findall(r"= (\w+)\[(\d+)\]\S* gather\((%\S+),", text)
+        bucket = str(compacts[0].capacity)
+        assert all(lanes == bucket for _t, lanes, _src in gathers)
+        assert len(gathers) <= 7 + 2, gathers
