@@ -1176,7 +1176,7 @@ class ExecTimes:
     bind_s = sidecar_build_s = lower_s = compile_s = dispatch_s = 0.0
     merge_s = parse_s = admission_s = virtuals_s = prepare_s = 0.0
     tables_s = device_copy_s = trace_s = cache_lookup_s = shard_s = 0.0
-    delta_apply_s = 0.0
+    delta_apply_s = dml_s = tx_commit_s = log_sync_s = freeze_s = 0.0
     unshard_s = monitor_s = record_s = materialize_s = gc_s = 0.0
     close_s = 0.0
 

@@ -24,6 +24,8 @@ from oceanbase_tpu.palf.election import (
     VoteRequest,
 )
 from oceanbase_tpu.palf.log import LogEntry, PalfReplica
+from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 
 
 class NotLeader(RuntimeError):
@@ -109,6 +111,7 @@ class PalfCluster:
                 continue
             if self._ship(ldr, r):
                 acks += 1
+        qmetrics.inc("palf.acks", acks)
         if acks >= len(self.replicas) // 2 + 1:
             ldr.advance_commit(entries[-1].lsn)
             self._broadcast_commit(ldr.committed_lsn)
@@ -124,12 +127,21 @@ class PalfCluster:
     # append path (≙ submit_log -> replicate -> majority ack -> commit)
     # ------------------------------------------------------------------
     def append(self, payloads: list[bytes]) -> int:
-        """Group-append on the leader; returns committed end LSN."""
+        """Group-append on the leader; returns committed end LSN.  Span
+        ``palf.append`` (its self time: shipping, the commit rule) over a
+        ``palf.persist`` for each replica that wrote and a ``palf.apply``
+        for each that applied; an election the append had to run first
+        (the lease lapsed) lies under it too, tagged ``elected``."""
         from oceanbase_tpu.server.errsim import ERRSIM
 
         ERRSIM.hit("palf.append")
-        with self._lock:
+        with qtrace.span("palf.append", entries=len(payloads),
+                         bytes=sum(map(len, payloads))) as sp, self._lock:
+            was = None if self.leader_id is None else \
+                (self.leader_id, self.replicas[self.leader_id].current_term)
             ldr = self.leader()
+            if (ldr.replica_id, ldr.current_term) != was:
+                sp.tags["elected"] = 1
             entries = ldr.leader_append(payloads)
             acks = 1
             for i, r in self.replicas.items():
@@ -140,6 +152,9 @@ class PalfCluster:
                 if self._ship(ldr, r):
                     acks += 1
             quorum = len(self.replicas) // 2 + 1
+            sp.tags.update(acks=acks, quorum=quorum,
+                           replicas=len(self.replicas))
+            qmetrics.inc("palf.acks", acks)
             if acks < quorum:
                 raise NoQuorum(
                     f"append replicated to {acks}/{len(self.replicas)}")

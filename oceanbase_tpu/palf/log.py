@@ -27,20 +27,27 @@ from typing import Callable, Optional
 
 from oceanbase_tpu.native import crc64
 from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 
 log = logging.getLogger(__name__)
 
-# replication-plane accounting (host side; server/metrics.py registry)
+# replication-plane accounting (host side; server/metrics.py registry;
+# who reads each: PERF.md section 3)
 qmetrics.declare("palf.appends", "counter",
                  "leader group-append batches")
-qmetrics.declare("palf.entries_appended", "counter",
-                 "log entries appended on the leader")
+qmetrics.declare("palf.acks", "counter",
+                 "replicas that had a group append on disk when it was "
+                 "acknowledged, the leader's own included, summed over "
+                 "appends")
+qmetrics.declare("palf.append_bytes", "counter",
+                 "encoded entries handed to the log files' write(), every "
+                 "replica of this process", unit="bytes")
 qmetrics.declare("palf.fsyncs", "counter",
                  "durable log fsyncs (append path)")
+qmetrics.declare("palf.fsync_ns", "counter",
+                 "time in flush + fsync on the append path", unit="ns")
 qmetrics.declare("palf.fsync_s", "histogram",
                  "append-path fsync latency", unit="s")
-qmetrics.declare("palf.entries_applied", "counter",
-                 "committed entries applied through the state machine")
 
 _HDR = struct.Struct("<QQIQ")  # term, lsn(index), payload_len, crc64
 _MAGIC = b"OBTPULG1"  # file magic + format version (bump on layout change)
@@ -153,6 +160,14 @@ class PalfReplica:
         as typed DiskFull/DiskIOError, never a bare OSError."""
         if self.log_dir is None:
             return
+        with qtrace.span("palf.persist", replica=self.replica_id,
+                         role=self.role) as sp:
+            sp.tags["bytes"], sp.tags["fsync_ns"] = self._write_durably(
+                entries)
+
+    def _write_durably(self, entries: list[LogEntry]) -> tuple[int, int]:
+        """``_persist``'s body: encode, write, flush, ``os.fsync``; ->
+        (bytes written, ns in flush + fsync)."""
         path = self._log_path()
         buf = b"".join(e.encode() for e in entries)
         pre_off = None
@@ -180,9 +195,10 @@ class PalfReplica:
                     raise OSError(errno.ENOSPC,
                                   "fault: partial WAL write", path)
             self._log_f.write(buf)
-            t0 = time.perf_counter()
+            t0 = time.perf_counter_ns()
             self._log_f.flush()
             os.fsync(self._log_f.fileno())
+            fsync_ns = time.perf_counter_ns() - t0
         except OSError as exc:
             self._unwind_append(pre_off)
             from oceanbase_tpu.server.diskmgr import wrap_disk_error
@@ -191,9 +207,12 @@ class PalfReplica:
                 exc, f"palf replica {self.replica_id} wal append"
             ) from exc
         qmetrics.inc("palf.fsyncs")
-        qmetrics.observe("palf.fsync_s", time.perf_counter() - t0)
+        qmetrics.inc("palf.fsync_ns", fsync_ns)
+        qmetrics.inc("palf.append_bytes", len(buf))
+        qmetrics.observe("palf.fsync_s", fsync_ns * 1e-9)
         if self.faults is not None:
             self.faults.act_disk("wal", path)
+        return len(buf), fsync_ns
 
     def _unwind_append(self, pre_off: int | None):
         """Roll the append file back to the pre-write offset after a
@@ -405,7 +424,6 @@ class PalfReplica:
                 del self.entries[len(self.entries) - len(out):]
                 raise
             qmetrics.inc("palf.appends")
-            qmetrics.inc("palf.entries_appended", len(out))
             return out
 
     def last_lsn(self) -> int:
@@ -520,19 +538,25 @@ class PalfReplica:
         if not self._apply_mutex.acquire(blocking=False):
             return  # an active drainer will observe the new commit point
         try:
-            while True:
-                with self._lock:
-                    if self.applied_lsn >= self.committed_lsn:
-                        return
-                    # applied_lsn never trails base_lsn: recycle clamps
-                    # to the apply point, and recovery of a recycled
-                    # log resumes both points at the base
-                    e = self.entries[self.applied_lsn - self.base_lsn]
-                if self.apply_cb is not None:
-                    self.apply_cb(e)
-                qmetrics.inc("palf.entries_applied")
-                with self._lock:
-                    self.applied_lsn += 1
+            with self._lock:
+                if self.applied_lsn >= self.committed_lsn:
+                    return
+            with qtrace.span("palf.apply", replica=self.replica_id) as sp:
+                applied = 0
+                while True:
+                    with self._lock:
+                        if self.applied_lsn >= self.committed_lsn:
+                            break
+                        # applied_lsn never trails base_lsn: recycle
+                        # clamps to the apply point, and recovery of a
+                        # recycled log resumes both points at the base
+                        e = self.entries[self.applied_lsn - self.base_lsn]
+                    if self.apply_cb is not None:
+                        self.apply_cb(e)
+                    applied += 1
+                    with self._lock:
+                        self.applied_lsn += 1
+                sp.tags["entries"] = applied
         finally:
             self._apply_mutex.release()
 

@@ -36,6 +36,8 @@ from oceanbase_tpu.palf.election import (
     VoteRequest,
 )
 from oceanbase_tpu.palf.log import LogEntry, PalfReplica
+from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 
 
 def _encode_entries(entries: list[LogEntry]) -> list[dict]:
@@ -265,14 +267,19 @@ class NetPalf:
     # append path (PalfCluster-compatible surface)
     # ------------------------------------------------------------------
     def append(self, payloads: list[bytes]) -> int:
-        with self._lock:
-            self.ensure_leader()
-            out = self._replicate(payloads)
-        # deferred applies (drain=False in _replicate) run lock-free
-        self.replica.drain_applies()
+        # the in-process cluster's span and tags; the followers' persists
+        # are their own processes' (no span of theirs reaches this tree)
+        with qtrace.span("palf.append", entries=len(payloads),
+                         bytes=sum(map(len, payloads))) as sp:
+            with self._lock:
+                self.ensure_leader()
+                out = self._replicate(payloads, sp.tags)
+            # deferred applies (drain=False in _replicate) run lock-free
+            self.replica.drain_applies()
         return out
 
-    def _replicate(self, payloads: list[bytes]) -> int:
+    def _replicate(self, payloads: list[bytes],
+                   tags: dict | None = None) -> int:
         r = self.replica
         entries = r.leader_append(payloads)
         commit_target = entries[-1].lsn if entries else r.last_lsn()
@@ -281,6 +288,10 @@ class NetPalf:
             if self._ship_to(pid, r.committed_lsn):
                 acks += 1
         quorum = (len(self.peers) + 1) // 2 + 1
+        qmetrics.inc("palf.acks", acks)
+        if tags is not None:
+            tags.update(acks=acks, quorum=quorum,
+                        replicas=len(self.peers) + 1)
         if acks < quorum:
             raise NoQuorum(
                 f"append replicated to {acks}/{len(self.peers) + 1}")

@@ -94,6 +94,22 @@ PHASE_OF = {
     "px.unshard": "unshard_s",
     "px.merge": "merge_s",
     "px.device_wait": "device_s",
+    # the write path: a DML statement's own work, the commit, what the
+    # commit waits for its replicated log, a foreground flush
+    "dml.bind": "dml_s",
+    "dml.match": "dml_s",
+    "dml.candidates": "dml_s",
+    "dml.predicate": "dml_s",
+    "dml.assign": "dml_s",
+    "dml.rows": "dml_s",
+    "dml.write": "dml_s",
+    "tx.commit": "tx_commit_s",
+    "tx.log_encode": "tx_commit_s",
+    "tx.apply": "tx_commit_s",
+    "palf.append": "log_sync_s",
+    "palf.persist": "log_sync_s",
+    "palf.apply": "log_sync_s",
+    "storage.freeze": "freeze_s",
 }
 
 #: the host phases of a statement in pipeline order: ``ExecTimes``
@@ -106,7 +122,8 @@ PHASES = ("parse_s", "admission_s", "virtuals_s", "bind_s", "prepare_s",
           "trace_s",
           "lower_s", "compile_s", "cache_lookup_s", "dispatch_s",
           "shard_s", "unshard_s", "merge_s", "monitor_s", "record_s",
-          "materialize_s", "gc_s")
+          "materialize_s", "dml_s", "tx_commit_s", "log_sync_s", "freeze_s",
+          "gc_s")
 
 #: spans that never ride an rpc reply (TraceCtx.wire_spans)
 _NODE_LOCAL = frozenset(("plan.dispatch", "plan.device_wait", "plan.monitor",

@@ -31,10 +31,7 @@ import numpy as np
 
 from oceanbase_tpu.exec import plan as pp
 from oceanbase_tpu.expr import ir
-from oceanbase_tpu.storage.lookup import (
-    estimate_rows_in_ranges,
-    range_rows,
-)
+from oceanbase_tpu.storage.lookup import estimate_in_ranges, range_rows
 
 # a path is taken only when its zone-map row estimate is under both an
 # absolute cap (keep host decode + upload small) and a fraction of the
@@ -190,6 +187,7 @@ class AccessChoice:
     index_name: str | None
     prune: dict          # ranges driving zone-map pruning
     est_rows: int
+    chunks: int = 0      # segment chunks the zone maps let through
 
 
 def choose_path(engine, table: str, ranges: dict):
@@ -229,11 +227,11 @@ def choose_path(engine, table: str, ranges: dict):
     prim = {c: ranges[c] for c in ranges
             if c in kc or c == part_col}
     if prim:
-        est = estimate_rows_in_ranges(tablet, prim)
+        est, chunks = estimate_in_ranges(tablet, prim)
         est = _card_refine(est, prim, [c for c in kc
                                        if c != "__rowid__"] or kc, True)
         if est <= budget:
-            best = AccessChoice(table, "primary", None, prim, est)
+            best = AccessChoice(table, "primary", None, prim, est, chunks)
 
     # secondary paths: a usable prefix of some index's columns
     for ix in ts.tdef.indexes:
@@ -250,11 +248,11 @@ def choose_path(engine, table: str, ranges: dict):
         istore = engine.tables.get(ix.storage_table)
         if istore is None:
             continue
-        est = estimate_rows_in_ranges(istore.tablet, pre)
+        est, chunks = estimate_in_ranges(istore.tablet, pre)
         est = _card_refine(est, pre, ix.columns,
                            ix.unique and set(ix.columns) <= _eq_cols(pre))
         if est <= budget and (best is None or est < best.est_rows):
-            best = AccessChoice(table, "index", ix.name, pre, est)
+            best = AccessChoice(table, "index", ix.name, pre, est, chunks)
     return best
 
 

@@ -30,6 +30,7 @@ from oceanbase_tpu.sql import ast
 from oceanbase_tpu.sql.binder import Binder
 from oceanbase_tpu.sql.optimizer import scale_capacities
 from oceanbase_tpu.sql.parser import parse_sql
+from oceanbase_tpu.tx.service import WriteStats
 from oceanbase_tpu.vector import Relation, from_numpy, to_numpy
 
 # serving-plane statement accounting (host side, statement boundary —
@@ -38,6 +39,9 @@ qmetrics.declare("sql.statements", "counter",
                  "statements executed (labels: tenant, ok)")
 qmetrics.declare("sql.statement_s", "histogram",
                  "end-to-end statement latency", unit="s")
+qmetrics.declare("sql.parse_bytes", "counter",
+                 "SQL text handed to the parser (beside gv$time_model's "
+                 "parse_s: seconds per KB of text)", unit="bytes")
 qmetrics.declare("sql.work_area_bytes", "gauge",
                  "the work-area budget in force, in bytes: "
                  "ob_sql_work_area_percentage of the device's memory; "
@@ -230,7 +234,7 @@ class Session:
             with qtrace.activate(tctx):
                 with qtrace.span("statement", sql=sql[:200],
                                  session=self.session_id):
-                    with qtrace.span("parse"):
+                    with qtrace.span("parse", bytes=len(sql)):
                         stmt = parse_sql(sql)
                     with qtrace.span("admission"):
                         # the statement's own bookkeeping lives here,
@@ -287,6 +291,7 @@ class Session:
                 qmetrics.inc("sql.statements", tenant=tname,
                              ok=0 if err else 1)
                 qmetrics.observe("sql.statement_s", elapsed, tenant=tname)
+                qmetrics.inc("sql.parse_bytes", len(sql))
                 trace_id = ""
                 if tctx is not None:
                     kept = qtrace.finish_trace(self.db, tctx, elapsed,
@@ -941,7 +946,8 @@ class Session:
                 # so their (group-committed) redo precedes the barrier
                 self.tenant.locks.acquire(stmt.table, "X", tx.tx_id,
                                           timeout=30.0)
-            lsn = self._txsvc._log({"op": "truncate", "table": stmt.table})
+            lsn = self._txsvc._log_batch(
+                [{"op": "truncate", "table": stmt.table}])
             self._engine.truncate_table(stmt.table, wal_lsn=lsn)
             # MySQL: TRUNCATE resets AUTO_INCREMENT
             if self.tenant is not None:
@@ -983,12 +989,19 @@ class Session:
             return
         limit = int(self.tenant.config["memstore_limit_rows"])
         if len(ts.tablet.active) >= limit:
-            # horizon-clamped: see _alter_system major_freeze
-            self._engine.freeze_and_flush(
-                table, snapshot=self._txsvc.flush_snapshot())
-            l0 = sum(1 for s in ts.tablet.segments if s.level == 0)
-            if l0 >= int(self.tenant.config["minor_compact_trigger"]):
-                self._engine.minor_compact(table)
+            # the foreground statement pays for the flush (and, at the
+            # trigger, a minor compaction): a span of its own
+            with qtrace.span("storage.freeze", table=table,
+                             rows=len(ts.tablet.active)) as sp:
+                # horizon-clamped: see _alter_system major_freeze
+                self._engine.freeze_and_flush(
+                    table, snapshot=self._txsvc.flush_snapshot())
+                l0 = sum(1 for s in ts.tablet.segments if s.level == 0)
+                compact = \
+                    l0 >= int(self.tenant.config["minor_compact_trigger"])
+                sp.tags.update(l0_segments=l0, compacted=int(compact))
+                if compact:
+                    self._engine.minor_compact(table)
 
     def _analyze_workload(self, stmt: ast.AnalyzeWorkloadStmt) -> Result:
         """ANALYZE WORKLOAD REPORT [FROM <id> TO <id>]: build (and
@@ -1888,7 +1901,7 @@ class Session:
             return set()
         from oceanbase_tpu.exec.plan import referenced_tables
         from oceanbase_tpu.sql import access_path as ap
-        from oceanbase_tpu.storage.lookup import estimate_rows_in_ranges
+        from oceanbase_tpu.storage.lookup import estimate_in_ranges
 
         refs = list(referenced_tables(plan))
         if self._tx is not None:
@@ -1926,7 +1939,7 @@ class Session:
             if choice is not None:
                 est[t] = choice.est_rows
             else:
-                est[t] = estimate_rows_in_ranges(ts.tablet, rngs)
+                est[t] = estimate_in_ranges(ts.tablet, rngs)[0]
         # a table at a time, as the spill tier streams: the rows reaching
         # the plan against the rows of it the work area holds
         big = {t for t, e in est.items() if e > fits(t)}
@@ -2839,36 +2852,13 @@ class Session:
         td = self.catalog.table_def(stmt.table)
         cols = stmt.columns or td.column_names
         rows_values: list[dict] = []
-        if stmt.rows is not None:
-            for row in stmt.rows:
-                if len(row) != len(cols):
-                    raise ValueError("INSERT arity mismatch")
-                values: dict = {}
-                for c, e in zip(cols, row):
-                    seqs = (self.tenant.sequences
-                            if self.tenant is not None else None)
-                    v, t = literal_value(_as_literal(e, params, seqs))
-                    cdef = td.column(c)
-                    values[c] = _coerce_value(v, t, cdef.dtype)
-                for c in td.columns:
-                    values.setdefault(c.name, None)
-                self._fill_auto_increment(td, values)
-                rows_values.append(values)
-        else:
-            sub = self._execute_select(stmt.select, params)
-            for i in range(sub.rowcount):
-                values = {}
-                for c, sn in zip(cols, sub.names):
-                    x = sub.arrays[sn][i]
-                    vd = sub.valids.get(sn)
-                    if vd is not None and not vd[i]:
-                        values[c] = None
-                    else:
-                        values[c] = x.item() if hasattr(x, "item") else x
-                for c in td.columns:
-                    values.setdefault(c.name, None)
-                self._fill_auto_increment(td, values)
-                rows_values.append(values)
+        sub = self._execute_select(stmt.select, params) \
+            if stmt.rows is None else None
+        # text (or a sub-select's arrays) to typed rows: one span for the
+        # statement, whatever its rows
+        with qtrace.span("dml.bind", table=stmt.table) as bsp:
+            self._insert_rows(stmt, params, td, cols, sub, rows_values)
+            bsp.tags["rows"] = len(rows_values)
         tablet = self._engine.tables[stmt.table].tablet
         replace = getattr(stmt, "replace", False)
         kv = None
@@ -2878,15 +2868,64 @@ class Session:
             kv = KvTable(self.tenant, stmt.table)
 
         def op(tx):
-            if not replace and self._pdml_eligible(len(rows_values)):
-                keyed = [(tablet.make_key(v), v) for v in rows_values]
-                if len({k for k, _ in keyed}) == len(keyed):
-                    # distinct keys: the write phase is order-free, fan
-                    # it out (intra-statement dup keys need serial
-                    # first-wins ordering)
-                    self._pdml_write(tx, stmt.table, tablet, keyed,
-                                     "insert")
-                    return
+            with qtrace.span("dml.write", table=stmt.table,
+                             kind="replace" if replace else "insert") as sp:
+                self._insert_write(tx, stmt.table, tablet, rows_values,
+                                   replace, kv, sp.tags)
+
+        self._run_in_tx(op)
+        # keep the binder's est_rows current: a plan bound while the
+        # table looked empty would budget capacities for one row and
+        # ride the CapacityOverflow retry ladder on every execution
+        td.row_count = tablet.row_count_estimate()
+        self._maybe_freeze(stmt.table)
+        return _ok(rowcount=len(rows_values))
+
+    def _insert_rows(self, stmt, params, td, cols, sub, rows_values: list):
+        """``dml.bind``'s loop: literal evaluation, coercion to the
+        column's storage value, defaults, auto-increment."""
+        if sub is None:
+            seqs = self.tenant.sequences if self.tenant is not None else None
+            for row in stmt.rows:
+                if len(row) != len(cols):
+                    raise ValueError("INSERT arity mismatch")
+                values: dict = {}
+                for c, e in zip(cols, row):
+                    v, t = literal_value(_as_literal(e, params, seqs))
+                    cdef = td.column(c)
+                    values[c] = _coerce_value(v, t, cdef.dtype)
+                for c in td.columns:
+                    values.setdefault(c.name, None)
+                self._fill_auto_increment(td, values)
+                rows_values.append(values)
+            return
+        for i in range(sub.rowcount):
+            values = {}
+            for c, sn in zip(cols, sub.names):
+                x = sub.arrays[sn][i]
+                vd = sub.valids.get(sn)
+                if vd is not None and not vd[i]:
+                    values[c] = None
+                else:
+                    values[c] = x.item() if hasattr(x, "item") else x
+            for c in td.columns:
+                values.setdefault(c.name, None)
+            self._fill_auto_increment(td, values)
+            rows_values.append(values)
+
+    def _insert_write(self, tx, table, tablet, rows_values, replace, kv,
+                      tags):
+        """``dml.write``'s loop for INSERT / REPLACE."""
+        if not replace and self._pdml_eligible(len(rows_values)):
+            keyed = [(tablet.make_key(v), v) for v in rows_values]
+            if len({k for k, _ in keyed}) == len(keyed):
+                # distinct keys: the write phase is order-free, fan
+                # it out (intra-statement dup keys need serial
+                # first-wins ordering)
+                self._pdml_write(tx, table, tablet, keyed, "insert", tags)
+                return
+        stats = WriteStats()
+        try:
             for values in rows_values:
                 key = tablet.make_key(values)
                 kind = "insert"
@@ -2899,16 +2938,10 @@ class Session:
                                       tx_id=tx.tx_id) \
                         if kv is not None else None
                     kind = "update" if existing is not None else "insert"
-                self._txsvc.write(tx, stmt.table, tablet, key, kind,
-                                  values)
-
-        self._run_in_tx(op)
-        # keep the binder's est_rows current: a plan bound while the
-        # table looked empty would budget capacities for one row and
-        # ride the CapacityOverflow retry ladder on every execution
-        td.row_count = tablet.row_count_estimate()
-        self._maybe_freeze(stmt.table)
-        return _ok(rowcount=len(rows_values))
+                self._txsvc.write(tx, table, tablet, key, kind, values,
+                                  stats)
+        finally:
+            stats.book(tags)
 
     # ------------------------------------------------------------------
     # parallel DML (≙ src/sql/engine/pdml: partition-aware parallel
@@ -2920,8 +2953,10 @@ class Session:
                 and n_rows >= int(self.db.config["pdml_min_rows"]))
 
     def _pdml_write(self, tx, table: str, tablet, keyed: list,
-                    kind: str):
-        """Fan the write phase of one statement out over tenant workers.
+                    kind: str, tags: dict):
+        """Fan the write phase of one statement out over tenant workers;
+        each keeps its own ``WriteStats`` (no span on a worker's thread),
+        summed into ``tags`` (the ``dml.write`` span's) when all are in.
 
         keyed: [(key, values)].  Rows group by target partition so each
         worker owns whole partitions (no cross-worker tablet contention;
@@ -2941,9 +2976,15 @@ class Session:
                 groups.setdefault(i % dop, []).append(kv_)
 
         def worker(batch):
-            for key, values in batch:
-                self._txsvc.write(tx, table, tablet, key, kind, values)
+            stats = WriteStats()
+            try:
+                for key, values in batch:
+                    self._txsvc.write(tx, table, tablet, key, kind, values,
+                                      stats)
+            finally:
+                done.append(stats)
 
+        done: list = []
         futures = [self.tenant.submit(worker, batch)
                    for batch in groups.values()]
         errs = []
@@ -2952,6 +2993,11 @@ class Session:
                 f.result()
             except Exception as e:  # noqa: BLE001 — surface first error
                 errs.append(e)
+        total = WriteStats()
+        for stats in done:
+            total.add(stats)
+        total.book(tags)
+        tags["pdml_workers"] = len(futures)
         if errs:
             raise errs[0]
 
@@ -2973,9 +3019,10 @@ class Session:
                     pass
 
     def _matching_rows(self, table: str, where, params, tx):
-        """-> (rel, mask, tablet): relation at the statement tx's snapshot
-        + WHERE mask (reads and writes share one snapshot so the SI
-        write-conflict check is sound).
+        """-> (rel, mask, tablet, binder, scope, full_table): relation at
+        the statement tx's snapshot + WHERE mask (reads and writes share
+        one snapshot so the SI write-conflict check is sound);
+        ``full_table``: no candidate path was taken.
 
         Point/range WHERE clauses on the primary key or an index take the
         candidate-superset access path — an OLTP UPDATE/DELETE touches a
@@ -2985,30 +3032,58 @@ class Session:
 
         ts = self._engine.tables[table]
         tablet = ts.tablet
-        binder = Binder(self.catalog, params=params or [])
-        scope = Scope()
-        for cname in tablet.columns:
-            scope.add(cname, cname, alias=table)
-        pred = binder.bind_expr(where, scope) if where is not None else None
+        with qtrace.span("dml.bind", table=table):
+            binder = Binder(self.catalog, params=params or [])
+            scope = Scope()
+            for cname in tablet.columns:
+                scope.add(cname, cname, alias=table)
+            pred = binder.bind_expr(where, scope) \
+                if where is not None else None
         rel = None
         if pred is not None and \
                 bool(self.variables.get("enable_index_access", 1)):
             from oceanbase_tpu.sql import access_path as ap
 
-            try:
-                ranges = ap.ranges_of_pred(pred, tablet.types)
-                choice = ap.choose_path(self._engine, table, ranges)
-                if choice is not None:
-                    arrays, valids = ap.materialize_candidates(
-                        self._engine, choice, tx.snapshot, tx.tx_id)
-                    rel = self._candidate_relation(ts, arrays, valids)
-            except Exception:
-                rel = None  # any surprise -> full-table path
-        if rel is None:
+            # the chunk decode on the host, or the decision against it
+            with qtrace.span("dml.candidates", path="none") as csp:
+                try:
+                    ranges = ap.ranges_of_pred(pred, tablet.types)
+                    choice = ap.choose_path(self._engine, table, ranges)
+                    if choice is not None:
+                        arrays, valids = ap.materialize_candidates(
+                            self._engine, choice, tx.snapshot, tx.tx_id)
+                        rel = self._candidate_relation(ts, arrays, valids)
+                        csp.tags.update(
+                            path=choice.kind, chunks=choice.chunks,
+                            rows=len(next(iter(arrays.values())))
+                            if arrays else 0)
+                except Exception:
+                    rel = None  # any surprise -> full-table path
+        full_table = rel is None
+        if full_table:
+            # its own spans: ``storage.device_copy`` / ``delta_apply``
             rel = self.catalog.table_data_at(table, tx.snapshot, tx.tx_id)
-        mask = eval_predicate(pred, rel) if pred is not None \
-            else rel.mask_or_true()
-        return rel, mask, tablet, binder, scope
+        with qtrace.span("dml.predicate"):
+            mask = eval_predicate(pred, rel) if pred is not None \
+                else rel.mask_or_true()
+        return rel, mask, tablet, binder, scope, full_table
+
+    @staticmethod
+    def _matched_rows(tablet, matched: dict, n: int) -> list:
+        """``dml.rows``'s loop: the matched host arrays as one dict of
+        python values a row."""
+        out = []
+        for i in range(n):
+            values = {}
+            for c in tablet.columns:
+                if c in matched:
+                    x = matched[c][i]
+                    vd = matched.get("__valid__" + c)
+                    values[c] = (None if vd is not None and not vd[i]
+                                 else (x.item() if hasattr(x, "item")
+                                       else x))
+            out.append(values)
+        return out
 
     def _update_tx(self, stmt: ast.UpdateStmt, params) -> Result:
         td = self.catalog.table_def(stmt.table)
@@ -3022,28 +3097,46 @@ class Session:
 
     def _update_tx_body(self, stmt, params, td, tx, tx_hint) -> Result:
         from oceanbase_tpu.expr.compile import cast_column, eval_expr
-
-        rel, mask, tablet, binder, scope = self._matching_rows(
-            stmt.table, stmt.where, params, tx)
-        # evaluate assignments over the snapshot, then pull matched rows
-        new_cols = {}
-        for cname, e in stmt.assignments:
-            b = binder.bind_expr(e, scope)
-            c = eval_expr(b, rel)
-            new_cols[cname] = cast_column(c, td.column(cname).dtype)
-        matched = to_numpy(rel.with_mask(mask))
-        n_upd = len(next(iter(matched.values()))) if matched else 0
-        new_host = {}
         import numpy as _np
 
-        midx = _np.nonzero(_np.asarray(mask))[0]
-        for cname, c in new_cols.items():
-            vals = _np.asarray(c.data)[midx]
-            if c.sdict is not None:
-                vals = c.sdict.values[_np.clip(vals, 0, c.sdict.size - 1)]
-            vv = (_np.asarray(c.valid)[midx] if c.valid is not None
-                  else _np.ones(len(midx), dtype=bool))
-            new_host[cname] = (vals, vv)
+        # everything before the first write: a parent span, its leaves
+        # ``dml.bind``, ``dml.candidates``, ``dml.predicate``,
+        # ``dml.assign``, ``materialize`` and ``dml.rows``
+        with qtrace.span("dml.match", table=stmt.table) as msp:
+            rel, mask, tablet, binder, scope, full_table = \
+                self._matching_rows(stmt.table, stmt.where, params, tx)
+            # evaluate assignments over the snapshot, then pull matched rows
+            with qtrace.span("dml.assign", columns=len(stmt.assignments)):
+                new_cols = {}
+                for cname, e in stmt.assignments:
+                    b = binder.bind_expr(e, scope)
+                    c = eval_expr(b, rel)
+                    new_cols[cname] = cast_column(c, td.column(cname).dtype)
+                new_host = {}
+                midx = _np.nonzero(_np.asarray(mask))[0]
+                for cname, c in new_cols.items():
+                    vals = _np.asarray(c.data)[midx]
+                    if c.sdict is not None:
+                        vals = c.sdict.values[
+                            _np.clip(vals, 0, c.sdict.size - 1)]
+                    vv = (_np.asarray(c.valid)[midx] if c.valid is not None
+                          else _np.ones(len(midx), dtype=bool))
+                    new_host[cname] = (vals, vv)
+            with qtrace.span("materialize") as fsp:
+                matched = to_numpy(rel.with_mask(mask), tags=fsp.tags)
+            n_upd = len(next(iter(matched.values()))) if matched else 0
+            with qtrace.span("dml.rows", rows=n_upd):
+                keyed = []
+                for i, old_values in enumerate(
+                        self._matched_rows(tablet, matched, n_upd)):
+                    values = dict(old_values)
+                    for cname, (vals, vv) in new_host.items():
+                        x = vals[i]
+                        values[cname] = (None if not vv[i]
+                                         else (x.item() if hasattr(x, "item")
+                                               else x))
+                    keyed.append((old_values, values))
+            msp.tags.update(rows=n_upd, full_table=int(full_table))
 
         key_changed = any(c in tablet.key_cols for c, _ in stmt.assignments)
         # an update that moves a row across range partitions must also be
@@ -3052,32 +3145,29 @@ class Session:
         part_changed = any(c in part_cols for c, _ in stmt.assignments)
 
         def op(tx):
-            keyed = []
-            for i in range(n_upd):
-                old_values = {}
-                for c in tablet.columns:
-                    if c in matched:
-                        x = matched[c][i]
-                        vd = matched.get("__valid__" + c)
-                        old_values[c] = (None if vd is not None and not vd[i]
-                                         else (x.item() if hasattr(x, "item")
-                                               else x))
-                values = dict(old_values)
-                for cname, (vals, vv) in new_host.items():
-                    x = vals[i]
-                    values[cname] = (None if not vv[i]
-                                     else (x.item() if hasattr(x, "item")
-                                           else x))
-                keyed.append((old_values, values))
-            if not key_changed and not part_changed and \
-                    self._pdml_eligible(n_upd):
-                # plain (no PK/partition move) bulk update: per-row
-                # target keys are distinct, the write phase fans out
-                self._pdml_write(
-                    tx, stmt.table, tablet,
-                    [(tuple(v[k] for k in tablet.key_cols), v)
-                     for _o, v in keyed], "update")
-                return
+            with qtrace.span("dml.write", table=stmt.table,
+                             kind="update") as sp:
+                self._update_write(tx, stmt.table, tablet, keyed,
+                                   key_changed, part_changed, sp.tags)
+
+        self._run_in_tx(op, tx_hint=tx_hint)
+        self._maybe_freeze(stmt.table)
+        return _ok(rowcount=n_upd)
+
+    def _update_write(self, tx, table, tablet, keyed, key_changed,
+                      part_changed, tags):
+        """``dml.write``'s loop for UPDATE."""
+        if not key_changed and not part_changed and \
+                self._pdml_eligible(len(keyed)):
+            # plain (no PK/partition move) bulk update: per-row
+            # target keys are distinct, the write phase fans out
+            self._pdml_write(
+                tx, table, tablet,
+                [(tuple(v[k] for k in tablet.key_cols), v)
+                 for _o, v in keyed], "update", tags)
+            return
+        stats = WriteStats()
+        try:
             for old_values, values in keyed:
                 new_key = tuple(values[k] for k in tablet.key_cols)
                 moved = False
@@ -3088,17 +3178,15 @@ class Session:
                     old_key = tuple(old_values[k] for k in tablet.key_cols)
                     if old_key != new_key or moved:
                         # PK/partition move = delete old row + insert new
-                        self._txsvc.write(tx, stmt.table, tablet, old_key,
-                                          "delete", old_values)
-                        self._txsvc.write(tx, stmt.table, tablet, new_key,
-                                          "insert", values)
+                        self._txsvc.write(tx, table, tablet, old_key,
+                                          "delete", old_values, stats)
+                        self._txsvc.write(tx, table, tablet, new_key,
+                                          "insert", values, stats)
                         continue
-                self._txsvc.write(tx, stmt.table, tablet, new_key, "update",
-                                  values)
-
-        self._run_in_tx(op, tx_hint=tx_hint)
-        self._maybe_freeze(stmt.table)
-        return _ok(rowcount=n_upd)
+                self._txsvc.write(tx, table, tablet, new_key, "update",
+                                  values, stats)
+        finally:
+            stats.book(tags)
 
     def _delete_tx(self, stmt: ast.DeleteStmt, params) -> Result:
         tx, tx_hint = self._stmt_tx()
@@ -3110,30 +3198,32 @@ class Session:
             raise
 
     def _delete_tx_body(self, stmt, params, tx, tx_hint) -> Result:
-        rel, mask, tablet, _b, _s = self._matching_rows(
-            stmt.table, stmt.where, params, tx)
-        matched = to_numpy(rel.with_mask(mask))
-        n_del = len(next(iter(matched.values()))) if matched else 0
+        with qtrace.span("dml.match", table=stmt.table) as msp:
+            rel, mask, tablet, _b, _s, full_table = self._matching_rows(
+                stmt.table, stmt.where, params, tx)
+            with qtrace.span("materialize") as fsp:
+                matched = to_numpy(rel.with_mask(mask), tags=fsp.tags)
+            n_del = len(next(iter(matched.values()))) if matched else 0
+            with qtrace.span("dml.rows", rows=n_del):
+                keyed = [(tuple(values[k] for k in tablet.key_cols), values)
+                         for values in self._matched_rows(tablet, matched,
+                                                          n_del)]
+            msp.tags.update(rows=n_del, full_table=int(full_table))
 
         def op(tx):
-            keyed = []
-            for i in range(n_del):
-                values = {}
-                for c in tablet.columns:
-                    if c in matched:
-                        x = matched[c][i]
-                        vd = matched.get("__valid__" + c)
-                        values[c] = (None if vd is not None and not vd[i]
-                                     else (x.item() if hasattr(x, "item")
-                                           else x))
-                keyed.append((tuple(values[k] for k in tablet.key_cols),
-                              values))
-            if self._pdml_eligible(n_del):
-                self._pdml_write(tx, stmt.table, tablet, keyed, "delete")
-                return
-            for key, values in keyed:
-                self._txsvc.write(tx, stmt.table, tablet, key, "delete",
-                                  values)
+            with qtrace.span("dml.write", table=stmt.table,
+                             kind="delete") as sp:
+                if self._pdml_eligible(n_del):
+                    self._pdml_write(tx, stmt.table, tablet, keyed,
+                                     "delete", sp.tags)
+                    return
+                stats = WriteStats()
+                try:
+                    for key, values in keyed:
+                        self._txsvc.write(tx, stmt.table, tablet, key,
+                                          "delete", values, stats)
+                finally:
+                    stats.book(sp.tags)
 
         self._run_in_tx(op, tx_hint=tx_hint)
         self._maybe_freeze(stmt.table)
@@ -3179,28 +3269,34 @@ class Session:
         cols = stmt.columns or td.column_names
         if stmt.rows is not None:
             new = {c: [] for c in cols}
-            for row in stmt.rows:
-                if len(row) != len(cols):
-                    raise ValueError("INSERT arity mismatch")
-                for c, e in zip(cols, row):
-                    v, t = literal_value(_as_literal(e, params))
-                    cdef = td.column(c)
-                    if v is not None and cdef.dtype.kind == TypeKind.DECIMAL:
-                        # rescale the parsed fixed-point value to the
-                        # column's declared scale
-                        if t.kind == TypeKind.DECIMAL:
-                            v = _rescale(v, t.scale, cdef.dtype.scale)
-                        elif isinstance(v, int):
-                            v = v * _POW10[cdef.dtype.scale]
-                        elif isinstance(v, float):
-                            v = round(v * _POW10[cdef.dtype.scale])
-                    new[c].append(v)
+            with qtrace.span("dml.bind", table=stmt.table,
+                             rows=len(stmt.rows)):
+                self._legacy_insert_rows(stmt, params, td, cols, new)
             n_new = len(stmt.rows)
         else:
             sub = self._execute_select(stmt.select, params)
             new = {c: list(sub.arrays[sn]) for c, sn in zip(cols, sub.names)}
             n_new = sub.rowcount
         return self._append_rows(td, cols, new, n_new)
+
+    @staticmethod
+    def _legacy_insert_rows(stmt, params, td, cols, new: dict):
+        for row in stmt.rows:
+            if len(row) != len(cols):
+                raise ValueError("INSERT arity mismatch")
+            for c, e in zip(cols, row):
+                v, t = literal_value(_as_literal(e, params))
+                cdef = td.column(c)
+                if v is not None and cdef.dtype.kind == TypeKind.DECIMAL:
+                    # rescale the parsed fixed-point value to the
+                    # column's declared scale
+                    if t.kind == TypeKind.DECIMAL:
+                        v = _rescale(v, t.scale, cdef.dtype.scale)
+                    elif isinstance(v, int):
+                        v = v * _POW10[cdef.dtype.scale]
+                    elif isinstance(v, float):
+                        v = round(v * _POW10[cdef.dtype.scale])
+                new[c].append(v)
 
     def _append_rows(self, td: TableDef, cols, new, n_new) -> Result:
         # host-side append: decode existing live rows, concat, re-encode.

@@ -75,15 +75,17 @@ class IndexKeyLocks:
 
 
 def maintain_indexes(svc, engine, tx, table: str, tablet, key: tuple,
-                     op: str, values: dict):
-    """Write index-table entries matching a base-table write.
+                     op: str, values: dict) -> int:
+    """Write index-table entries matching a base-table write; -> how
+    many entries it wrote (``tx.rows_written{op=index}``).
 
     MUST be called before the base ``tablet.write`` so the pre-image is
     still the old row.  ``values`` must carry every indexed column for
     insert/update ops (the session DML paths write full rows)."""
     ts = engine.tables.get(table)
     if ts is None or not ts.tdef.indexes:
-        return
+        return 0
+    written = 0
     old = point_lookup(tablet, key, tx.snapshot, tx.tx_id)
     newvals = dict(values)
     for kc, kv in zip(tablet.key_cols, key):
@@ -101,6 +103,7 @@ def maintain_indexes(svc, engine, tx, table: str, tablet, key: tuple,
             if old_ekey is not None:
                 svc.write(tx, ix.storage_table, itab, old_ekey, "delete",
                           dict(zip(ikey_cols, old_ekey)))
+                written += 1
             continue
         new_ekey = tuple(newvals.get(c) for c in ikey_cols)
         if old_ekey == new_ekey:
@@ -111,8 +114,11 @@ def maintain_indexes(svc, engine, tx, table: str, tablet, key: tuple,
         if old_ekey is not None:
             svc.write(tx, ix.storage_table, itab, old_ekey, "delete",
                       dict(zip(ikey_cols, old_ekey)))
+            written += 1
         svc.write(tx, ix.storage_table, itab, new_ekey, "insert",
                   dict(zip(ikey_cols, new_ekey)))
+        written += 1
+    return written
 
 
 def _check_unique(svc, tx, ix, itab, new_ekey: tuple, ikey_cols):

@@ -41,25 +41,29 @@ def _chunk_mask(seg, ranges: dict):
     return cm
 
 
-def estimate_rows_in_ranges(tablet, ranges: dict) -> int:
-    """Upper bound on rows a pruned scan would decode (zone-map metadata
-    only — no decode).  Feeds the access-path cost decision."""
-    total = 0
+def estimate_in_ranges(tablet, ranges: dict) -> tuple[int, int]:
+    """(upper bound on rows, segment chunks) a pruned scan would decode
+    (zone-map metadata only — no decode).  Feeds the access-path cost
+    decision; the chunks are ``dml.candidates``' tag."""
+    total = chunks = 0
     for t in _base_tablets(tablet):
         sub = {k: v for k, v in ranges.items() if k in t.key_cols}
         for seg in t.segments:
             if not sub:
                 total += seg.n_rows
+                chunks += seg.n_chunks
                 continue
             cm = _chunk_mask(seg, sub)
             if cm is None:
                 continue
             any_col = next(iter(seg.columns.values()))
-            total += sum(any_col[i].n for i in np.nonzero(cm)[0])
+            kept = np.nonzero(cm)[0]
+            total += sum(any_col[i].n for i in kept)
+            chunks += len(kept)
         within = t.key_ranges(sub)
         total += sum(len(m.keys_within(within)) if within else len(m)
                      for m in [t.active] + t.frozen)
-    return total
+    return total, chunks
 
 
 _INF = 2**62

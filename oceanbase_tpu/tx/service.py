@@ -23,11 +23,73 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from dataclasses import dataclass, field
 from enum import Enum
 
+from oceanbase_tpu.server import metrics as qmetrics
+from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.tx.errors import TxAborted, WriteConflict
 from oceanbase_tpu.tx.gts import GTS
+
+# the write path's books (gv$sysstat; readers: PERF.md section 3)
+qmetrics.declare("tx.commits", "counter",
+                 "transactions committed, by path (one_phase | two_phase | "
+                 "xa | empty: no participant, nothing logged)")
+qmetrics.declare("tx.rollbacks", "counter", "transactions rolled back")
+qmetrics.declare("tx.rows_written", "counter",
+                 "rows handed to TransService.write by a statement's write "
+                 "loop, by op (insert | update | delete: base-table rows; "
+                 "index: the index entries written for them)")
+qmetrics.declare("tx.redo_bytes", "counter",
+                 "encoded log records handed to the log by commits",
+                 unit="bytes")
+
+
+class WriteStats:
+    """What the rows of ONE statement's write loop cost inside
+    ``TransService.write``: integer accumulators, no span and no metric
+    call a row.  The clock is read four times a row and each reading
+    closes one part, so the four parts sum to the loop's time:
+
+    - ``admit_ns``: from the end of the row before (the loop's own glue:
+      ``make_key``, REPLACE's lookup) through the disk gate, the
+      memstore throttle and the table lock,
+    - ``index_ns``: ``maintain_indexes`` (pre-image lookup, the index
+      entries' own writes),
+    - ``memtable_ns``: ``Tablet.write`` with its conflict checks,
+    - ``redo_ns``: the participant's key list and the redo record.
+
+    A PDML worker keeps its own and the statement sums them (``add``);
+    ``book`` writes the sums once, as the ``dml.write`` span's tags and
+    as ``tx.rows_written``."""
+
+    __slots__ = ("t", "rows", "admit_ns", "index_ns", "memtable_ns",
+                 "redo_ns")
+
+    def __init__(self):
+        # base-table rows by op; the index entries written for them
+        self.rows = {"insert": 0, "update": 0, "delete": 0, "index": 0}
+        self.admit_ns = self.index_ns = self.memtable_ns = self.redo_ns = 0
+        self.t = time.perf_counter_ns()
+
+    def add(self, other: "WriteStats"):
+        for op, n in other.rows.items():
+            self.rows[op] += n
+        self.admit_ns += other.admit_ns
+        self.index_ns += other.index_ns
+        self.memtable_ns += other.memtable_ns
+        self.redo_ns += other.redo_ns
+
+    def book(self, tags: dict):
+        index_rows = self.rows["index"]
+        tags.update(rows=sum(self.rows.values()) - index_rows,
+                    index_rows=index_rows, admit_ns=self.admit_ns,
+                    index_ns=self.index_ns, memtable_ns=self.memtable_ns,
+                    redo_ns=self.redo_ns)
+        for op, n in self.rows.items():
+            if n:
+                qmetrics.inc("tx.rows_written", n, op=op)
 
 
 class TxState(Enum):
@@ -178,7 +240,11 @@ class TransService:
         return self.flush_horizon()[0]
 
     def write(self, tx: Transaction, table: str, tablet, key: tuple,
-              op: str, values: dict):
+              op: str, values: dict, stats: WriteStats | None = None):
+        """One row.  ``stats`` (the statement's, or a PDML worker's) takes
+        what the row cost; the index entries ``maintain_indexes`` writes
+        through here pass none: their time is the base row's
+        ``index_ns``."""
         if tx.state != TxState.ACTIVE:
             raise TxAborted(f"tx {tx.tx_id} is {tx.state.value}")
         if self.diskmgr is not None and not table.startswith("__idx__"):
@@ -196,6 +262,9 @@ class TransService:
             # READ/WRITE held by other transactions (released at tx end)
             self.lock_table.acquire(table, "IX", tx.tx_id,
                                     timeout=self.lock_wait_timeout_s)
+        if stats is not None:
+            t_admit = time.perf_counter_ns()
+        n_index = 0
         if self.engine is not None:
             # secondary indexes update in the SAME transaction, before
             # the base write (pre-image must still be the old row);
@@ -203,10 +272,14 @@ class TransService:
             # statement rollback, and replay for free
             from oceanbase_tpu.storage.indexes import maintain_indexes
 
-            maintain_indexes(self, self.engine, tx, table, tablet, key,
-                             op, values)
+            n_index = maintain_indexes(self, self.engine, tx, table, tablet,
+                                       key, op, values)
+        if stats is not None:
+            t_index = time.perf_counter_ns()
         tablet.write(key, op, values, tx.tx_id, stmt_seq=tx.stmt_seq,
                      snapshot=tx.snapshot)
+        if stats is not None:
+            t_memtable = time.perf_counter_ns()
         p = tx.participant(table, tablet)
         p.keys.append(key)
         # redo buffers in the tx and ships in ONE replicated group append
@@ -216,6 +289,15 @@ class TransService:
             {"op": "redo", "tx": tx.tx_id, "table": table,
              "key": list(key), "kind": op, "stmt": tx.stmt_seq,
              "values": _jsonable(values)})
+        if stats is not None:
+            t_redo = time.perf_counter_ns()
+            stats.admit_ns += t_admit - stats.t
+            stats.index_ns += t_index - t_admit
+            stats.memtable_ns += t_memtable - t_index
+            stats.redo_ns += t_redo - t_memtable
+            stats.t = t_redo
+            stats.rows[op] += 1
+            stats.rows["index"] += n_index
 
     def rollback_statement(self, tx: Transaction, stmt_seq: int,
                            stmt_writes: dict):
@@ -238,56 +320,65 @@ class TransService:
 
     # ------------------------------------------------------------------
     def commit(self, tx: Transaction) -> int:
-        """One-phase fast path or full 2PC; returns the commit version."""
+        """One-phase fast path or full 2PC; returns the commit version.
+        Span ``tx.commit`` (its self time: GTS, the state machine, lock
+        release) over ``tx.log_encode``, the log's ``palf.append`` and
+        ``tx.apply``."""
         from oceanbase_tpu.server.errsim import ERRSIM
 
         ERRSIM.hit("tx.commit")
-        with self._lock:
+        with qtrace.span("tx.commit", tx=tx.tx_id) as sp, self._lock:
             if tx.state != TxState.ACTIVE:
                 raise TxAborted(f"tx {tx.tx_id} is {tx.state.value}")
             parts = list(tx.participants.values())
+            path = ("empty", "one_phase", "two_phase")[min(len(parts), 2)]
+            sp.tags.update(participants=len(parts), path=path,
+                           rows=len(tx.pending_redo))
             if not parts:
-                tx.state = TxState.CLEAR
-                self._live.pop(tx.tx_id, None)
-                self._release_locks(tx)
-                return self.gts.get_ts()
-            if len(parts) == 1:
+                version = self.gts.get_ts()
+            elif len(parts) == 1:
                 # single-LS fast path (≙ one-phase commit optimization):
                 # buffered redo + commit ship as one group append
                 version = self.gts.get_ts()
                 self._log_batch(tx.pending_redo +
                                 [{"op": "commit", "tx": tx.tx_id,
-                                  "version": version}])
+                                  "version": version}], sp.tags)
                 tx.pending_redo = []
-                parts[0].tablet.commit(tx.tx_id, version, parts[0].keys)
-                tx.state = TxState.CLEAR
-                self._live.pop(tx.tx_id, None)
-                self._release_locks(tx)
-                return version
-
-            # ---- 2PC (≙ upstream/downstream committer state machine) ----
-            tx.state = TxState.REDO_COMPLETE
-            records = list(tx.pending_redo)
-            for p in parts:
-                p.state = TxState.PREPARE
-                p.prepare_version = self.gts.get_ts()
-                records.append({"op": "prepare", "tx": tx.tx_id,
-                                "table": p.table,
-                                "version": p.prepare_version})
-            version = max(p.prepare_version for p in parts)
-            tx.state = TxState.PRE_COMMIT
-            records.append({"op": "commit", "tx": tx.tx_id,
-                            "version": version})
-            self._log_batch(records)
-            tx.pending_redo = []
-            tx.state = TxState.COMMIT
-            for p in parts:
-                p.tablet.commit(tx.tx_id, version, p.keys)
-                p.state = TxState.COMMIT
+                self._apply(tx, parts, version)
+            else:
+                # -- 2PC (≙ upstream/downstream committer state machine) --
+                tx.state = TxState.REDO_COMPLETE
+                records = list(tx.pending_redo)
+                for p in parts:
+                    p.state = TxState.PREPARE
+                    p.prepare_version = self.gts.get_ts()
+                    records.append({"op": "prepare", "tx": tx.tx_id,
+                                    "table": p.table,
+                                    "version": p.prepare_version})
+                version = max(p.prepare_version for p in parts)
+                tx.state = TxState.PRE_COMMIT
+                records.append({"op": "commit", "tx": tx.tx_id,
+                                "version": version})
+                self._log_batch(records, sp.tags)
+                tx.pending_redo = []
+                tx.state = TxState.COMMIT
+                self._apply(tx, parts, version)
             tx.state = TxState.CLEAR
             self._live.pop(tx.tx_id, None)
             self._release_locks(tx)
+            qmetrics.inc("tx.commits", path=path)
             return version
+
+    def _apply(self, tx: Transaction, parts: list, version: int):
+        """The commit's versions become visible, a participant at a
+        time (``Tablet.commit``, which also writes the commit log the
+        device copy's delta reads)."""
+        with qtrace.span("tx.apply", tables=len(parts),
+                         keys=sum(len(p.keys) for p in parts)):
+            for p in parts:
+                if p.tablet is not None:
+                    p.tablet.commit(tx.tx_id, version, p.keys)
+                p.state = TxState.COMMIT
 
     # ------------------------------------------------------------------
     # XA: externally-coordinated two-phase commit (≙ ObXAService,
@@ -305,7 +396,9 @@ class TransService:
         (src/storage/tx/ob_xa_service.h).  ``tx.prepare_lsn`` records
         the WAL replay point that checkpoints must not advance past
         while the branch is pending (its redo exists ONLY in the WAL)."""
-        with self._lock:
+        with qtrace.span("tx.commit", tx=tx.tx_id, path="xa_prepare",
+                         participants=len(tx.participants),
+                         rows=len(tx.pending_redo)) as sp, self._lock:
             if tx.state != TxState.ACTIVE:
                 raise TxAborted(f"tx {tx.tx_id} is {tx.state.value}")
             records = list(tx.pending_redo)
@@ -315,7 +408,7 @@ class TransService:
                 records.append({"op": "prepare", "tx": tx.tx_id,
                                 "table": p.table, "xid": tx.xid,
                                 "version": p.prepare_version})
-            end_lsn = self._log_batch(records)
+            end_lsn = self._log_batch(records, sp.tags)
             # the batch occupies [end-len+1, end]: a checkpoint replay
             # point at end-len still replays every record of the batch
             # (an empty or WAL-less branch has nothing to protect)
@@ -330,7 +423,9 @@ class TransService:
         """Phase 2 commit of a PREPARED tx (any session may drive it) —
         crash-recovered branches included (sync_recovered restored
         their uncommitted tablet versions, so this is one code path)."""
-        with self._lock:
+        with qtrace.span("tx.commit", tx=tx.tx_id, path="xa",
+                         participants=len(tx.participants)) as sp, \
+                self._lock:
             if tx.state != TxState.PREPARE:
                 raise TxAborted(
                     f"tx {tx.tx_id} is {tx.state.value}, not prepared")
@@ -342,16 +437,14 @@ class TransService:
             parts = list(tx.participants.values())
             version = max((p.prepare_version for p in parts),
                           default=self.gts.get_ts())
-            self._log({"op": "commit", "tx": tx.tx_id,
-                       "version": version})
-            for p in parts:
-                if p.tablet is not None:
-                    p.tablet.commit(tx.tx_id, version, p.keys)
-                p.state = TxState.COMMIT
+            self._log_batch([{"op": "commit", "tx": tx.tx_id,
+                              "version": version}], sp.tags)
+            self._apply(tx, parts, version)
             self.gts.advance_to(version)
             tx.state = TxState.CLEAR
             self._forget_xa_locked(tx)
             self._release_locks(tx)
+            qmetrics.inc("tx.commits", path="xa")
             return version
 
     def xa_rollback_prepared(self, tx: Transaction):
@@ -360,13 +453,14 @@ class TransService:
                 return self.rollback(tx)
             # redo already reached the WAL at prepare: log the abort so
             # replay drops the buffered records
-            self._log({"op": "abort", "tx": tx.tx_id})
+            self._log_batch([{"op": "abort", "tx": tx.tx_id}])
             for p in tx.participants.values():
                 if p.tablet is not None:
                     p.tablet.abort(tx.tx_id, p.keys)
             tx.state = TxState.ABORT
             self._forget_xa_locked(tx)
             self._release_locks(tx)
+            qmetrics.inc("tx.rollbacks")
 
     def _forget_xa_locked(self, tx: Transaction):
         """Drop every trace of a terminated XA branch: the live map, the
@@ -410,6 +504,7 @@ class TransService:
             tx.state = TxState.ABORT
             self._live.pop(tx.tx_id, None)
             self._release_locks(tx)
+            qmetrics.inc("tx.rollbacks")
 
     # ------------------------------------------------------------------
     def _release_locks(self, tx: Transaction):
@@ -417,18 +512,20 @@ class TransService:
         if self.lock_table is not None:
             self.lock_table.release_all(tx.tx_id)
 
-    def _log(self, record: dict) -> int:
-        if self.wal is not None:
-            return self.wal.append([json.dumps(record).encode()])
-        return 0
-
-    def _log_batch(self, records: list) -> int:
+    def _log_batch(self, records: list, tags: dict | None = None) -> int:
         """Group append: one majority-replicated fsync for the whole
-        batch (≙ LogSlidingWindow group buffer)."""
-        if self.wal is not None and records:
-            return self.wal.append(
-                [json.dumps(r).encode() for r in records])
-        return 0
+        batch (≙ LogSlidingWindow group buffer).  Span ``tx.log_encode``
+        is the records' way to bytes; ``tags`` (the commit span's) takes
+        ``redo_bytes``."""
+        if self.wal is None or not records:
+            return 0
+        with qtrace.span("tx.log_encode", records=len(records)) as sp:
+            payloads = [json.dumps(r).encode() for r in records]
+            sp.tags["bytes"] = nbytes = sum(map(len, payloads))
+        qmetrics.inc("tx.redo_bytes", nbytes)
+        if tags is not None:
+            tags["redo_bytes"] = nbytes
+        return self.wal.append(payloads)
 
     # NOTE: with group commit, a live transaction has NO presence in the
     # WAL (redo ships atomically with its commit record), so checkpoints
