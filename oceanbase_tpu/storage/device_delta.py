@@ -15,11 +15,30 @@ the table:
   in and the codes at or after each insertion point move up, by compares
   against the insertion points (no gather), ``NEW_CODES`` of them a pass.
 
+How a chunk is written.  New rows always land in the run of neighbouring
+lanes ``[first, first + n)`` after the high-water mark, so each column (its
+validity and the mask's new lanes alike) takes them as ONE contiguous window
+of ``W = min(DELTA_LANES, capacity)`` lanes: the old window is read
+(``dynamic_slice``), the chunk's rows are rolled to their offset inside it,
+the lanes outside ``[first, first + n)`` keep their old values (``where``),
+and the window goes back with one ``dynamic_update_slice``: the column is
+copied once and ``W`` lanes are updated in place in the copy (0.6 us of
+device time for a 64-bit column of 8,388,608 lanes, beside the copy), where
+a scatter of the same rows rewrote its whole operand inside the scatter's
+fusion (0.57-1.1 ms for that column; PERF.md section 6, PR 41).
+``dynamic_update_slice`` CLAMPS a start whose window would pass the end,
+which would shift the rows down onto live lanes, so the program computes
+``start = min(first, capacity - W)`` itself and places the rows at
+``first - start``.  ``W`` follows from the relation's shape: a table under a
+chunk's size takes the same program with a smaller window.  The lanes that
+go dead lie anywhere in the table and stay one scatter over the mask alone.
+
 Capacity, dtypes and pytree structure stay the baseline's, so a plan
-compiled for the baseline runs on.  JAX arrays are immutable: a statement
-that holds the old relation keeps its snapshot, and no buffer is donated.
-Every delta goes through in chunks of ``DELTA_LANES`` rows, so one program
-a table layout serves every delta size and compiles with the first commit.
+compiled for the baseline runs on.  Nothing is donated: JAX arrays are
+immutable and a statement may still hold the old relation, which keeps its
+snapshot whole (whether no one holds it is not this module's to know).
+Every delta goes through in chunks of ``W`` rows, so one program a table
+layout serves every delta size and compiles with the first commit.
 """
 
 from __future__ import annotations
@@ -29,9 +48,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from oceanbase_tpu.storage.keyindex import KeyIndex
-from oceanbase_tpu.vector.column import Column, Relation
+from oceanbase_tpu.vector.column import Column, Relation, factorize_strings
 
 #: rows (and cleared lanes) a chunk of a delta carries
 DELTA_LANES = 8192
@@ -62,21 +82,31 @@ class PadExhausted(Exception):
 
 @jax.jit
 def _apply_chunk(cols, mask, clear, first, n, rows):
-    """One chunk: ``clear`` lanes go dead, then rows ``[0, n)`` of ``rows``
-    land in lanes ``[first, first + n)``.  ``cols`` / ``rows``: name ->
-    (data, validity | None); lanes at or past the capacity are dropped."""
-    cap = mask.shape[0]
-    at = jnp.arange(clear.shape[0], dtype=jnp.int32)
-    lanes = jnp.where(at < n, first + at, cap + at)
-    hints = dict(mode="drop", indices_are_sorted=True, unique_indices=True)
-    mask = mask.at[clear].set(False, **hints).at[lanes].set(True, **hints)
+    """One chunk: ``clear`` lanes go dead (lanes at or past the capacity
+    are dropped), then rows ``[0, n)`` of ``rows`` land in lanes ``[first,
+    first + n)``, one window a column.  ``cols`` / ``rows``: name -> (data,
+    validity | None); ``rows`` and ``clear`` are ``W`` long."""
+    cap, w = mask.shape[0], clear.shape[0]
+    start = jnp.minimum(first, cap - w)     # the clamp, made here
+    off = first - start
+    at = jnp.arange(w, dtype=jnp.int32)
+    new = (at >= off) & (at < off + n)
+
+    def write(data, vals):
+        old = lax.dynamic_slice_in_dim(data, start, w)
+        placed = jnp.roll(vals, off, axis=0)
+        here = new.reshape((w,) + (1,) * (data.ndim - 1))
+        return lax.dynamic_update_slice_in_dim(
+            data, jnp.where(here, placed, old), start, axis=0)
+
+    mask = mask.at[clear].set(False, mode="drop", indices_are_sorted=True,
+                              unique_indices=True)
+    mask = write(mask, jnp.ones(w, dtype=mask.dtype))
     out = {}
     for name, (data, valid) in cols.items():
         vals, vvalid = rows[name]
-        data = data.at[lanes].set(vals, **hints)
-        if valid is not None:
-            valid = valid.at[lanes].set(vvalid, **hints)
-        out[name] = (data, valid)
+        out[name] = (write(data, vals),
+                     None if valid is None else write(valid, vvalid))
     return out, mask
 
 
@@ -91,9 +121,12 @@ def _shift_codes(codes, at):
 
 def _grown(col: Column, values, valid):
     """``col`` with the strings of ``values`` in its dictionary -> (data,
-    dictionary, the rows' codes)."""
-    strings = values if valid is None else values[valid]
-    sdict, at = col.sdict.merged(strings)
+    dictionary, the rows' codes).  The rows' strings take one hash pass
+    (``factorize_strings``); only the distinct ones are merged and looked
+    up in the dictionary."""
+    local, distinct = factorize_strings(
+        np.asarray(values if valid is None else values[valid], dtype=object))
+    sdict, at = col.sdict.merged(distinct)
     data = col.data
     if at is not None:
         # highest insertion points first: a code a pass moved up stays
@@ -103,9 +136,28 @@ def _grown(col: Column, values, valid):
             padded = np.full(NEW_CODES, _INT32_MAX, dtype=np.int32)
             padded[:len(part)] = part
             data = _shift_codes(data, padded)
-    codes = sdict.codes_of(np.where(valid, values, sdict.values[0])
-                           if valid is not None else values)
+    codes = sdict.codes_of(distinct)[local]
+    if valid is not None:       # a NULL row's code is never read
+        codes, rows = np.zeros(len(values), dtype=np.int32), codes
+        codes[valid] = rows
     return data, sdict, codes
+
+
+def _window(capacity: int) -> int:
+    """Lanes a chunk's window spans: static, from the relation's shape."""
+    return min(DELTA_LANES, capacity)
+
+
+def delta_chunks(capacity: int, n_rows: int, n_cleared: int) -> int:
+    """Chunks (``_apply_chunk`` programs run) a delta of that size takes."""
+    return -(-max(n_rows, n_cleared) // _window(capacity))
+
+
+def new_codes(old: Relation, new: Relation) -> int:
+    """Dictionary insertion points an apply brought, summed over the string
+    columns: each ``NEW_CODES`` of a column cost one pass over its codes."""
+    return sum(c.sdict.size - old.columns[name].sdict.size
+               for name, c in new.columns.items() if c.sdict is not None)
 
 
 def delta_bytes(rel: Relation, n_rows: int, n_cleared: int) -> int:
@@ -153,18 +205,19 @@ def apply_delta(copy: DeviceCopy, delta, key_cols) -> tuple:
                       np.ones(n, dtype=bool) if vvalid is None else vvalid)
 
     mask = rel.mask_or_true()
-    for lo in range(0, max(n, len(old)), DELTA_LANES):
-        m = max(min(n - lo, DELTA_LANES), 0)
-        clear = cap + np.arange(DELTA_LANES, dtype=np.int32)
-        part = old[lo:lo + DELTA_LANES]
+    w = _window(cap)
+    for lo in range(0, max(n, len(old)), w):
+        m = max(min(n - lo, w), 0)
+        clear = cap + np.arange(w, dtype=np.int32)
+        part = old[lo:lo + w]
         clear[:len(part)] = part
         chunk = {}
         for name, (vals, vvalid) in rows.items():
-            pv = np.zeros((DELTA_LANES,) + vals.shape[1:], dtype=vals.dtype)
+            pv = np.zeros((w,) + vals.shape[1:], dtype=vals.dtype)
             pv[:m] = vals[lo:lo + m]
             pvalid = None
             if vvalid is not None:
-                pvalid = np.zeros(DELTA_LANES, dtype=bool)
+                pvalid = np.zeros(w, dtype=bool)
                 pvalid[:m] = vvalid[lo:lo + m]
             chunk[name] = (pv, pvalid)
         cols, mask = _apply_chunk(cols, mask, clear,

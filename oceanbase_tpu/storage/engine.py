@@ -37,6 +37,8 @@ from oceanbase_tpu.storage.device_delta import (
     PadExhausted,
     apply_delta,
     delta_bytes,
+    delta_chunks,
+    new_codes,
 )
 from oceanbase_tpu.storage.encoding import CodedStrings
 from oceanbase_tpu.storage.segment import Segment
@@ -1708,8 +1710,12 @@ class StorageCatalog(Catalog):
             # half)
             return None, "partitioned"
         with qtrace.span("storage.delta_apply", table=ts.tdef.name) as sp:
-            with qtrace.span("storage.delta_read"):
+            with qtrace.span("storage.delta_read") as read:
                 delta = ts.tablet.delta_since(hit.mark, hit.snapshot, snap)
+                if delta is not None:
+                    read.tags.update(keys=len(delta.keys),
+                                     rows=len(delta.row_keys),
+                                     segment_keys=delta.segment_keys)
             if delta is None or set(delta.arrays) != set(hit.rel.columns):
                 return None, "delta_unavailable"
             try:
@@ -1722,7 +1728,10 @@ class StorageCatalog(Catalog):
                             ts.tdef.name, exc_info=True)
                 return None, "delta_unavailable"
             sp.tags.update(rows_inserted=n_rows, lanes_cleared=n_cleared,
-                           bytes=delta_bytes(rel, n_rows, n_cleared))
+                           bytes=delta_bytes(rel, n_rows, n_cleared),
+                           chunks=delta_chunks(rel.capacity, n_rows,
+                                               n_cleared),
+                           new_codes=new_codes(hit.rel, rel))
         qmetrics.inc("storage.delta_applies")
         qmetrics.inc("storage.delta_apply_ns", int(sp.elapsed_s * 1e9))
         qmetrics.inc("storage.delta_rows", n_rows, op="insert")

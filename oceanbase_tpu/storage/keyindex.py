@@ -15,6 +15,8 @@ vectorised ``searchsorted`` whatever the key's types.
 
 from __future__ import annotations
 
+from itertools import repeat
+
 import numpy as np
 
 
@@ -61,9 +63,8 @@ class KeyIndex:
         n = len(keys)
         ok = np.ones(n, dtype=bool)
         ranks = []
-        for j, u in enumerate(self._uniques):
-            col = np.array([k[j] for k in keys],
-                           dtype=object if u.dtype == object else None)
+        for u, part in zip(self._uniques, zip(*keys)):
+            col = np.array(part, dtype=object if u.dtype == object else None)
             r = np.minimum(np.searchsorted(u, col), max(len(u) - 1, 0))
             ok &= (u[r] == col) if len(u) else False
             ranks.append(r)
@@ -76,23 +77,17 @@ class KeyIndex:
     def take(self, keys: list) -> np.ndarray:
         """The lanes that hold ``keys`` now (-1: none), struck out of the
         index: whoever asks is about to clear them."""
-        out = np.full(len(keys), -1, dtype=np.int64)
-        rest = []
-        for i, k in enumerate(keys):
-            lane = self._overlay.pop(k, None)
-            if lane is None:
-                rest.append(i)
-            else:
-                out[i] = lane
-        if rest and len(self._codes):
+        out = np.fromiter(map(self._overlay.pop, keys, repeat(-1)),
+                          dtype=np.int64, count=len(keys))
+        rest = np.nonzero(out < 0)[0]
+        if len(rest) and len(self._codes):
             slots = self._base_slots([keys[i] for i in rest])
             hit = slots >= 0
-            at = np.asarray(rest)[hit]
-            out[at] = self._lanes[slots[hit]]
+            out[rest[hit]] = self._lanes[slots[hit]]
             self._lanes[slots[hit]] = -1
         return out
 
     def put(self, keys: list, first_lane: int):
         """``keys`` now live in consecutive lanes from ``first_lane``."""
-        for i, k in enumerate(keys):
-            self._overlay[k] = first_lane + i
+        self._overlay.update(zip(keys, range(first_lane,
+                                             first_lane + len(keys))))

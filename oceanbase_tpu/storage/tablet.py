@@ -16,11 +16,12 @@ from __future__ import annotations
 import itertools
 import threading
 from dataclasses import dataclass, field
+from operator import itemgetter
 
 import numpy as np
 
 from oceanbase_tpu.datatypes import SqlType
-from oceanbase_tpu.storage.memtable import MemTable
+from oceanbase_tpu.storage.memtable import MemTable, Version
 from oceanbase_tpu.storage.segment import Segment, merge_segments
 
 
@@ -57,6 +58,7 @@ class TabletDelta:
     arrays: dict                  # column -> values of those rows
     valids: dict                  # column -> validity, None = all valid
     mark: tuple                   # the mark a copy holds once applied
+    segment_keys: int = 0         # keys that were looked up in L0 segments
 
 
 class Tablet:
@@ -75,7 +77,8 @@ class Tablet:
         self._auto_key = itertools.count()  # rowid for keyless tables
         self.data_version = 0               # bumps on any visible change
         # what delta_since answers from: every commit applied here, in
-        # the order applied, as (sequence, commit version, keys)
+        # the order applied, as (sequence, commit version, keys, the
+        # version each key got | None once a flush moved them to L0)
         # (a PartitionedTablet turns it off for its partitions: their
         # copies are rebuilt, and every partition is told every key)
         self.logs_commits = True
@@ -147,16 +150,33 @@ class Tablet:
                 mt.commit(tx_id, commit_version, keys)
             self.data_version += 1
             if self.logs_commits:
-                self._log_commit(commit_version, tuple(keys))
+                self._log_commit(tx_id, commit_version, tuple(keys))
 
-    def _log_commit(self, commit_version: int, keys: tuple):
+    def _log_commit(self, tx_id: int, commit_version: int, keys: tuple):
+        """The commit joins the log with the version it gave each key: the
+        head of the key's chain in the newest memtable that holds one, when
+        that is this transaction's (``None`` where its statement was rolled
+        back, so that ``delta_since`` looks the key up).  The memtables hold
+        those versions anyway; ``mini_compact`` lets go of them here."""
         with self._lock:
+            tables = [mt._rows for mt in self.memtables()]
+            versions = []
+            for key in keys:
+                head = None
+                for rows in tables:
+                    head = rows.get(key)
+                    if head is not None:
+                        break
+                versions.append(
+                    head if head is not None and head.tx_id == tx_id
+                    and head.commit_version == commit_version else None)
             self._commit_seq += 1
-            self._commit_log.append((self._commit_seq, commit_version, keys))
+            self._commit_log.append((self._commit_seq, commit_version, keys,
+                                     tuple(versions)))
             self._log_keys += len(keys)
             while self._log_keys > COMMIT_LOG_KEYS and \
                     len(self._commit_log) > 1:
-                seq, version, dropped = self._commit_log.pop(0)
+                seq, version, dropped, _ = self._commit_log.pop(0)
                 self._log_keys -= len(dropped)
                 self._log_floor = (seq, max(self._log_floor[1], version))
 
@@ -184,42 +204,50 @@ class Tablet:
         answered exactly (the baseline was rewritten, or the log no
         longer reaches back to the mark): the caller rebuilds.
 
-        Reads the memtables (active and frozen) and, for a key a freeze
-        and mini-compaction moved meanwhile, the L0 segments that hold
+        One pass over the commit log: each entry carries the version its
+        commit gave every key, and a key's commits lie in the log in the
+        order of its version chain, so folding the entries in order leaves
+        the newest.  Only a key whose newest entry cannot say (its
+        statement was rolled back, or a flush moved the versions to L0) is
+        looked up: in the memtables, then in the L0 segments that hold
         versions as new as the commits asked for."""
         epoch, seq = mark
         with self._lock:
             if epoch != self.baseline_epoch or seq < self._log_floor[0] \
                     or after < self._log_floor[1]:
                 return None
-            keys: dict = {}
+            newest: dict = {}
             oldest = upto
-            for s, version, ks in self._commit_log:
+            for s, version, ks, versions in self._commit_log:
                 if (s > seq or version > after) and version <= upto:
                     oldest = min(oldest, version)
-                    for k in ks:
-                        keys[k] = None
+                    newest.update(zip(ks, versions) if versions is not None
+                                  else dict.fromkeys(ks))
             new_mark = (self.baseline_epoch, self._commit_seq)
-            found: dict = {}
+            tables = self.memtables()
             missing = []
-            tables = [self.active] + self.frozen[::-1]
-            for key in keys:
+            for key in [k for k, v in newest.items() if v is None]:
                 for mt in tables:
                     v = mt.visible_version(key, upto)
                     if v is not None:
-                        found[key] = (v.op == "delete", v.values)
+                        newest[key] = v
                         break
                 else:
                     missing.append(key)
             if missing:
-                found.update(self._segment_versions(missing, oldest, upto))
+                for key, (deleted, values) in self._segment_versions(
+                        missing, oldest, upto).items():
+                    newest[key] = Version(
+                        0, 0, "delete" if deleted else "insert", values)
         # a key with no committed version at all was written and rolled
         # back by its statement: nothing changed for it
-        touched = [k for k in keys if k in found]
-        row_keys = [k for k in touched if not found[k][0]]
-        arrays, valids = _values_to_arrays(
-            [found[k][1] for k in row_keys], self.columns, self.types)
-        return TabletDelta(touched, row_keys, arrays, valids, new_mark)
+        found = {k: v for k, v in newest.items() if v is not None}
+        touched = list(found)
+        row_keys = [k for k, v in found.items() if v.op != "delete"]
+        rows = [found[k].values for k in row_keys]
+        arrays, valids = _values_to_arrays(rows, self.columns, self.types)
+        return TabletDelta(touched, row_keys, arrays, valids, new_mark,
+                           len(missing))
 
     def _segment_versions(self, keys: list, oldest: int, upto: int) -> dict:
         """{key: (deleted, values)} of the newest version <= ``upto`` of
@@ -308,6 +336,11 @@ class Tablet:
             self.frozen = []
             for lo in leftovers:
                 self._graft_versions(lo)
+            # the flushed versions live in the segment now: the log keeps
+            # none of them alive (delta_since looks those keys up)
+            self._commit_log = [
+                (s, version, ks, None if version <= snapshot else versions)
+                for s, version, ks, versions in self._commit_log]
             self.data_version += 1
             return seg
 
@@ -481,24 +514,36 @@ def _item(x):
     return x.item() if hasattr(x, "item") else x
 
 
+def _has_null(vals) -> bool:
+    try:
+        return None in vals     # identity first, at C speed
+    except ValueError:          # an array-valued cell compares elementwise
+        return any(x is None for x in vals)
+
+
 def _values_to_arrays(rows: list, columns, types):
     """[{column: python value | None}] -> (arrays, valids), a column at a
     time; ``valids[c]`` is None where no row is NULL there."""
+    columns = list(columns)
+    cell = itemgetter(*columns) if len(columns) > 1 else \
+        (lambda r, c=columns[0]: (r[c],))
+    try:
+        # one C-level pass a row, transposed once
+        cells = list(zip(*map(cell, rows)))
+    except KeyError:
+        # a row lacks a column (a delete's, or one written before an ALTER
+        # TABLE ADD COLUMN): NULL there
+        cells = [tuple(r.get(c) for r in rows) for c in columns]
     arrays, valids = {}, {}
-    for c in columns:
-        vals = [r.get(c) for r in rows]
-        null = np.fromiter((x is None for x in vals), dtype=bool,
-                           count=len(vals))
-        if null.any():
+    for c, vals in zip(columns, cells or [()] * len(columns)):
+        valids[c] = None
+        if _has_null(vals):
             fill = "" if types[c].is_string else 0
+            valids[c] = np.fromiter((x is not None for x in vals),
+                                    dtype=bool, count=len(vals))
             vals = [fill if x is None else x for x in vals]
-            valids[c] = ~null
-        else:
-            valids[c] = None
-        if types[c].is_string:
-            arrays[c] = np.array(vals, dtype=object)
-        else:
-            arrays[c] = np.asarray(vals, dtype=types[c].np_dtype)
+        arrays[c] = np.array(vals, dtype=object) if types[c].is_string \
+            else np.asarray(vals, dtype=types[c].np_dtype)
     return arrays, valids
 
 

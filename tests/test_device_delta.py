@@ -176,6 +176,106 @@ def test_maintained_copy_equals_rebuild_and_row_model(db, seed):
     s.close()
 
 
+def _rows(first_k, n, **over):
+    return [dict({"k": first_k + i, "j": i % 3, "v": i * 7 + 1,
+                  "s": f"e{i % 4}", "d": 9500 + i}, **over)
+            for i in range(n)]
+
+
+#: the window's edges: (DELTA_LANES or None for the module's own, rows the
+#: baseline holds (its capacity is 64), steps); a step inserts rows or
+#: deletes by ``k``, and is one commit that the next read applies
+WINDOW_EDGES = {
+    # first = capacity - W: the window needs no clamp and ends the table
+    "last_row_on_the_last_lane": (8, 56, [("insert", _rows(2000, 8))]),
+    # first within W of the end: the start is clamped by the program, the
+    # lanes below ``first`` (rows 56-58, then 56-61) keep their values
+    "start_within_a_window_of_the_end": (
+        8, 59, [("insert", _rows(2000, 3)), ("insert", _rows(2100, 2))]),
+    # the relation is shorter than a chunk: W is its capacity
+    "capacity_under_a_chunk": (
+        None, 10, [("insert", _rows(2000, 5)), ("delete", [2001, 3, 4]),
+                   ("insert", _rows(2100, 3, s=None))]),
+    "more_rows_than_a_chunk": (8, 30, [("insert", _rows(2000, 21))]),
+    "clears_alone": (8, 40, [("delete", list(range(0, 19)))]),
+    "rows_and_clears_of_different_chunk_counts": (
+        8, 40, [("mixed", _rows(2000, 3), list(range(5, 25)))]),
+}
+
+
+@pytest.mark.parametrize("edge", sorted(WINDOW_EDGES))
+def test_window_edges_equal_rebuild_and_row_model(db, monkeypatch, edge):
+    lanes, seeded, steps = WINDOW_EDGES[edge]
+    if lanes is not None:
+        monkeypatch.setattr(device_delta, "DELTA_LANES", lanes)
+    s = db.session()
+    model = RowModel()
+    _seeded(s, model, n=seeded)
+    assert s.catalog.table_data("t").capacity == 64
+    for kind, *what in steps:
+        if kind == "mixed":
+            s.execute("begin")
+        if kind in ("insert", "mixed"):
+            s.execute("insert into t values "
+                      + ", ".join(map(_row_sql, what[0])))
+            for r in what[0]:
+                model.insert(r)
+        if kind in ("delete", "mixed"):
+            s.execute("delete from t where k in ("
+                      + ", ".join(map(str, what[-1])) + ")")
+            model.delete_k(set(what[-1]))
+        if kind == "mixed":
+            s.execute("commit")
+        before = s.catalog._cache.get("t")
+        applies = _count("storage.delta_applies")
+        builds = _count("storage.device_copy_builds")
+        rel = s.catalog.table_data("t")
+        assert _count("storage.delta_applies") == applies + 1
+        assert _count("storage.device_copy_builds") == builds
+        assert rel.capacity == 64
+        got = _columns_of(rel)
+        assert got == model.columns()
+        assert got == _columns_of(_rebuilt(s))
+        # the lanes the delta did not name are the old relation's, value
+        # for value: nothing was shifted onto them
+        kept = np.asarray(before.rel.mask_or_true()) \
+            & np.asarray(rel.mask_or_true())
+        for c in ("k", "j", "v", "d"):
+            old, new = (np.asarray(r.columns[c].data)
+                        for r in (before.rel, rel))
+            assert (old[kept] == new[kept]).all(), c
+    s.close()
+
+
+def test_a_columns_first_null_lands_inside_the_window(db):
+    s = db.session()
+    s.execute(DDL)
+    n = 20
+    s.catalog.load_numpy(
+        "t", {"k": np.arange(n), "j": np.arange(n) % 3,
+              "v": np.arange(n) * 100, "d": 9000 + np.arange(n),
+              "s": np.array([f"s{i % 5}" for i in range(n)], dtype=object)},
+        primary_key=["k", "j"])
+    model = RowModel()
+    for i in range(n):
+        model.insert({"k": i, "j": i % 3, "v": i * 100, "s": f"s{i % 5}",
+                      "d": 9000 + i})
+    before = s.catalog.table_data("t")
+    assert before.columns["v"].valid is None    # loaded with no NULL
+    rows = _rows(2000, 3, v=None) + _rows(2100, 2, d=None)
+    s.execute("insert into t values " + ", ".join(map(_row_sql, rows)))
+    for r in rows:
+        model.insert(r)
+    applies = _count("storage.delta_applies")
+    after = s.catalog.table_data("t")
+    assert _count("storage.delta_applies") == applies + 1
+    assert after.columns["v"].valid is not None
+    assert _columns_of(after) == model.columns()
+    assert _columns_of(after) == _columns_of(_rebuilt(s))
+    assert _columns_of(before)["k"] == list(range(n))   # its snapshot
+    s.close()
+
+
 def test_nulls_and_new_strings_keep_a_sorted_dictionary(db):
     s = db.session()
     model = RowModel()
@@ -551,6 +651,119 @@ def test_delta_since_lists_the_newest_version_of_each_key(db):
     s.close()
 
 
+def _delta_by_lookup(tab, mark, after, upto):
+    """The plain reference of ``delta_since``: the loop it replaced, which
+    asks every memtable for every key the log names, then the L0 segments
+    -> (keys touched, keys live, their rows)."""
+    _epoch, seq = mark
+    keys, oldest = {}, upto
+    for s, version, ks, *_ in tab._commit_log:
+        if (s > seq or version > after) and version <= upto:
+            oldest = min(oldest, version)
+            keys.update(dict.fromkeys(ks))
+    found, missing = {}, []
+    for key in keys:
+        for mt in [tab.active] + tab.frozen[::-1]:
+            v = mt.visible_version(key, upto)
+            if v is not None:
+                found[key] = (v.op == "delete", v.values)
+                break
+        else:
+            missing.append(key)
+    if missing:
+        found.update(tab._segment_versions(missing, oldest, upto))
+    touched = [k for k in keys if k in found]
+    live = [k for k in touched if not found[k][0]]
+    return touched, live, [found[k][1] for k in live]
+
+
+def _twice(s, tab):
+    s.execute("insert into t values (70, 0, 1.00, 'first', null)")
+    s.execute("update t set s = 'second', v = 2.50 where k = 70 and j = 0")
+    s.execute("update t set d = date '1995-01-01' where k = 2 and j = 2")
+
+
+def _inserted_then_deleted(s, tab):
+    s.execute("insert into t values (71, 0, 1.00, 'gone', null), "
+              "(72, 1, 1.00, 'stays', null)")
+    s.execute("delete from t where k in (71, 3)")
+
+
+def _frozen(s, tab):
+    s.execute("insert into t values (73, 0, 1.00, 'cold', null)")
+    s.execute("delete from t where k in (4)")
+    assert tab.freeze() is not None and tab.frozen      # no compaction
+    s.execute("insert into t values (74, 0, 1.00, 'warm', null)")
+
+
+def _moved_to_l0(s, tab):
+    s.execute("insert into t values (75, 0, 1.00, 'flushed', null)")
+    s.execute("delete from t where k in (5)")
+    s.execute("alter system minor freeze")
+    assert not tab.frozen and tab.segments
+    s.execute("insert into t values (76, 0, 1.00, 'after', null)")
+
+
+def _rolled_back_statement(s, tab):
+    from oceanbase_tpu.tx.errors import DuplicateKey
+
+    s.execute("begin")
+    s.execute("insert into t values (77, 0, 1.00, 'kept', null)")
+    with pytest.raises(DuplicateKey):   # 78 is written, then taken back
+        s.execute("insert into t values (78, 0, 1.00, 'no', null), "
+                  "(77, 0, 1.00, 'twice', null)")
+    s.execute("update t set s = 'mine' where k = 1 and j = 1")
+    s.execute("commit")
+
+
+#: what is committed between the mark and the read -> (commits, does the
+#: log answer every key alone)
+DELTA_HISTORIES = {
+    "written_twice_in_two_commits": (_twice, True),
+    "inserted_then_deleted": (_inserted_then_deleted, True),
+    "committed_then_frozen": (_frozen, True),
+    "moved_to_l0_by_a_mini_compaction": (_moved_to_l0, False),
+    "written_and_rolled_back_by_its_statement": (_rolled_back_statement,
+                                                 None),
+}
+
+
+@pytest.mark.parametrize("below_newest", [False, True],
+                         ids=["upto_newest", "upto_below_newest"])
+@pytest.mark.parametrize("history", sorted(DELTA_HISTORIES))
+def test_delta_since_from_the_log_equals_the_per_key_lookup(
+        db, history, below_newest):
+    commits, log_answers = DELTA_HISTORIES[history]
+    s = db.session()
+    model = RowModel()
+    _seeded(s, model, n=8)
+    tab = s.catalog.engine.tables["t"].tablet
+    mark, after = tab.delta_mark(), s._txsvc.gts.current()
+    commits(s, tab)
+    upto = s._txsvc.gts.current()
+    if below_newest:    # one more commit on a key of the delta, above upto
+        s.execute("insert into t values (99, 0, 1.00, 'later', null)")
+        s.execute("update t set s = 'later' where k = 2 and j = 2")
+        s.execute("delete from t where k in (72, 74, 76, 77)")
+    want_keys, want_live, want_rows = _delta_by_lookup(tab, mark, after,
+                                                       upto)
+    d = tab.delta_since(mark, after, upto)
+    assert d.keys == want_keys and want_keys
+    assert d.row_keys == want_live
+    assert (99, 0) not in d.keys
+    for c in COLS:
+        valid = d.valids[c]
+        got = [None if valid is not None and not valid[i] else x
+               for i, x in enumerate(d.arrays[c].tolist())]
+        assert got == [r[c] for r in want_rows], c
+    if log_answers is not None:
+        assert (d.segment_keys == 0) == log_answers
+    # and the maintained copy it feeds equals a rebuild
+    assert _columns_of(s.catalog.table_data("t")) == \
+        _columns_of(_rebuilt(s))
+    s.close()
+
+
 @pytest.mark.parametrize("strings", [False, True])
 def test_key_index_finds_takes_and_puts(strings):
     import jax.numpy as jnp
@@ -592,6 +805,54 @@ def test_apply_in_chunks_meets_every_size_with_one_program(db, monkeypatch):
         str(k) for k in range(0, 19)) + ")")    # 19 lanes: three chunks
     model.delete_k(set(range(0, 19)))
     assert _columns_of(s.catalog.table_data("t")) == model.columns()
+    s.close()
+
+
+def test_a_chunk_is_written_by_windows_and_one_scatter_over_the_mask():
+    """The mechanism, with no chip: in the program ``_apply_chunk`` lowers
+    to, no scatter takes a column's data or validity (they go through
+    ``dynamic_update_slice``); the one scatter left clears the mask."""
+    import re
+
+    import jax.numpy as jnp
+
+    cap, w = 4096, 256
+    cols = {"a": (jnp.zeros(cap, jnp.int64), None),
+            "b": (jnp.zeros(cap, jnp.int32), jnp.ones(cap, jnp.bool_))}
+    rows = {"a": (np.zeros(w, np.int64), None),
+            "b": (np.zeros(w, np.int32), np.ones(w, bool))}
+    lowered = device_delta._apply_chunk.lower(
+        cols, jnp.ones(cap, jnp.bool_), np.zeros(w, np.int32),
+        np.int32(0), np.int32(0), rows)
+    hlo = lowered.compiler_ir(dialect="hlo").as_hlo_text()
+    scatters = re.findall(r"= (\w+)\[([\d,]*)\][^=]* scatter\(", hlo)
+    assert scatters == [("pred", str(cap))], scatters
+    # data of a, data and validity of b, the mask's new lanes
+    updates = re.findall(r"= (\w+)\[([\d,]*)\][^=]* "
+                         r"dynamic-update-slice\(", hlo)
+    assert sorted(u for u in updates if u[1] == str(cap)) == sorted(
+        [("s64", str(cap)), ("s32", str(cap)), ("pred", str(cap)),
+         ("pred", str(cap))]), updates
+    assert "gather(" not in hlo
+
+
+def test_a_second_apply_of_another_size_compiles_nothing(db):
+    s = db.session()
+    model = RowModel()
+    _seeded(s, model, n=30)
+    s.execute("insert into t values " + ", ".join(map(_row_sql, _rows(
+        2000, 2, s="new-a"))))
+    s.catalog.table_data("t")               # the layout's program compiles
+    events = _backend_compiles()
+    s.execute("insert into t values " + ", ".join(map(_row_sql, _rows(
+        2100, 7, s="new-b"))))
+    s.execute("delete from t where k in (1, 2, 3, 2000)")
+    e0 = len(events)
+    applies = _count("storage.delta_applies")
+    rel = s.catalog.table_data("t")
+    assert _count("storage.delta_applies") == applies + 1
+    assert len(events) == e0, events[e0:]
+    assert int(np.asarray(rel.mask_or_true()).sum()) == 30 + 2 + 7 - 4
     s.close()
 
 
