@@ -46,3 +46,61 @@ def numpy_q6(li: dict, d0: int, d1: int) -> int:
     )
     return int((li["l_extendedprice"][sel].astype(np.int64)
                 * li["l_discount"][sel].astype(np.int64)).sum())
+
+
+def _lookup(keys: np.ndarray, probe: np.ndarray):
+    """-> (position in the unique ``keys`` of each ``probe`` value,
+    whether it is there)."""
+    order = np.argsort(keys, kind="stable")
+    at = np.minimum(np.searchsorted(keys[order], probe), len(keys) - 1)
+    return order[at], keys[order][at] == probe
+
+
+def numpy_q9(tables: dict, color: str = "green") -> list:
+    """TPC-H Q9 -> [(nation, year, sum_profit at scale 4)], ordered by
+    nation, year descending; every sum an exact Python int.  The six-table
+    join by ``searchsorted`` on the keys, ``amount = l_extendedprice *
+    (1 - l_discount) - ps_supplycost * l_quantity`` in int64."""
+    part, supp, li = tables["part"], tables["supplier"], tables["lineitem"]
+    ps, orders, nation = (tables["partsupp"], tables["orders"],
+                          tables["nation"])
+    named = part["p_partkey"][
+        np.char.find(part["p_name"].astype("U"), color) >= 0]
+    sel = np.flatnonzero(np.isin(li["l_partkey"], named))
+    l_part = li["l_partkey"][sel].astype(np.int64)
+    l_supp = li["l_suppkey"][sel].astype(np.int64)
+    width = int(max(ps["ps_suppkey"].max(), l_supp.max(initial=0))) + 1
+    at_ps, in_ps = _lookup(ps["ps_partkey"].astype(np.int64) * width
+                           + ps["ps_suppkey"], l_part * width + l_supp)
+    at_s, in_s = _lookup(supp["s_suppkey"].astype(np.int64), l_supp)
+    at_o, in_o = _lookup(orders["o_orderkey"].astype(np.int64),
+                         li["l_orderkey"][sel].astype(np.int64))
+    at_n, in_n = _lookup(nation["n_nationkey"].astype(np.int64),
+                         supp["s_nationkey"][at_s].astype(np.int64))
+    keep = in_ps & in_s & in_o & in_n
+    sel = sel[keep]
+    amount = (li["l_extendedprice"][sel].astype(np.int64)
+              * (100 - li["l_discount"][sel].astype(np.int64))
+              - ps["ps_supplycost"][at_ps[keep]].astype(np.int64)
+              * li["l_quantity"][sel].astype(np.int64))
+    year = orders["o_orderdate"][at_o[keep]].astype("datetime64[D]") \
+        .astype("datetime64[Y]").astype(np.int64) + 1970
+    sums: dict = {}
+    for n, y, a in zip(nation["n_name"][at_n[keep]], year.tolist(),
+                       amount.tolist()):
+        sums[str(n), y] = sums.get((str(n), y), 0) + a
+    return sorted(((n, y, s) for (n, y), s in sums.items()),
+                  key=lambda r: (r[0], -r[1]))
+
+
+def numpy_q14(tables: dict, d0: int, d1: int) -> tuple[int, int]:
+    """TPC-H Q14 -> (promo revenue, total revenue), both at scale 4, of
+    the lineitems shipped in ``[d0, d1)`` whose part exists."""
+    li, part = tables["lineitem"], tables["part"]
+    sel = (li["l_shipdate"] >= d0) & (li["l_shipdate"] < d1)
+    at, there = _lookup(part["p_partkey"].astype(np.int64),
+                        li["l_partkey"][sel].astype(np.int64))
+    revenue = li["l_extendedprice"][sel].astype(np.int64) \
+        * (100 - li["l_discount"][sel].astype(np.int64))
+    promo = np.char.startswith(part["p_type"].astype("U"), "PROMO")[at]
+    return int(revenue[there & promo].sum()), int(revenue[there].sum())

@@ -60,6 +60,10 @@ class TableDef:
     # equality selectivity reads the measured frequency instead of a
     # guess (≙ ObOptColumnStat top-k frequency histogram)
     mcv: dict = field(default_factory=dict)
+    # a row-weighted sample of each dict-encoded string column's values
+    # from ANALYZE: col -> tuple of strings at evenly spaced ranks of
+    # the rows; LIKE selectivity is the share of it that matches
+    samples: dict = field(default_factory=dict)
     # range partitioning: (column, [upper-exclusive split points]) or None
     partition: tuple | None = None
     # hash / key partitioning: (method, [columns], partitions) or None.

@@ -92,6 +92,31 @@ def monitor_push(op_name: str, count_scalar, est: int | None = None) -> None:
 
 
 # ---------------------------------------------------------------------------
+# count lane: what an execution MEASURES beside its result and is booked
+# to ``gv$sysstat`` (the live rows a PX exchange received, by kind).  A
+# shard program sums the lanes over the mesh and hands them to the host
+# in the vector that carries its overflow lanes: no sync of their own.
+# ---------------------------------------------------------------------------
+
+_counts: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "ob_tpu_counts", default=None
+)
+
+
+def count_collect():
+    """Activate the lane; yields the list of (kind, row_bytes, scalar)."""
+    return _collecting(_counts)
+
+
+def count_rows(kind: str, row_bytes: int, scalar) -> None:
+    """Record the traced live-row count of one exchange of ``kind`` whose
+    rows are ``row_bytes`` wide (no-op outside a collector)."""
+    entries = _counts.get()
+    if entries is not None:
+        entries.append((kind, row_bytes, scalar))
+
+
+# ---------------------------------------------------------------------------
 # note lane: facts of the traced program that an operator picks from
 # static shapes and types (which way a probe ranks its keys, which way a
 # group-by reduces, whether a join's input was compacted to its estimate's

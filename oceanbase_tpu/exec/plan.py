@@ -29,6 +29,12 @@ import jax
 from oceanbase_tpu.exec import diag, ops
 from oceanbase_tpu.exec.ops import AggSpec
 from oceanbase_tpu.expr import ir
+from oceanbase_tpu.expr.compile import (
+    LUTS_TABLE,
+    dictionary_luts,
+    like_patterns,
+    provided_luts,
+)
 from oceanbase_tpu.server import metrics as qmetrics
 from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.vector.column import Relation, prefetch
@@ -925,10 +931,11 @@ class Program:
 
     ``body(*args, tables) -> Relation`` is the function to trace: the
     serial lowering of a plan (``_lower``), or one shard's half of a PX
-    plan (``px/planner.py``).  ``shard`` = (mesh, axis, table names) wraps
-    the traced function in ``jax.shard_map`` over the mesh, every table
-    on ``P(axis)``, the result relation on ``P(axis)`` and the overflow
-    total ``psum``med over the axis.  ``stats_key`` names the program's
+    plan (``px/planner.py``).  ``shard`` = (mesh, axis, table names[, the
+    names of those every shard holds whole]) wraps the traced function in
+    ``jax.shard_map`` over the mesh, every other table on ``P(axis)``, the
+    result relation on ``P(axis)`` and its overflow and count lanes as one
+    vector ``psum``med over the axis.  ``stats_key`` names the program's
     ``gv$plan_cache`` row.  Only ``key`` is hashed and compared: the
     rest rides along to the cache miss that builds the executable."""
 
@@ -960,7 +967,8 @@ class _PlanExecutable:
     MAX_SIGNATURES = 64  # >> the bucket-ladder rungs a table ever visits
 
     __slots__ = ("program", "stats", "diag_names", "monitor_names",
-                 "_noted", "_run", "_execs", "_lock", "scan_columns")
+                 "count_names", "_noted", "_run", "_execs", "_lock",
+                 "scan_columns", "like_patterns")
 
     def __init__(self, program: Program, with_monitor: bool = False):
         self.program = program
@@ -968,9 +976,14 @@ class _PlanExecutable:
         #: tables by it); None: the tables go in whole
         self.scan_columns = scan_columns(program.args[0]) \
             if program.body is _lower else None
+        #: the plan's LIKE patterns: over a large dictionary each one's
+        #: lookup table is an input of the program (``call``)
+        self.like_patterns = frozenset(like_patterns(program.args))
         self.stats = _stats_for(program.stats_key)
         self.diag_names: list[str] = []     # filled at trace time
         self.monitor_names: list[str] = []
+        #: a shard program's count lanes: (kind, row bytes) of each
+        self.count_names: list[tuple] = []
         self._noted: Counter = Counter()    # the last trace's notes
         last_noted = self._noted
         body, args, shard = program.body, program.args, program.shard
@@ -979,9 +992,12 @@ class _PlanExecutable:
             raise ValueError("a shard program carries no monitor lanes")
         diag_names = self.diag_names
         monitor_names = self.monitor_names
+        count_names = self.count_names
 
         def run(tables):
-            with diag.collect() as entries, diag.note_collect() as noted:
+            with diag.collect() as entries, diag.note_collect() as noted, \
+                    diag.count_collect() as counted, \
+                    provided_luts(tables.get(LUTS_TABLE)):
                 if with_monitor:
                     with diag.monitor_collect() as mons:
                         out = body(*args, tables)
@@ -1020,17 +1036,30 @@ class _PlanExecutable:
                     jnp.asarray(v, dtype=jnp.int64), 0)
             lanes = [v for _, v, _ in entries]
             if shard is not None:
-                # the total over the mesh decides; a lane's detail would
-                # be one shard's
-                lanes, total = [], jax.lax.psum(total, shard[1])
+                # one vector summed over the mesh: every overflow lane
+                # (a lane's detail on one shard would be that shard's),
+                # then every count lane; the host reads it once
+                count_names.clear()
+                count_names.extend((k, b) for k, b, _ in counted)
+                vec = [jnp.maximum(jnp.asarray(v, dtype=jnp.int64), 0)
+                       for v in lanes + [c for _, _, c in counted]]
+                lanes = jax.lax.psum(
+                    jnp.stack(vec) if vec else jnp.zeros((0,), jnp.int64),
+                    shard[1])
+                total = jnp.sum(lanes[:len(entries)])
             return out, lanes, total, mon_vec
 
         if shard is not None:
             from jax.sharding import PartitionSpec as P
 
-            mesh, axis, names = shard
+            mesh, axis, names, *rest = shard
+            whole = rest[0] if rest else ()     # tables every shard holds
             run = jax.shard_map(
-                run, mesh=mesh, in_specs=({t: P(axis) for t in names},),
+                run, mesh=mesh,
+                in_specs=({t: P() if t in whole else P(axis)
+                           for t in names}
+                          | ({LUTS_TABLE: P()} if self.like_patterns
+                             else {}),),
                 out_specs=(P(axis), P(), P(), P()), check_vma=False)
         # only ever driven through .lower()/.compile(): the jit wrapper
         # exists for the lowering machinery (and so obcheck keeps seeing
@@ -1088,6 +1117,10 @@ class _PlanExecutable:
         trace's notes are the executed SIGNATURE's, so callers can
         attribute measured device time to the program that actually
         ran and book what it noted (``diag.book_notes``)."""
+        if self.like_patterns:
+            shard = self.program.shard
+            tables = {**tables, LUTS_TABLE: dictionary_luts(
+                self.like_patterns, tables, shard[0] if shard else None)}
         sig = _input_signature(tables, self.program.shard is not None)
         entry = self._execs.get(sig)
         compiled_now = False

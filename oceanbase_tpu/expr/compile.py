@@ -18,6 +18,8 @@ device gather/compare (see vector/column.py StringDict).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import re
 
 import jax.numpy as jnp
@@ -190,6 +192,101 @@ def _temporal_literal(s: str, kind: TypeKind) -> int:
     return us
 
 
+# ---------------------------------------------------------------------------
+# LIKE over a large dictionary: the lookup table is an INPUT of the program.
+# As a constant it put the dictionary's every value into the HLO, so a table
+# loaded from other data (a high-cardinality string column such as TPC-H's
+# p_name) was another program to the persistent compile cache: minutes of
+# compile for a statement whose plan had not changed.
+# ---------------------------------------------------------------------------
+
+#: a dictionary of at least this many values hands its tables over as inputs
+LUT_INPUT_MIN = 4096
+#: the pseudo table of a program's inputs that holds them, a column each
+LUTS_TABLE = "__dictionary_luts__"
+
+_luts: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "ob_tpu_dictionary_luts", default=None)
+_LUT_CACHE: dict = {}       # (name, where) -> the placed table, newest last
+_LUT_CACHE_MAX = 32
+
+
+def lut_name(sdict: StringDict, pattern: str) -> str:
+    """The column of ``LUTS_TABLE`` that holds ``pattern`` over ``sdict``."""
+    return f"{sdict._content_digest():016x}|{pattern}"
+
+
+def like_lut(sdict: StringDict, pattern: str) -> np.ndarray:
+    """bool[|dict|]: which values of ``sdict`` match ``pattern``."""
+    rx = re.compile(like_to_regex(pattern))
+    return sdict.lut(lambda s: rx.match(s) is not None)
+
+
+def dictionary_luts(patterns, tables: dict, mesh=None) -> Relation:
+    """What a program whose plan holds LIKE ``patterns`` takes as
+    ``LUTS_TABLE``: for every dictionary of ``LUT_INPUT_MIN`` values or
+    more among ``tables``' columns and every pattern, the pattern's table
+    over it, padded with False to the dictionary's bucket (a shape that a
+    reload of other data keeps) and placed once (on ``mesh``, whole on
+    every device, for a shard program).  Which pattern meets which column
+    is the trace's business: a table nobody reads is an unused input."""
+    from oceanbase_tpu.vector.column import bucket_capacity
+
+    cols = {}
+    big = {c.sdict for rel in tables.values() for c in rel.columns.values()
+           if c.sdict is not None and c.sdict.size >= LUT_INPUT_MIN}
+    for sdict in big:
+        for pattern in patterns:
+            name = lut_name(sdict, pattern)
+            placed = _LUT_CACHE.pop((name, mesh), None)
+            if placed is None:
+                lut = np.zeros(bucket_capacity(sdict.size), dtype=bool)
+                lut[:sdict.size] = like_lut(sdict, pattern)
+                if mesh is None:
+                    placed = jnp.asarray(lut)
+                else:
+                    import jax
+                    from jax.sharding import NamedSharding, PartitionSpec
+
+                    placed = jax.device_put(
+                        lut, NamedSharding(mesh, PartitionSpec()))
+            _LUT_CACHE[name, mesh] = placed
+            while len(_LUT_CACHE) > _LUT_CACHE_MAX:
+                _LUT_CACHE.pop(next(iter(_LUT_CACHE)))
+            cols[name] = Column(placed, None, SqlType.bool_(), None)
+    return Relation(columns=cols, mask=None)
+
+
+@contextlib.contextmanager
+def provided_luts(luts: Relation | None):
+    """While a program is traced: the tables its inputs brought."""
+    tok = _luts.set(None if luts is None else
+                    {n: c.data for n, c in luts.columns.items()})
+    try:
+        yield
+    finally:
+        _luts.reset(tok)
+
+
+def like_patterns(x, out: set | None = None) -> set:
+    """Every LIKE pattern anywhere inside a plan (its nodes, their
+    expressions and aggregate specs are dataclasses)."""
+    import dataclasses
+
+    out = set() if out is None else out
+    if isinstance(x, ir.Like):
+        out.add(x.pattern)
+    if isinstance(x, dict):
+        x = list(x.values())
+    if isinstance(x, (list, tuple, set, frozenset)):
+        for v in x:
+            like_patterns(v, out)
+    elif dataclasses.is_dataclass(x) and not isinstance(x, (type, SqlType)):
+        for f in dataclasses.fields(x):
+            like_patterns(getattr(x, f.name), out)
+    return out
+
+
 def like_to_regex(pattern: str) -> str:
     out = []
     for ch in pattern:
@@ -331,9 +428,11 @@ def eval_expr(e: ir.Expr, rel: Relation) -> Column:
     if isinstance(e, ir.Like):
         c = eval_expr(e.arg, rel)
         assert c.sdict is not None, "LIKE requires a dict-encoded column"
-        rx = re.compile(like_to_regex(e.pattern))
-        lut = jnp.asarray(c.sdict.lut(lambda s: rx.match(s) is not None))
-        val = lut[jnp.clip(c.data, 0, c.sdict.size - 1)]
+        luts, name = _luts.get() or {}, lut_name(c.sdict, e.pattern)
+        # a large dictionary's table is an input; a small one's a constant
+        lut = luts[name] if name in luts \
+            else jnp.asarray(like_lut(c.sdict, e.pattern))
+        val = lut[jnp.clip(c.data, 0, lut.shape[0] - 1)]
         if e.negated:
             val = ~val
         return Column(data=val, valid=c.valid, dtype=SqlType.bool_())

@@ -88,8 +88,8 @@ def dist_groupby_shard(
                                 out_capacity=local_cap, return_overflow=True)
     key_cols = [ir.col(k) for k in keys]
     recv, x_ovf = all_to_all_repartition(
-        local, key_cols, ndev, cap_per_dest=local_cap, axis_name=axis_name
-    )
+        local, key_cols, ndev, cap_per_dest=local_cap, axis_name=axis_name,
+        kind="groupby")
     final, f_ovf = hash_groupby(
         recv, {k: ir.col(k) for k in keys}, final_specs,
         out_capacity=out_cap, return_overflow=True,
@@ -240,9 +240,9 @@ def dist_join_shard_hybrid(
     l_cap = (probe_cap_per_dest if probe_cap_per_dest is not None
              else cap_per_dest)
     lrecv, lov = exchange_by_dest(left, hash_dest(lk, lm, l_hot), ndev,
-                                  l_cap, axis_name)
+                                  l_cap, axis_name, kind="hash")
     rrecv, rov = exchange_by_dest(right, hash_dest(rk, rm, r_hot), ndev,
-                                  cap_per_dest, axis_name)
+                                  cap_per_dest, axis_name, kind="hash")
     # hot probe rows stay home; hot build rows compact + broadcast.
     # The hot-build budget is a FRACTION of a destination bucket: hot
     # rows span at most 2*n_hot distinct keys, and a small static buffer
@@ -254,7 +254,7 @@ def dist_join_shard_hybrid(
     hot_build_local = compact(right.with_mask(r_hot), capacity=hot_cap)
     hot_overflow = jnp.maximum(
         jnp.sum(r_hot.astype(jnp.int64)) - hot_cap, 0)
-    hot_build = broadcast_gather(hot_build_local, axis_name)
+    hot_build = broadcast_gather(hot_build_local, axis_name, kind="hash")
 
     probe_all = concat([lrecv, local_hot_probe])
     build_all = concat([rrecv, hot_build])
@@ -289,9 +289,9 @@ def dist_join_shard(
     lrecv, lov = all_to_all_repartition(
         left, left_keys, ndev,
         probe_cap_per_dest if probe_cap_per_dest is not None
-        else cap_per_dest, axis_name)
+        else cap_per_dest, axis_name, kind="hash")
     rrecv, rov = all_to_all_repartition(right, right_keys, ndev, cap_per_dest,
-                                        axis_name)
+                                        axis_name, kind="hash")
     out = join(lrecv, rrecv, left_keys, right_keys, how=how,
                out_capacity=out_capacity)
     return out, lov + rov  # LOCAL count; callers psum as needed

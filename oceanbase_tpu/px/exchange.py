@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from oceanbase_tpu.datatypes import SqlType
+from oceanbase_tpu.exec import diag
 from oceanbase_tpu.exec.ops import _combined_key
 from oceanbase_tpu.expr import ir
 from oceanbase_tpu.expr.compile import eval_expr
@@ -162,12 +163,31 @@ def _hash_dest(rel: Relation, keys: Sequence[ir.Expr], ndev: int):
     return keyhash.dest_of(k, ndev)
 
 
+def row_bytes(rel: Relation) -> int:
+    """Bytes of one row of ``rel`` as an exchange moves it: every
+    column's element, a byte where it has a validity mask, and the row
+    mask's byte."""
+    mask_bytes = 1 if rel.mask is None else rel.mask.dtype.itemsize
+    return mask_bytes + sum(
+        c.data.dtype.itemsize * int(np.prod(c.data.shape[1:], dtype=int))
+        + (c.valid is not None) for c in rel.columns.values())
+
+
+def _count_received(kind: str | None, recv: Relation):
+    """The live rows this shard received over an exchange of ``kind``
+    (``px.exchange_rows``; ``diag.count_rows``)."""
+    if kind is not None:
+        diag.count_rows(kind, row_bytes(recv),
+                        jnp.sum(recv.mask_or_true(), dtype=jnp.int64))
+
+
 def exchange_by_dest(
     rel: Relation,
     dest,
     ndev: int,
     cap_per_dest: int,
     axis_name: str = PX_AXIS,
+    kind: str | None = None,
 ) -> tuple[Relation, jnp.ndarray]:
     """Ship each local row to the shard named by ``dest`` (dead rows must
     carry dest == ndev, the drop sentinel).  The generic transmit half of
@@ -177,40 +197,43 @@ def exchange_by_dest(
     Returns (received relation with capacity ndev*cap_per_dest, local
     overflow count)."""
     n = rel.capacity
-    m = rel.mask_or_true()
-    order = jnp.argsort(dest, stable=True)
-    s_dest = jnp.take(dest, order)
-    # rank within destination bucket
-    counts = jnp.bincount(s_dest, length=ndev + 1)
-    start = jnp.cumsum(counts) - counts
-    pos_in_bucket = jnp.arange(n) - jnp.take(start, s_dest)
-    live_lane = (s_dest < ndev) & (pos_in_bucket < cap_per_dest)
-    overflow = jnp.sum((s_dest < ndev) & (pos_in_bucket >= cap_per_dest))
+    # rows by destination: one sort of (dest, lane); equal destinations
+    # may come in any order.  Destination d's rows are then the sorted
+    # lanes [bounds[d], bounds[d + 1]): the send buffer's slot (d, j) is
+    # FILLED FROM sorted lane bounds[d] + j, a gather of ndev x cap lanes
+    # a column (a scatter of n lanes a column costs the TPU ten times as
+    # much an element), and nothing counts with a scatter-add
+    s_dest, order = jax.lax.sort(
+        (dest.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)),
+        num_keys=1, is_stable=False)
+    bounds = jnp.searchsorted(
+        s_dest, jnp.arange(ndev + 1, dtype=jnp.int32), side="left")
+    counts = bounds[1:] - bounds[:-1]
+    overflow = jnp.sum(jnp.maximum(counts - cap_per_dest, 0))
+    j = jnp.arange(cap_per_dest, dtype=jnp.int32)
+    sent_mask = j[None, :] < counts[:, None]            # [ndev, cap]
+    src = jnp.take(order, jnp.clip(bounds[:-1, None] + j[None, :],
+                                   0, n - 1))           # source lanes
 
-    slot = jnp.where(
-        live_lane, s_dest.astype(jnp.int64) * cap_per_dest + pos_in_bucket,
-        ndev * cap_per_dest,  # spill slot (dropped)
-    )
-
-    def scatter(x, fill=0):
-        buf = jnp.full((ndev * cap_per_dest + 1,) + x.shape[1:], fill, x.dtype)
-        return buf.at[slot].set(jnp.take(x, order, axis=0))[:-1]
+    def gather(x):
+        keep = sent_mask.reshape(sent_mask.shape + (1,) * (x.ndim - 1))
+        return jnp.where(keep, jnp.take(x, src, axis=0),
+                         jnp.zeros((), x.dtype))
 
     recv_cols = {}
-    sent_mask = scatter(m.astype(jnp.int8)).astype(jnp.bool_)
-    # reshape to [ndev, cap] and exchange
-    ex_mask = _a2a(sent_mask.reshape(ndev, cap_per_dest), axis_name)
+    # exchange the [ndev, cap] buffers; only live rows have dest < ndev,
+    # so a slot that is sent is a live row
+    ex_mask = _a2a(sent_mask, axis_name)
     for name, c in rel.columns.items():
-        sd = scatter(c.data)
-        rd = _a2a(sd.reshape((ndev, cap_per_dest) + sd.shape[1:]), axis_name)
+        rd = _a2a(gather(c.data), axis_name)
         rv = None
         if c.valid is not None:
-            sv = scatter(c.valid.astype(jnp.int8)).astype(jnp.bool_)
-            rv = _a2a(sv.reshape(ndev, cap_per_dest), axis_name).reshape(-1)
+            rv = _a2a(gather(c.valid), axis_name).reshape(-1)
         recv_cols[name] = Column(
             rd.reshape((ndev * cap_per_dest,) + rd.shape[2:]), rv, c.dtype, c.sdict
         )
     out = Relation(columns=recv_cols, mask=ex_mask.reshape(-1))
+    _count_received(kind, out)
     return out, overflow
 
 
@@ -220,6 +243,7 @@ def all_to_all_repartition(
     ndev: int,
     cap_per_dest: int,
     axis_name: str = PX_AXIS,
+    kind: str | None = None,
 ) -> tuple[Relation, jnp.ndarray]:
     """HASH-repartition the local shard across the mesh axis.
 
@@ -228,14 +252,15 @@ def all_to_all_repartition(
     """
     m = rel.mask_or_true()
     dest = jnp.where(m, _hash_dest(rel, keys, ndev), ndev)  # dead -> sentinel
-    return exchange_by_dest(rel, dest, ndev, cap_per_dest, axis_name)
+    return exchange_by_dest(rel, dest, ndev, cap_per_dest, axis_name, kind)
 
 
 def _a2a(x, axis_name):
     return jax.lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0, tiled=False)
 
 
-def broadcast_gather(rel: Relation, axis_name: str = PX_AXIS) -> Relation:
+def broadcast_gather(rel: Relation, axis_name: str = PX_AXIS,
+                     kind: str | None = None) -> Relation:
     """BROADCAST distribution: every chip receives every shard's rows
     (≙ ObSliceIdxCalc BROADCAST + bc2host; on TPU it's one all_gather)."""
     cols = {}
@@ -246,7 +271,9 @@ def broadcast_gather(rel: Relation, axis_name: str = PX_AXIS) -> Relation:
             v = jax.lax.all_gather(c.valid, axis_name, axis=0, tiled=True)
         cols[name] = Column(d, v, c.dtype, c.sdict)
     m = jax.lax.all_gather(rel.mask_or_true(), axis_name, axis=0, tiled=True)
-    return Relation(columns=cols, mask=m)
+    out = Relation(columns=cols, mask=m)
+    _count_received(kind, out)
+    return out
 
 
 def datahub_psum(x, axis_name: str = PX_AXIS):

@@ -108,5 +108,6 @@ def dist_sort_shard(
     spl = _splitters(prim, m, ndev, axis_name)
     dest = jnp.searchsorted(spl, prim, side="right").astype(jnp.int32)
     dest = jnp.where(m, dest, ndev)
-    recv, ovf = exchange_by_dest(rel, dest, ndev, cap_per_dest, axis_name)
+    recv, ovf = exchange_by_dest(rel, dest, ndev, cap_per_dest, axis_name,
+                                 kind="sort")
     return sort_rows(recv, keys, ascending), ovf

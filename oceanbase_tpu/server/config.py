@@ -202,6 +202,11 @@ DEF("px_exchange_capacity_per_dest", 1 << 20, "int",
     "all_to_all per-destination row budget", _pos)
 DEF("px_workers_per_tenant", 64, "int",
     "PX admission quota (≙ px_workers_per_cpu_quota)", _pos)
+DEF("parallel_servers_target", 0, "int",
+    "PX workers a tenant's statements may hold at once before the next "
+    "one is downgraded to a serial plan (upstream's name; its TPC-H "
+    "guide sets it from CPUs x nodes); a statement holds px_dop of "
+    "them.  0 (the default): px_workers_per_tenant is the quota", _nonneg)
 DEF("pdml_min_rows", 8192, "int",
     "parallel-DML threshold: statements writing at least this many rows "
     "fan the write phase out over tenant workers (≙ enable_parallel_dml "

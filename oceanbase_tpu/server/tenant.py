@@ -24,6 +24,36 @@ from oceanbase_tpu.storage.engine import StorageCatalog, StorageEngine
 from oceanbase_tpu.tx.service import TransService
 
 
+class PxQuota:
+    """The PX workers a tenant's statements may hold at once (≙ the px
+    target monitor behind ``parallel_servers_target``): a statement asks
+    for its degree of parallelism in workers and runs serially when they
+    are not there.  ``resize`` takes effect for the next statement; what
+    is held is given back against the new limit."""
+
+    def __init__(self, limit: int):
+        self.limit = int(limit)
+        self._held = 0
+        self._lock = threading.Lock()
+
+    def acquire(self, blocking: bool = False, n: int = 1) -> bool:
+        # admission never waits: a statement without workers is downgraded
+        del blocking
+        with self._lock:
+            if self._held + n > self.limit:
+                return False
+            self._held += n
+            return True
+
+    def release(self, n: int = 1):
+        with self._lock:
+            self._held = max(self._held - n, 0)
+
+    def resize(self, limit: int):
+        with self._lock:
+            self.limit = int(limit)
+
+
 class Tenant:
     def __init__(self, name: str, root: str | None, cluster_config: Config,
                  wal_replicas: int = 3, wal=None, recovery=None,
@@ -144,6 +174,8 @@ class Tenant:
                 # cached relations were padded under the old policy;
                 # drop them so the next read re-materializes
                 self.catalog._cache.invalidate()
+            elif k in ("parallel_servers_target", "px_workers_per_tenant"):
+                self.px_admission.resize(self._px_workers())
 
         # hot-reload from the tenant overlay AND the cluster config
         self.config.watch(_on_cfg)
@@ -154,8 +186,7 @@ class Tenant:
             max_workers=int(self.config["tenant_cpu_quota"]),
             thread_name_prefix=f"tnt-{name}")
         # PX admission quota (≙ px target monitor)
-        self.px_admission = threading.BoundedSemaphore(
-            int(self.config["px_workers_per_tenant"]))
+        self.px_admission = PxQuota(self._px_workers())
         self.memory_used = 0
 
         # memstore write backpressure (≙ writing throttling): byte
@@ -184,6 +215,12 @@ class Tenant:
                    "data": [data_dir] if data_dir else []},
             reclaim_cb=self.reclaim_log_disk)
         self.tx.diskmgr = self.diskmgr
+
+    def _px_workers(self) -> int:
+        """``parallel_servers_target`` when set, else the older
+        ``px_workers_per_tenant``."""
+        return int(self.config["parallel_servers_target"]) \
+            or int(self.config["px_workers_per_tenant"])
 
     def _pressure_flush(self, table: str):
         """Memstore-pressure flush: freeze + flush ``table`` at the

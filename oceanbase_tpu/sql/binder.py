@@ -106,13 +106,17 @@ class Fragment:
     plan: pp.PlanNode
     cols: dict[str, str]  # visible name -> colid (display/debug only)
     est_rows: int
-    unique_cols: frozenset = frozenset()  # colids known unique (PK)
+    # colids known unique (a single-column PK), and the tuple of colids of
+    # a composite PK: unique together
+    unique_cols: frozenset = frozenset()
     colids: frozenset = frozenset()       # every colid this subtree produces
     ndv: dict = field(default_factory=dict)  # colid -> distinct-value est
     # colid -> (equi-height edges, null_frac, SqlType) from ANALYZE
     hist: dict = field(default_factory=dict)
     # colid -> (mcv values, frequency fractions) from ANALYZE (strings)
     mcv: dict = field(default_factory=dict)
+    # colid -> a row-weighted sample of a string column's values (ANALYZE)
+    samples: dict = field(default_factory=dict)
     # colid -> (lo, hi, selectivity charged): the range bounds the
     # fragment's filters already priced (_and_selectivity)
     ranges: dict = field(default_factory=dict)
@@ -473,6 +477,7 @@ class Binder:
         ndv = {}
         hist = {}
         mcv = {}
+        samples = {}
         for c in tdef.columns:
             cid = fresh(f"{alias}_{c.name}")
             rename[c.name] = cid
@@ -485,14 +490,18 @@ class Binder:
                 hist[cid] = (edges, nf, c.dtype)
             if c.name in getattr(tdef, "mcv", {}):
                 mcv[cid] = tdef.mcv[c.name]
+            if c.name in getattr(tdef, "samples", {}):
+                samples[cid] = tdef.samples[c.name]
         if len(tdef.primary_key) == 1:
             unique.append(rename[tdef.primary_key[0]])
             ndv[rename[tdef.primary_key[0]]] = max(tdef.row_count, 1)
+        elif tdef.primary_key:
+            unique.append(tuple(rename[c] for c in tdef.primary_key))
         qb.fragments.append(Fragment(
             pp.TableScan(name, rename=rename,
                          est_rows=max(tdef.row_count, 1)),
             cols, max(tdef.row_count, 1), frozenset(unique), ndv=ndv,
-            hist=hist, mcv=mcv,
+            hist=hist, mcv=mcv, samples=samples,
         ))
 
     def _bind_view(self, name: str, vdef: dict, tref, qb, scope):
@@ -737,7 +746,7 @@ class Binder:
         i = homes[0]
         f = qb.fragments[i]
         sel, ranges = _and_selectivity([bound], f.hist, f.mcv, f.ndv,
-                                       f.ranges)
+                                       f.ranges, f.samples)
         new_est = max(1, int(f.est_rows * sel))
         qb.fragments[i] = dataclasses.replace(
             f, plan=pp.Filter(f.plan, bound, est_rows=new_est),
@@ -1448,7 +1457,8 @@ def _tighter(old, new, is_lo: bool):
     return new if (new[1] > old[1]) == is_lo else old
 
 
-def _and_selectivity(preds, hist, mcv, ndv, ranges: dict | None = None):
+def _and_selectivity(preds, hist, mcv, ndv, ranges: dict | None = None,
+                     samples: dict | None = None):
     """Selectivity of a conjunction, given the range bounds already
     priced on the same rows: ``ranges`` maps colid -> (lo, hi, the
     selectivity those bounds were charged).  A further bound on a column
@@ -1460,7 +1470,7 @@ def _and_selectivity(preds, hist, mcv, ndv, ranges: dict | None = None):
     for p in preds:
         rb = _range_bound(p, hist)
         if rb is None:
-            sel *= _selectivity(p, hist, mcv, ndv)
+            sel *= _selectivity(p, hist, mcv, ndv, samples)
             continue
         col, op, v = rb
         lo, hi, charged = ranges.get(col, (None, None, 1.0))
@@ -1497,9 +1507,28 @@ def _mcv_selectivity(col: str, value, op: str, mcv: dict,
     return float(min(max(f, 0.0001), 1.0))
 
 
+def _like_selectivity(pred: ir.Like, samples: dict | None) -> float:
+    """The share of ANALYZE's row-weighted sample of the column that the
+    pattern matches, never under half a sampled row; 0.1 without one."""
+    sample = (samples or {}).get(pred.arg.name) \
+        if isinstance(pred.arg, ir.ColumnRef) else None
+    if not sample:
+        return 0.1
+    import re
+
+    from oceanbase_tpu.expr.compile import like_to_regex
+
+    rx = re.compile(like_to_regex(pred.pattern))
+    hit = sum(rx.match(v) is not None for v in sample)
+    if pred.negated:
+        hit = len(sample) - hit
+    return max(hit, 0.5) / len(sample)
+
+
 def _selectivity(pred: ir.Expr, hist: dict | None = None,
                  mcv: dict | None = None,
-                 ndv: dict | None = None) -> float:
+                 ndv: dict | None = None,
+                 samples: dict | None = None) -> float:
     if isinstance(pred, ir.Cmp):
         rb = _range_bound(pred, hist)
         if rb is not None:
@@ -1521,11 +1550,12 @@ def _selectivity(pred: ir.Expr, hist: dict | None = None,
                 return min(0.9, sum(per))
         return min(0.9, 0.1 * max(len(pred.values), 1))
     if isinstance(pred, ir.Like):
-        return 0.1
+        return _like_selectivity(pred, samples)
     if isinstance(pred, ir.Logic):
         if pred.op == "and":
-            return _and_selectivity(_conjuncts(pred), hist, mcv, ndv)[0]
-        return min(1.0, sum(_selectivity(a, hist, mcv, ndv)
+            return _and_selectivity(_conjuncts(pred), hist, mcv, ndv,
+                                    samples=samples)[0]
+        return min(1.0, sum(_selectivity(a, hist, mcv, ndv, samples)
                             for a in pred.args))
     return 0.5
 

@@ -179,8 +179,8 @@ def _join_out_est(lest: int, lndv: dict, rest: int, rndv: dict,
             ndvs.append(rndv[rk.name])
     lkey_cols = {k.name for k, _ in keys if isinstance(k, ir.ColumnRef)}
     rkey_cols = {k.name for _, k in keys if isinstance(k, ir.ColumnRef)}
-    unique_hit = bool(rkey_cols & set(runique)) or \
-        bool(lkey_cols & set(lunique))
+    r_hit = _holds_key(rkey_cols, runique)
+    unique_hit = r_hit or _holds_key(lkey_cols, lunique)
     if ndvs:
         out = max(1, lest * max(rest, 1) // max(ndvs))
     elif unique_hit:
@@ -190,10 +190,17 @@ def _join_out_est(lest: int, lndv: dict, rest: int, rndv: dict,
     if unique_hit:
         # each probe row matches at most one build row (and vice versa
         # on a both-unique join): cap at the smaller preserved side
-        bound = lest if rkey_cols & set(runique) else rest
+        bound = lest if r_hit else rest
         return max(1, min(out, bound))
     # keep headroom: non-unique estimates are approximate
     return max(out, lest // 2, rest // 2)
+
+
+def _holds_key(key_cols: set, unique) -> bool:
+    """Do a join's key columns on one side hold a key of that side: a
+    unique column, or every column of a composite primary key?"""
+    return any(u in key_cols if isinstance(u, str)
+               else set(u) <= key_cols for u in unique)
 
 
 def _edge_keys(edges, left_members, right_members):
@@ -787,8 +794,9 @@ def after_overflow(plan: pp.PlanNode, drops: list,
     """What a CapacityOverflow asks of the next attempt: -> (the plan to
     scale, the factor its budgets grow by).  A build side that repeated
     its declared key (lane ``join_build_dup`` of ``ops.join``) takes the
-    ``build_unique`` marks off; a PX program's total names no lane, so
-    an overflow without lanes takes them off as well.  Budgets grow when
+    ``build_unique`` marks off, as does an overflow that names no lane
+    (a PX program names its own since PR 42, its exchanges' apart: the
+    session raises an exchange's budget alone).  Budgets grow when
     a budget overflowed: by ``overflow_jump_factor`` with ``jump``, else
     by the ladder's 4."""
     dup = [d for d in drops if d[0] == "join_build_dup"]

@@ -10,6 +10,7 @@ re-plans with 4x budgets (the TPU analog of spill-on-overflow).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Optional
@@ -121,6 +122,10 @@ class Session:
         # LRU plan cache: most-recently-used last; byte-accounted against
         # plan_cache_mem_limit (≙ ObPlanCache memory-bounded eviction)
         self.plan_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
+        # logical plan hash -> {PX exchange overflow lane: the factor its
+        # budget was raised by}: a statement that overflowed an exchange
+        # starts its next execution at the budget that cleared it
+        self._px_budgets: dict[str, dict] = {}
         self._plan_cache_bytes: dict[tuple, int] = {}
         self._plan_cache_total = 0
         self._last_spill = None  # SpillStats of the last spilled query
@@ -1073,6 +1078,10 @@ class Session:
                 store = td.mcv if col.sdict is not None else td.histograms
                 if detail is None:
                     store.pop(c.name, None)
+                    td.samples.pop(c.name, None)
+                elif col.sdict is not None:
+                    store[c.name] = detail[:2]
+                    td.samples[c.name] = detail[2]
                 else:
                     store[c.name] = detail
         qmetrics.inc("storage.analyze_ns", int(sp.elapsed_s * 1e9))
@@ -1442,6 +1451,9 @@ class Session:
         self._last_access_paths = {}
         dop = self._px_dop()
         factor = 1
+        # PX exchange budgets this statement's earlier overflows raised
+        # ({overflow lane: factor}, kept by logical plan for the session)
+        px_budgets = dict(self._px_budgets.get(lhash, ()))
         from oceanbase_tpu.exec.plan import (
             compile_flag,
             reset_compile_flag,
@@ -1479,10 +1491,15 @@ class Session:
                             rel = None  # exchange surprise -> serial
                         self._last_dtl = rel is not None
                     if rel is None and dop > 1:
-                        rel = self._try_px(p, local_tables(), dop,
-                                           factor=factor,
-                                           monitor=monitor
-                                           if mon_collect else None)
+                        # a re-plan after an overflow has a span of its
+                        # own: a new shard program is compiled inside it
+                        with qtrace.span("px.replan", attempt=attempt,
+                                         raised=len(px_budgets)) \
+                                if attempt else contextlib.nullcontext():
+                            rel = self._try_px(
+                                p, local_tables(), dop, factor=factor,
+                                monitor=monitor if mon_collect else None,
+                                budgets=px_budgets)
                         self._last_px = rel is not None
                     if rel is None:
                         rel = execute_plan(p, local_tables(),
@@ -1507,12 +1524,26 @@ class Session:
                     # a clearing budget instead of riding the blind 4x
                     # ladder; a build side that repeated its key gives
                     # up its joins' marks, not its budgets
-                    from oceanbase_tpu.sql.optimizer import after_overflow
+                    from oceanbase_tpu.px.planner import EXCHANGE_LANE
+                    from oceanbase_tpu.sql.optimizer import (
+                        after_overflow,
+                        overflow_jump_factor,
+                    )
 
-                    plan, step = after_overflow(
-                        plan, getattr(ovf, "drops", None) or [],
-                        jump=feedback_on)
-                    factor *= step
+                    drops = getattr(ovf, "drops", None) or []
+                    if drops and all(d[0].startswith(EXCHANGE_LANE)
+                                     for d in drops):
+                        # only exchange budgets overflowed, and each says
+                        # which: those alone grow; the plan, its other
+                        # budgets and its build_unique marks stay
+                        for d in drops:
+                            px_budgets[d[0]] = px_budgets.get(d[0], 1) \
+                                * overflow_jump_factor([d])
+                        self._px_budgets[lhash] = px_budgets
+                    else:
+                        plan, step = after_overflow(plan, drops,
+                                                    jump=feedback_on)
+                        factor *= step
                     if monitor is not None:
                         monitor.clear()
             xsp.tags.update(attempts=attempt + 1, factor=factor,
@@ -1827,7 +1858,8 @@ class Session:
 
         return min(dop, len(jax.devices()))
 
-    def _try_px(self, plan, tables, dop, factor=1, monitor=None):
+    def _try_px(self, plan, tables, dop, factor=1, monitor=None,
+                budgets=None):
         """Attempt distributed execution; None -> fall back to single-node
         (unsupported plan shape, ≙ the optimizer declining a PX plan)."""
         from oceanbase_tpu.px.planner import (
@@ -1836,7 +1868,7 @@ class Session:
         )
 
         if self.tenant is not None:
-            if not self.tenant.px_admission.acquire(blocking=False):
+            if not self.tenant.px_admission.acquire(n=dop):
                 # admission denied: run serial (≙ px downgrade) — but
                 # VISIBLY: counted, span-tagged, shown by EXPLAIN
                 # ANALYZE (the silent downgrade was unobservable)
@@ -1844,14 +1876,22 @@ class Session:
                              tenant=getattr(self.tenant, "name", "sys"))
                 self._last_px_downgrade = True
                 return None
+        # estimates over ANALYZEd tables may bound a shard's budgets; a
+        # guess may not
+        analyzed = all(
+            td.histograms or td.mcv for td in (
+                self.catalog.table_def(t) for t in tables
+                if self.catalog.has_table(t)))
         try:
             rel = execute_plan_distributed(plan, tables, dop=dop,
-                                           budget_factor=factor)
+                                           budget_factor=factor,
+                                           exchange_budgets=budgets,
+                                           trust_estimates=analyzed)
         except (NotDistributable, NotImplementedError):
             return None
         finally:
             if self.tenant is not None:
-                self.tenant.px_admission.release()
+                self.tenant.px_admission.release(n=dop)
         if monitor is not None:
             from oceanbase_tpu.exec.plan import q_error as _qe
 
@@ -2294,8 +2334,8 @@ class Session:
             # surface the px_admission verdict the statement would get
             # RIGHT NOW: a denied probe means concurrent PX statements
             # hold the tenant quota and this plan runs serial
-            if self.tenant.px_admission.acquire(blocking=False):
-                self.tenant.px_admission.release()
+            if self.tenant.px_admission.acquire(n=self._px_dop()):
+                self.tenant.px_admission.release(n=self._px_dop())
             else:
                 text += ("\npx: admission denied "
                          f"(dop={self._px_dop()} downgraded to serial; "

@@ -33,6 +33,10 @@ import numpy as np
 
 HIST_BUCKETS = 64
 MCV_K = 16          # most-common-values kept per string column
+#: values kept of a string column's row-weighted sample: a pattern that
+#: 5 % of the rows match is then estimated to 3 % of itself (one sigma), so
+#: that the budgets derived from it do not change from one load to the next
+SAMPLE_K = 16384
 #: the code-count program is compiled per dictionary size rounded up to a
 #: power of two, never under this (small dictionaries share one program)
 _MIN_SLOTS = 1024
@@ -154,8 +158,9 @@ def code_counts(col, mask) -> np.ndarray:
 
 def column_stats(col, mask, n: int, n_valid: int):
     """One column's statistics -> (NDV, detail | None): for a dictionary
-    column ``(values, frequencies)`` of its ``MCV_K`` most frequent
-    strings, for any other ``(edges, null fraction)`` of an equi-height
+    column ``(values, frequencies, sample)``: its ``MCV_K`` most frequent
+    strings and the strings at ``SAMPLE_K`` evenly spaced ranks of its
+    rows; for any other ``(edges, null fraction)`` of an equi-height
     histogram when it has ``HIST_BUCKETS`` live values or more."""
     if col.sdict is not None:
         counts = code_counts(col, mask)
@@ -167,9 +172,15 @@ def column_stats(col, mask, n: int, n_valid: int):
         # this instead of the 0.1 guess
         order = np.argsort(counts)[::-1][:MCV_K]
         total = max(int(counts.sum()), 1)
+        # the rows in code order, read at evenly spaced ranks: a code is
+        # drawn in proportion to its rows (LIKE selectivity reads this)
+        ranks = (np.arange(SAMPLE_K) + 0.5) * total / SAMPLE_K
+        drawn = codes[np.searchsorted(np.cumsum(counts), ranks,
+                                      side="right").clip(0, len(codes) - 1)]
         return len(codes), (
             [str(col.sdict.values[int(codes[i])]) for i in order],
-            [float(counts[i]) / total for i in order])
+            [float(counts[i]) / total for i in order],
+            tuple(str(col.sdict.values[int(c)]) for c in drawn))
     if on_device(col):
         ndv, edges = numeric_stats(col, mask, n_valid)
     else:

@@ -256,7 +256,8 @@ def test_px_plan_over_declared_partitions_compiles_for_four_chips(
     db.close()
     exe, sharded = built[-1]
     program = exe.program
-    _mesh, axis, names = program.shard
+    _mesh, axis, names, whole = program.shard
+    assert whole == ()
     assert dict(program.args[-1].declared) == {
         "customer": ("c_custkey",), "lineitem": ("l_orderkey",),
         "orders": ("o_orderkey",)}
@@ -267,7 +268,7 @@ def test_px_plan_over_declared_partitions_compiles_for_four_chips(
     mesh = Mesh(np.array(topo.devices), (axis,))
     run = qplan._PlanExecutable(qplan.Program(
         program.body, program.args, "described", "described",
-        shard=(mesh, axis, names)))._run
+        shard=(mesh, axis, names, whole)))._run
     on_mesh = NamedSharding(mesh, P(axis))
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_mesh),
@@ -408,3 +409,157 @@ def test_sf10_plan_compiles_for_v5e_and_fits(qnum, sf10_session, one_chip,
         bucket = str(compacts[0].capacity)
         assert all(lanes == bucket for _t, lanes, _src in gathers)
         assert len(gathers) <= 7 + 2, gathers
+
+
+#: one partition's lanes at SF10 under ``tpch_sf10_part4``'s DDL
+SF10_PART4_LANES = {"lineitem": 16_777_216, "orders": 4_194_304,
+                    "partsupp": 2_097_152, "part": 524_288,
+                    "supplier": 32_768}
+#: what ANALYZE finds at SF10 where SF 0.01 x 1,000 would say otherwise
+SF10_NDV = {"l_orderkey": 15_000_000, "l_partkey": 2_000_000,
+            "l_suppkey": 100_000, "ps_partkey": 2_000_000,
+            "ps_suppkey": 100_000, "p_partkey": 2_000_000,
+            "p_name": 2_000_000, "s_suppkey": 100_000, "s_nationkey": 25,
+            "o_orderkey": 15_000_000, "o_custkey": 1_000_000,
+            "o_orderdate": 2406}
+
+
+class _Captured(Exception):
+    """Raised in place of a shard program's execution."""
+
+
+@pytest.fixture(scope="module")
+def sf10_part4(tmp_path_factory):
+    """The six tables of ``tpch_sf10_part4`` under its DDL at SF 0.01,
+    with statistics that say SF10 (as ``sf10_session``): the binder and
+    the PX planner size every budget as over the real tables."""
+    import json
+    import os
+
+    from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
+    from oceanbase_tpu.server import Database
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "tpch_sf10_part4.json")) as f:
+        cfg = json.load(f)
+    tables, types = gen_tpch(sf=0.01)
+    db = Database(str(tmp_path_factory.mktemp("sf10part4") / "db"))
+    s = db.session()
+    for sql in cfg["system_settings"]:
+        s.execute(sql)
+    names = [sql.split()[2] for sql in cfg["system_settings"]
+             if sql.startswith("create table ")]
+    for name in names:
+        s.catalog.load_numpy(
+            name, tables[name],
+            types={k: v for k, v in types.items() if k in tables[name]},
+            primary_key=TPCH_PRIMARY_KEYS[name])
+        s.execute(f"analyze table {name}")
+    for name in SF10_PART4_LANES:
+        td = s.catalog.table_def(name)
+        rows = td.row_count
+        for c, ndv in td.ndv.items():
+            td.ndv[c] = SF10_NDV.get(c, ndv * 1000 if ndv * 10 > rows
+                                     else ndv)
+        td.row_count = rows * 1000
+    lanes = s.catalog.scan_lanes
+    s.catalog.scan_lanes = lambda t: 4 * SF10_PART4_LANES[t] \
+        if t in SF10_PART4_LANES else lanes(t)
+    s.execute("set px_dop = 4")
+    statements = {}
+    for q in ("q9", "q14"):
+        with open(os.path.join(bench, "statements",
+                               f"tpch_{q}_sf10.json")) as f:
+            statements[q] = json.load(f)["sql"].replace(
+                "{COLOR}", "green").replace("{DATE}", "1995-09-01")
+    yield s, statements
+    db.close()
+
+
+@pytest.mark.parametrize("q", [
+    "q14",                                          # 40 s of compile here
+    pytest.param("q9", marks=pytest.mark.slow)])    # 260 s of compile here
+def test_sf10_part4_shard_program_compiles_for_four_chips_and_fits(
+        q, sf10_part4, topo, no_persistent_cache, monkeypatch):
+    """The shard program of a statement of ``tpch_sf10_part4.q9q14``,
+    planned over statistics that say SF10 and lowered at one SF10
+    partition's lanes a chip for the described 2x2 mesh: which joins move
+    rows, what the exchanges are budgeted at, and arguments + temporaries
+    + outputs on a chip by the compiler's own analysis.  Q9 is marked
+    slow: the TPU compiler takes over four minutes for it in the sandbox
+    (PERF.md section 6, PR 42, has its numbers)."""
+    import time
+
+    from oceanbase_tpu.exec import plan as qplan
+
+    s, statements = sf10_part4
+    built = []
+
+    def capture(self, sharded):
+        if self.program.shard is None:
+            return call(self, sharded)
+        built.append((self, sharded))
+        raise _Captured
+
+    call = qplan._PlanExecutable.call
+    from oceanbase_tpu.expr import compile as xcompile
+
+    monkeypatch.setattr(qplan._PlanExecutable, "call", capture)
+    # p_name's dictionary is 2,000 values here and 2M at SF10, where its
+    # LIKE table is an input of the program: make it one here too
+    monkeypatch.setattr(xcompile, "LUT_INPUT_MIN", 0)
+    with pytest.raises(_Captured):
+        s.execute(statements[q])
+    exe, sharded = built[-1]
+    luts = xcompile.dictionary_luts(exe.like_patterns, sharded)
+    program = exe.program
+    _mesh, axis, names, whole = program.shard
+    assert whole == (("nation",) if q == "q9" else ())
+    mesh = Mesh(np.array(topo.devices), (axis,))
+    described = qplan._PlanExecutable(qplan.Program(
+        program.body, program.args, "described", "described",
+        shard=(mesh, axis, names, whole)))
+    shapes = {}
+    for t, rel in sharded.items():
+        spec = NamedSharding(mesh, P() if t in whole else P(axis))
+        lanes = None if t in whole else 4 * SF10_PART4_LANES[t]
+        shapes[t] = jax.tree.map(
+            lambda x, lanes=lanes, spec=spec: jax.ShapeDtypeStruct(
+                ((lanes,) if lanes else x.shape[:1]) + x.shape[1:],
+                x.dtype, sharding=spec), rel)
+    shapes[xcompile.LUTS_TABLE] = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (2_097_152 if x.shape[0] >= 2048 else x.shape[0],), x.dtype,
+            sharding=NamedSharding(mesh, P())), luts)
+    t0 = time.monotonic()
+    compiled = described._run.lower(shapes).compile()
+    seconds = time.monotonic() - t0
+    notes = dict(described._noted)
+    budgets = dict(described.diag_names)
+    ma = compiled.memory_analysis()
+    print(f"{q} over SF10 partitions: lower+compile {seconds:.0f} s, "
+          f"arguments {ma.argument_size_in_bytes}, outputs "
+          f"{ma.output_size_in_bytes}, temporaries "
+          f"{ma.temp_size_in_bytes} bytes a chip; notes {notes}; "
+          f"budgets {budgets}")
+    _fits(compiled)
+    text = compiled.as_text()
+    assert " all-to-all(" in text
+    if q == "q14":
+        # part's partition is over the broadcast threshold at SF10: the
+        # month's lineitems move to it, compacted first, and the marked
+        # join emits on the lanes they arrive on
+        assert notes["join", "pkey"] == 1 and ("join", "broadcast") \
+            not in notes
+        assert notes["join_emit", "probe_lanes"] == 1
+        assert notes["lanes", "pkey"] <= 1 << 20
+        assert ma.temp_size_in_bytes < 1 << 30
+    else:
+        # filtered part, supplier x nation broadcast; the joined lineitems
+        # move to partsupp's partitions and on to orders': 2 of 5
+        assert notes["join", "pkey"] == 2
+        assert notes["join", "broadcast"] == 3
+        assert notes["lanes", "pkey"] <= 8 << 20
+        assert ma.temp_size_in_bytes < 4 << 30
+    assert any(n.startswith("px_exchange.pkey.") for n in budgets)
