@@ -119,9 +119,10 @@ def count_rows(kind: str, row_bytes: int, scalar) -> None:
 # ---------------------------------------------------------------------------
 # note lane: facts of the traced program that an operator picks from
 # static shapes and types (which way a probe ranks its keys, which way a
-# group-by reduces, whether a join's input was compacted to its estimate's
-# bucket, whether a join emits on its probe's lanes or expands, how a PX
-# join is distributed, an exchange buffer's lanes).
+# group-by reduces, and by scans or a scatter where it sorted, whether a
+# join's input was compacted to its estimate's bucket, whether a join
+# emits on its probe's lanes or expands, how a PX join is distributed, an
+# exchange buffer's lanes).
 # Nothing is traced: the notes are known when lowering ends, the
 # executable keeps their counts per input signature, and every execution
 # adds them to ``gv$sysstat`` (``book_notes``).  A new operator's counter
@@ -132,6 +133,9 @@ def count_rows(kind: str, row_bytes: int, scalar) -> None:
 NOTE_SERIES = {
     "probe": ("plan.join_probes", "kind"),        # merge | search
     "groupby": ("plan.groupby_reduces", "kind"),  # masked | sort
+    # (a sort-path group-by's reductions over its sorted lanes)
+    "groupby_reduce": ("plan.groupby_segment_reduces",
+                       "kind"),                   # scan | scatter
     "join_input": ("plan.join_inputs", "kind"),   # compacted | whole
     "join_emit": ("plan.join_emits", "kind"),   # probe_lanes | expanded
     "join": ("px.joins", "dist"),    # partition_wise|broadcast|pkey|hash

@@ -47,7 +47,8 @@ from oceanbase_tpu.expr import ir
 from oceanbase_tpu.expr.compile import cast_column, eval_expr, eval_predicate
 from oceanbase_tpu.share import keyhash
 from oceanbase_tpu.share.keyhash import mix64 as _mix64
-from oceanbase_tpu.vector.column import Column, Relation, StringDict
+from oceanbase_tpu.vector.column import (SCAN_ROW, Column, Relation,
+                                          StringDict, prefix_sum)
 
 # ---------------------------------------------------------------------------
 # basics
@@ -210,6 +211,7 @@ class AggSpec:
 
 
 _INT_MIN = np.iinfo(np.int64).min
+_INT32_MIN = np.iinfo(np.int32).min
 _INT_MAX = np.iinfo(np.int64).max
 
 
@@ -239,8 +241,9 @@ def _agg_result_type(fn: str, argt: SqlType | None) -> SqlType:
 
 
 def _scoped_segment_reduce(fn):
-    """The group-by's scatter reductions under one scope name, so a
-    profile reads "GroupBy#k/groupby.segment_reduce" (HLO metadata only)."""
+    """The group-by's reductions over its sorted lanes under one scope
+    name, so a profile reads "GroupBy#k/groupby.segment_reduce" (HLO
+    metadata only)."""
     @functools.wraps(fn)
     def scoped(*args, **kw):
         with jax.named_scope("groupby.segment_reduce"):
@@ -248,22 +251,106 @@ def _scoped_segment_reduce(fn):
     return scoped
 
 
-@_scoped_segment_reduce
-def _segment_agg(fn: str, data, weight, gid, num_segments, dtype):
-    """weight: bool lane = live & arg-valid (identity applied when False)."""
-    if fn in ("count", "count_star"):
-        return jax.ops.segment_sum(weight.astype(jnp.int64), gid,
-                                   num_segments=num_segments)
-    if fn in ("sum", "avg"):
-        d = jnp.where(weight, data, jnp.zeros((), dtype=data.dtype))
-        return jax.ops.segment_sum(d, gid, num_segments=num_segments)
-    if fn == "min":
-        d = jnp.where(weight, data, _agg_identity("min", data.dtype))
-        return jax.ops.segment_min(d, gid, num_segments=num_segments)
-    if fn == "max":
-        d = jnp.where(weight, data, _agg_identity("max", data.dtype))
-        return jax.ops.segment_max(d, gid, num_segments=num_segments)
-    raise ValueError(fn)
+_SCAN_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _segmented_scan(fn: str, d: jax.Array, head: jax.Array) -> jax.Array:
+    """The running ``fn`` (sum | min | max) of ``d`` that starts anew on
+    every lane where ``head`` is set: on the last lane of a run it is the
+    run's aggregate.  In two levels, as ``prefix_sum``: within rows of
+    ``SCAN_ROW`` lanes by doubling shifts (ten steps of elementwise
+    passes; a value and the flag "a head lies in what I cover"), then the
+    rows' last values by the same scan over the rows.  For what a
+    difference of prefixes cannot give: a minimum, a maximum, and a
+    floating-point sum (a difference of two long prefixes cancels)."""
+    n = d.shape[0]
+    if n == 0:
+        return d
+    op, ident = _SCAN_OPS[fn], _agg_identity(fn, d.dtype)
+    width = min(n, SCAN_ROW)
+    v = jnp.pad(d, (0, -n % width), constant_values=ident)
+    v = v.reshape(-1, width)
+    f = jnp.pad(head, (0, -n % width)).reshape(-1, width)
+    rows = v.shape[0]
+    k = 1
+    while k < width:
+        before = jnp.concatenate(
+            [jnp.full((rows, k), ident), v[:, :-k]], axis=1)
+        v = jnp.where(f, v, op(before, v))
+        f = f | jnp.concatenate(
+            [jnp.zeros((rows, k), jnp.bool_), f[:, :-k]], axis=1)
+        k *= 2
+    if rows > 1:
+        through = _segmented_scan(fn, v[:, -1], f[:, -1])
+        before = jnp.concatenate([ident[None], through[:-1]])
+        v = jnp.where(f, v, op(before[:, None], v))
+    return v.reshape(-1)[:n]
+
+
+def _since_previous(x: jax.Array, first) -> jax.Array:
+    """``x[j] - x[j - 1]``, ``x[0] - first`` on lane 0 (wrapping)."""
+    return x - jnp.concatenate([jnp.full((1,), first, x.dtype), x])[:-1]
+
+
+class _SortedGroups:
+    """The groups of lanes that lie in group order (live lanes first, a
+    group's lanes together; ``newgrp`` marks the lane that starts one),
+    and their reductions WITHOUT a scatter: the lane that ends each group
+    is brought to the front, in group order, by ONE sort of a single
+    int32 key (the lane number, made negative on a lane that ends a
+    group); a per-group value is then read at those ``cap`` lanes.  A
+    sum or a count is the difference of a prefix sum between one group's
+    end and the previous group's (wrapping integer arithmetic: bit-equal
+    to a scatter's sum whatever wraps on the way); a minimum, a maximum
+    and a floating-point sum are a segmented scan's value at the end.
+
+    Measured on a v5e (PR 43, PERF.md section 6): ``jax.ops.segment_*``
+    over ``gid`` ran 70-88 ns a lane and a reduction, this a few ns a
+    lane for the sort and the scans plus a gather of ``cap`` lanes a
+    32-bit word.  The prefixes do not ride the sort as operands: every
+    32-bit operand costs the TPU compiler 6-8 s more (a sort of the key
+    alone 3 s, with one int64 operand 18 s, at 524,288 lanes), an
+    aggregate more would be seconds of every first run more."""
+
+    def __init__(self, s_live: jax.Array, newgrp: jax.Array, cap: int):
+        n = s_live.shape[0]
+        self.newgrp = newgrp
+        self.n_groups = jnp.sum(newgrp, dtype=jnp.int64)
+        self.mask = jnp.arange(cap) < self.n_groups
+        # dead lanes lie last: a live lane ends its group when the next
+        # lane starts one or is dead
+        ends = s_live & jnp.concatenate(
+            [newgrp[1:] | ~s_live[1:], jnp.ones(1, jnp.bool_)])[:n]
+        lane = lax.iota(jnp.int32, n)
+        (key,) = lax.sort((jnp.where(ends, lane + _INT32_MIN, lane),),
+                          num_keys=1, is_stable=False)
+        # (lanes past the last group all read lane 0)
+        self.at = jnp.where(self.mask, key[:cap] - _INT32_MIN, 0)
+        diag.note("groupby_reduce", "scan")
+
+    def _of(self, x: jax.Array) -> jax.Array:
+        return jnp.where(self.mask, x, jnp.zeros((), x.dtype))
+
+    @functools.cached_property
+    def sizes(self) -> jax.Array:
+        """Lanes a group (all live: ``count(*)``)."""
+        return self._of(_since_previous(self.at, -1)).astype(jnp.int64)
+
+    @_scoped_segment_reduce
+    def total(self, d: jax.Array) -> jax.Array:
+        """The integer sum of ``d`` a group (0 on the lanes that do not
+        count, dead lanes included)."""
+        diag.note("groupby_reduce", "scan")
+        return self._of(_since_previous(
+            jnp.take(prefix_sum(d), self.at), 0))
+
+    @_scoped_segment_reduce
+    def scanned(self, fn: str, d: jax.Array) -> jax.Array:
+        """``fn`` (sum | min | max) of ``d`` a group (``fn``'s identity on
+        the lanes that do not count)."""
+        diag.note("groupby_reduce", "scan")
+        return self._of(jnp.take(_segmented_scan(fn, d, self.newgrp),
+                                 self.at))
 
 
 LOWCARD_GROUP_LIMIT = 4096
@@ -316,7 +403,9 @@ def hash_groupby(
     out_capacity: int | None = None,
     return_overflow: bool = False,
 ):
-    """Vectorized GROUP BY via sort + segment reduce.
+    """Vectorized GROUP BY via sort + segment reduce (the reductions over
+    the sorted lanes are scans read at each group's last lane, with no
+    scatter: ``_SortedGroups``).
 
     Fast path: when every group key is dictionary-encoded (or bool) and
     the code-space product is small, the group id IS the combined code —
@@ -379,70 +468,63 @@ def hash_groupby(
     if not key_cols:
         diff = jnp.concatenate([jnp.ones(1, jnp.bool_), jnp.zeros(n - 1, jnp.bool_)])
     newgrp = diff & s_live
-    gid_live = jnp.cumsum(newgrp.astype(jnp.int64)) - 1
-    n_groups = jnp.maximum(gid_live[-1] + 1, 0) if n > 0 else jnp.asarray(0)
-    gid = jnp.where(s_live, jnp.maximum(gid_live, 0), n - 1 if n > 0 else 0)
 
     cap = min(out_capacity, n) if out_capacity is not None else n
+    groups = _SortedGroups(s_live, newgrp, cap)
     # groups beyond capacity would vanish silently — surface it (diag when
     # lowered via execute_plan, explicit lane for shard_map callers)
-    gb_overflow = jnp.maximum(n_groups - cap, 0)
+    gb_overflow = jnp.maximum(groups.n_groups - cap, 0)
     diag.push("groupby_overflow", gb_overflow, capacity=cap)
 
-    # first sorted position of each group -> group key values
-    first_pos = jax.ops.segment_min(
-        jnp.where(s_live, jnp.arange(n), _INT_MAX), gid, num_segments=n
-    )[:cap]
-    first_pos_c = jnp.clip(first_pos, 0, n - 1)
-
+    # a group's key values: those of the lane that ends it
     out_cols: dict[str, Column] = {}
-    out_mask = jnp.arange(cap) < n_groups
+    out_mask = groups.mask
     for name, c in s_keys.items():
-        out_cols[name] = c.gather(first_pos_c)
+        out_cols[name] = c.gather(groups.at)
 
     # aggregate lanes (evaluated pre-sort then permuted)
     for spec in aggs:
         if spec.fn == "count_star":
-            res = _segment_agg("count_star", None, s_live, gid, n, None)[:cap]
-            out_cols[spec.name] = Column(res, None, SqlType.int_())
+            out_cols[spec.name] = Column(groups.sizes, None, SqlType.int_())
             continue
         assert spec.arg is not None
         ac = eval_expr(spec.arg, rel)
         if ac.dtype.kind == TypeKind.BOOL:
             ac = cast_column(ac, SqlType.int_())
-        s_data = jnp.take(ac.data, order)
-        s_valid = jnp.take(ac.valid, order) if ac.valid is not None else None
-        weight = s_live if s_valid is None else (s_live & s_valid)
         if spec.fn == "count_distinct":
-            res = _count_distinct(minor_to_major, order, s_data, s_valid,
-                                  s_live, key_cols, rel, spec, gid, n)[:cap]
+            diag.note("groupby_reduce", "scatter")
+            res = _count_distinct(minor_to_major, key_cols, rel, spec,
+                                  n)[:cap]
             out_cols[spec.name] = Column(res, None, SqlType.int_())
             continue
-        rt = _agg_result_type(spec.fn, ac.dtype)
-        if spec.fn == "avg":
-            ssum = _segment_agg("sum", s_data, weight, gid, n, None)[:cap]
-            scnt = _segment_agg("count", None, weight, gid, n, None)[:cap]
-            if ac.dtype.kind == TypeKind.DECIMAL:
-                num = ssum.astype(jnp.float64) / (10 ** ac.dtype.scale)
-            else:
-                num = ssum.astype(jnp.float64)
-            res = num / jnp.maximum(scnt, 1).astype(jnp.float64)
-            valid = scnt > 0
-            out_cols[spec.name] = Column(res, valid, SqlType.double())
+        s_data = jnp.take(ac.data, order)
+        s_valid = jnp.take(ac.valid, order) if ac.valid is not None else None
+        # the lanes that count, and how many a group has of them
+        if s_valid is None:
+            weight, cnt = s_live, groups.sizes
+        else:
+            weight = s_live & s_valid
+            cnt = groups.total(weight.astype(jnp.int32)).astype(jnp.int64)
+        if spec.fn == "count":
+            out_cols[spec.name] = Column(cnt, None, SqlType.int_())
             continue
-        res = _segment_agg(spec.fn, s_data, weight, gid, n, None)[:cap]
-        if spec.fn in ("min", "max"):
-            cnt = _segment_agg("count", None, weight, gid, n, None)[:cap]
-            valid = cnt > 0
-            out_cols[spec.name] = Column(res, valid,
-                                         _agg_result_type(spec.fn, ac.dtype),
-                                         sdict=ac.sdict)
-        elif spec.fn == "sum":
-            cnt = _segment_agg("count", None, weight, gid, n, None)[:cap]
-            valid = cnt > 0  # SUM over empty/all-null group is NULL
-            out_cols[spec.name] = Column(res, valid, rt)
-        else:  # count
-            out_cols[spec.name] = Column(res, None, rt)
+        fn = "sum" if spec.fn == "avg" else spec.fn
+        d = jnp.where(weight, s_data, _agg_identity(fn, s_data.dtype))
+        if fn == "sum" and not jnp.issubdtype(d.dtype, jnp.floating):
+            res = groups.total(d)
+        else:
+            res = groups.scanned(fn, d)
+        valid = cnt > 0  # SUM / MIN / MAX over an all-NULL group is NULL
+        if spec.fn == "avg":
+            num = res.astype(jnp.float64)
+            if ac.dtype.kind == TypeKind.DECIMAL:
+                num = num / (10 ** ac.dtype.scale)
+            res = num / jnp.maximum(cnt, 1).astype(jnp.float64)
+            out_cols[spec.name] = Column(res, valid, SqlType.double())
+        else:
+            out_cols[spec.name] = Column(
+                res, valid, _agg_result_type(spec.fn, ac.dtype),
+                sdict=ac.sdict if spec.fn in ("min", "max") else None)
 
     result = Relation(columns=out_cols, mask=out_mask)
     if return_overflow:
@@ -557,8 +639,7 @@ def _lowcard_groupby(rel, group_by, aggs, out_capacity, n, m):
     return Relation(columns=out_cols, mask=occupied)
 
 
-def _count_distinct(minor_to_major, order, s_data, s_valid, s_live,
-                    key_cols, rel, spec, gid, n):
+def _count_distinct(minor_to_major, key_cols, rel, spec, n):
     """COUNT(DISTINCT arg): re-sort by (group keys, arg) and count
     first-occurrence flags per group."""
     ac = eval_expr(spec.arg, rel)
@@ -917,7 +998,7 @@ def _running_max(x: jax.Array) -> jax.Array:
     v5e in the sandbox, PR 39; ``vector/column.py::_live_through`` found
     the same of a flat ``cumsum``)."""
     n = x.shape[0]
-    rows = jnp.pad(x, (0, -n % 1024)).reshape(-1, 1024)
+    rows = jnp.pad(x, (0, -n % SCAN_ROW)).reshape(-1, SCAN_ROW)
     within = lax.cummax(rows, axis=1)
     over_rows = lax.cummax(within[:, -1])
     before = jnp.concatenate([jnp.zeros(1, x.dtype), over_rows[:-1]])

@@ -51,6 +51,15 @@ qmetrics.declare("plan.groupby_reduces", "counter",
                  "(kind=masked: dictionary / bool keys, no sort, masked "
                  "streaming reductions; kind=sort: sort + segment "
                  "reduce; ops.hash_groupby picks at trace time)")
+qmetrics.declare("plan.groupby_segment_reduces", "counter",
+                 "reductions of the sort-path group-bys executed over "
+                 "their sorted lanes (the groups' own lanes and one an "
+                 "aggregate), by the way taken (kind=scan: prefix sums "
+                 "or a segmented scan read at the groups' end lanes; "
+                 "kind=scatter: jax.ops.segment_* over the group "
+                 "number, left to count_distinct; "
+                 "ops.hash_groupby picks from the aggregate's "
+                 "function and its argument's type at trace time)")
 qmetrics.declare("plan.join_inputs", "counter",
                  "inputs of the joins and index probes executed, by "
                  "the lanes they arrive on (kind=compacted: densified "

@@ -551,18 +551,27 @@ def _plane_rows(leaves) -> list:
     return where
 
 
-def _live_through(mask):
-    """Traced: for every lane, the live lanes up to and including it.  A
-    prefix sum in two levels, within rows of 1,024 lanes and over the
-    rows' totals: the TPU compiler takes 0.2 s over it at 524,288 lanes
-    where one flat ``cumsum`` costs it 7.7 s (compiled for a described
-    v5e in the sandbox, PR 34; 21 s of a warm-up on the chip)."""
-    n = mask.shape[0]
-    rows = jnp.pad(mask.astype(jnp.int32), (0, -n % 1024)).reshape(-1, 1024)
+#: lanes a row of a two-level scan (``prefix_sum``, ``exec/ops.py``'s)
+SCAN_ROW = 1024
+
+
+def prefix_sum(x):
+    """Traced: the inclusive prefix sum of ``x`` (wrapping, for an integer
+    dtype), in two levels, within rows of ``SCAN_ROW`` lanes and over the
+    rows' totals: the TPU compiler takes 0.2 s over it at 524,288 int32
+    lanes where one flat ``cumsum`` costs it 7.7 s (compiled for a
+    described v5e in the sandbox, PR 34; 21 s of a warm-up on the chip)."""
+    n = x.shape[0]
+    rows = jnp.pad(x, (0, -n % SCAN_ROW)).reshape(-1, SCAN_ROW)
     within = jnp.cumsum(rows, axis=1)
     totals = within[:, -1]
     before = jnp.cumsum(totals) - totals
     return (within + before[:, None]).reshape(-1)[:n]
+
+
+def _live_through(mask):
+    """Traced: for every lane, the live lanes up to and including it."""
+    return prefix_sum(mask.astype(jnp.int32))
 
 
 def _pack_body(bucket, tables):

@@ -101,7 +101,9 @@ def _case(name):
 
     r = np.random.default_rng(11)
     # (most inputs are one block of the masked reduce)
-    n = 2 * ops._MASKED_REDUCE_BLOCK if name == "two_blocks" else 3000
+    n = {"two_blocks": 2 * ops._MASKED_REDUCE_BLOCK,
+         # two rows of a two-level scan and 77 lanes of a third
+         "odd_lanes": 2 * 1024 + 77}.get(name, 3000)
     flag = r.choice(np.array(["A", "N", "R"]), n)
     status = r.choice(np.array(["F", "O"]), n)
     v = r.integers(-1000, 1000, n)
@@ -139,6 +141,17 @@ def _case(name):
         keys = ("f",)
         del cols["s"]
         valids = {"f": r.random(n) > 0.05}
+    elif name == "one_group":
+        cols["f"], cols["s"] = np.full(n, "A"), np.full(n, "F")
+        valids = {"v": r.random(n) > 0.1}
+    elif name == "every_lane_a_group":
+        cols["f"] = np.array([f"k{i:04d}" for i in r.permutation(n)])
+        keys = ("f",)
+        del cols["s"]
+        valids = {"v": r.random(n) > 0.1}
+    elif name == "odd_lanes":
+        valids = {"v": r.random(n) > 0.1, "f": r.random(n) > 0.1}
+        mask = r.random(n) > 0.2
     else:
         raise ValueError(name)
     rel = from_numpy(cols, valids=valids)
@@ -150,7 +163,8 @@ def _case(name):
 @pytest.mark.parametrize("case", [
     "nullable_keys", "nullable_arguments", "dead_lanes", "all_dead",
     "bool_keys", "int64_near_2_62", "float_values", "forty_codes",
-    "two_blocks", "zero_lanes"])
+    "two_blocks", "zero_lanes", "one_group", "every_lane_a_group",
+    "odd_lanes"])
 def test_masked_reduce_matches_sort_path(case):
     from oceanbase_tpu.exec import diag
 
@@ -161,7 +175,9 @@ def test_masked_reduce_matches_sort_path(case):
     assert notes == [("groupby", "masked", 1)]
     with diag.note_collect() as notes:
         slow = _groups(_run(rel, group_by, _ALL_AGGS, force_sort=True), keys)
-    assert notes == [("groupby", "sort", 1)]
+    # (and every reduction over the sorted lanes a scan)
+    assert notes[0] == ("groupby", "sort", 1)
+    assert set(notes[1:]) == {("groupby_reduce", "scan", 1)}
     assert fast.keys() == slow.keys()
     assert (len(fast) == 0) == (case in ("all_dead", "zero_lanes"))
     for key, want in slow.items():
@@ -173,6 +189,106 @@ def test_masked_reduce_matches_sort_path(case):
                 assert g == pytest.approx(w, rel=1e-9, abs=1e-9), (key, agg)
             else:
                 assert g == w and type(g) is type(w), (key, agg, g, w)
+
+
+def _by_scatter(rel, keys):
+    """``_groups``' answer for ``_ALL_AGGS`` by ``jax.ops.segment_*`` over
+    group numbers that NumPy assigns: what the sort path's reductions
+    were before they became scans."""
+    import jax
+    import jax.numpy as jnp
+
+    host = to_numpy(rel)
+    lanes = len(host["v"])
+    where = {k: host.get("__valid__" + k, np.ones(lanes, bool))
+             for k in (*keys, "v")}
+    tuples = [tuple(host[k][i] if where[k][i] else None for k in keys)
+              for i in range(lanes)]
+    numbers = {t: g for g, t in enumerate(dict.fromkeys(tuples))}
+    nseg = len(numbers)
+    if nseg == 0:
+        return {}
+    gid = jnp.asarray(np.array([numbers[t] for t in tuples], np.int32))
+    v, w = jnp.asarray(host["v"]), jnp.asarray(where["v"])
+    zero = jnp.zeros((), v.dtype)
+
+    def seg(fn, d):
+        return np.asarray(getattr(jax.ops, "segment_" + fn)(
+            d, gid, num_segments=nseg))
+
+    sm = seg("sum", jnp.where(w, v, zero))
+    ct = seg("sum", w.astype(jnp.int64))
+    n = seg("sum", jnp.ones(lanes, jnp.int64))
+    lo = seg("min", jnp.where(w, v, ops._agg_identity("min", v.dtype)))
+    hi = seg("max", jnp.where(w, v, ops._agg_identity("max", v.dtype)))
+    return {t: {"sm": sm[g] if ct[g] else None, "ct": ct[g], "n": n[g],
+                "av": float(sm[g]) / ct[g] if ct[g] else None,
+                "lo": lo[g] if ct[g] else None,
+                "hi": hi[g] if ct[g] else None}
+            for t, g in numbers.items()}
+
+
+@pytest.mark.parametrize("case", [
+    "nullable_keys", "nullable_arguments", "dead_lanes", "all_dead",
+    "zero_lanes", "int64_near_2_62", "float_values", "one_group",
+    "every_lane_a_group", "odd_lanes"])
+def test_sort_path_reduces_as_the_scatters_did(case, monkeypatch):
+    """The sort path's scans and boundary compaction against
+    ``jax.ops.segment_*``: integer sums and counts bit-equal (every
+    group's sum of ``int64_near_2_62`` wraps several times), float sums to
+    rounding, NULL where a group has no value, the groups dense at the
+    front and in key order."""
+    monkeypatch.setattr(ops, "LOWCARD_GROUP_LIMIT", 0)
+    rel, keys = _case(case)
+    out = hash_groupby(rel, {k: ir.col(k) for k in keys}, _ALL_AGGS)
+    got, want = _groups(to_numpy(out), keys), _by_scatter(rel, keys)
+    assert got.keys() == want.keys()
+    live = np.asarray(out.mask)
+    assert live[:len(want)].all() and not live[len(want):].any()
+    for key, w in want.items():
+        for agg, x in w.items():
+            g = got[key][agg]
+            if x is None:
+                assert g is None, (key, agg, g)
+            elif agg == "av" or case == "float_values":
+                assert g == pytest.approx(x, rel=1e-12, abs=1e-9), (key, agg)
+            else:
+                assert g == x and g.dtype == x.dtype, (key, agg, g, x)
+    # key order: codes ascending, a NULL key behind every value
+    codes = [tuple((np.inf if c is None else c) for c in
+                   (_code(out, k, i) for k in keys))
+             for i in range(len(want))]
+    assert codes == sorted(codes)
+    assert out.columns["sm"].data.dtype == rel.columns["v"].data.dtype
+
+
+def _code(out, name, i):
+    c = out.columns[name]
+    if c.valid is not None and not bool(c.valid[i]):
+        return None
+    return int(c.data[i])
+
+
+@pytest.mark.parametrize("cap", [1, 4, 5, 6, 64])
+def test_sort_path_reports_groups_over_its_capacity(cap, monkeypatch):
+    """``out_capacity`` below the group count (``nullable_keys`` makes 12
+    groups: 3 x 2 codes and their NULLs): the overflow lane counts the
+    groups that did not fit, and the first ``cap`` groups are right."""
+    monkeypatch.setattr(ops, "LOWCARD_GROUP_LIMIT", 0)
+    rel, keys = _case("nullable_keys")
+    group_by = {k: ir.col(k) for k in keys}
+    whole = hash_groupby(rel, group_by, _ALL_AGGS)
+    cut, over = hash_groupby(rel, group_by, _ALL_AGGS, out_capacity=cap,
+                             return_overflow=True)
+    groups = int(np.asarray(whole.mask).sum())
+    assert groups == 12
+    assert int(over) == max(groups - cap, 0)
+    assert cut.capacity == cap
+    kept = min(cap, groups)
+    assert int(np.asarray(cut.mask).sum()) == kept
+    a, b = to_numpy(whole), to_numpy(cut)
+    for name in a:
+        np.testing.assert_array_equal(a[name][:kept], b[name], err_msg=name)
 
 
 @pytest.mark.parametrize("lanes", [0, 3000, 2 * ops._MASKED_REDUCE_BLOCK])
@@ -212,7 +328,8 @@ def test_groupby_kind_follows_the_code_space(monkeypatch):
         rel = from_numpy({"k": k, "v": v})
         with diag.note_collect() as notes:
             got = _groups(_run(rel, {"k": ir.col("k")}, _ALL_AGGS), ("k",))
-        assert notes == [("groupby", want, 1)], (codes, notes)
+        assert [x for x in notes if x[0] == "groupby"] \
+            == [("groupby", want, 1)], (codes, notes)
         slow = _groups(_run(rel, {"k": ir.col("k")}, _ALL_AGGS,
                             force_sort=True), ("k",))
         assert got.keys() == slow.keys() and len(got) == codes

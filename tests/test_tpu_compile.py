@@ -298,6 +298,45 @@ def test_lowcard_reduce_compiles_for_v5e(fn, one_chip, no_persistent_cache):
     assert compiled.memory_analysis().temp_size_in_bytes <= lanes * 8
 
 
+def test_sort_path_groupby_compiles_for_v5e_without_a_scatter(
+        one_chip, no_persistent_cache):
+    """A Q3-shaped sort-path group-by (an int64 key, a DECIMAL sum and
+    ``count(*)``) for the described chip: its reductions over the sorted
+    lanes are prefix sums read at the groups' end lanes, so the program
+    holds no scatter, and every scan is in two levels (rows of 1,024
+    lanes, then the rows' totals): none runs over the flat lanes, which
+    costs the TPU compiler minutes at real lane counts (PR 34, PR 39).  A
+    small relation: the compiler's time for the sorts is what a case at
+    Q3's 524,288 lanes would add, and it says nothing more."""
+    from oceanbase_tpu.datatypes import SqlType
+    from oceanbase_tpu.exec import ops
+    from oceanbase_tpu.exec.ops import AggSpec
+    from oceanbase_tpu.expr import ir
+    from oceanbase_tpu.vector import Relation, from_numpy
+
+    lanes = 8192
+    rel = from_numpy({"k": np.zeros(8, np.int64),
+                      "rev": np.zeros(8, np.int64)},
+                     types={"rev": SqlType.decimal(15, 2)})
+    rel = Relation(rel.columns, jnp.ones(8, jnp.bool_))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((lanes,), x.dtype, sharding=one_chip),
+        rel)
+    lowered = jax.jit(lambda r: ops.hash_groupby(
+        r, {"k": ir.col("k")},
+        [AggSpec("revenue", "sum", ir.col("rev")),
+         AggSpec("n", "count_star")])).lower(shapes)
+    text = lowered.as_text()
+    assert "stablehlo.scatter" not in text
+    scans = re.findall(r"reduce_window.*?\) : \(tensor<([0-9x]+)x(i\d+)>",
+                       text, re.S)
+    assert (f"{lanes // 1024}x1024", "i64") in scans, scans
+    assert not any(shape == str(lanes) for shape, _dtype in scans), scans
+    compiled = lowered.compile()
+    _fits(compiled)
+    assert " scatter(" not in compiled.as_text()
+
+
 @pytest.mark.parametrize("spread", [False, True])
 def test_result_pack_compiles_for_v5e(spread, topo, one_chip,
                                       no_persistent_cache):
