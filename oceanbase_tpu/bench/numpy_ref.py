@@ -1,4 +1,4 @@
-"""Exact NumPy references for the two scan-aggregate TPC-H queries.
+"""Exact NumPy references for TPC-H queries (Q1, Q6, Q9, Q14; Q4, Q13, Q18).
 
 Independent of the engine: plain int64 arithmetic on the generated
 arrays (decimals are scaled integers, dates are day numbers), so the
@@ -104,3 +104,64 @@ def numpy_q14(tables: dict, d0: int, d1: int) -> tuple[int, int]:
         * (100 - li["l_discount"][sel].astype(np.int64))
     promo = np.char.startswith(part["p_type"].astype("U"), "PROMO")[at]
     return int(revenue[there & promo].sum()), int(revenue[there].sum())
+
+
+def numpy_q4(tables: dict, d0: int, d1: int) -> list:
+    """TPC-H Q4 -> [(o_orderpriority, order_count)] by priority: the
+    orders of ``[d0, d1)`` (day numbers) with at least one lineitem
+    received after its commit date; ``EXISTS`` as a flag by order key."""
+    orders, li = tables["orders"], tables["lineitem"]
+    okey = orders["o_orderkey"].astype(np.int64)
+    lkey = li["l_orderkey"].astype(np.int64)
+    has_late = np.zeros(int(max(okey.max(), lkey.max())) + 1, dtype=bool)
+    has_late[lkey[li["l_commitdate"] < li["l_receiptdate"]]] = True
+    keep = (orders["o_orderdate"] >= d0) & (orders["o_orderdate"] < d1) \
+        & has_late[okey]
+    names, counts = np.unique(orders["o_orderpriority"][keep].astype("U"),
+                              return_counts=True)
+    return [(str(n), int(c)) for n, c in zip(names, counts)]
+
+
+def numpy_q13(tables: dict, word1: str = "special",
+              word2: str = "requests") -> list:
+    """TPC-H Q13 -> [(c_count, custdist)] by custdist, c_count descending:
+    orders whose comment does not match ``%word1%word2%`` counted a
+    customer, over ALL customers (``c_count = 0`` is a group)."""
+    cust, orders = tables["customer"], tables["orders"]
+
+    def matches(s: str) -> bool:
+        i = s.find(word1)
+        return i >= 0 and s.find(word2, i + len(word1)) >= 0
+
+    counted = ~np.fromiter((matches(str(s)) for s in orders["o_comment"]),
+                           dtype=bool, count=len(orders["o_comment"]))
+    ckey = cust["c_custkey"].astype(np.int64)
+    ocust = orders["o_custkey"].astype(np.int64)
+    per_key = np.bincount(ocust[counted],
+                          minlength=int(max(ckey.max(), ocust.max())) + 1)
+    custdist = np.bincount(per_key[ckey])
+    return sorted(((int(c), int(n)) for c, n in enumerate(custdist) if n),
+                  key=lambda r: (-r[1], -r[0]))
+
+
+def numpy_q18(tables: dict, quantity: int = 300) -> list:
+    """TPC-H Q18 WITHOUT its limit -> [(c_name, c_custkey, o_orderkey,
+    o_orderdate as a day number, o_totalprice in cents, sum(l_quantity)
+    at scale 2)] by o_totalprice descending, o_orderdate, then the rest
+    of the row: the orders whose lineitems' quantities sum OVER
+    ``quantity``."""
+    cust, orders, li = (tables["customer"], tables["orders"],
+                        tables["lineitem"])
+    lkey = li["l_orderkey"].astype(np.int64)
+    okey = orders["o_orderkey"].astype(np.int64)
+    size = int(max(lkey.max(), okey.max())) + 1
+    total = np.zeros(size, dtype=np.int64)
+    np.add.at(total, lkey, li["l_quantity"].astype(np.int64))
+    sel = np.flatnonzero(total[okey] > quantity * 100)
+    at_c, in_c = _lookup(cust["c_custkey"].astype(np.int64),
+                         orders["o_custkey"][sel].astype(np.int64))
+    rows = [(str(cust["c_name"][c]), int(cust["c_custkey"][c]),
+             int(okey[o]), int(orders["o_orderdate"][o]),
+             int(orders["o_totalprice"][o]), int(total[okey[o]]))
+            for o, c in zip(sel[in_c].tolist(), at_c[in_c].tolist())]
+    return sorted(rows, key=lambda r: (-r[4], r[3]) + r)

@@ -93,9 +93,10 @@ def monitor_push(op_name: str, count_scalar, est: int | None = None) -> None:
 
 # ---------------------------------------------------------------------------
 # count lane: what an execution MEASURES beside its result and is booked
-# to ``gv$sysstat`` (the live rows a PX exchange received, by kind).  A
-# shard program sums the lanes over the mesh and hands them to the host
-# in the vector that carries its overflow lanes: no sync of their own.
+# to ``gv$sysstat`` (the live rows a PX exchange received, by kind; the
+# groups a sort-path group-by found).  The lanes leave the program in the
+# vector the host reads for the overflow check anyway (a shard program's
+# summed over the mesh): no sync of their own.
 # ---------------------------------------------------------------------------
 
 _counts: contextvars.ContextVar[list | None] = contextvars.ContextVar(
@@ -114,6 +115,25 @@ def count_rows(kind: str, row_bytes: int, scalar) -> None:
     entries = _counts.get()
     if entries is not None:
         entries.append((kind, row_bytes, scalar))
+
+
+#: the ``kind`` under which a sort-path group-by counts its live groups
+GROUPS = "groupby_groups"
+
+
+def book_counts(names, values) -> int:
+    """One execution's count lanes (``names``: (kind, row bytes) of each,
+    ``values`` the host's copy): add each to its series.  -> the rows the
+    exchanges among them received."""
+    moved = 0
+    for (kind, width), v in zip(names, values):
+        if kind == GROUPS:
+            qmetrics.inc("plan.groupby_groups", int(v))
+            continue
+        qmetrics.inc("px.exchange_rows", int(v), kind=kind)
+        qmetrics.inc("px.exchange_bytes", int(v) * width, kind=kind)
+        moved += int(v)
+    return moved
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +158,10 @@ NOTE_SERIES = {
                        "kind"),                   # scan | scatter
     "join_input": ("plan.join_inputs", "kind"),   # compacted | whole
     "join_emit": ("plan.join_emits", "kind"),   # probe_lanes | expanded
+    "join_kind": ("plan.join_kinds", "how"),  # inner|left|semi|anti|full
+    # (a sort-path group-by's lanes: n = what it sorts, what it emits on)
+    "groupby_sort_lanes": ("plan.groupby_sort_lanes", None),
+    "groupby_out_lanes": ("plan.groupby_out_lanes", None),
     "join": ("px.joins", "dist"),    # partition_wise|broadcast|pkey|hash
     "lanes": ("px.exchange_lanes", "kind"),  # n = lanes a shard
 }
@@ -171,4 +195,4 @@ def book_notes(noted) -> None:
         series, label = NOTE_SERIES[what]
         # a name out of the table above, each declare()d where its
         # operator lives  # obcheck: ok(metric.dynamic-name)
-        qmetrics.inc(series, n, **{label: value})
+        qmetrics.inc(series, n, **({label: value} if label else {}))

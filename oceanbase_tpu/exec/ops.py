@@ -429,6 +429,7 @@ def hash_groupby(
         return fast
 
     diag.note("groupby", "sort")
+    diag.note("groupby_sort_lanes", "", n)
     key_cols = {name: eval_expr(e, rel) for name, e in group_by.items()}
     # canonicalize NULL payloads so all NULLs of a key share one group
     # (GROUP BY treats NULLs as equal; the validity lane separates them
@@ -471,6 +472,8 @@ def hash_groupby(
 
     cap = min(out_capacity, n) if out_capacity is not None else n
     groups = _SortedGroups(s_live, newgrp, cap)
+    diag.note("groupby_out_lanes", "", cap)
+    diag.count_rows(diag.GROUPS, 0, groups.n_groups)
     # groups beyond capacity would vanish silently — surface it (diag when
     # lowered via execute_plan, explicit lane for shard_map callers)
     gb_overflow = jnp.maximum(groups.n_groups - cap, 0)
@@ -831,6 +834,7 @@ def join(
     how: str = "inner",
     out_capacity: int | None = None,
     build_unique: bool = False,
+    noted_as: str | None = None,
 ) -> Relation:
     """Sort-based equi-join; probe side = left, build side = right.
 
@@ -840,7 +844,11 @@ def join(
     ``build_unique``: the planner found the build side unique on the key
     (``HashJoin.build_unique``); an inner or left join on an exact key
     then emits on the probe's lanes (``_join_on_probe_lanes``).
+    ``noted_as``: the kind ``plan.join_kinds`` counts this join under
+    where it is not ``how`` (a semi-join with a residual expands as an
+    inner join).
     """
+    diag.note("join_kind", noted_as or how)
     ln, rn = left.capacity, right.capacity
     lm, rm = left.mask_or_true(), right.mask_or_true()
 
@@ -871,6 +879,14 @@ def join(
         return _join_on_probe_lanes(
             left, right, jnp.where(lvalid, lkey, BIG - 1), lvalid, rkey_s,
             how)
+    if exact and how in ("semi", "anti"):
+        # membership alone: is there a build row of the probe's key?  What
+        # a join on its probe's lanes asks, whatever repeats on the build
+        # side; NULL keys never match, so NOT EXISTS keeps them (NOT IN's
+        # null-poisoning is the planner's, layered on top)
+        matched = lvalid & (_match_rows(
+            rkey_s, jnp.where(lvalid, lkey, BIG - 1))[0] < rn)
+        return left.with_mask(lm & (matched if how == "semi" else ~matched))
     # the two expensive steps carry a scope of their own: HLO metadata
     # only (device ops read "HashJoin#k/join.probe" in a profile), no
     # cache key sees it
@@ -885,14 +901,8 @@ def join(
     # lo/hi ∈ [0, rn] so counts <= rn always — no clamp needed
     counts = jnp.where(lvalid, hi - lo, 0)
 
-    if exact and how == "semi":
-        return left.with_mask(lm & (counts > 0))
-    if exact and how == "anti":
-        # NOT EXISTS semantics: NULL keys never match, so they survive.
-        # (NOT IN adds null-poisoning on top; the planner layers that.)
-        return left.with_mask(lm & (counts == 0))
-    # inexact (hash-combined) semi/anti fall through: candidate counts
-    # include hash collisions, so matches must be verified by expansion
+    # inexact (hash-combined) semi/anti expand too: candidate counts
+    # include hash collisions, so matches must be verified
 
     keep_unmatched = how in ("left", "full")
     if keep_unmatched:
@@ -1047,6 +1057,21 @@ def _unique_match_by_search(rkey_s: jax.Array, lkey_p: jax.Array):
     return jnp.where(found, jnp.take(border, at), rn), dups
 
 
+def _match_rows(rkey_s: jax.Array, lkey_p: jax.Array):
+    """For every probe key a build row of that key (``>= rn``: none), and
+    how many build keys repeat another; by merge or by search from the
+    static shapes.  The merge sorts twice and the search once (the build
+    side): one sort more to compile, not ``_probe_ranges``' two, so it
+    pays from half the gathers (Q14 at SF1, 131,072 lanes into 262,144
+    keys: 2.5 ms merged, about 35 searched; my chip run, PR 39)."""
+    path = "merge" if _ranks_by_merge(
+        rkey_s.shape[0], lkey_p.shape[0], sorts=1) else "search"
+    diag.note("probe", path)
+    with jax.named_scope("join.probe"):
+        return (_unique_match_by_merge if path == "merge"
+                else _unique_match_by_search)(rkey_s, lkey_p)
+
+
 def _join_on_probe_lanes(left: Relation, right: Relation,
                          lkey_p: jax.Array, lvalid: jax.Array,
                          rkey_s: jax.Array, how: str) -> Relation:
@@ -1063,17 +1088,9 @@ def _join_on_probe_lanes(left: Relation, right: Relation,
     is checked here: build keys that repeat another are counted on the
     ``join_build_dup`` lane, and a count above zero makes the session
     re-plan with the mark off."""
-    rn, ln = right.capacity, left.capacity
-    # the merge sorts twice and the search once (the build side): one
-    # sort more to compile, not ``_probe_ranges``' two, so it pays from
-    # half the gathers (Q14 at SF1, 131,072 lanes into 262,144 keys: 2.5
-    # ms merged, about 35 searched; my chip run, PR 39)
-    path = "merge" if _ranks_by_merge(rn, ln, sorts=1) else "search"
-    diag.note("probe", path)
+    rn = right.capacity
+    row, dups = _match_rows(rkey_s, lkey_p)
     diag.note("join_emit", "probe_lanes")
-    with jax.named_scope("join.probe"):
-        row, dups = (_unique_match_by_merge if path == "merge"
-                     else _unique_match_by_search)(rkey_s, lkey_p)
     diag.push("join_build_dup", dups)
     matched = lvalid & (row < rn)
     build_idx = jnp.minimum(row, rn - 1)
@@ -1174,7 +1191,8 @@ def semi_join_residual(
     rid = Column(jnp.arange(ln, dtype=jnp.int64), None, SqlType.int_())
     left2 = Relation(columns={**left.columns, "__rid__": rid}, mask=left.mask)
     expanded = join(left2, right, left_keys, right_keys, how="inner",
-                    out_capacity=out_capacity)
+                    out_capacity=out_capacity,
+                    noted_as="anti" if anti else "semi")
     ok = expanded.mask_or_true()
     for pred in residual:
         from oceanbase_tpu.expr.compile import eval_predicate

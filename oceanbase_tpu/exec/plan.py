@@ -25,6 +25,7 @@ from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
 import jax
+import numpy as np
 
 from oceanbase_tpu.exec import diag, ops
 from oceanbase_tpu.exec.ops import AggSpec
@@ -75,6 +76,20 @@ qmetrics.declare("plan.join_emits", "counter",
                  "kind=expanded: prefix sum + repeat into out_capacity "
                  "lanes; a semi / anti join on an exact key only masks "
                  "its probe and books neither)")
+qmetrics.declare("plan.join_kinds", "counter",
+                 "joins executed, by kind (how=inner|left|semi|anti|full: "
+                 "one a join as ops.join lowers it, the exact-key semi / "
+                 "anti joins that only mask their probe included)")
+qmetrics.declare("plan.groupby_sort_lanes", "counter",
+                 "lanes the sort-path group-bys executed handed their "
+                 "sort (the input's static lanes, one note a group-by)")
+qmetrics.declare("plan.groupby_out_lanes", "counter",
+                 "static output lanes of the sort-path group-bys "
+                 "executed (min(out_capacity, input lanes) each)")
+qmetrics.declare("plan.groupby_groups", "counter",
+                 "live groups the sort-path group-bys executed found (a "
+                 "traced count read with the overflow total; over "
+                 "plan.groupby_out_lanes: how full their outputs are)")
 qmetrics.declare("plan.compiles", "counter",
                  "XLA trace+compile events (per plan x input signature)")
 qmetrics.declare("plan.capacity_retries", "counter",
@@ -991,7 +1006,7 @@ class _PlanExecutable:
         self.stats = _stats_for(program.stats_key)
         self.diag_names: list[str] = []     # filled at trace time
         self.monitor_names: list[str] = []
-        #: a shard program's count lanes: (kind, row bytes) of each
+        #: the program's count lanes: (kind, row bytes) of each
         self.count_names: list[tuple] = []
         self._noted: Counter = Counter()    # the last trace's notes
         last_noted = self._noted
@@ -1044,18 +1059,25 @@ class _PlanExecutable:
                 total = total + jnp.maximum(
                     jnp.asarray(v, dtype=jnp.int64), 0)
             lanes = [v for _, v, _ in entries]
+            count_names.clear()
+            count_names.extend((k, b) for k, b, _ in counted)
+            counts = [jnp.maximum(jnp.asarray(c, dtype=jnp.int64), 0)
+                      for _, _, c in counted]
             if shard is not None:
                 # one vector summed over the mesh: every overflow lane
                 # (a lane's detail on one shard would be that shard's),
                 # then every count lane; the host reads it once
-                count_names.clear()
-                count_names.extend((k, b) for k, b, _ in counted)
                 vec = [jnp.maximum(jnp.asarray(v, dtype=jnp.int64), 0)
-                       for v in lanes + [c for _, _, c in counted]]
+                       for v in lanes] + counts
                 lanes = jax.lax.psum(
                     jnp.stack(vec) if vec else jnp.zeros((0,), jnp.int64),
                     shard[1])
                 total = jnp.sum(lanes[:len(entries)])
+            elif counts:
+                # the one value the host reads at every execution, with
+                # the count lanes behind it: [overflow total, counts...]
+                # (a program that counts nothing keeps its scalar)
+                total = jnp.stack([total] + counts)
             return out, lanes, total, mon_vec
 
         if shard is not None:
@@ -1355,7 +1377,7 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
             # (to_numpy finds them there), the overflow total, a sampled
             # execution's counts
             prefetch(out)
-            if check_overflow and diag_vals:
+            if check_overflow and (diag_vals or bundle.count_names):
                 diag_total.copy_to_host_async()
             if with_monitor and monitor_collect:
                 mon_vals.copy_to_host_async()
@@ -1444,13 +1466,17 @@ def execute_plan(plan: PlanNode, tables: dict[str, Relation],
                     # plan total
                     op_rows[-1]["elapsed_s"] = plan_elapsed
                 monitor_out.extend(op_rows)
-        if check_overflow and diag_vals:
+        if check_overflow and (diag_vals or bundle.count_names):
             with qtrace.span("plan.overflow_check"):
                 # audited result-boundary sync: ONE host read decides
-                # validity; the per-lane detail below only materializes
-                # on the error path
-                total = int(diag_total)  # obcheck: ok(trace.host-sync)
-                if total > 0:
+                # validity and brings the count lanes; the per-lane
+                # detail below only materializes on the error path
+                head = np.asarray(  # obcheck: ok(trace.host-sync)
+                    diag_total).reshape(-1)
+                total = int(head[0])
+                if total == 0:
+                    diag.book_counts(bundle.count_names, head[1:])
+                else:
                     vals = [int(v) for v in diag_vals]  # obcheck: ok(trace.host-sync)
                     drops = [(n, cap, v)
                              for (n, cap), v in zip(diag_names, vals)

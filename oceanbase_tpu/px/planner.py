@@ -1111,12 +1111,8 @@ def _execute_distributed(plan, tables, mesh, axis, ndev, budget_factor,
     add_exec_times(host_s=psp.self_s, calls=1)
     diag.book_notes(noted)
     n_lanes = len(exe.diag_names)
-    moved = 0
-    for (kind, width), rows in zip(exe.count_names, lanes[n_lanes:]):
-        qmetrics.inc("px.exchange_rows", int(rows), kind=kind)
-        qmetrics.inc("px.exchange_bytes", int(rows) * width, kind=kind)
-        moved += int(rows)
-    psp.tags["exchange_rows"] = moved
+    psp.tags["exchange_rows"] = diag.book_counts(exe.count_names,
+                                                 lanes[n_lanes:])
     drops = [(name, cap, int(v))
              for (name, cap), v in zip(exe.diag_names, lanes[:n_lanes])
              if v > 0]

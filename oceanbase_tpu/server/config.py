@@ -126,11 +126,13 @@ DEF("shape_bucket_floor", 64, "int",
     "smallest capacity bucket (tables below it pad up to the floor); "
     "governs storage materialization — derived chunk/exchange budgets "
     "use the default ladder", _pos)
-DEF("query_timeout_s", 3600, "int",
+DEF("query_timeout_s", 3600, "float",
     "per-statement deadline seconds (settable per session via SET "
     "query_timeout_s); checked host-side at result-boundary "
     "checkpoints — operator close, spill chunk, DTL slice join, the "
-    "capacity-retry ladder — raising typed QueryTimeout", _pos)
+    "capacity-retry ladder — raising typed QueryTimeout.  Upstream's "
+    "name for the same deadline is ob_query_timeout, in microseconds "
+    "(ALIASES): either name sets and reads the one value", _pos)
 
 # overload robustness: statement admission + fair queuing
 # (server/admission.py)
@@ -385,6 +387,26 @@ DEF("workload_retention_max_age_s", 7 * 24 * 3600.0, "float",
     "snapshots older than this are pruned regardless of count", _pos)
 
 
+#: upstream's name -> (the parameter it IS here, how many of upstream's
+#: units make one of ours).  An alias is no parameter of its own: setting
+#: it sets the parameter, reading it reads the parameter, at both scopes
+#: (``Config`` and a session's variables, through ``canonical``).
+ALIASES = {"ob_query_timeout": ("query_timeout_s", 1_000_000)}
+
+
+def canonical(name: str, value):
+    """-> (the parameter ``name`` is, ``value`` in its units)."""
+    if name not in ALIASES:
+        return name, value
+    target, per_unit = ALIASES[name]
+    return target, float(value) / per_unit
+
+
+def aliased(name: str, value):
+    """``value`` of parameter ``name``'s target, in the alias's units."""
+    return round(float(value) * ALIASES[name][1])
+
+
 class Config:
     """One configuration instance (cluster-level or tenant overlay)."""
 
@@ -404,6 +426,8 @@ class Config:
 
     # ------------------------------------------------------------------
     def get(self, name: str):
+        if name in ALIASES:
+            return aliased(name, self.get(ALIASES[name][0]))
         if name not in _DEFS:
             raise KeyError(f"unknown parameter {name!r}")
         with self._lock:
@@ -419,6 +443,7 @@ class Config:
     def set(self, name: str, value):
         """Runtime update with type coercion + validation
         (≙ ALTER SYSTEM SET)."""
+        name, value = canonical(name, value)
         d = _DEFS.get(name)
         if d is None:
             raise KeyError(f"unknown parameter {name!r}")

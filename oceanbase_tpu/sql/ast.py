@@ -71,6 +71,8 @@ class TableRef:
 class SubqueryRef:
     select: "SelectStmt"
     alias: str
+    #: ``(select ...) as t (a, b)``: the derived table's column names
+    columns: Optional[list] = None
 
 
 @dataclass

@@ -310,12 +310,16 @@ class Binder:
         is_agg = bool(stmt.group_by or agg_calls)
         replace_fn = None
         if is_agg:
+            rows_in = est
             plan, items, having_bound, est, replace_fn = self._bind_aggregate(
                 stmt, qb, scope, plan, items, having_bound, agg_calls, est,
             )
             if having_bound is not None:
-                plan = pp.Filter(plan, having_bound)
-                est = max(1, est // 3)
+                sel = _having_selectivity(
+                    having_bound, plan.aggs, qb.fragments,
+                    rows_in / max(plan.est_rows, 1))
+                est = max(1, int(est * sel + 1e-9))
+                plan = pp.Filter(plan, having_bound, est_rows=est)
 
         # window functions: strip WindowCalls out of the items into a
         # Window operator (runs after WHERE/GROUP BY/HAVING, before
@@ -427,6 +431,14 @@ class Binder:
         elif isinstance(tref, ast.SubqueryRef):
             sub_plan, sub_outs, sub_est = self.bind_select(tref.select,
                                                            outer=None)
+            if tref.columns:
+                if len(tref.columns) != len(sub_outs):
+                    raise BindError(
+                        f"derived table {tref.alias} declares "
+                        f"{len(tref.columns)} columns but its body "
+                        f"produces {len(sub_outs)}")
+                sub_outs = [(cid, a) for (cid, _), a in
+                            zip(sub_outs, tref.columns)]
             cols = {}
             for cid, name in sub_outs:
                 scope.add(name, cid, alias=tref.alias)
@@ -584,23 +596,29 @@ class Binder:
             raise BindError(
                 "FULL OUTER JOIN supports equi-join ON conditions only")
         for p in rpreds:
-            rf = Fragment(pp.Filter(rf.plan, p,
-                                    est_rows=max(1, rf.est_rows // 3)),
-                          rf.cols,
-                          max(1, rf.est_rows // 3), rf.unique_cols,
-                          colids=rf.colids, ndv=rf.ndv,
-                          hist=rf.hist, mcv=rf.mcv)
+            # an ON predicate of the NULL-supplying side filters it under
+            # the join: priced as a WHERE predicate on it is (histograms,
+            # frequency lists, the string sample), not at a flat third
+            sel, ranges = _and_selectivity([p], rf.hist, rf.mcv, rf.ndv,
+                                           rf.ranges, rf.samples)
+            est = max(1, int(rf.est_rows * sel))
+            rf = dataclasses.replace(
+                rf, plan=pp.Filter(rf.plan, p, est_rows=est), est_rows=est,
+                ranges=ranges)
         lkeys = [e[0] for e in eqs]
         rkeys = [e[1] for e in eqs]
-        cap = _pow2(int((lf.est_rows + (rf.est_rows
-                                        if how == "full" else 0))
-                        * 1.5) + 16)
-        from oceanbase_tpu.sql.optimizer import unique_build
+        from oceanbase_tpu.sql.optimizer import _join_out_est, unique_build
 
+        # every preserved row comes out at least once, and a preserved
+        # row that matches comes out once a MATCH: the inner join's
+        # estimate where that is more (a customer has ten orders)
+        preserved = lf.est_rows + (rf.est_rows if how == "full" else 0)
+        out_est = max(preserved, _join_out_est(
+            lf.est_rows, lf.ndv, rf.est_rows, rf.ndv, lf.unique_cols,
+            rf.unique_cols, eqs))
+        cap = _pow2(int(out_est * 1.5) + 16)
         plan = pp.HashJoin(lf.plan, rf.plan, lkeys, rkeys, how=how,
-                           out_capacity=cap,
-                           est_rows=max(1, lf.est_rows + (
-                               rf.est_rows if how == "full" else 0)),
+                           out_capacity=cap, est_rows=max(1, out_est),
                            build_unique=how == "left" and unique_build(
                                lf.plan, rf.plan, rkeys, cap, self.catalog))
         for p in lpreds + residual:
@@ -612,7 +630,6 @@ class Binder:
         merged_cols = {**lf.cols, **rf.cols}
         # FULL emits unmatched build rows too, and NULL-extends the left
         # PKs on them (no longer unique downstream)
-        out_est = lf.est_rows + (rf.est_rows if how == "full" else 0)
         qb.fragments.append(Fragment(
             plan, merged_cols, out_est,
             frozenset() if how == "full" else lf.unique_cols,
@@ -892,13 +909,7 @@ class Binder:
         ndv_by_cid = {}
         for f in qb.fragments:
             ndv_by_cid.update(f.ndv)
-        n_keys_est = 1
-        for b in key_map.values():
-            if isinstance(b, ir.ColumnRef) and b.name in ndv_by_cid:
-                n_keys_est *= max(1, ndv_by_cid[b.name])
-            else:
-                n_keys_est *= 32
-            n_keys_est = min(n_keys_est, 1 << 40)  # overflow guard
+        n_keys_est, _known = _groups_estimate(key_map.values(), ndv_by_cid)
         out_cap = _pow2(min(est, max(64, min(n_keys_est, est))))
         if key_map:
             plan = pp.GroupBy(plan, key_map, agg_specs, out_capacity=out_cap,
@@ -1113,17 +1124,28 @@ class _CorrelationCollector:
             new_items = [(replace(bound), name) for bound, name in items]
             plan, est = self._seed_magic_set(
                 plan, est, eq_outer, eq_inner, qb, outer_qb, b)
+            rows_in = est
             if key_map:
-                cap = _pow2(max(64, min(est, 1 << 22)))
+                # the keys' distinct values where ANALYZE knows them all
+                # (a capacity under the groups re-plans: minutes of
+                # compile at SF10's lanes), else the rows, to 4M
+                ndv = {}
+                for f in qb.fragments:
+                    ndv.update(f.ndv)
+                groups, known = _groups_estimate(key_map.values(), ndv)
+                groups = min(est, groups if known else 1 << 22)
+                cap = _pow2(max(64, groups))
                 plan = pp.GroupBy(plan, key_map, agg_specs, out_capacity=cap,
-                                  est_rows=max(1, min(est, cap)))
-                est = min(est, cap)
+                                  est_rows=max(1, groups))
+                est = max(1, groups)
             else:
                 plan = pp.ScalarAgg(plan, agg_specs, est_rows=1)
                 est = 1
             if inner.having is not None:
                 hb = replace(b.bind_expr(inner.having, scope, allow_agg=True))
-                plan = pp.Filter(plan, hb)
+                est = max(1, int(est * _having_selectivity(
+                    hb, agg_specs, qb.fragments, rows_in / est) + 1e-9))
+                plan = pp.Filter(plan, hb, est_rows=est)
             # project the select outputs
             outs = []
             proj = {c: ir.col(c) for c in eq_inner_cids}
@@ -1505,6 +1527,93 @@ def _mcv_selectivity(col: str, value, op: str, mcv: dict,
     if op == "!=":
         f = 1.0 - f
     return float(min(max(f, 0.0001), 1.0))
+
+
+def _groups_estimate(keys, ndv: dict) -> tuple[int, bool]:
+    """-> (the groups a GROUP BY of ``keys`` can make: the product of the
+    keys' distinct values, a key ANALYZE does not know counted as 32;
+    whether every key was known)."""
+    n, known = 1, True
+    for b in keys:
+        if isinstance(b, ir.ColumnRef) and b.name in ndv:
+            n *= max(1, ndv[b.name])
+        else:
+            n *= 32
+            known = False
+        n = min(n, 1 << 40)  # overflow guard
+    return n, known
+
+
+def _column_moments(entry) -> tuple[float, float]:
+    """(mean, variance) of a column from its equi-height histogram: every
+    bucket holds the same share of the rows, spread evenly between its
+    two edges."""
+    import numpy as np
+
+    edges = np.asarray(entry[0], dtype=np.float64)
+    lo, hi = edges[:-1], edges[1:]
+    mean = float(np.mean((lo + hi) / 2))
+    square = float(np.mean((lo * lo + lo * hi + hi * hi) / 3))
+    return mean, max(square - mean * mean, 0.0)
+
+
+def _aggregate_tail(pred, specs: dict, hist: dict, per_group: float):
+    """The share of groups that pass ``sum(column) op literal`` or
+    ``count(*) op literal``, bounded by Cantelli's inequality: a group's
+    sum is ``N`` draws of the column (mean ``m``, variance ``v`` from
+    ANALYZE's histogram), ``N`` spread about ``per_group`` (the rows over
+    the groups) as a count is (variance = mean), so the sum has mean
+    ``per_group * m`` and variance ``per_group * (v + m * m)``, and no
+    more than ``var / (var + d * d)`` of the groups lie ``d`` beyond the
+    mean on one side.  None: not such a comparison, no histogram, or a
+    bound on the wrong side of the mean (nothing to say)."""
+    if not isinstance(pred, ir.Cmp) or pred.op not in _FLIP:
+        return None
+    l, r, op = pred.left, pred.right, pred.op
+    if isinstance(l, ir.Literal) and isinstance(r, ir.ColumnRef):
+        l, r, op = r, l, _FLIP[op]
+    if not (isinstance(l, ir.ColumnRef) and isinstance(r, ir.Literal)
+            and l.name in specs):
+        return None
+    spec = specs[l.name]
+    try:
+        from oceanbase_tpu.expr.compile import literal_value
+        from oceanbase_tpu.sql.session import _coerce_value
+
+        bound, t = literal_value(r)
+        if spec.fn in ("count", "count_star"):
+            mean, var, bound = per_group, per_group, float(bound)
+        elif spec.fn == "sum" and isinstance(spec.arg, ir.ColumnRef) \
+                and spec.arg.name in hist:
+            entry = hist[spec.arg.name]
+            m, v = _column_moments(entry)
+            mean, var = per_group * m, per_group * (v + m * m)
+            bound = float(_coerce_value(bound, t, entry[2]))
+        else:
+            return None
+    except Exception:  # noqa: BLE001 — a literal the column cannot take
+        return None
+    beyond = bound - mean if op in (">", ">=") else mean - bound
+    if beyond <= 0 or var <= 0:
+        return None
+    return var / (var + beyond * beyond)
+
+
+def _having_selectivity(pred, agg_specs, fragments, per_group: float):
+    """A HAVING clause's share of the groups: each conjunct that compares
+    a sum or a count with a literal by ``_aggregate_tail``, any other at
+    a third (as every HAVING was), never under one group in 10,000."""
+    if pred is None:
+        return 1.0
+    hist = {}
+    for f in fragments:
+        hist.update(f.hist)
+    specs = {a.name: a for a in agg_specs}
+    sel = 1.0
+    for p in _conjuncts(pred):
+        tail = _aggregate_tail(p, specs, hist, max(per_group, 1.0))
+        sel *= 1 / 3 if tail is None else tail
+    return max(sel, 1e-4)
 
 
 def _like_selectivity(pred: ir.Like, samples: dict | None) -> float:

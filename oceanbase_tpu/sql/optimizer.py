@@ -389,23 +389,41 @@ _COMPACT_LANE_SHARE = 8
 
 
 def compact_join_input(f, catalog, capacity_factor: float = 1.5):
+    """``compacted`` for a fragment of the join graph.  -> the fragment,
+    compacted or as it was."""
+    plan = compacted(f.plan, f.est_rows, catalog, capacity_factor)
+    return f if plan is f.plan else _clone_fragment(f, plan, f.est_rows)
+
+
+def _masks(plan) -> bool:
+    """Does ``plan`` end, under any projections, in an operator that
+    leaves dead lanes behind: a filter, or a semi / anti join (which only
+    mask their probe)?"""
+    while isinstance(plan, pp.Project):
+        plan = plan.child
+    return isinstance(plan, (pp.Filter, pp.SemiJoinResidual)) or (
+        isinstance(plan, pp.HashJoin) and plan.how in ("semi", "anti"))
+
+
+def compacted(plan, est_rows: int, catalog, capacity_factor: float = 1.5):
     """A join pays by the LANES of its inputs (the build side sorts them,
     the probe ranks and expands them), the cost model prices their
-    estimated ROWS.  Where a filter chain over a scan is estimated to
-    leave at most 1/8 of the scan's lanes, densify it to the estimate's
+    estimated ROWS.  Where an input ends in an operator that masks (a
+    filter chain over a scan; since PR 44 also a filter over a derived
+    table such as a group-by's HAVING, and a semi / anti join's output)
+    and is estimated to leave at most 1/8 of the lanes it arrives on
+    (``_static_lanes``; unknown = as it is), densify it to the estimate's
     bucket before the join, so that both agree.  ``strict``: a row that
     does not fit is reported on the ``compact_overflow`` lane and the
     session re-plans with scaled budgets, as for a join's out_capacity.
-    -> the fragment, compacted or as it was."""
-    chain = _frag_scan_chain(f.plan)
-    if chain is None or not isinstance(f.plan, pp.Filter):
-        return f
-    bucket = _bucket(f.est_rows, capacity_factor)
-    if bucket * _COMPACT_LANE_SHARE > catalog.scan_lanes(chain[0].table):
-        return f
-    return _clone_fragment(
-        f, pp.Compact(f.plan, capacity=bucket, strict=True,
-                      est_rows=f.est_rows), f.est_rows)
+    -> the plan, compacted or as it was."""
+    if not _masks(plan):
+        return plan
+    lanes = _static_lanes(plan, catalog)
+    bucket = _bucket(est_rows, capacity_factor)
+    if lanes is None or bucket * _COMPACT_LANE_SHARE > lanes:
+        return plan
+    return pp.Compact(plan, capacity=bucket, strict=True, est_rows=est_rows)
 
 
 def _base_column(scan: pp.TableScan, col: ir.ColumnRef) -> str:
@@ -417,10 +435,20 @@ def _base_column(scan: pp.TableScan, col: ir.ColumnRef) -> str:
 def _static_lanes(plan, catalog) -> int | None:
     """The lanes ``plan``'s output arrives on, where the plan alone says
     so: a ``Compact``'s capacity, a scan's ``catalog.scan_lanes`` (filters
-    only mask), a join's output lanes.  None: unknown."""
+    and semi / anti joins only mask, projections keep the lanes), a
+    join's output lanes, a group-by's (its capacity, or its input's lanes
+    if fewer; a group-by over dictionary codes emits fewer still).  None:
+    unknown."""
     node = plan
-    while isinstance(node, pp.Filter):
-        node = node.child
+    while True:
+        if isinstance(node, (pp.Filter, pp.Project)):
+            node = node.child
+        elif isinstance(node, pp.SemiJoinResidual) or (
+                isinstance(node, pp.HashJoin)
+                and node.how in ("semi", "anti")):
+            node = node.left
+        else:
+            break
     if isinstance(node, pp.Compact):
         return node.capacity
     if isinstance(node, pp.TableScan):
@@ -432,6 +460,10 @@ def _static_lanes(plan, catalog) -> int | None:
         if node.build_unique:
             return _static_lanes(node.left, catalog)
         return node.out_capacity
+    if isinstance(node, pp.GroupBy) and node.out_capacity is not None:
+        under = _static_lanes(node.child, catalog)
+        return node.out_capacity if under is None \
+            else min(node.out_capacity, under)
     return None
 
 
@@ -608,18 +640,27 @@ def _semi_key_ndv(e, ndv: dict, probe_est: int) -> int:
     return max(ndvs) if ndvs else max(probe_est, 1)
 
 
-def _attach_semi(plan, probe_est: int, e, key_ndv: int):
-    """Wrap ``plan`` with the semi/anti edge; -> (plan, est)."""
+def _attach_semi(plan, probe_est: int, e, key_ndv: int, catalog):
+    """Wrap ``plan`` with the semi/anti edge; -> (plan, est).  A
+    semi-join keeps half its probe, and no more probe rows than its build
+    side's rows can match (each build row's key meets ``probe_est /
+    key_ndv`` of them); its build side is compacted as any join input
+    is (``compacted``)."""
     exp = _semi_expansion(probe_est, e.build_est, key_ndv)
     cap = _pow2(int(min(exp, CAP_MAX) * 2) + 16)
-    est = max(1, probe_est // (3 if e.anti else 2))
+    if e.anti:
+        est = max(1, probe_est // 3)
+    else:
+        est = max(1, min(probe_est // 2, e.build_est * max(
+            probe_est // max(key_ndv, 1), 1)))
+    build = compacted(e.plan, e.build_est, catalog)
     if e.residual:
-        node = pp.SemiJoinResidual(plan, e.plan, list(e.lhs),
+        node = pp.SemiJoinResidual(plan, build, list(e.lhs),
                                    list(e.rkeys), list(e.residual),
                                    anti=e.anti, out_capacity=cap,
                                    est_rows=est)
     else:
-        node = pp.HashJoin(plan, e.plan, list(e.lhs), list(e.rkeys),
+        node = pp.HashJoin(plan, build, list(e.lhs), list(e.rkeys),
                            how="anti" if e.anti else "semi",
                            out_capacity=cap, est_rows=est)
     return node, est
@@ -666,7 +707,7 @@ def build_join_tree(qb, catalog, capacity_factor: float = 1.5,
         plan, est = f.plan, max(f.est_rows, 1)
         for e in semi_edges:
             key_ndv = _semi_key_ndv(e, f.ndv, est)
-            plan, est = _attach_semi(plan, est, e, key_ndv)
+            plan, est = _attach_semi(plan, est, e, key_ndv, catalog)
         qb.cbo_choice = {"pred_s": 0.0, "runner_up_s": 0.0,
                          "enumerated": 1, "method": "single",
                          "n_rels": 1, "index_probes": 0}
@@ -695,8 +736,11 @@ def build_join_tree(qb, catalog, capacity_factor: float = 1.5,
                 top_semis.append(e)
             else:
                 new_plan, new_est = _attach_semi(
-                    f.plan, max(f.est_rows, 1), e, key_ndv)
-                frags[e.home] = _clone_fragment(f, new_plan, new_est)
+                    f.plan, max(f.est_rows, 1), e, key_ndv, catalog)
+                # what the semi-join leaves is a join input as any other
+                frags[e.home] = compact_join_input(
+                    _clone_fragment(f, new_plan, new_est), catalog,
+                    capacity_factor)
 
     items = [_frag_item(i, f) for i, f in enumerate(frags)]
     method = "greedy"
@@ -720,7 +764,7 @@ def build_join_tree(qb, catalog, capacity_factor: float = 1.5,
 
     for e in top_semis:
         key_ndv = _semi_key_ndv(e, tree_ndv, est)
-        plan, est = _attach_semi(plan, est, e, key_ndv)
+        plan, est = _attach_semi(plan, est, e, key_ndv, catalog)
 
     saving = stats.get("probe_saving_s", 0.0)
     pred_s = max(best.cost_s - saving, 0.0)

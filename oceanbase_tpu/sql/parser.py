@@ -473,7 +473,13 @@ class Parser:
                 self.expect_op(")")
                 self.accept_kw("as")
                 alias = self.expect_ident()
-                return ast.SubqueryRef(sub, alias)
+                columns = None
+                if self.accept_op("("):     # t (a, b): names its columns
+                    columns = [self.expect_ident()]
+                    while self.accept_op(","):
+                        columns.append(self.expect_ident())
+                    self.expect_op(")")
+                return ast.SubqueryRef(sub, alias, columns)
             inner = self.parse_table_expr()
             self.expect_op(")")
             return inner

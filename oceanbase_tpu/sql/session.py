@@ -190,7 +190,9 @@ class Session:
         """Effective per-statement deadline: the session variable wins
         (SET query_timeout_s = 0.5 works sub-second), then the tenant's
         config overlay (SET GLOBAL writes there — reading db.config
-        directly would silently ignore it), else the cluster default."""
+        directly would silently ignore it), else the cluster default.
+        ``ob_query_timeout`` (upstream's name, microseconds) is the same
+        variable at both scopes (``server/config.py::ALIASES``)."""
         v = self.variables.get("query_timeout_s")
         if v is None:
             if self.tenant is not None:
@@ -237,8 +239,11 @@ class Session:
                 # statements (typed; the client reconnects)
                 admission.check_session(self.session_id)
             with qtrace.activate(tctx):
+                timeout_s = self._stmt_timeout_s()
                 with qtrace.span("statement", sql=sql[:200],
-                                 session=self.session_id):
+                                 session=self.session_id,
+                                 ob_query_timeout=round(
+                                     (timeout_s or 0) * 1_000_000)):
                     with qtrace.span("parse", bytes=len(sql)):
                         stmt = parse_sql(sql)
                     with qtrace.span("admission"):
@@ -253,8 +258,7 @@ class Session:
                             ctx = qadmission.StmtCtx(
                                 session_id=self.session_id,
                                 tenant=getattr(self.tenant, "name", "sys"),
-                                sql=sql,
-                                timeout_s=self._stmt_timeout_s(),
+                                sql=sql, timeout_s=timeout_s,
                                 controller=admission,
                                 ash_state=self._ash_state)
                             self._ash_state["state"] = "queued"
@@ -677,12 +681,19 @@ class Session:
                                       dtype=object)},
                     {}, {}, rowcount=len(rows))
             if stmt.what == "variables":
-                names = sorted(self.variables)
+                from oceanbase_tpu.server.config import ALIASES, aliased
+
+                shown = dict(self.variables)
+                # a variable set under either of its names shows under both
+                shown.update({a: aliased(a, shown[t])
+                              for a, (t, _) in ALIASES.items()
+                              if t in shown})
+                names = sorted(shown)
                 return Result(
                     ["variable_name", "value"],
                     {"variable_name": np.array(names, dtype=object),
-                     "value": np.array([str(self.variables[n])
-                                        for n in names], dtype=object)},
+                     "value": np.array([str(shown[n]) for n in names],
+                                       dtype=object)},
                     {}, {}, rowcount=len(names))
             cfg = (self.tenant.config if self.tenant is not None
                    else self.db.config if self.db else None)
@@ -729,7 +740,10 @@ class Session:
                 raise ValueError("no global config available")
             cfg.set(stmt.name, stmt.value)
         else:
-            self.variables[stmt.name] = stmt.value
+            from oceanbase_tpu.server.config import canonical
+
+            name, value = canonical(stmt.name, stmt.value)
+            self.variables[name] = value
         return _ok()
 
     def _alter_system(self, stmt: ast.AlterSystemStmt) -> Result:

@@ -177,7 +177,11 @@ def test_masked_reduce_matches_sort_path(case):
         slow = _groups(_run(rel, group_by, _ALL_AGGS, force_sort=True), keys)
     # (and every reduction over the sorted lanes a scan)
     assert notes[0] == ("groupby", "sort", 1)
-    assert set(notes[1:]) == {("groupby_reduce", "scan", 1)}
+    assert {n for n in notes if n[0] == "groupby_reduce"} == {
+        ("groupby_reduce", "scan", 1)}
+    # (and, since PR 44, the lanes it sorts and emits on)
+    assert {n[0] for n in notes[1:]} == {
+        "groupby_reduce", "groupby_sort_lanes", "groupby_out_lanes"}
     assert fast.keys() == slow.keys()
     assert (len(fast) == 0) == (case in ("all_dead", "zero_lanes"))
     for key, want in slow.items():

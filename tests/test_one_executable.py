@@ -179,6 +179,7 @@ def test_a_note_made_while_tracing_is_booked_on_every_execution(
     (what, "by_test", 3) once a table scan, the executable keeps the
     count, each execution adds it to the row's series."""
     series, label = diag.NOTE_SERIES[what]
+    labels = {label: "by_test"} if label else {}    # a series may have none
 
     def noting(lower):
         def wrapped(node, *rest):
@@ -192,10 +193,10 @@ def test_a_note_made_while_tracing_is_booked_on_every_execution(
     pair.execute(f"set px_dop = {4 if path == 'px' else 1}")
     want = None
     for _ in range(3):
-        before = qmetrics.counter_value(series, **{label: "by_test"})
+        before = qmetrics.counter_value(series, **labels)
         rows = pair.execute(Q_JOIN).rows()
         assert bool(pair._last_px) == (path == "px")
-        after = qmetrics.counter_value(series, **{label: "by_test"})
+        after = qmetrics.counter_value(series, **labels)
         assert after - before == 6, (series, before, after)  # two scans
         want = want or rows
         assert rows == want

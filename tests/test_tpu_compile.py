@@ -371,9 +371,12 @@ def test_result_pack_compiles_for_v5e(spread, topo, one_chip,
     assert set(compiled.output_shardings) == {"int64", "float64"}
 
 
-#: SF10's buckets (``benchmark/configs/tpch_sf10.json``): lineitem's 60M
-#: rows in 67,108,864 lanes, part's 2,000,000 in 2,097,152
-SF10_LANES = {"lineitem": 67_108_864, "part": 2_097_152}
+#: SF10's buckets (``benchmark/configs/tpch_sf10.json``,
+#: ``tpch_sf10_orders.json``): lineitem's 60M rows in 67,108,864 lanes,
+#: part's 2,000,000 in 2,097,152, orders' 15,000,000 in 16,777,216,
+#: customer's 1,500,000 in 2,097,152
+SF10_LANES = {"lineitem": 67_108_864, "part": 2_097_152,
+              "orders": 16_777_216, "customer": 2_097_152}
 #: the compiler's own limit for one v5e chip's programs
 V5E_PROGRAM_BYTES = int(15.75 * 2**30)
 
@@ -385,7 +388,7 @@ def sf10_session():
     distributions and stay), so the binder sizes every capacity as it
     does over the real tables, and nothing of that size exists here."""
     sess = _tpch_session(("lineitem", "part"), analyze=True)
-    for name in SF10_LANES:
+    for name in ("lineitem", "part"):
         td = sess.catalog.table_def(name)
         rows = td.row_count
         for c, ndv in td.ndv.items():
@@ -448,6 +451,131 @@ def test_sf10_plan_compiles_for_v5e_and_fits(qnum, sf10_session, one_chip,
         bucket = str(compacts[0].capacity)
         assert all(lanes == bucket for _t, lanes, _src in gathers)
         assert len(gathers) <= 7 + 2, gathers
+
+
+@pytest.fixture(scope="module")
+def sf10_orders_session():
+    """``tpch_sf10_orders``' three tables at SF 0.01 with statistics that
+    say SF10 (rows x 1,000; a key's distinct values as ANALYZE finds them
+    at SF10, ``SF10_NDV``; histograms and samples describe distributions
+    and stay), and the scans' lanes SF10's buckets."""
+    sess = _tpch_session(("lineitem", "orders", "customer"), analyze=True)
+    ndv = dict(SF10_NDV, c_custkey=1_500_000, c_name=1_500_000,
+               o_comment=150_000, o_totalprice=14_000_000)
+    for name in ("lineitem", "orders", "customer"):
+        td = sess.catalog.table_def(name)
+        rows = td.row_count
+        for c, n in td.ndv.items():
+            td.ndv[c] = ndv.get(c, n * 1000 if n * 10 > rows else n)
+        td.row_count = rows * 1000
+    lanes = sess.catalog.scan_lanes
+    sess.catalog.scan_lanes = lambda t: SF10_LANES.get(t) or lanes(t)
+    return sess
+
+
+def _orders_statement(q: str) -> str:
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "statements",
+        f"tpch_{q}_sf10.json")
+    with open(path) as f:
+        st = json.load(f)
+    sql = st["sql"]
+    for k, p in st["parameters"].items():
+        sql = sql.replace("{%s}" % k, str(p["validation"]))
+    return sql
+
+
+@pytest.mark.parametrize("q", [
+    "q4",                                           # 120 s of compile here
+    "q13",                                          # 100 s
+    pytest.param("q18", marks=pytest.mark.slow)])   # 480 s
+def test_sf10_orders_plan_compiles_for_v5e_and_fits(
+        q, sf10_orders_session, one_chip, no_persistent_cache, monkeypatch):
+    """The plan program of a statement of ``tpch_sf10_orders.q4q13q18``,
+    planned over statistics that say SF10 and lowered at SF10's lanes for
+    the described chip: arguments + temporaries + outputs under the
+    chip's 15.75 GiB, and under what is left of it beside 7.4 GB of
+    resident tables.  Q18 is marked slow: the TPU compiler takes minutes
+    for its fourteen sorts in the sandbox (PERF.md section 6, PR 44)."""
+    import time
+
+    from oceanbase_tpu.exec import plan as qplan
+    from oceanbase_tpu.expr import compile as xcompile
+    from oceanbase_tpu.sql.parser import parse_sql
+
+    sess = sf10_orders_session
+    # o_comment's dictionary is 500 values here and 150,000 at SF10, where
+    # its NOT LIKE table is an input of the program: make it one here too
+    monkeypatch.setattr(xcompile, "LUT_INPUT_MIN", 0)
+    plan, _outs, _est = sess._plan_select(parse_sql(_orders_statement(q)),
+                                          None)
+    key = plan.fingerprint()
+    bundle = qplan.executable_for(
+        qplan.Program(qplan._lower, (plan,), key, key), True)
+    mentioned, renames = bundle.scan_columns
+    shapes, loaded = {}, {}
+    for name in qplan.referenced_tables(plan):
+        rel = loaded[name] = qplan.narrowed(
+            sess.catalog.table_data(name), mentioned, renames.get(name))
+        lanes = SF10_LANES[name]
+        shapes[name] = jax.tree.map(
+            lambda x, lanes=lanes: jax.ShapeDtypeStruct(
+                (lanes,) + x.shape[1:], x.dtype, sharding=one_chip),
+            rel.pad_to(rel.capacity + 1))   # with the mask a load gives
+    if bundle.like_patterns:
+        shapes[xcompile.LUTS_TABLE] = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((262_144,), x.dtype,
+                                           sharding=one_chip),
+            xcompile.dictionary_luts(bundle.like_patterns, loaded))
+    t0 = time.monotonic()
+    compiled = bundle._run.lower(shapes).compile()
+    seconds = time.monotonic() - t0
+    ma = compiled.memory_analysis()
+    notes = dict(bundle._noted)
+    budgets = list(bundle.diag_names)
+    print(f"{q} at SF10 lanes: lower+compile {seconds:.0f} s, arguments "
+          f"{ma.argument_size_in_bytes}, outputs {ma.output_size_in_bytes}"
+          f", temporaries {ma.temp_size_in_bytes} bytes; notes {notes}; "
+          f"budgets {budgets}")
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) < V5E_PROGRAM_BYTES, ma
+    # beside lineitem, orders and customer resident (7.4 GB, of which the
+    # arguments are a part)
+    assert ma.temp_size_in_bytes + ma.output_size_in_bytes < 6 * 10**9, ma
+    compacts = [n for n in qplan._postorder(plan)
+                if isinstance(n, qplan.Compact)]
+    assert all(c.strict for c in compacts)
+    text = compiled.as_text()
+    if q == "q4":
+        # the quarter's orders compacted, then ONE merged match of
+        # lineitem's keys and theirs: no sort of the build side alone
+        assert [c.capacity for c in compacts] == [1 << 20]
+        assert notes["join_kind", "semi"] == 1 and notes["probe", "merge"] == 1
+        assert notes["groupby", "masked"] == 1
+        # (the compaction's, the match's two over 68,157,440 lanes, and the
+        # ORDER BY's over five rows)
+        assert len(re.findall(r" sort\(", text)) == 4
+        assert len(re.findall(r"\[68157440\]\S*\) sort\(", text)) == 2
+    elif q == "q13":
+        # sized by its matches: every order's lane, not every customer's
+        assert notes["join_kind", "left"] == 1
+        assert ("join_overflow", 1 << 25) in budgets
+        assert ("groupby_overflow", 1 << 21) in budgets
+        assert notes["groupby_sort_lanes", ""] == (1 << 25) + (1 << 21)
+    else:
+        # the subquery's group-by holds every order; what its HAVING and
+        # the semi-join leave is compacted to 2M lanes; customer joins on
+        # the probe's lanes; lineitem's join expands into 8M
+        assert ("groupby_overflow", 1 << 24) in budgets
+        assert [c.capacity for c in compacts] == [1 << 21, 1 << 21]
+        assert notes["join_kind", "semi"] == 1
+        assert notes["join_kind", "inner"] == 2
+        assert notes["join_emit", "probe_lanes"] == 1
+        assert ("join_overflow", 1 << 23) in budgets
+        assert notes["groupby_sort_lanes", ""] == (1 << 26) + (1 << 23)
 
 
 #: one partition's lanes at SF10 under ``tpch_sf10_part4``'s DDL
