@@ -156,6 +156,10 @@ NOTE_SERIES = {
     # (a sort-path group-by's reductions over its sorted lanes)
     "groupby_reduce": ("plan.groupby_segment_reduces",
                        "kind"),                   # scan | scatter
+    # (where a sort-path group-by's sorted lanes come from: its own
+    # sort's outputs and payloads, or gathers through its row numbers)
+    "groupby_sorted_read": ("plan.groupby_sorted_reads",
+                            "kind"),              # sort | gather
     "join_input": ("plan.join_inputs", "kind"),   # compacted | whole
     "join_emit": ("plan.join_emits", "kind"),   # probe_lanes | expanded
     "join_kind": ("plan.join_kinds", "how"),  # inner|left|semi|anti|full

@@ -131,21 +131,33 @@ def compact(rel: Relation, capacity: int | None = None,
 # ---------------------------------------------------------------------------
 
 
-def _sort_with_rows(keys: Sequence[jax.Array]) -> tuple[jax.Array, ...]:
-    """The keys sorted (last key primary, returned first) and, last, the
-    int32 row numbers in that order: an UNSTABLE sort that takes the row
-    number as its last key.  Ties break exactly as the stable sort
-    breaks them, so the answer is the same, and the TPU compiler takes
-    about half as long over it: a stable lexsort of three int64 keys at
-    1M rows compiled for a v5e in 448 s, this form in 243 s; a one-key
-    argsort in 106 s against 43 s (PR 22)."""
+def _sort_with_rows(keys: Sequence[jax.Array],
+                    payloads: Sequence[jax.Array] = ()
+                    ) -> tuple[jax.Array, ...]:
+    """The keys sorted (last key primary, returned first), then the int32
+    row numbers in that order, then each of ``payloads`` moved as the
+    rows moved: an UNSTABLE sort that takes the row number as its last
+    key.  Ties break exactly as the stable sort breaks them, so the
+    answer is the same, and the TPU compiler takes about half as long
+    over it: a stable lexsort of three int64 keys at 1M rows compiled
+    for a v5e in 448 s, this form in 243 s; a one-key argsort in 106 s
+    against 43 s (PR 22).
+
+    What a caller then reads in sorted order it takes from here and not
+    by a gather through the row numbers: the sorted keys cost nothing
+    (they are the sort's own outputs), a payload about 1-1.5 ns a lane
+    and 32-bit word, a gather 17-28 ns an element at every lane count
+    the records hold (PR 45, PERF.md section 6)."""
     iota = lax.iota(jnp.int32, keys[0].shape[0])
-    return lax.sort((*reversed(tuple(keys)), iota),
+    return lax.sort((*reversed(tuple(keys)), iota, *payloads),
                     num_keys=len(keys) + 1, is_stable=False)
 
 
 def _lexsort(keys: Sequence[jax.Array]) -> jax.Array:
-    """``jnp.lexsort``'s permutation (last key primary)."""
+    """``jnp.lexsort``'s permutation (last key primary): the row numbers
+    of ``_sort_with_rows`` alone, for a caller that reads whole relations
+    through them (``compact``, ``sort_rows``).  The sorted keys are
+    dropped here; a caller that wants them calls ``_sort_with_rows``."""
     return _sort_with_rows(keys)[-1].astype(jnp.int64)
 
 
@@ -307,10 +319,15 @@ class _SortedGroups:
     Measured on a v5e (PR 43, PERF.md section 6): ``jax.ops.segment_*``
     over ``gid`` ran 70-88 ns a lane and a reduction, this a few ns a
     lane for the sort and the scans plus a gather of ``cap`` lanes a
-    32-bit word.  The prefixes do not ride the sort as operands: every
+    32-bit word.  The prefixes do not ride THIS sort as operands: every
     32-bit operand costs the TPU compiler 6-8 s more (a sort of the key
     alone 3 s, with one int64 operand 18 s, at 524,288 lanes), an
-    aggregate more would be seconds of every first run more."""
+    aggregate more would be seconds of every first run more, for the
+    ``cap``-lane gathers it saves (31 ms of Q3's 484).  The sort BEFORE
+    this one, which puts the lanes in group order, is where operands
+    pay: it carries the keys anyway and each aggregate's argument as a
+    payload (``hash_groupby``), which spares gathers over all ``n``
+    lanes, not ``cap``."""
 
     def __init__(self, s_live: jax.Array, newgrp: jax.Array, cap: int):
         n = s_live.shape[0]
@@ -396,6 +413,41 @@ def _lowcard_reduce(fn: str, d: jax.Array, gid: jax.Array,
         return reduce(partial, axis=1)
 
 
+# An aggregate's argument rides the group-by's sort as a payload when the
+# gathers it spares (``n`` lanes x the argument's 32-bit operands) reach
+# the first constant AND the sort stays within the second.  On a TPU v5e
+# (PR 45, PERF.md section 6; the group-by alone, argument gathered |
+# riding): at 67,108,864 lanes and one int64 key 3.898 | 2.132 s an
+# execution for 22.7 s more of backend compile (6 operands); at
+# 33,554,432 lanes with a nullable argument 0.663 | 0.347 s for 22.1 s
+# (7); at 524,288 lanes and three keys 0.0692 | 0.0567 s for 35.6 s (8
+# operands: 28 s more of TPC-H Q3's 146 s program for 12 ms of its 462):
+# under 16.8M reads a program keeps its compile time (a read is 13-22 ns,
+# so 0.2-0.4 s an execution at the constant).  What a sort costs the
+# compiler grows faster than its operands: Q18's five-key group-by over
+# 8,388,608 lanes (10 operands) compiled for a described v5e in the
+# sandbox in 612 s, and in 2,118 s with the int64 payload as its 11th and
+# 12th, which alone takes the cell's first run past its limit.
+_RIDE_MIN_READS = 1 << 24
+_RIDE_MAX_SORT_OPERANDS = 8
+
+
+def _sort_operands(*arrays) -> int:
+    """32-bit operands the TPU sorts ``arrays`` as (a 64-bit array is two,
+    anything narrower one; ``None`` none)."""
+    return sum(max(a.dtype.itemsize // 4, 1) for a in arrays
+               if a is not None)
+
+
+def _rides_sort(n: int, sort_operands: int, words: int) -> bool:
+    """The shape rule: does an argument of ``words`` 32-bit operands (its
+    validity one of them) of a group-by over ``n`` lanes ride the sort
+    that already has ``sort_operands`` (or is it gathered through the
+    sort's row numbers)?"""
+    return (n * words >= _RIDE_MIN_READS
+            and sort_operands + words <= _RIDE_MAX_SORT_OPERANDS)
+
+
 def hash_groupby(
     rel: Relation,
     group_by: dict[str, ir.Expr],
@@ -406,6 +458,25 @@ def hash_groupby(
     """Vectorized GROUP BY via sort + segment reduce (the reductions over
     the sorted lanes are scans read at each group's last lane, with no
     scatter: ``_SortedGroups``).
+
+    Two sorts, and what each carries.  The FIRST (``_sort_with_rows``)
+    orders the lanes: its keys are the dead-lane flag, each group key
+    (with its validity) and the row number, and what the group-by reads
+    in sorted order are its outputs, not gathers through its row
+    numbers: the live flag and the keys ARE the sorted keys (no operand
+    more, no compile time more), and each distinct aggregate argument
+    (``sum(x)`` and ``avg(x)`` share one; an argument that is a group
+    key needs none) rides behind the row number as a payload operand
+    where ``_rides_sort`` finds the lanes many enough and the sort
+    narrow enough for what an operand costs the compiler, and is
+    gathered through the row numbers where not.
+    On a v5e a gather over ``n`` lanes costs 17-28 ns an element and
+    word, a sort operand 1-1.5 ns (PR 45, PERF.md section 6: Q18's
+    subquery group-by over 67,108,864 lanes read five words through the
+    permutation in 7.35 s beside a sort of 0.36 s).  The SECOND
+    (``_SortedGroups``) brings the groups' end lanes to the front and
+    stays key-only.  ``count(distinct)`` keeps its own re-sort and
+    gathers.
 
     Fast path: when every group key is dictionary-encoded (or bool) and
     the code-space product is small, the group id IS the combined code —
@@ -440,7 +511,22 @@ def hash_groupby(
                 jnp.where(c.valid, c.data, jnp.zeros((), c.data.dtype))
             )
 
-    # sort: dead rows last, then lexicographic group keys (nulls are a group)
+    # what the aggregates read in sorted order: one column a distinct
+    # argument (``sum(x)`` and ``avg(x)`` share one), none for an
+    # argument that is a group key
+    key_of = {ir.structural_key(e): name for name, e in group_by.items()}
+    arg_cols: dict = {}
+    for spec in aggs:
+        if spec.fn in ("count_star", "count_distinct"):
+            continue
+        assert spec.arg is not None
+        k = ir.structural_key(spec.arg)
+        if k not in key_of and k not in arg_cols:
+            arg_cols[k] = eval_expr(spec.arg, rel)
+
+    # sort: dead rows last, then lexicographic group keys (nulls are a
+    # group); an argument rides it as a payload behind the row number
+    # where the shape rule says so, and is gathered where not
     minor_to_major = []
     for name in reversed(list(key_cols)):
         c = key_cols[name]
@@ -449,10 +535,40 @@ def hash_groupby(
         if c.valid is not None:
             minor_to_major.append((~c.valid).astype(jnp.int8))
     minor_to_major.append((~m).astype(jnp.int8))
-    order = _lexsort(minor_to_major)
+    width = _sort_operands(*minor_to_major) + 1   # (and the row number)
+    riders, payloads = set(), []
+    for k, ac in arg_cols.items():
+        words = _sort_operands(ac.data, ac.valid)
+        if _rides_sort(n, width, words):
+            riders.add(k)
+            width += words
+            payloads.append(ac.data)
+            if ac.valid is not None:
+                payloads.append(ac.valid.astype(jnp.int8))
+    # (major key first, as the sort returns them)
+    moved = iter(_sort_with_rows(minor_to_major, payloads))
 
-    s_live = jnp.take(m, order)
-    s_keys = {name: c.gather(order) for name, c in key_cols.items()}
+    # the sorted lanes are the sort's own outputs: the same values a
+    # gather through its row numbers reads, lane for lane
+    s_live = next(moved) == 0
+    diag.note("groupby_sorted_read", "sort")
+    s_keys = {}
+    for name, c in key_cols.items():
+        s_valid = (next(moved) == 0) if c.valid is not None else None
+        s_keys[name] = c.with_data(next(moved).astype(c.data.dtype), s_valid)
+    order = next(moved)
+    s_args = {}
+    for k, ac in arg_cols.items():
+        if k in riders:
+            diag.note("groupby_sorted_read", "sort")
+            s_data = next(moved)
+            s_args[k] = ac.with_data(
+                s_data, (next(moved) != 0) if ac.valid is not None else None)
+        else:
+            diag.note("groupby_sorted_read", "gather")
+            s_args[k] = ac.gather(order)
+    for k, name in key_of.items():
+        s_args[k] = s_keys[name]
 
     # new-group boundary among live rows
     diff = jnp.zeros(n, dtype=jnp.bool_)
@@ -485,23 +601,22 @@ def hash_groupby(
     for name, c in s_keys.items():
         out_cols[name] = c.gather(groups.at)
 
-    # aggregate lanes (evaluated pre-sort then permuted)
+    # aggregate lanes (evaluated pre-sort, moved by the sort)
     for spec in aggs:
         if spec.fn == "count_star":
             out_cols[spec.name] = Column(groups.sizes, None, SqlType.int_())
             continue
-        assert spec.arg is not None
-        ac = eval_expr(spec.arg, rel)
-        if ac.dtype.kind == TypeKind.BOOL:
-            ac = cast_column(ac, SqlType.int_())
         if spec.fn == "count_distinct":
             diag.note("groupby_reduce", "scatter")
+            diag.note("groupby_sorted_read", "gather")
             res = _count_distinct(minor_to_major, key_cols, rel, spec,
                                   n)[:cap]
             out_cols[spec.name] = Column(res, None, SqlType.int_())
             continue
-        s_data = jnp.take(ac.data, order)
-        s_valid = jnp.take(ac.valid, order) if ac.valid is not None else None
+        ac = s_args[ir.structural_key(spec.arg)]
+        if ac.dtype.kind == TypeKind.BOOL:
+            ac = cast_column(ac, SqlType.int_())
+        s_data, s_valid = ac.data, ac.valid
         # the lanes that count, and how many a group has of them
         if s_valid is None:
             weight, cnt = s_live, groups.sizes

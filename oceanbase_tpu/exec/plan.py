@@ -61,6 +61,18 @@ qmetrics.declare("plan.groupby_segment_reduces", "counter",
                  "number, left to count_distinct; "
                  "ops.hash_groupby picks from the aggregate's "
                  "function and its argument's type at trace time)")
+qmetrics.declare("plan.groupby_sorted_reads", "counter",
+                 "reads of the sort-path group-bys executed of their "
+                 "lanes in sorted order (the live flag and the keys "
+                 "together one, and one an aggregate's distinct "
+                 "argument), by where the lanes come from (kind=sort: "
+                 "the group-by's own sort returns them, keys as its "
+                 "outputs, an argument as a payload operand; "
+                 "kind=gather: read through the sort's row numbers: an "
+                 "argument that ops._rides_sort keeps off the sort, "
+                 "too few lanes or too wide a sort for what an operand "
+                 "costs the compiler, and count_distinct, which sorts "
+                 "again)")
 qmetrics.declare("plan.join_inputs", "counter",
                  "inputs of the joins and index probes executed, by "
                  "the lanes they arrive on (kind=compacted: densified "

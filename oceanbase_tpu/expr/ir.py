@@ -272,3 +272,25 @@ def walk(e: Expr):
     yield e
     for c in e.children():
         yield from walk(c)
+
+
+def structural_key(e: Expr):
+    """Structural identity key of an expression tree (ir nodes use
+    identity equality): two trees with one key compute one value over one
+    relation.  Unknown node kinds key on object identity, so a match is
+    never a false positive."""
+    if isinstance(e, ColumnRef):
+        return ("col", e.name)
+    if isinstance(e, Literal):
+        return ("lit", repr(e.value), repr(e.dtype))
+    if isinstance(e, (Cmp, Arith)):
+        return (type(e).__name__, e.op, structural_key(e.left),
+                structural_key(e.right))
+    if isinstance(e, Logic):
+        return ("logic", e.op, tuple(structural_key(a) for a in e.args))
+    if isinstance(e, Not):
+        return ("not", structural_key(e.arg))
+    if isinstance(e, InList):
+        return ("in", e.negated, structural_key(e.arg),
+                tuple(structural_key(v) for v in e.values))
+    return ("id", id(e))

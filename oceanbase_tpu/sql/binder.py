@@ -1244,27 +1244,6 @@ def _conjuncts(e: ir.Expr):
         yield e
 
 
-def _expr_key(e):
-    """Structural identity key for unbound predicate trees (ir nodes use
-    identity equality).  Unknown node kinds key on object identity so
-    factoring never produces a false positive."""
-    if isinstance(e, ir.ColumnRef):
-        return ("col", e.name)
-    if isinstance(e, ir.Literal):
-        return ("lit", repr(e.value), repr(e.dtype))
-    if isinstance(e, (ir.Cmp, ir.Arith)):
-        return (type(e).__name__, e.op, _expr_key(e.left),
-                _expr_key(e.right))
-    if isinstance(e, ir.Logic):
-        return ("logic", e.op, tuple(_expr_key(a) for a in e.args))
-    if isinstance(e, ir.Not):
-        return ("not", _expr_key(e.arg))
-    if isinstance(e, ir.InList):
-        return ("in", e.negated, _expr_key(e.arg),
-                tuple(_expr_key(v) for v in e.values))
-    return ("id", id(e))
-
-
 def _and_of(conjs: list):
     return conjs[0] if len(conjs) == 1 else ir.Logic("and", conjs)
 
@@ -1286,19 +1265,19 @@ def factor_or_common(e):
     if e.op != "or" or len(args) < 2:
         return ir.Logic(e.op, args)
     branches = [list(_conjuncts(a)) for a in args]
-    keysets = [{_expr_key(c) for c in bs} for bs in branches]
+    keysets = [{ir.structural_key(c) for c in bs} for bs in branches]
     common_keys = set.intersection(*keysets)
     if not common_keys:
         return ir.Logic("or", args)
     common, seen = [], set()
     for c in branches[0]:
-        k = _expr_key(c)
+        k = ir.structural_key(c)
         if k in common_keys and k not in seen:
             seen.add(k)
             common.append(c)
     rests = []
     for bs in branches:
-        rest = [c for c in bs if _expr_key(c) not in common_keys]
+        rest = [c for c in bs if ir.structural_key(c) not in common_keys]
         if not rest:
             # a branch reduced to exactly the common part:
             # (A) or (A and X) == A

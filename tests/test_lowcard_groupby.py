@@ -179,9 +179,14 @@ def test_masked_reduce_matches_sort_path(case):
     assert notes[0] == ("groupby", "sort", 1)
     assert {n for n in notes if n[0] == "groupby_reduce"} == {
         ("groupby_reduce", "scan", 1)}
-    # (and, since PR 44, the lanes it sorts and emits on)
+    # (and, since PR 44, the lanes it sorts and emits on; since PR 45
+    # where its sorted lanes come from: the keys' out of the sort, and
+    # ``v``'s, once, gathered: the shape rule at so few lanes)
     assert {n[0] for n in notes[1:]} == {
-        "groupby_reduce", "groupby_sort_lanes", "groupby_out_lanes"}
+        "groupby_reduce", "groupby_sort_lanes", "groupby_out_lanes",
+        "groupby_sorted_read"}
+    assert [n[1] for n in notes if n[0] == "groupby_sorted_read"] == [
+        "sort", "gather"]
     assert fast.keys() == slow.keys()
     assert (len(fast) == 0) == (case in ("all_dead", "zero_lanes"))
     for key, want in slow.items():
@@ -235,7 +240,7 @@ def _by_scatter(rel, keys):
 @pytest.mark.parametrize("case", [
     "nullable_keys", "nullable_arguments", "dead_lanes", "all_dead",
     "zero_lanes", "int64_near_2_62", "float_values", "one_group",
-    "every_lane_a_group", "odd_lanes"])
+    "every_lane_a_group", "odd_lanes", "bool_keys", "forty_codes"])
 def test_sort_path_reduces_as_the_scatters_did(case, monkeypatch):
     """The sort path's scans and boundary compaction against
     ``jax.ops.segment_*``: integer sums and counts bit-equal (every
@@ -264,6 +269,161 @@ def test_sort_path_reduces_as_the_scatters_did(case, monkeypatch):
              for i in range(len(want))]
     assert codes == sorted(codes)
     assert out.columns["sm"].data.dtype == rel.columns["v"].data.dtype
+    # a key leaves the sort as it went in: type, width and dictionary
+    for k in keys:
+        assert out.columns[k].dtype == rel.columns[k].dtype
+        assert out.columns[k].data.dtype == rel.columns[k].data.dtype
+        assert out.columns[k].sdict is rel.columns[k].sdict
+
+
+def _sort_then_gather(keys, payloads=()):
+    """``_sort_with_rows`` as the group-by used it before PR 45: a sort of
+    the keys and the row numbers alone, and every other lane (the sorted
+    keys too) read through the row numbers."""
+    import jax.numpy as jnp
+
+    rows = _SORT_WITH_ROWS(keys)[-1]
+    return (*(jnp.take(k, rows) for k in reversed(tuple(keys))), rows,
+            *(jnp.take(p, rows) for p in payloads))
+
+
+_SORT_WITH_ROWS = ops._sort_with_rows
+
+#: name -> (aggregates, the ``groupby_sorted_read`` notes besides the
+#: keys' own, the columns that ride the sort as payloads)
+_READS = {
+    "all_aggs": (_ALL_AGGS, ["sort"], ["v"]),
+    # aggregates over one argument share one payload, spelled twice or not
+    "shared_argument": ([AggSpec("sm", "sum", ir.col("v") * 2),
+                         AggSpec("av", "avg", ir.col("v") * 2),
+                         AggSpec("lo", "min", ir.col("v"))],
+                        ["sort", "sort"], ["v", "v"]),
+    # an argument that is a group key is read from the sorted key
+    "argument_is_a_key": ([AggSpec("sm", "sum", ir.col("f")),
+                           AggSpec("hi", "max", ir.col("f")),
+                           AggSpec("ct", "count", ir.col("f")),
+                           AggSpec("n", "count_star")], [], []),
+    "count_star_alone": ([AggSpec("n", "count_star")], [], []),
+    # count(distinct) sorts again and gathers, as before
+    "count_distinct": ([AggSpec("d", "count_distinct", ir.col("v")),
+                        AggSpec("sm", "sum", ir.col("v"))],
+                       ["sort", "gather"], ["v"]),
+}
+
+
+@pytest.mark.parametrize("reads", list(_READS))
+@pytest.mark.parametrize("case", [
+    "nullable_keys", "nullable_arguments", "dead_lanes", "all_dead",
+    "zero_lanes", "int64_near_2_62", "float_values", "bool_keys",
+    "forty_codes"])
+def test_sort_path_reads_its_sorted_lanes_from_its_sort(case, reads,
+                                                        monkeypatch):
+    """The keys, the live flag and the arguments as the group-by's own
+    sort returns them (the shape rule lifted: at these few lanes an
+    argument would be gathered) against the same lanes gathered through
+    the sort's row numbers (the parent's reads): every output bit-equal,
+    DOUBLE sums (``float_values``) included, since ties still break by row
+    number and a group's values are added in one order; the dictionary of
+    a string key survives; one payload a distinct argument, none for a
+    key."""
+    import jax
+    from oceanbase_tpu.exec import diag
+
+    monkeypatch.setattr(ops, "LOWCARD_GROUP_LIMIT", 0)
+    monkeypatch.setattr(ops, "_RIDE_MIN_READS", 0)
+    monkeypatch.setattr(ops, "_RIDE_MAX_SORT_OPERANDS", 64)
+    rel, keys = _case(case)
+    aggs, noted, ridden = _READS[reads]
+    group_by = {k: ir.col(k) for k in keys}
+
+    def run(r):
+        return hash_groupby(r, group_by, aggs)
+
+    with diag.note_collect() as notes:
+        jaxpr = jax.make_jaxpr(run)(rel)
+    assert [n[1] for n in notes if n[0] == "groupby_sorted_read"] == [
+        "sort", *noted]
+    # the group-by's own sort: dead flag, keys with their validity, row
+    # number, and behind them one payload a distinct argument (with its
+    # validity); count(distinct)'s re-sort takes its argument as a key
+    nullable = {c: rel.columns[c].valid is not None for c in ("v", *keys)}
+    width = 2 + sum(1 + nullable[k] for k in keys)
+    sorts = sorted(len(e.invars) for e in jaxpr.eqns
+                   if e.primitive.name == "sort")
+    assert width + sum(1 + nullable[c] for c in ridden) in sorts
+    assert sorts[-1] == max(
+        width + sum(1 + nullable[c] for c in ridden),
+        (width + 1) * (reads == "count_distinct"))
+
+    new = run(rel)
+    monkeypatch.setattr(ops, "_sort_with_rows", _sort_then_gather)
+    old = run(rel)
+    assert new.columns.keys() == old.columns.keys()
+    np.testing.assert_array_equal(np.asarray(new.mask), np.asarray(old.mask))
+    for name, a in new.columns.items():
+        b = old.columns[name]
+        assert a.dtype == b.dtype and a.data.dtype == b.data.dtype, name
+        assert a.sdict is b.sdict, name
+        assert (a.valid is None) == (b.valid is None), name
+        # bit for bit: a float sum's every bit too
+        assert np.asarray(a.data).tobytes() == np.asarray(b.data).tobytes(), \
+            name
+        if a.valid is not None:
+            np.testing.assert_array_equal(np.asarray(a.valid),
+                                          np.asarray(b.valid), err_msg=name)
+
+
+@pytest.mark.parametrize("lanes, keys, arg, nullable, rides", [
+    # Q18's subquery: l_orderkey, sum(l_quantity) over lineitem's lanes
+    (67_108_864, ["int64"], "int64", False, True),
+    # Q13's first: c_custkey, count(o_orderkey) behind the outer join
+    (33_554_432, ["int64"], "int64", True, True),
+    # a 16.8M-lane group-by of an int32 argument: at the constant
+    (16_777_216, ["int64"], "int32", False, True),
+    (16_777_216 - 1, ["int64"], "int32", False, False),
+    # Q9's local group-by a shard, Q3's: too few lanes for the compiler's
+    # seconds
+    (1_966_080, ["int32", "int32"], "int64", False, False),
+    (524_288, ["int64", "int32", "int32"], "int64", False, False),
+    # Q18's last: five keys are ten operands before the argument's two
+    (8_388_608, ["int32", "int64", "int64", "int32", "int64"], "int64",
+     False, False),
+    (67_108_864, ["int32", "int64", "int64", "int32", "int64"], "int64",
+     False, False),
+    # a bool key is sorted as an int64: six operands, and eight or nine
+    # with the argument
+    (67_108_864, ["bool", "int64"], "int64", False, True),
+    (67_108_864, ["bool", "int64"], "int64", True, False),
+])
+def test_the_shape_rule_of_a_riding_argument(lanes, keys, arg, nullable,
+                                             rides, monkeypatch):
+    """``_rides_sort`` at the cells' shapes (no array is made: the notes
+    of an abstract trace say which way each argument went)."""
+    import jax
+    import jax.numpy as jnp
+    from oceanbase_tpu.datatypes import SqlType
+    from oceanbase_tpu.exec import diag
+    from oceanbase_tpu.vector import Column, Relation
+
+    monkeypatch.setattr(ops, "LOWCARD_GROUP_LIMIT", 0)
+
+    def lane(dtype):
+        return jax.ShapeDtypeStruct((lanes,), jnp.dtype(dtype))
+
+    types = {"bool": SqlType.bool_(), "int32": SqlType.int_(),
+             "int64": SqlType.int_()}
+    cols = {f"k{i}": Column(lane(t), None, types[t])
+            for i, t in enumerate(keys)}
+    cols["v"] = Column(lane(arg), lane("bool") if nullable else None,
+                       types[arg])
+    rel = Relation(cols, lane("bool"))
+    with diag.note_collect() as notes:
+        jax.eval_shape(lambda r: hash_groupby(
+            r, {k: ir.col(k) for k in cols if k != "v"},
+            [AggSpec("s", "sum", ir.col("v")),
+             AggSpec("a", "avg", ir.col("v"))], out_capacity=1024), rel)
+    assert [n[1] for n in notes if n[0] == "groupby_sorted_read"] == [
+        "sort", "sort" if rides else "gather"]
 
 
 def _code(out, name, i):

@@ -337,6 +337,64 @@ def test_sort_path_groupby_compiles_for_v5e_without_a_scatter(
     assert " scatter(" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("rides", [True, False])
+def test_sort_path_groupby_reads_no_lane_through_its_permutation(
+        rides, one_chip, no_persistent_cache, monkeypatch):
+    """A sort-path group-by (a nullable int64 key, a DECIMAL sum and an
+    average of the same nullable argument, ``count(*)``; 1,024 groups'
+    lanes out of 8,192) for the described chip: the live flag and the key
+    in sorted order are outputs of the group-by's own sort (dead flag, key
+    validity, key, row number), so no gather is indexed by the sort's row
+    numbers over all the lanes for them, before or after the compiler.
+    The argument rides the same sort where the shape rule says so (lifted
+    here: behind the row number, the argument and its validity, once), and
+    every gather left reads at the groups' 1,024 end lanes; at the rule's
+    own word for so few lanes the argument's two arrays are the only ones
+    read through the row numbers."""
+    from oceanbase_tpu.datatypes import SqlType
+    from oceanbase_tpu.exec import ops
+    from oceanbase_tpu.exec.ops import AggSpec
+    from oceanbase_tpu.expr import ir
+    from oceanbase_tpu.vector import Relation, from_numpy
+
+    if rides:
+        monkeypatch.setattr(ops, "_RIDE_MIN_READS", 0)
+    lanes, cap = 8192, 1024
+    rel = from_numpy({"k": np.zeros(8, np.int64),
+                      "rev": np.zeros(8, np.int64)},
+                     types={"rev": SqlType.decimal(15, 2)},
+                     valids={"k": np.ones(8, bool), "rev": np.ones(8, bool)})
+    rel = Relation(rel.columns, jnp.ones(8, jnp.bool_))
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((lanes,), x.dtype, sharding=one_chip),
+        rel)
+    lowered = jax.jit(lambda r: ops.hash_groupby(
+        r, {"k": ir.col("k")},
+        [AggSpec("revenue", "sum", ir.col("rev")),
+         AggSpec("mean", "avg", ir.col("rev")),
+         AggSpec("n", "count_star")], out_capacity=cap)).lower(shapes)
+    text = lowered.as_text()
+    sorts = re.findall(r'"stablehlo.sort"\(([^)]*)\)', text)
+    assert sorted(len(s.split(",")) for s in sorts) == [
+        1, 6 if rides else 4], sorts
+    gathers = re.findall(
+        r"stablehlo.gather.*?: \(tensor<(\d+)xi(\d+)>, tensor<(\d+)x1xi32>\)",
+        text)
+    through = sorted(bits for src, bits, idx in gathers
+                     if idx == str(lanes))
+    assert through == ([] if rides else ["1", "64"]), gathers
+    assert any(idx == str(cap) for _src, _bits, idx in gathers)
+    compiled = lowered.compile()
+    _fits(compiled)
+    hlo = compiled.as_text()
+    assert " scatter(" not in hlo
+    # (the compiled module spells a gather's result, one element an index:
+    # the argument's two halves and its validity, or nothing)
+    reads = re.findall(r"= \w+\[(\d+)\]\S* gather\(", hlo)
+    assert reads.count(str(lanes)) == (0 if rides else 3), reads
+    assert set(reads) <= {str(cap), str(lanes)}
+
+
 @pytest.mark.parametrize("spread", [False, True])
 def test_result_pack_compiles_for_v5e(spread, topo, one_chip,
                                       no_persistent_cache):
