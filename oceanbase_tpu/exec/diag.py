@@ -141,8 +141,9 @@ def book_counts(names, values) -> int:
 # static shapes and types (which way a probe ranks its keys, which way a
 # group-by reduces, and by scans or a scatter where it sorted, whether a
 # join's input was compacted to its estimate's bucket, whether a join
-# emits on its probe's lanes or expands, how a PX join is distributed, an
-# exchange buffer's lanes).
+# emits on its probe's lanes or expands, whether a group-by over an outer
+# join's NULL-supplying side runs below the join or above it, how a PX
+# join is distributed, an exchange buffer's lanes).
 # Nothing is traced: the notes are known when lowering ends, the
 # executable keeps their counts per input signature, and every execution
 # adds them to ``gv$sysstat`` (``book_notes``).  A new operator's counter
@@ -166,6 +167,10 @@ NOTE_SERIES = {
     # (a sort-path group-by's lanes: n = what it sorts, what it emits on)
     "groupby_sort_lanes": ("plan.groupby_sort_lanes", None),
     "groupby_out_lanes": ("plan.groupby_out_lanes", None),
+    # (a group-by over an outer join's NULL-supplying side: where the
+    # planner put it)
+    "groupby_placement": ("plan.groupby_placements",
+                          "at"),               # below_join | above_join
     "join": ("px.joins", "dist"),    # partition_wise|broadcast|pkey|hash
     "lanes": ("px.exchange_lanes", "kind"),  # n = lanes a shard
 }

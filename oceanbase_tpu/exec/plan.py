@@ -102,6 +102,17 @@ qmetrics.declare("plan.groupby_groups", "counter",
                  "live groups the sort-path group-bys executed found (a "
                  "traced count read with the overflow total; over "
                  "plan.groupby_out_lanes: how full their outputs are)")
+qmetrics.declare("plan.groupby_placements", "counter",
+                 "group-bys executed that aggregate over an outer join's "
+                 "NULL-supplying side, by where the planner put the "
+                 "aggregation (at=below_join: under the join, over that "
+                 "side's own lanes, GroupBy.below_join, its partials "
+                 "combined above the join by a group-by that books "
+                 "nothing; at=above_join: a GroupBy lowered directly "
+                 "over a left HashJoin, through Projects, over the "
+                 "lanes the join expands into; "
+                 "sql/binder.py::_groupby_below_join decides at bind "
+                 "time)")
 qmetrics.declare("plan.compiles", "counter",
                  "XLA trace+compile events (per plan x input signature)")
 qmetrics.declare("plan.capacity_retries", "counter",
@@ -242,6 +253,19 @@ def _est_field():
     return field(default=None, repr=False, compare=False)
 
 
+def _marked_repr(node, mark: str) -> str:
+    """``node``'s dataclass rendering with ``mark=True`` appended where the
+    node carries the mark: an unmarked node renders as it did before the
+    mark existed, so every plan that does not take the new path keeps its
+    fingerprint (and with it gv$plan_cache's plan_hash and the AOT cache's
+    key)."""
+    parts = [f"{f.name}={getattr(node, f.name)!r}"
+             for f in dataclasses.fields(node) if f.repr]
+    if getattr(node, mark):
+        parts.append(f"{mark}=True")
+    return f"{type(node).__qualname__}({', '.join(parts)})"
+
+
 @dataclass(repr=True)
 class TableScan(PlanNode):
     table: str
@@ -277,9 +301,17 @@ class GroupBy(PlanNode):
     aggs: list  # list[AggSpec]
     out_capacity: Optional[int] = None
     est_rows: Optional[int] = _est_field()
+    # the aggregation of an outer join's NULL-supplying side, planned
+    # under the join by its join key (eager aggregation; sql/binder.py::
+    # _groupby_below_join decides at bind time): unique on its one key
+    # by construction
+    below_join: bool = field(default=False, repr=False)
 
     def children(self):
         return (self.child,)
+
+    def __repr__(self):
+        return _marked_repr(self, "below_join")
 
 
 @dataclass(repr=True)
@@ -312,14 +344,7 @@ class HashJoin(PlanNode):
         return (self.left, self.right)
 
     def __repr__(self):
-        # an unmarked join renders as it did before the mark existed, so
-        # every plan that does not take the new path keeps its fingerprint
-        # (and with it gv$plan_cache's plan_hash and the AOT cache's key)
-        parts = [f"{f.name}={getattr(self, f.name)!r}"
-                 for f in dataclasses.fields(self) if f.repr]
-        if self.build_unique:
-            parts.append("build_unique=True")
-        return f"{type(self).__qualname__}({', '.join(parts)})"
+        return _marked_repr(self, "build_unique")
 
 
 @dataclass(repr=True)
@@ -448,7 +473,8 @@ def _logical_repr(node: PlanNode) -> str:
     parts = []
     for k, v in vars(node).items():
         if k in ("out_capacity", "capacity", "est_rows") or \
-                k.startswith("_") or (k == "build_unique" and not v):
+                k.startswith("_") or (
+                    k in ("build_unique", "below_join") and not v):
             continue
         if isinstance(v, PlanNode) or k in ("child", "left", "right",
                                             "inputs"):
@@ -647,6 +673,30 @@ def note_join_inputs(node: PlanNode) -> None:
                   else "whole")
 
 
+def note_groupby_placement(node: GroupBy) -> None:
+    """One note for a group-by being lowered (serial or PX) that
+    aggregates over an outer join's NULL-supplying side: ``below_join``
+    where the planner pushed it under the join (the node carries the
+    mark), ``above_join`` where it lies directly over a left join
+    (through projections) and so groups the lanes the join expands into.
+    The group-by that combines a pushed one's partials above the join is
+    the same aggregation's second half and books nothing."""
+    if node.below_join:
+        diag.note("groupby_placement", "below_join")
+        return
+    join = _under_projects(node.child)
+    if node.aggs and isinstance(join, HashJoin) and join.how == "left":
+        build = _under_projects(join.right)
+        if not (isinstance(build, GroupBy) and build.below_join):
+            diag.note("groupby_placement", "above_join")
+
+
+def _under_projects(node: PlanNode) -> PlanNode:
+    while isinstance(node, Project):
+        node = node.child
+    return node
+
+
 def _lower_inner(node: PlanNode, tables: dict[str, Relation]) -> Relation:
     if isinstance(node, TableScan):
         rel = tables[node.table]
@@ -665,6 +715,7 @@ def _lower_inner(node: PlanNode, tables: dict[str, Relation]) -> Relation:
         return ops.project(_lower(node.child, tables, node),
                            node.outputs)
     if isinstance(node, GroupBy):
+        note_groupby_placement(node)
         return ops.hash_groupby(
             _lower(node.child, tables, node), node.keys, node.aggs,
             out_capacity=node.out_capacity,

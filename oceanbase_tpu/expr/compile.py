@@ -973,6 +973,8 @@ def _eval_func(e: ir.FuncCall, rel: Relation, n: int) -> Column:
             v = c.valid_or_true()
             data = jnp.where(v, c.data, data)
             valid = v | valid
+        if any(c.valid is None for c in cols):
+            valid = None    # a NOT NULL branch: the result is NOT NULL
         return Column(data=data, valid=valid, dtype=rt, sdict=sdict)
     if name in ("substring", "substr", "upper", "lower"):
         return _dict_string_func(name, e, rel)

@@ -305,7 +305,8 @@ def encode_plan(node: pp.PlanNode):
     if isinstance(node, pp.GroupBy):
         return {"p": "groupby", "child": encode_plan(node.child),
                 "keys": {n: encode_expr(e) for n, e in node.keys.items()},
-                "aggs": _enc_aggs(node.aggs), "cap": node.out_capacity}
+                "aggs": _enc_aggs(node.aggs), "cap": node.out_capacity,
+                "below": node.below_join}
     if isinstance(node, pp.ScalarAgg):
         return {"p": "scalaragg", "child": encode_plan(node.child),
                 "aggs": _enc_aggs(node.aggs)}
@@ -340,7 +341,8 @@ def decode_plan(d) -> pp.PlanNode:
         return pp.GroupBy(decode_plan(d["child"]),
                           {n: decode_expr(e)
                            for n, e in d["keys"].items()},
-                          _dec_aggs(d["aggs"]), out_capacity=d.get("cap"))
+                          _dec_aggs(d["aggs"]), out_capacity=d.get("cap"),
+                          below_join=bool(d.get("below", False)))
     if k == "scalaragg":
         return pp.ScalarAgg(decode_plan(d["child"]), _dec_aggs(d["aggs"]))
     if k == "join":

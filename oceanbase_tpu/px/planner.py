@@ -544,6 +544,7 @@ def _dlower(node: pp.PlanNode, tables: dict, lo: _Lowering) -> Relation:
             raise NotDistributable("UNION over a replicated input")
         return ops.concat(kids)
     if isinstance(node, pp.GroupBy):
+        pp.note_groupby_placement(node)
         child = _dlower(node.child, tables, lo)
         if getattr(child, "_px_replicated", False):
             raise NotDistributable("GroupBy over a replicated input")

@@ -120,6 +120,9 @@ class Fragment:
     # colid -> (lo, hi, selectivity charged): the range bounds the
     # fragment's filters already priced (_and_selectivity)
     ranges: dict = field(default_factory=dict)
+    # what an outer join was bound from: (preserved side, NULL-supplying
+    # side with its ON filters under it), for _groupby_below_join
+    outer_sides: Optional[tuple] = None
 
     def __post_init__(self):
         if not self.colids:
@@ -314,10 +317,13 @@ class Binder:
             plan, items, having_bound, est, replace_fn = self._bind_aggregate(
                 stmt, qb, scope, plan, items, having_bound, agg_calls, est,
             )
+            aggs, groups = plan.aggs, plan.est_rows
+            if isinstance(plan, pp.GroupBy):
+                plan = self._groupby_below_join(plan, qb, agg_calls)
             if having_bound is not None:
                 sel = _having_selectivity(
-                    having_bound, plan.aggs, qb.fragments,
-                    rows_in / max(plan.est_rows, 1))
+                    having_bound, aggs, qb.fragments,
+                    rows_in / max(groups, 1))
                 est = max(1, int(est * sel + 1e-9))
                 plan = pp.Filter(plan, having_bound, est_rows=est)
 
@@ -605,22 +611,7 @@ class Binder:
             rf = dataclasses.replace(
                 rf, plan=pp.Filter(rf.plan, p, est_rows=est), est_rows=est,
                 ranges=ranges)
-        lkeys = [e[0] for e in eqs]
-        rkeys = [e[1] for e in eqs]
-        from oceanbase_tpu.sql.optimizer import _join_out_est, unique_build
-
-        # every preserved row comes out at least once, and a preserved
-        # row that matches comes out once a MATCH: the inner join's
-        # estimate where that is more (a customer has ten orders)
-        preserved = lf.est_rows + (rf.est_rows if how == "full" else 0)
-        out_est = max(preserved, _join_out_est(
-            lf.est_rows, lf.ndv, rf.est_rows, rf.ndv, lf.unique_cols,
-            rf.unique_cols, eqs))
-        cap = _pow2(int(out_est * 1.5) + 16)
-        plan = pp.HashJoin(lf.plan, rf.plan, lkeys, rkeys, how=how,
-                           out_capacity=cap, est_rows=max(1, out_est),
-                           build_unique=how == "left" and unique_build(
-                               lf.plan, rf.plan, rkeys, cap, self.catalog))
+        plan = join = self._outer_join(lf, rf, eqs, how)
         for p in lpreds + residual:
             # ON predicates on the left side of a LEFT JOIN semantically
             # only nullify matches; approximate by post-filtering matched
@@ -631,12 +622,31 @@ class Binder:
         # FULL emits unmatched build rows too, and NULL-extends the left
         # PKs on them (no longer unique downstream)
         qb.fragments.append(Fragment(
-            plan, merged_cols, out_est,
+            plan, merged_cols, join.est_rows,
             frozenset() if how == "full" else lf.unique_cols,
             colids=lf.colids | rf.colids,
             ndv={**lf.ndv, **rf.ndv},
             hist={**lf.hist, **rf.hist},
-            mcv={**lf.mcv, **rf.mcv}))
+            mcv={**lf.mcv, **rf.mcv}, outer_sides=(lf, rf)))
+
+    def _outer_join(self, lf: Fragment, rf: Fragment, eqs, how: str):
+        """The LEFT / FULL join of two bound sides on ``eqs``, sized."""
+        from oceanbase_tpu.sql.optimizer import _join_out_est, unique_build
+
+        lkeys = [e[0] for e in eqs]
+        rkeys = [e[1] for e in eqs]
+        # every preserved row comes out at least once, and a preserved
+        # row that matches comes out once a MATCH: the inner join's
+        # estimate where that is more (a customer has ten orders)
+        preserved = lf.est_rows + (rf.est_rows if how == "full" else 0)
+        out_est = max(preserved, _join_out_est(
+            lf.est_rows, lf.ndv, rf.est_rows, rf.ndv, lf.unique_cols,
+            rf.unique_cols, eqs))
+        cap = _pow2(int(out_est * 1.5) + 16)
+        return pp.HashJoin(lf.plan, rf.plan, lkeys, rkeys, how=how,
+                           out_capacity=cap, est_rows=max(1, out_est),
+                           build_unique=how == "left" and unique_build(
+                               lf.plan, rf.plan, rkeys, cap, self.catalog))
 
     def _bind_side(self, tref, scope: Scope) -> Fragment:
         """Bind one side of an eager (outer) join into a single fragment."""
@@ -902,6 +912,18 @@ class Binder:
         new_items = [(replace(b), name) for b, name in items]
         if having_bound is not None:
             having_bound = replace(having_bound)
+        # an aggregate that ORDER BY alone names joins the list now, not
+        # when ORDER BY binds (through ``replace``): the list is whole
+        # before a plan is built on it (_groupby_below_join)
+        for item in stmt.order_by:
+            for node in ir.walk(item.expr):
+                if isinstance(node, ir.AggCall):
+                    try:
+                        bound = self.bind_expr(node, scope, allow_agg=True)
+                    except BindError:
+                        continue    # raised again where ORDER BY binds
+                    agg_calls.append(bound)
+                    replace(bound)
 
         # NDV-driven key-cardinality estimate (≙ ObOptEstCost group-by
         # cardinality from basic stats): a plain column key with known
@@ -910,7 +932,7 @@ class Binder:
         for f in qb.fragments:
             ndv_by_cid.update(f.ndv)
         n_keys_est, _known = _groups_estimate(key_map.values(), ndv_by_cid)
-        out_cap = _pow2(min(est, max(64, min(n_keys_est, est))))
+        out_cap = _groups_capacity(n_keys_est, est)
         if key_map:
             plan = pp.GroupBy(plan, key_map, agg_specs, out_capacity=out_cap,
                               est_rows=max(1, min(n_keys_est, est)))
@@ -919,6 +941,91 @@ class Binder:
             plan = pp.ScalarAgg(plan, agg_specs, est_rows=1)
             est = 1
         return plan, new_items, having_bound, est, replace
+
+    def _groupby_below_join(self, g: pp.GroupBy, qb: QueryBlock,
+                            agg_calls) -> pp.PlanNode:
+        """Eager aggregation (Yan and Larson, VLDB 1995; upstream's
+        group-by pushdown): a GROUP BY directly on a LEFT OUTER JOIN that
+        groups by columns of the preserved side and aggregates columns of
+        the NULL-supplying side alone is planned BELOW the join.  The
+        NULL-supplying side is grouped by its join key (one partial an
+        aggregate, its ON filters under it), the same left join runs
+        against that group-by (unique on its key by construction: the
+        join emits on the preserved side's lanes), and ``g``'s keys
+        combine the partials above it (count -> sum, sum / min / max as
+        they are), so the answer holds whatever the preserved side holds,
+        repeated keys included; a projection reads an unmatched group's
+        count as 0 and keeps ``g``'s output names.
+
+        Decided from what the plan shows.  Shape: ``g``'s input is the
+        block's one fragment, the left join itself (a WHERE, a semi edge,
+        a left-side or residual ON predicate leaves something between),
+        ONE key pair of plain columns; every group key a preserved-side
+        column; every aggregate count / sum / min / max, not DISTINCT,
+        of an argument that is NULL on a NULL-extended row (columns of
+        the NULL-supplying side under arithmetic and casts; ``count(*)``
+        and ``count(1)`` count that row).  Lanes: the join's
+        ``out_capacity``, which ``g`` would sort, is at least the
+        NULL-supplying side's static lanes, which the pushed group-by
+        sorts instead.  -> the plan, rewritten or ``g`` as it was."""
+        from oceanbase_tpu.sql.optimizer import _static_lanes
+
+        join = g.child
+        frag = qb.fragments[0]
+        if (len(qb.fragments) != 1 or frag.outer_sides is None
+                or join is not frag.plan or not isinstance(join, pp.HashJoin)
+                or join.how != "left" or len(join.left_keys) != 1):
+            return g
+        lf, rf = frag.outer_sides
+        lkey, rkey = join.left_keys[0], join.right_keys[0]
+        if not (isinstance(lkey, ir.ColumnRef)
+                and isinstance(rkey, ir.ColumnRef)):
+            return g
+        if not all(isinstance(k, ir.ColumnRef) and k.name in lf.colids
+                   for k in g.keys.values()):
+            return g
+        if not g.aggs or any(a.distinct for a in agg_calls) or not all(
+                a.fn in _COMBINES and _null_on_null_extension(
+                    a.arg, rf.colids) for a in g.aggs):
+            return g
+        lanes = _static_lanes(join.right, self.catalog)
+        if lanes is None or join.out_capacity < lanes:
+            return g
+        # below the join: the NULL-supplying side by its join key.  The
+        # new columns are named after g's own, not by fresh(): its counter
+        # is the process's, and a rule that drew from it would rename the
+        # columns of every statement bound later, and with them its plan
+        # hash and its program's cache key
+        gk = f"joinkey_{g.aggs[0].name}"
+        groups = max(1, min(_groups_estimate([rkey], rf.ndv)[0],
+                            rf.est_rows))
+        partials = [AggSpec(f"partial_{a.name}", a.fn, a.arg)
+                    for a in g.aggs]
+        below = Fragment(
+            pp.GroupBy(join.right, {gk: rkey}, partials,
+                       out_capacity=_groups_capacity(groups, rf.est_rows),
+                       est_rows=groups, below_join=True),
+            {}, groups, frozenset([gk]), ndv={gk: groups})
+        # the same join against it, every preserved row once; the proof
+        # of uniqueness is the group-by itself, not unique_build's
+        join = self._outer_join(lf, below, [(lkey, ir.col(gk))], "left")
+        probe = _static_lanes(lf.plan, self.catalog)
+        join = dataclasses.replace(
+            join, build_unique=probe is not None
+            and probe <= join.out_capacity)
+        # above the join: the partials combined by g's keys
+        finals = [AggSpec(f"combined_{a.name}", _COMBINES[a.fn],
+                          ir.col(p.name)) for a, p in zip(g.aggs, partials)]
+        rows = max(1, min(g.est_rows, lf.est_rows))
+        plan = pp.GroupBy(
+            join, g.keys, finals, est_rows=rows,
+            out_capacity=min(g.out_capacity, _pow2(lf.est_rows)))
+        outs = {k: ir.col(k) for k in g.keys}
+        for a, f in zip(g.aggs, finals):
+            outs[a.name] = ir.FuncCall(
+                "coalesce", [ir.col(f.name), ir.Literal(0)]) \
+                if a.fn == "count" else ir.col(f.name)
+        return pp.Project(plan, outs, est_rows=rows)
 
     # ------------------------------------------------------------------
     # expression binding
@@ -1521,6 +1628,30 @@ def _groups_estimate(keys, ndv: dict) -> tuple[int, bool]:
             known = False
         n = min(n, 1 << 40)  # overflow guard
     return n, known
+
+
+def _groups_capacity(groups: int, rows: int) -> int:
+    """A group-by's static output lanes from its keys' estimated groups
+    and its input's estimated rows."""
+    return _pow2(min(rows, max(64, min(groups, rows))))
+
+
+#: how the partial of an aggregate planned below a join is combined
+#: above it (_groupby_below_join)
+_COMBINES = {"count": "sum", "sum": "sum", "min": "min", "max": "max"}
+
+
+def _null_on_null_extension(e, colids) -> bool:
+    """Is ``e`` NULL on a row whose columns ``colids`` are all NULL, and
+    over no other column?  A column of ``colids``, under arithmetic and
+    casts (NULL when an operand is) with literals."""
+    if isinstance(e, ir.ColumnRef):
+        return e.name in colids
+    if isinstance(e, (ir.Arith, ir.Cast)):
+        kids = [c for c in e.children() if not isinstance(c, ir.Literal)]
+        return bool(kids) and all(
+            _null_on_null_extension(c, colids) for c in kids)
+    return False
 
 
 def _column_moments(entry) -> tuple[float, float]:

@@ -3585,6 +3585,11 @@ def format_plan(node, indent: int = 0, row_counts: dict | None = None) -> str:
             if v:
                 attrs.insert(0, "unique build, on probe lanes")
             continue
+        if k == "below_join":
+            # an outer join's aggregation planned under the join
+            if v:
+                attrs.insert(0, "below join")
+            continue
         if isinstance(v, pp.PlanNode) or k in ("child", "left", "right",
                                                "inputs"):
             continue

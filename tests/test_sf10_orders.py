@@ -298,16 +298,34 @@ def test_q18s_having_is_priced_and_its_inputs_are_compacted(loaded):
 
 
 def test_an_outer_join_is_sized_by_its_matches(loaded):
-    """Q13's join emits one lane a MATCH (ten orders a customer), not one
-    a customer; its ON predicate over ``o_comment`` is priced from
-    ANALYZE's sample (99 % pass), not at a third."""
+    """An outer join that expands emits one lane a MATCH (ten orders a
+    customer), not one a customer: Q13 with ``count(*)``, which counts the
+    NULL-extended row and so keeps its group-by above the join.  Q13
+    itself groups ``orders`` below the join (PR 46) and joins on
+    ``customer``'s lanes; either way the ON predicate over ``o_comment``
+    is priced from ANALYZE's sample (99 % pass), not at a third."""
     _db, s = loaded
     sql, _ = _sql("tpch_q13_sf10")
+    orders = s.catalog.table_def("orders").row_count
+    plan, _o, _e = s._plan_select(
+        parse_sql(sql.replace("count(o_orderkey)", "count(*)")), None)
+    (join,) = _nodes(plan, pp.HashJoin)
+    assert join.how == "left" and not join.build_unique
+    assert join.out_capacity >= orders
+    assert isinstance(join.right, pp.Filter)
+    assert join.right.est_rows > orders * 0.9
+    assert not any(g.below_join for g in _nodes(plan, pp.GroupBy))
+
     plan, _o, _e = s._plan_select(parse_sql(sql), None)
     (join,) = _nodes(plan, pp.HashJoin)
-    orders = s.catalog.table_def("orders").row_count
-    assert join.how == "left" and join.out_capacity >= orders
-    assert join.right.est_rows > orders * 0.9
+    below = join.right
+    assert join.how == "left" and join.build_unique
+    assert isinstance(below, pp.GroupBy) and below.below_join
+    assert isinstance(below.child, pp.Filter)
+    assert below.child.est_rows > orders * 0.9
+    # every preserved row once, on the preserved side's lanes
+    customers = s.catalog.table_def("customer").row_count
+    assert customers <= join.out_capacity < orders
     before = _counter("plan.capacity_retries")
     s.execute(sql)
     assert _counter("plan.capacity_retries") == before
@@ -349,14 +367,23 @@ def test_joins_are_counted_by_kind_and_sorted_group_bys_by_lanes(loaded):
         deltas[name] = ({k: k1[k] - k0[k] for k in kinds if k1[k] != k0[k]},
                         sort1 - sort0, out1 - out0, groups1 - groups0,
                         res.rowcount)
+        if name == "tpch_q13_sf10":
+            res13 = res.rows()              # (c_count, custdist)
     # Q4: one semi-join (the exact-key path that only masks its probe is
     # counted too), its group-by is masked: no sorted lanes
     assert deltas["tpch_q4_sf10"][:4] == ({"semi": 1}, 0, 0, 0)
-    # Q13: one left join; two sort-path group-bys; the groups found are
-    # every customer, then the distinct counts
+    # Q13: one left join; three sort-path group-bys: orders by customer
+    # below the join (over orders' lanes), the partial counts combined
+    # by customer above it (over customer's), then the distinct counts;
+    # the groups found are the customers with a counted order, every
+    # customer, and the distinct counts
     kinds13, sort13, out13, groups13, rows13 = deltas["tpch_q13_sf10"]
-    assert kinds13 == {"left": 1} and sort13 > orders
-    assert groups13 == customers + rows13 and out13 >= groups13
+    assert kinds13 == {"left": 1}
+    assert sort13 == s.catalog.scan_lanes("orders") \
+        + 2 * s.catalog.scan_lanes("customer")
+    without_one = next(n for c, n in res13 if c == 0)
+    assert groups13 == (customers - without_one) + customers + rows13
+    assert out13 >= groups13
     # Q18: a semi-join and two inner joins; the subquery's group-by sorts
     # the whole of lineitem's lanes and finds every order
     kinds18, sort18, out18, groups18, rows18 = deltas["tpch_q18_sf10"]

@@ -618,11 +618,21 @@ def test_sf10_orders_plan_compiles_for_v5e_and_fits(
         assert len(re.findall(r" sort\(", text)) == 4
         assert len(re.findall(r"\[68157440\]\S*\) sort\(", text)) == 2
     elif q == "q13":
-        # sized by its matches: every order's lane, not every customer's
+        # orders counted by customer BELOW the join (PR 46), over orders'
+        # lanes; the left join against those counts on customer's lanes,
+        # with no expansion and so no budget of its own; the counts
+        # combined by customer and then grouped, over customer's lanes.
+        # Nothing runs at the 33,554,432 lanes the join expanded into
         assert notes["join_kind", "left"] == 1
-        assert ("join_overflow", 1 << 25) in budgets
-        assert ("groupby_overflow", 1 << 21) in budgets
-        assert notes["groupby_sort_lanes", ""] == (1 << 25) + (1 << 21)
+        assert notes["join_emit", "probe_lanes"] == 1
+        assert ("join_emit", "expanded") not in notes
+        assert notes["groupby_placement", "below_join"] == 1
+        assert ("groupby_placement", "above_join") not in notes
+        assert not [b for b in budgets if b[0] == "join_overflow"]
+        assert [cap for name, cap in budgets if name == "groupby_overflow"] \
+            == [1 << 20, 1 << 21, 64]
+        assert notes["groupby_sort_lanes", ""] == (1 << 24) + 2 * (1 << 21)
+        assert "33554432" not in text
     else:
         # the subquery's group-by holds every order; what its HAVING and
         # the semi-join leave is compacted to 2M lanes; customer joins on
