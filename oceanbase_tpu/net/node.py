@@ -33,6 +33,7 @@ from oceanbase_tpu.palf.cluster import NoQuorum, NotLeader
 from oceanbase_tpu.palf.netcluster import NetPalf
 from oceanbase_tpu.server import admission as qadmission
 from oceanbase_tpu.server import trace as qtrace
+from oceanbase_tpu.server.database import Host
 from oceanbase_tpu.share.location import LocationCache
 from oceanbase_tpu.storage.integrity import CorruptionError, arrays_crc
 
@@ -47,84 +48,20 @@ _WRITE_PREFIXES = ("insert", "update", "delete", "replace", "create",
 SCAN_CHUNK_ROWS = 65536
 
 
-class NodeDatabase:
-    """Database facade for one node process: the attribute surface
-    sessions touch (config, tx/engine routing, observability), bound to
-    the node's sys tenant over the networked WAL."""
+class NodeDatabase(Host):
+    """One node process's host: the planes of ``server/database.py::
+    Host`` over the node's config and its sys tenant on the networked
+    WAL.  NodeServer installs the fault plane, the DTL exchange, the
+    failure detector and the scrub state, and its start()/stop() drive
+    the ASH sampler and the workload snapshot thread."""
 
     def __init__(self, node, root):
-        import itertools
-
         from oceanbase_tpu.px.dtl import DtlMetrics
-        from oceanbase_tpu.server.monitor import (
-            AshSampler,
-            PlanFeedback,
-            PlanHistory,
-            PlanMonitor,
-            SqlAudit,
-            TimeModel,
-            WaitEvents,
-        )
-        from oceanbase_tpu.server.trace import TraceRegistry
-        from oceanbase_tpu.server.virtual_tables import VirtualTables
 
-        self._node = node
-        self.root = root
-        self.config = node.config
-        self.node_id = node.node_id  # stamps trace spans / gv$trace
-        self.tenants = {"sys": node.tenant}
-        self.workarea_history: list = []
-        self.plan_monitor = PlanMonitor()
-        self.plan_feedback = PlanFeedback(
-            int(self.config["plan_feedback_entries"]))
-        self.plan_history = PlanHistory(
-            int(self.config["plan_history_entries"]))
-        self.audit = SqlAudit(int(self.config["sql_audit_queue_size"]))
-        self.wait_events = WaitEvents()
-        self.time_model = TimeModel()  # gv$time_model (phase split)
-        # ASH + full-link trace ring: NodeServer.start()/stop() drive
-        # the sampler lifecycle; sessions register their state slots in
-        # Session.__init__ like they do against a plain Database
-        self.ash = AshSampler(
-            interval_s=int(self.config["ash_sample_interval_ms"])
-            / 1000.0)
-        self.trace_registry = TraceRegistry(
-            int(self.config["trace_ring_spans"]))
-        from oceanbase_tpu.server.trace import install_runtime_hooks
-
-        install_runtime_hooks()
+        super().__init__(node.config, root, node.node_id,
+                         tenants={"sys": node.tenant})
+        self.node = node
         self.dtl_metrics = DtlMetrics()
-        self.dtl = None  # DtlExchange, installed by NodeServer
-        self.health = None  # HealthMonitor, installed by NodeServer
-        self.scrub = None  # ScrubState, installed by NodeServer
-        # overload plane: statement admission + KILL for the sessions
-        # this node's wire threads run (one sys tenant per node)
-        from oceanbase_tpu.server.admission import AdmissionController
-
-        self.admission = AdmissionController(
-            self.config,
-            weight_of=lambda name: int(
-                self.config["admission_tenant_weight"]))
-        self.virtual_tables = VirtualTables(self)
-        self._session_ids = itertools.count(1)
-        # workload diagnostics repository: NodeServer installs the
-        # fault plane on self.faults first, then start() launches the
-        # snapshot thread beside scrub/hb/ckpt
-        from oceanbase_tpu.server.workload import WorkloadRepository
-
-        self.workload = WorkloadRepository(self, root)
-
-    @property
-    def tx(self):
-        return self._node.tx
-
-    @property
-    def engine(self):
-        return self._node.engine
-
-    @property
-    def catalog(self):
-        return self._node.catalog
 
     def create_tenant(self, *a, **kw):
         raise NotImplementedError(
@@ -592,8 +529,7 @@ class NodeServer:
             with self._apply_lock:
                 s = self._sessions.get(session_id)
                 if s is None:
-                    s = Session(self.catalog, tenant=self.tenant,
-                                db=self.db)
+                    s = Session(self.tenant, self.db)
                     self._sessions[session_id] = s
         return s
 
@@ -734,15 +670,13 @@ class NodeServer:
         if stats is not None:
             stats["bytes"] = nbytes
             stats["rows"] = chunks[0]["total"]
-        metrics = getattr(self.db, "dtl_metrics", None)
-        if metrics is not None:
-            from oceanbase_tpu.px.dtl import DtlRecord
+        from oceanbase_tpu.px.dtl import DtlRecord
 
-            metrics.record(DtlRecord(
-                ts=t0, table=table, mode="pull", parts=1,
-                pushdown_hit=False, bytes_shipped=nbytes,
-                rows_shipped=chunks[0]["total"],
-                elapsed_s=_time.monotonic() - m0))
+        self.db.dtl_metrics.record(DtlRecord(
+            ts=t0, table=table, mode="pull", parts=1,
+            pushdown_hit=False, bytes_shipped=nbytes,
+            rows_shipped=chunks[0]["total"],
+            elapsed_s=_time.monotonic() - m0))
         return arrays, valids, chunks[0]["types"], snap
 
     def _local_table_pages(self, table: str, snapshot: int | None,

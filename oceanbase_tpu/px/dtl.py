@@ -778,13 +778,11 @@ class DtlExchange:
                 # injected dtl.execute faults hit the data channels too,
                 # and their outcomes feed the breaker like control
                 # traffic does
-                health = getattr(self.node, "health", None)
                 cli = RpcClient(
                     h, p, timeout_s=60.0, peer_id=pid,
                     local_id=self.node.node_id,
-                    faults=getattr(self.node, "faults", None),
-                    observer=(health.observer(pid)
-                              if health is not None else None))
+                    faults=self.node.faults,
+                    observer=self.node.health.observer(pid))
                 self._chan[pid] = cli
             return cli
 
@@ -829,11 +827,11 @@ class DtlExchange:
         # the PX scheduler consulting the server blacklist when it
         # places SQCs).  The hash slicing is node-independent, so WHO
         # executes a part never changes the result.
-        health = getattr(node, "health", None)
+        health = node.health
         remote: list = []        # (part index, client) worth shipping
         avoided_parts: list = [0]  # part 0 is always the coordinator's
         for i, (pid, cli) in enumerate(peers):
-            if health is not None and health.state(pid) != "up":
+            if health.state(pid) != "up":
                 avoided_parts.append(i + 1)
             else:
                 remote.append((i + 1, cli))
@@ -1020,9 +1018,7 @@ class DtlExchange:
                             bytes=rec.bytes_shipped,
                             slice_skew=round(rec.slice_skew, 3))
         self.metrics.record(rec)
-        we = getattr(getattr(node, "db", None), "wait_events", None)
-        if we is not None:
-            we.add("dtl exchange", elapsed)
+        node.db.wait_events.add("dtl exchange", elapsed)
         if want_ops:
             # estimate-vs-actual ledger for the DTL path: per-slice op
             # rows (shipped back beside the data, ``spans``-style, as

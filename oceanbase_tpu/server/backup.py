@@ -38,12 +38,6 @@ from oceanbase_tpu.storage.integrity import CorruptionError
 MANIFEST = "BACKUP_MANIFEST.json"
 
 
-def _faults(db):
-    """The node's fault plane (net/faults.FaultPlane) when armed —
-    backup writes consult it per destination file (kind="backup")."""
-    return getattr(db, "faults", None)
-
-
 def _check_backup_write(faults, dst: str):
     if faults is not None:
         faults.check_write("backup", dst)
@@ -110,7 +104,7 @@ def full_backup(db, dest: str) -> str:
     if db.root is None:
         raise ValueError("in-memory database cannot be backed up")
     db.checkpoint()
-    faults = _faults(db)
+    faults = db.faults
     os.makedirs(os.path.dirname(dest) or ".", exist_ok=True)
 
     def _copy(src, dst, *, follow_symlinks=True):
@@ -151,7 +145,7 @@ def incremental_backup(db, dest: str, base: str) -> str:
     with open(os.path.join(base, MANIFEST)) as fh:
         base_m = json.load(fh)
     db.checkpoint()
-    faults = _faults(db)
+    faults = db.faults
     os.makedirs(dest, exist_ok=False)
     copied, skipped = {}, 0
     try:
@@ -188,7 +182,7 @@ def archive_wal(db, dest: str):
     """Append-only WAL archiving: copies each replica log's NEW suffix
     (byte offset recorded per file — ≙ archive progress points)."""
     os.makedirs(dest, exist_ok=True)
-    faults = _faults(db)
+    faults = db.faults
     state_p = os.path.join(dest, "ARCHIVE_STATE.json")
     state = {}
     if os.path.exists(state_p):

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import json
+import logging
 import os
 import threading
 from dataclasses import dataclass
@@ -55,13 +56,6 @@ def _frac(v):
 # ---------------------------------------------------------------------------
 
 # SQL engine
-DEF("max_batch_size", 65536, "int",
-    "row batch capacity per morsel on device (multiple of 8*128 lanes)",
-    _pos)
-DEF("default_group_capacity", 1 << 16, "int",
-    "default static capacity for GROUP BY outputs", _pos)
-DEF("join_capacity_factor", 1.5, "float",
-    "safety multiplier over join cardinality estimates", _pos)
 DEF("max_capacity_retry", 3, "int",
     "re-plan attempts (4x budget each) after CapacityOverflow", _nonneg)
 DEF("ob_sql_work_area_percentage", 5, "int",
@@ -200,8 +194,6 @@ DEF("log_disk_utilization_threshold", 80, "int",
 # PX / distributed
 DEF("px_default_dop", 0, "int",
     "degree of parallelism (0 = mesh size)", _nonneg)
-DEF("px_exchange_capacity_per_dest", 1 << 20, "int",
-    "all_to_all per-destination row budget", _pos)
 DEF("px_workers_per_tenant", 64, "int",
     "PX admission quota (≙ px_workers_per_cpu_quota)", _pos)
 DEF("parallel_servers_target", 0, "int",
@@ -256,16 +248,9 @@ DEF("memstore_limit_rows", 1_000_000, "int",
 DEF("minor_compact_trigger", 4, "int",
     "L0 segment count triggering minor compaction (≙ minor_compact_trigger)",
     _pos)
-DEF("major_compaction_interval_s", 86400, "int",
-    "major merge cadence (≙ daily merge)", _pos)
-DEF("segment_chunk_rows", 65536, "int",
-    "rows per encoded chunk (micro-block analog)", _pos)
-DEF("enable_zone_map_pruning", True, "bool",
-    "skip chunks via min/max zone maps on range predicates")
 
 # WAL / replication
 DEF("wal_replica_count", 3, "int", "PALF replica count", _pos)
-DEF("palf_lease_ms", 400, "int", "election lease duration", _pos)
 DEF("log_checkpoint_interval_s", 60, "int",
     "periodic checkpoint cadence advancing the WAL replay point so "
     "restart replay cost is O(tail), not O(history)", _pos)
@@ -351,8 +336,6 @@ DEF("enable_ash", True, "bool",
 DEF("ash_sample_interval_ms", 1000, "int", "ASH sampling period", _pos)
 DEF("sql_audit_queue_size", 10000, "int",
     "ring-buffer capacity of gv$sql_audit", _pos)
-DEF("enable_defensive_check", True, "bool",
-    "extra engine invariant checks (≙ _enable_defensive_check)")
 DEF("kv_cache_limit_bytes", 0, "cap",
     "device-relation (block) cache budget per tenant (≙ ObKVGlobalCache "
     "memory limit); 0: half of the device's memory, the share upstream "
@@ -423,6 +406,12 @@ class Config:
             for k, v in stored.items():
                 if k in _DEFS:
                     self._values[k] = v
+                else:
+                    # an option a later version removed: the instance
+                    # still boots, and says what it let go
+                    logging.getLogger("oceanbase_tpu.server").warning(
+                        "config: %s names no parameter, dropped (%s)",
+                        k, persist_path)
 
     # ------------------------------------------------------------------
     def get(self, name: str):

@@ -10,6 +10,11 @@ log replay — collapsed to the in-process instance:
 - observability singletons: SQL audit ring, plan monitor, ASH sampler,
   wait events, virtual tables (gv$/v$ served through SQL)
 
+``Host`` is what a session is served by: every plane a session,
+``VirtualTables``, ``WorkloadRepository`` or ``server/trace.py`` reads,
+built in one place.  ``Database`` is the single-process host;
+``net/node.py::NodeDatabase`` is one node's.
+
 ``Database.session(tenant=...)`` hands out SQL sessions
 (≙ MySQL frontend connections landing in a tenant's queue).
 """
@@ -37,21 +42,110 @@ from oceanbase_tpu.server.trace import TraceRegistry
 from oceanbase_tpu.server.virtual_tables import VirtualTables
 
 
-class Database:
+class Host:
+    """The planes under a session (≙ what ObServer hands every tenant
+    worker).  A plane one kind of host does not run is declared here as
+    ``None`` and set by the host that runs it, so a reader tests the
+    attribute, never its existence."""
+
+    def __init__(self, config: Config, root: str | None, node_id: int,
+                 tenants: dict):
+        self.config = config
+        self.root = root
+        self.node_id = node_id  # stamps trace spans / gv$trace
+        self.tenants = tenants
+        self._session_ids = itertools.count(1)
+
+        # observability (cluster-wide)
+        self.audit = SqlAudit(int(config["sql_audit_queue_size"]))
+        self.plan_monitor = PlanMonitor()
+        # plan-quality plane: cardinality feedback + regression watchdog
+        # (gv$plan_feedback / gv$plan_history; sql/session.py wires them
+        # into bind + the CapacityOverflow retry ladder)
+        self.plan_feedback = PlanFeedback(
+            int(config["plan_feedback_entries"]))
+        self.plan_history = PlanHistory(
+            int(config["plan_history_entries"]))
+        # per-tenant time-model accounting (gv$time_model): every
+        # statement folds its host-phase split + device/queue/wall here
+        self.time_model = TimeModel()
+        # full-link trace ring (gv$trace / SHOW TRACE; server/trace.py)
+        self.trace_registry = TraceRegistry(
+            int(config["trace_ring_spans"]))
+        # JAX's compile events and the collector's pauses, booked to the
+        # statement that paid them (one listener for the process)
+        from oceanbase_tpu.server.trace import install_runtime_hooks
+
+        install_runtime_hooks()
+        # sessions register their state slots in Session.__init__; the
+        # host decides when the sampler thread runs
+        self.ash = AshSampler(
+            interval_s=int(config["ash_sample_interval_ms"]) / 1000.0)
+        self.wait_events = WaitEvents()
+        # per-query spill records (feeds v$sql_workarea,
+        # ≙ the SQL memory manager's work-area profiles)
+        self.workarea_history: list[dict] = []
+        # overload plane: statement admission + fair queuing + KILL
+        # (server/admission.py); per-tenant WRR weights read live from
+        # each tenant's config overlay
+        from oceanbase_tpu.server.admission import AdmissionController
+
+        self.admission = AdmissionController(
+            config, weight_of=self._tenant_weight)
+        self.virtual_tables = VirtualTables(self)
+        # workload diagnostics repository (server/workload.py):
+        # persistent snapshots + ANALYZE WORKLOAD REPORT; the host
+        # starts its snapshot thread
+        from oceanbase_tpu.server.workload import WorkloadRepository
+
+        self.workload = WorkloadRepository(self, root)
+        # stored procedures, loaded by the first session that asks
+        self.procedures: dict | None = None
+
+        # a Database's alone: the CBO self-validation ledger, roofline
+        # constants and accounting, PROFILE captures, the job scheduler
+        self.plan_choice = None
+        self.cost_units = None
+        self.time_calibration = None
+        self.device_profiles = None
+        self.jobs = None
+        # a node's alone (net/node.py): its NodeServer, the disk-fault
+        # plane durable writers consult (None = no injection), the DTL
+        # exchange and its counters, the failure detector, the scrub state
+        self.node = None
+        self.faults = None
+        self.dtl = None
+        self.dtl_metrics = None
+        self.health = None
+        self.scrub = None
+
+    def _tenant_weight(self, name: str) -> int:
+        t = self.tenants.get(name)
+        cfg = t.config if t is not None else self.config
+        return int(cfg["admission_tenant_weight"])
+
+    # -- sys-tenant convenience (single-tenant callers) ------------------
+    @property
+    def engine(self):
+        return self.tenants["sys"].engine
+
+    @property
+    def tx(self):
+        return self.tenants["sys"].tx
+
+    @property
+    def catalog(self):
+        return self.tenants["sys"].catalog
+
+
+class Database(Host):
     def __init__(self, root: str | None = None, wal_replicas: int = 3,
                  start_ash: bool = False):
-        self.root = root
         cfg_path = os.path.join(root, "config.json") if root else None
         if root:
             os.makedirs(root, exist_ok=True)
-        self.config = Config(persist_path=cfg_path)
-        self.tenants: dict[str, Tenant] = {}
-        self._session_ids = itertools.count(1)
-        self.node_id = 0  # single-process instance (NodeDatabase overrides)
-        # disk-fault plane (net/faults.FaultPlane): a NodeServer arms
-        # its plane here so durable writers (backup, spill) consult it;
-        # None = no injection
-        self.faults = None
+        super().__init__(Config(persist_path=cfg_path), root, node_id=0,
+                         tenants={})
 
         # metrics plane on/off rides the config (ALTER SYSTEM SET
         # enable_metrics; scripts/metrics_bench.py prices the toggle)
@@ -78,25 +172,14 @@ class Database:
         # re-probed, never served (PR 9 contract)
         from oceanbase_tpu.server import calibrate as qcalibrate
 
-        self.cost_units = None
         if bool(self.config["enable_calibration"]):
             try:
                 self.cost_units = qcalibrate.ensure_units(root)
             except Exception:  # noqa: BLE001 — calibration is
                 # observability: a probe failure degrades predictions
                 # to zeros, never boot
-                self.cost_units = None
+                pass
 
-        # observability (cluster-wide)
-        self.audit = SqlAudit(int(self.config["sql_audit_queue_size"]))
-        self.plan_monitor = PlanMonitor()
-        # plan-quality plane: cardinality feedback + regression watchdog
-        # (gv$plan_feedback / gv$plan_history; sql/session.py wires them
-        # into bind + the CapacityOverflow retry ladder)
-        self.plan_feedback = PlanFeedback(
-            int(self.config["plan_feedback_entries"]))
-        self.plan_history = PlanHistory(
-            int(self.config["plan_history_entries"]))
         # CBO self-validation ledger: bind-time predicted seconds vs the
         # runner-up and the measured device seconds (gv$plan_choice)
         self.plan_choice = PlanChoiceLedger(
@@ -107,41 +190,12 @@ class Database:
 
         self.time_calibration = TimeCalibration()
         self.device_profiles = DeviceProfileStore()
-        # per-tenant time-model accounting (gv$time_model): every
-        # statement folds its host-phase split + device/queue/wall here
-        self.time_model = TimeModel()
-        # full-link trace ring (gv$trace / SHOW TRACE; server/trace.py)
-        self.trace_registry = TraceRegistry(
-            int(self.config["trace_ring_spans"]))
-        # JAX's compile events and the collector's pauses, booked to the
-        # statement that paid them (one listener for the process)
-        from oceanbase_tpu.server.trace import install_runtime_hooks
-
-        install_runtime_hooks()
-        self.ash = AshSampler(
-            interval_s=int(self.config["ash_sample_interval_ms"]) / 1000.0)
-        self.wait_events = WaitEvents()
-        # per-query spill records (feeds v$sql_workarea,
-        # ≙ the SQL memory manager's work-area profiles)
-        self.workarea_history: list[dict] = []
-        # overload plane: statement admission + fair queuing + KILL
-        # (server/admission.py); per-tenant WRR weights read live from
-        # each tenant's config overlay
-        from oceanbase_tpu.server.admission import AdmissionController
-
-        self.admission = AdmissionController(
-            self.config, weight_of=self._tenant_weight)
-        self.virtual_tables = VirtualTables(self)
         if start_ash and self.config["enable_ash"]:
             self.ash.start()
-        # workload diagnostics repository (server/workload.py):
-        # persistent snapshots + ANALYZE WORKLOAD REPORT.  The snapshot
-        # thread starts with the knob (or later, when ALTER SYSTEM
-        # turns it on — the watcher below); the loop re-reads both
-        # knobs every round, so turning it OFF needs no restart.
-        from oceanbase_tpu.server.workload import WorkloadRepository
-
-        self.workload = WorkloadRepository(self, root)
+        # The workload snapshot thread starts with the knob (or later,
+        # when ALTER SYSTEM turns it on — the watcher below); the loop
+        # re-reads both knobs every round, so turning it OFF needs no
+        # restart.
         if bool(self.config["enable_workload_repo"]):
             self.workload.start()
         self.config.watch(
@@ -193,11 +247,6 @@ class Database:
 
         logging.getLogger("oceanbase_tpu.server").info(
             "boot backend: %s", backend_summary(self.cost_units))
-
-    def _tenant_weight(self, name: str) -> int:
-        t = self.tenants.get(name)
-        cfg = t.config if t is not None else self.config
-        return int(cfg["admission_tenant_weight"])
 
     # ------------------------------------------------------------------
     def create_tenant(self, name: str, wal_replicas: int = 3,
@@ -271,29 +320,15 @@ class Database:
             _json.dump({u: h.hex() for u, h in self.users.items()}, fh)
         os.replace(tmp, self._users_path)
 
-    # -- sys-tenant convenience (single-tenant callers) ------------------
-    @property
-    def engine(self):
-        return self.tenants["sys"].engine
-
     @property
     def wal(self):
         return self.tenants["sys"].wal
-
-    @property
-    def tx(self):
-        return self.tenants["sys"].tx
-
-    @property
-    def catalog(self):
-        return self.tenants["sys"].catalog
 
     # ------------------------------------------------------------------
     def session(self, tenant: str = "sys"):
         from oceanbase_tpu.sql.session import Session
 
-        t = self.tenants[tenant]
-        return Session(t.catalog, tenant=t, db=self)
+        return Session(self.tenants[tenant], self)
 
     def checkpoint(self, tenant: str | None = None):
         for name, t in self.tenants.items():
@@ -314,7 +349,6 @@ class Database:
     def close(self):
         self.ash.stop()
         self.jobs.stop()
-        if getattr(self, "workload", None) is not None:
-            self.workload.stop()
+        self.workload.stop()
         for t in self.tenants.values():
             t.close()

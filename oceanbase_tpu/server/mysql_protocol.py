@@ -125,8 +125,7 @@ class _Conn:
     # ---- handshake ------------------------------------------------------
     def _tls_context(self):
         try:
-            return (self.session.db.tls_context
-                    if self.session.db is not None else None)
+            return self.session.db.tls_context
         except Exception:
             return None  # e.g. cert generation unavailable
 
@@ -170,9 +169,8 @@ class _Conn:
             if resp is None:
                 return False
         user, token = self._parse_handshake_response(resp)
-        users = getattr(self.session.db, "users", None) \
-            if self.session.db is not None else None
-        if not _verify_native_password(users, user, token, salt):
+        if not _verify_native_password(self.session.db.users, user,
+                                       token, salt):
             self.send_err(1045, f"Access denied for user '{user}'",
                           state=b"28000")
             return False
@@ -451,9 +449,6 @@ def _verify_native_password(users, user: str, token: bytes,
     """Challenge verification: client sends
     SHA1(pw) XOR SHA1(salt + SHA1(SHA1(pw))); recover SHA1(pw) and check
     SHA1(SHA1(pw)) against the stored hash."""
-    if users is None:
-        # no user store wired (bare Session tests): root/empty only
-        users = {"root": mysql_native_hash("")}
     stored = users.get(user)
     if stored is None:
         return False

@@ -748,23 +748,15 @@ def absorb(ctx: TraceCtx, wire_spans: list) -> None:
 
 
 def start_trace(db) -> TraceCtx | None:
-    """-> a fresh per-statement context, or None when tracing is off /
-    the session has no server behind it."""
-    if db is None:
+    """-> a fresh per-statement context, or None when tracing is off."""
+    cfg = db.config
+    if not bool(cfg["enable_query_trace"]):
         return None
-    cfg = getattr(db, "config", None)
-    if cfg is None or getattr(db, "trace_registry", None) is None:
-        return None
-    try:
-        if not bool(cfg["enable_query_trace"]):
-            return None
-        rate = float(cfg["trace_sample_rate"])
-        slow = float(cfg["trace_slow_threshold_s"])
-    except KeyError:
-        return None
+    rate = float(cfg["trace_sample_rate"])
+    slow = float(cfg["trace_slow_threshold_s"])
     sampled = rate >= 1.0 or random.random() < rate
     return TraceCtx(f"{random.getrandbits(64):016x}",
-                    node=getattr(db, "node_id", 0),
+                    node=db.node_id,
                     sampled=sampled, slow_s=slow)
 
 

@@ -142,8 +142,7 @@ class VirtualTables:
         rows): one row per (tenant, phase), with the phase's share of
         the tenant's measured statement wall — 'where did the wall
         clock go' as a GROUP BY."""
-        tm = getattr(self.db, "time_model", None)
-        rows = tm.rows() if tm is not None else []
+        rows = self.db.time_model.rows()
         return {
             "tenant": _obj(r["tenant"] for r in rows),
             "phase": _obj(r["phase"] for r in rows),
@@ -159,9 +158,9 @@ class VirtualTables:
         """Catalog of persisted workload snapshots (server/workload.py):
         id, capture time, merged node set, crc — the ids ANALYZE
         WORKLOAD REPORT FROM <id> TO <id> accepts."""
-        repo = getattr(self.db, "workload", None)
+        repo = self.db.workload
         rows = []
-        for sid in (repo.snapshot_ids() if repo is not None else []):
+        for sid in repo.snapshot_ids():
             try:
                 s = repo.load(sid)
             except Exception:  # noqa: BLE001 — a quarantined snapshot
@@ -182,8 +181,7 @@ class VirtualTables:
         """The LAST built workload report's structured rows (ANALYZE
         WORKLOAD REPORT populates; SHOW WORKLOAD REPORT renders the
         same report as a text tree)."""
-        repo = getattr(self.db, "workload", None)
-        rep = repo.last_report if repo is not None else None
+        rep = self.db.workload.last_report
         rows = rep["rows"] if rep else []
         fid = rep["from_id"] if rep else 0
         tid = rep["to_id"] if rep else 0
@@ -202,11 +200,8 @@ class VirtualTables:
         utilization, degradation state, plus one ``spill_stmt`` row per
         statement actively spilling."""
         rows = []
-        tenants = getattr(self.db, "tenants", {}) or {}
-        for name in sorted(tenants):
-            dm = getattr(tenants[name], "diskmgr", None)
-            if dm is not None:
-                rows.extend(dm.stats(tenant=name))
+        for name in sorted(self.db.tenants):
+            rows.extend(self.db.tenants[name].diskmgr.stats(tenant=name))
         return {
             "tenant": _obj(r["tenant"] for r in rows),
             "surface": _obj(r["surface"] for r in rows),
@@ -224,10 +219,9 @@ class VirtualTables:
         """Overload-plane snapshot per tenant (≙ gv$ob_units /
         __all_virtual_tenant_resource): admission slots + queue depth,
         the large-query lane, and memstore backpressure state."""
-        adm = getattr(self.db, "admission", None)
-        rows = adm.stats() if adm is not None else []
+        rows = self.db.admission.stats()
         by_tenant = {r["tenant"]: r for r in rows}
-        tenants = getattr(self.db, "tenants", {}) or {}
+        tenants = self.db.tenants
         # tenants that exist but have not run a statement yet still
         # get a row (their throttle state matters before first query)
         for name in tenants:
@@ -299,8 +293,7 @@ class VirtualTables:
         (≙ gv$ob_trace / SHOW TRACE's backing store)."""
         import json as _json
 
-        reg = getattr(self.db, "trace_registry", None)
-        spans = reg.recent() if reg is not None else []
+        spans = self.db.trace_registry.recent()
         return {
             "trace_id": _obj(s.trace_id for s in spans),
             "span_id": np.array([s.span_id for s in spans], np.int64),
@@ -318,8 +311,7 @@ class VirtualTables:
     def active_session_history(self):
         """ASH samples with the statement's trace_id, so session history
         joins against gv$trace (≙ gv$active_session_history)."""
-        ash = getattr(self.db, "ash", None)
-        h = ash.history(None) if ash is not None else []
+        h = self.db.ash.history(None)
         return {
             "sample_ts": np.array([x[0] for x in h], np.float64),
             "session_id": np.array([x[1] for x in h], np.int64),
@@ -383,8 +375,7 @@ class VirtualTables:
         import json as _json
 
         rows = []
-        fb = getattr(self.db, "plan_feedback", None)
-        for r in (fb.rows() if fb is not None else []):
+        for r in self.db.plan_feedback.rows():
             rows.append(("card", r["logical_hash"], r["pos"], r["op"],
                          -1 if r.get("est") is None else r["est"],
                          r["rows"], r.get("q_error", 0.0),
@@ -418,8 +409,7 @@ class VirtualTables:
         per logical plan hash, the latency distribution + EWMA against
         the frozen warmup baseline, flagged when the EWMA exceeds
         baseline * plan_regress_threshold."""
-        ph = getattr(self.db, "plan_history", None)
-        rows = ph.rows() if ph is not None else []
+        rows = self.db.plan_history.rows()
         return {
             "logical_hash": _obj(r["logical_hash"] for r in rows),
             "executions": np.array([r["executions"] for r in rows],
@@ -446,7 +436,7 @@ class VirtualTables:
         predicted seconds vs the runner-up's, the enumeration method,
         how many access paths were priced, and the prediction q-error
         against the measured device seconds."""
-        pc = getattr(self.db, "plan_choice", None)
+        pc = self.db.plan_choice
         rows = pc.rows() if pc is not None else []
         return {
             "logical_hash": _obj(r["logical_hash"] for r in rows),
@@ -534,7 +524,7 @@ class VirtualTables:
         contract): kind='constant' rows are the roofline inputs
         (peak flops/s, bytes/s, launch overhead, rpc per-byte);
         kind='probe' rows are the per-kernel-per-rung measurements."""
-        units = getattr(self.db, "cost_units", None)
+        units = self.db.cost_units
         rows = []
         if units is not None:
             base = (units.backend, units.device_kind,
@@ -573,7 +563,7 @@ class VirtualTables:
         """Per-operator-type roofline accounting (the calibration table
         the CBO arc reads): predicted vs measured device seconds and
         the time-q-error distribution per plan root operator."""
-        tc = getattr(self.db, "time_calibration", None)
+        tc = self.db.time_calibration
         rows = tc.rows() if tc is not None else []
         return {
             "operator": _obj(r["op"] for r in rows),
@@ -603,7 +593,7 @@ class VirtualTables:
         """Per-kernel rows of every PROFILE capture (server/profiler.py)
         joined to the statement by trace_id (≙ the SQL plan monitor's
         per-operator timing, taken down to real device kernels)."""
-        store = getattr(self.db, "device_profiles", None)
+        store = self.db.device_profiles
         profs = store.recent() if store is not None else []
         rows = []
         for p in profs:
@@ -633,7 +623,7 @@ class VirtualTables:
         from oceanbase_tpu.server.backend_info import resolve_backend
 
         b = resolve_backend()
-        units = getattr(self.db, "cost_units", None)
+        units = self.db.cost_units
         age = units.age_s() if units is not None else -1.0
         return {
             "platform": _obj([b["platform"]]),
@@ -652,7 +642,7 @@ class VirtualTables:
         (≙ gv$px_dtl traffic stats; px/dtl.py)."""
         import json as _json
 
-        m = getattr(self.db, "dtl_metrics", None)
+        m = self.db.dtl_metrics
         recs = m.recent(1000) if m is not None else []
         return {
             "ts": np.array([r.ts for r in recs], np.float64),
@@ -701,7 +691,7 @@ class VirtualTables:
         (up / suspect / down), RTT EWMA, and the retry/deadline counters
         the per-verb rpc policy table accumulates (≙ the server
         blacklist view, __all_virtual_server_blacklist_info)."""
-        h = getattr(self.db, "health", None)
+        h = self.db.health
         rows = h.snapshot() if h is not None else []
         return {
             "peer": np.array([r["peer"] for r in rows], np.int64),
@@ -741,7 +731,7 @@ class VirtualTables:
                              "phase": "prepared_xa",
                              "prepared": len(xids),
                              "xids": ",".join(xids)})
-        node = getattr(self.db, "_node", None)
+        node = self.db.node
         if node is not None:
             r = node.palf.replica
             rows.append({
@@ -779,7 +769,7 @@ class VirtualTables:
         cross-replica digest mismatches, repairs with their peer/bytes,
         and post-repair parity checks (≙ the replica-checksum
         verification surfaced by __all_virtual_tablet_checksum)."""
-        st = getattr(self.db, "scrub", None)
+        st = self.db.scrub
         rows = st.rows() if st is not None else []
         return {
             "ts": np.array([r["ts"] for r in rows], np.float64),
@@ -798,8 +788,7 @@ class VirtualTables:
         }
 
     def session_history(self):
-        ash = getattr(self.db, "ash", None)
-        h = ash.history(10000) if ash is not None else []
+        h = self.db.ash.history(10000)
         return {
             "sample_ts": np.array([x[0] for x in h], np.float64),
             "session_id": np.array([x[1] for x in h], np.int64),
@@ -810,7 +799,7 @@ class VirtualTables:
     def sql_workarea(self):
         """Spill activity per query (≙ GV$SQL_WORKAREA: the work-area
         profile rows the SQL memory manager publishes)."""
-        recs = list(getattr(self.db, "workarea_history", []))[-1000:]
+        recs = self.db.workarea_history[-1000:]
         return {
             "ts": np.array([r["ts"] for r in recs], np.float64),
             "sql": _obj(r["sql"][:200] for r in recs),
@@ -943,9 +932,7 @@ class VirtualTables:
         """Wait-event distributions (≙ gv$system_event): the legacy
         total_waits/time_waited_s columns stay wire-compatible; the
         histogram upgrade adds min/max/p95/p99 per event."""
-        we = getattr(self.db, "wait_events", None)
-        stats = we.stats() if we is not None \
-            and hasattr(we, "stats") else {}
+        stats = self.db.wait_events.stats()
         events = sorted(stats)
         return {
             "event": _obj(events),
@@ -976,19 +963,17 @@ class VirtualTables:
         from oceanbase_tpu.server import metrics as qmetrics
 
         wire = qmetrics.wire_snapshot()
-        node = getattr(self.db, "_node", None)
-        peers = getattr(node, "peers", None) if node is not None else None
-        if peers:
-            health = getattr(node, "health", None)
-            for pid in sorted(peers):
+        node = self.db.node
+        if node is not None:
+            for pid in sorted(node.peers):
                 # a peer the failure detector already declared DOWN
                 # would stall the read for the verb deadline — skip it
                 # (the same pre-emptive avoidance DTL routing applies)
-                if health is not None and health.state(pid) == "down":
+                if node.health.state(pid) == "down":
                     continue
                 try:
-                    r = peers[pid].call("metrics.scrape",
-                                        _deadline_s=2.0)
+                    r = node.peers[pid].call("metrics.scrape",
+                                             _deadline_s=2.0)
                     wire = qmetrics.merge_wire(wire, r["wire"])
                 except Exception:  # noqa: BLE001 — degraded view
                     continue
@@ -1115,7 +1100,7 @@ class VirtualTables:
     def dbms_jobs(self):
         """Scheduled-job registry + run history
         (≙ DBA_SCHEDULER_JOBS / __all_virtual_dbms_job)."""
-        sched = getattr(self.db, "jobs", None)
+        sched = self.db.jobs
         jobs = sched.jobs if sched is not None else {}
         names = sorted(jobs)
         return {

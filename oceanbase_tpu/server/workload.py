@@ -137,8 +137,7 @@ class WorkloadRepository:
                 "count": st["count"], "sum": round(st["sum"], 6),
                 "p50": st["p50"], "p95": st["p95"], "p99": st["p99"]}
         payload["sysstat_hist"] = hists
-        tm = getattr(db, "time_model", None)
-        payload["time_model"] = tm.snapshot() if tm is not None else {}
+        payload["time_model"] = db.time_model.snapshot()
         entries = plan_cache_stats()
         churn = sorted(entries, key=lambda e: -(e.xla_traces
                                                 + e.sidecar_builds))[:10]
@@ -157,34 +156,29 @@ class WorkloadRepository:
                      "sidecar_builds": e.sidecar_builds}
                     for e in churn],
         }
-        ph = getattr(db, "plan_history", None)
-        rows = ph.rows() if ph is not None else []
+        rows = db.plan_history.rows()
         payload["plan_history"] = {
             "plans": len(rows),
             "regress_count": sum(r["regress_count"] for r in rows),
             "regressed": sorted(r["logical_hash"] for r in rows
                                 if r["regressed"]),
         }
-        we = getattr(db, "wait_events", None)
         payload["wait_events"] = {
             e: {"count": int(c), "sum": round(float(s), 6)}
-            for e, (c, s) in
-            (we.snapshot() if we is not None else {}).items()}
-        ash = getattr(db, "ash", None)
+            for e, (c, s) in db.wait_events.snapshot().items()}
         roll: dict[str, int] = {}
-        for smp in (ash.history(None) if ash is not None else []):
+        for smp in db.ash.history(None):
             roll[smp[3]] = roll.get(smp[3], 0) + 1
         payload["ash"] = roll
         payload["top_sql"] = self._top_sql()
         disk = []
-        for tname in sorted(getattr(db, "tenants", {}) or {}):
-            dm = getattr(db.tenants[tname], "diskmgr", None)
-            for r in (dm.stats(tenant=tname) if dm is not None else []):
+        for tname in sorted(db.tenants):
+            for r in db.tenants[tname].diskmgr.stats(tenant=tname):
                 disk.append({k: r[k] for k in
                              ("tenant", "surface", "used_bytes",
                               "limit_bytes", "state")})
         payload["disk"] = disk
-        h = getattr(db, "health", None)
+        h = db.health
         payload["health"] = [
             {"peer": r["peer"], "state": r["state"],
              "failures": r["failures"]}
@@ -194,9 +188,8 @@ class WorkloadRepository:
     def _top_sql(self, n: int = 10) -> list:
         """Audit-ring rollup keyed by statement text: calls + elapsed/
         device plus the host-phase decomposition, top-n by elapsed."""
-        audit = getattr(self.db, "audit", None)
         agg: dict[str, dict] = {}
-        for r in (audit.recent(None) if audit is not None else []):
+        for r in self.db.audit.recent(None):
             a = agg.setdefault(r.sql[:200], {
                 "sql": r.sql[:200], "calls": 0, "elapsed_s": 0.0,
                 "device_s": 0.0, "bind_s": 0.0, "sidecar_build_s": 0.0,
@@ -226,7 +219,7 @@ class WorkloadRepository:
         """Take one snapshot (cluster-merged when peers exist), persist
         it, prune retention; -> the snapshot record."""
         payload = self.collect()
-        nodes = [int(getattr(self.db, "node_id", 0))]
+        nodes = [int(self.db.node_id)]
         if cluster:
             payload, nodes = self._merge_peers(payload, nodes)
         with self._lock:
@@ -235,7 +228,7 @@ class WorkloadRepository:
         snap = {
             "id": sid,
             "ts": time.time(),
-            "node_id": int(getattr(self.db, "node_id", 0)),
+            "node_id": int(self.db.node_id),
             "nodes": sorted(nodes),
             "crc": bytes_crc(canonical_bytes(payload)),
             "payload": payload,
@@ -249,16 +242,15 @@ class WorkloadRepository:
         """Fold every reachable peer's local payload in over the
         idempotent workload.snapshot verb; unreachable or digest-
         mismatching peers degrade the merge (gv$ semantics)."""
-        node = getattr(self.db, "_node", None)
-        peers = getattr(node, "peers", None) if node is not None else None
-        if not peers:
+        node = self.db.node
+        if node is None:
             return payload, nodes
-        health = getattr(node, "health", None)
-        for pid in sorted(peers):
-            if health is not None and health.state(pid) == "down":
+        for pid in sorted(node.peers):
+            if node.health.state(pid) == "down":
                 continue
             try:
-                r = peers[pid].call("workload.snapshot", _deadline_s=5.0)
+                r = node.peers[pid].call("workload.snapshot",
+                                         _deadline_s=5.0)
                 # the bulk reply carries its own digest: a merge must
                 # never fold in bytes the peer did not mean to send
                 if bytes_crc(canonical_bytes(r["payload"])) != r["crc"]:
@@ -280,7 +272,7 @@ class WorkloadRepository:
         os.makedirs(self.dir, exist_ok=True)
         path = self._path(snap["id"])
         data = json.dumps(snap, sort_keys=True, default=str)
-        faults = getattr(self.db, "faults", None)
+        faults = self.db.faults
         if faults is not None:
             faults.check_write("workload", path, nbytes=len(data))
         tmp = path + ".tmp"

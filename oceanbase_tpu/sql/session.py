@@ -18,7 +18,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from oceanbase_tpu.catalog import Catalog, ColumnDef, TableDef
+from oceanbase_tpu.catalog import ColumnDef, TableDef
 from oceanbase_tpu.datatypes import SqlType, TypeKind, days_to_date
 from oceanbase_tpu.exec.diag import CapacityOverflow
 from oceanbase_tpu.exec import plan as qplan
@@ -109,11 +109,11 @@ class Session:
 
     MAX_CAPACITY_RETRIES = 3
 
-    def __init__(self, catalog: Catalog | None = None, tenant=None, db=None):
-        self.catalog = catalog if catalog is not None else Catalog()
-        self.tenant = tenant  # server.Tenant when multi-tenant
-        self.db = db  # server.Database when backed by the storage/tx plane
-        self.session_id = 0
+    def __init__(self, tenant, db):
+        self.tenant = tenant  # server.Tenant: catalog, engine, tx, config
+        self.db = db  # its host (server.database.Host)
+        self.catalog = tenant.catalog
+        self.session_id = next(db._session_ids)
         self.variables: dict[str, object] = {
             "autocommit": 1, "max_capacity_retry": self.MAX_CAPACITY_RETRIES,
         }
@@ -134,36 +134,25 @@ class Session:
         self._last_compile_s = 0.0
         self._ash_state = {"active": False, "sql": "", "state": "idle",
                            "trace_id": ""}
-        if db is not None:
-            self.session_id = next(db._session_ids)
-            if getattr(db, "ash", None) is not None:
-                db.ash.register(self.session_id, self._ash_state)
+        db.ash.register(self.session_id, self._ash_state)
 
     def close(self):
         """Release session resources (ASH slot, open transaction,
         admission eviction flag)."""
-        if self._tx is not None and self.db is not None:
+        if self._tx is not None:
             self._txsvc.rollback(self._tx)
             self._tx = None
-        if self.db is not None and getattr(self.db, "ash", None) is not None:
-            self.db.ash.unregister(self.session_id)
-        adm = (getattr(self.db, "admission", None)
-               if self.db is not None else None)
-        if adm is not None:
-            adm.forget_session(self.session_id)
+        self.db.ash.unregister(self.session_id)
+        self.db.admission.forget_session(self.session_id)
 
-    # tenant-scoped module stack (falls back to the db's sys tenant)
+    # the tenant's module stack
     @property
     def _txsvc(self):
-        if self.tenant is not None:
-            return self.tenant.tx
-        return self.db.tx
+        return self.tenant.tx
 
     @property
     def _engine(self):
-        if self.tenant is not None:
-            return self.tenant.engine
-        return self.db.engine
+        return self.tenant.engine
 
     # ------------------------------------------------------------------
     # statement shapes that pay admission (queries + DML + anything
@@ -195,10 +184,7 @@ class Session:
         variable at both scopes (``server/config.py::ALIASES``)."""
         v = self.variables.get("query_timeout_s")
         if v is None:
-            if self.tenant is not None:
-                v = self.tenant.config["query_timeout_s"]
-            elif self.db is not None:
-                v = self.db.config["query_timeout_s"]
+            v = self.tenant.config["query_timeout_s"]
         try:
             v = float(v)
         except (TypeError, ValueError):
@@ -227,17 +213,15 @@ class Session:
         self._last_compile_s = 0.0
         self._stmt_is_show_trace = False  # set by _show_trace()
         tctx = qtrace.start_trace(self.db)
-        admission = (getattr(self.db, "admission", None)
-                     if self.db is not None else None)
+        admission = self.db.admission
         ctx: qadmission.StmtCtx | None = None
         # every host phase below is a span whose self time is a column
         # of this statement's audit row (trace.PHASE_OF); what lies
         # between them is ``other_s``
         try:
-            if admission is not None:
-                # a session evicted by plain KILL <id> takes no more
-                # statements (typed; the client reconnects)
-                admission.check_session(self.session_id)
+            # a session evicted by plain KILL <id> takes no more
+            # statements (typed; the client reconnects)
+            admission.check_session(self.session_id)
             with qtrace.activate(tctx):
                 timeout_s = self._stmt_timeout_s()
                 with qtrace.span("statement", sql=sql[:200],
@@ -253,11 +237,10 @@ class Session:
                             active=True, sql=sql, state="executing",
                             trace_id=tctx.trace_id
                             if tctx is not None else "")
-                        if admission is not None and \
-                                self._needs_admission(stmt):
+                        if self._needs_admission(stmt):
                             ctx = qadmission.StmtCtx(
                                 session_id=self.session_id,
-                                tenant=getattr(self.tenant, "name", "sys"),
+                                tenant=self.tenant.name,
                                 sql=sql, timeout_s=timeout_s,
                                 controller=admission,
                                 ash_state=self._ash_state)
@@ -296,7 +279,7 @@ class Session:
                     admission.release(ctx)
                 self._ash_state.update(active=False, state="idle",
                                        trace_id="")
-                tname = getattr(self.tenant, "name", "sys")
+                tname = self.tenant.name
                 qmetrics.inc("sql.statements", tenant=tname,
                              ok=0 if err else 1)
                 qmetrics.observe("sql.statement_s", elapsed, tenant=tname)
@@ -316,37 +299,33 @@ class Session:
                         # not silently attribute an OLDER statement's
                         # tree
                         self._last_trace_id = ""
-                if self.db is not None and \
-                        getattr(self.db, "audit", None) is not None:
-                    from oceanbase_tpu.server.monitor import AuditRecord
+                from oceanbase_tpu.server.monitor import AuditRecord
 
-                    queue_s = ctx.queue_s if ctx is not None else 0.0
-                    other_s = elapsed - queue_s - times.phase_sum()
-                    # the row keeps the statement's accumulator itself,
-                    # so close_s (booked when this span closes) shows
-                    self.db.audit.record(AuditRecord(
-                        sql=sql, session_id=self.session_id,
-                        tenant=getattr(self.tenant, "name", ""),
-                        start_ts=start, elapsed_s=elapsed,
-                        rows=out.rowcount if out is not None else 0,
-                        error=err,
-                        compile_s=self._last_compile_s,
-                        trace_id=trace_id,
-                        queue_s=queue_s,
-                        host_s=times.host_s, device_s=times.device_s,
-                        bind_s=times.bind_s,
-                        sidecar_build_s=times.sidecar_build_s,
-                        lower_s=times.lower_s,
-                        xla_compile_s=times.compile_s,
-                        dispatch_s=times.dispatch_s,
-                        merge_s=times.merge_s,
-                        other_s=other_s, times=times,
-                    ))
-                    tm = getattr(self.db, "time_model", None)
-                    if tm is not None:
-                        tm.observe(tname, times, elapsed_s=elapsed,
-                                   queue_s=queue_s, other_s=other_s,
-                                   close_s=csp.so_far_s())
+                queue_s = ctx.queue_s if ctx is not None else 0.0
+                other_s = elapsed - queue_s - times.phase_sum()
+                # the row keeps the statement's accumulator itself,
+                # so close_s (booked when this span closes) shows
+                self.db.audit.record(AuditRecord(
+                    sql=sql, session_id=self.session_id,
+                    tenant=tname,
+                    start_ts=start, elapsed_s=elapsed,
+                    rows=out.rowcount if out is not None else 0,
+                    error=err,
+                    compile_s=self._last_compile_s,
+                    trace_id=trace_id,
+                    queue_s=queue_s,
+                    host_s=times.host_s, device_s=times.device_s,
+                    bind_s=times.bind_s,
+                    sidecar_build_s=times.sidecar_build_s,
+                    lower_s=times.lower_s,
+                    xla_compile_s=times.compile_s,
+                    dispatch_s=times.dispatch_s,
+                    merge_s=times.merge_s,
+                    other_s=other_s, times=times,
+                ))
+                self.db.time_model.observe(
+                    tname, times, elapsed_s=elapsed, queue_s=queue_s,
+                    other_s=other_s, close_s=csp.so_far_s())
 
     def _materialize_virtuals(self, stmt):
         """Refresh any referenced gv$/v$ virtual tables as transient
@@ -354,12 +333,7 @@ class Session:
         Covers every statement shape that can reference a table: SELECT
         (FROM, CTEs, set ops, expression subqueries), EXPLAIN,
         INSERT ... SELECT, UPDATE/DELETE WHERE subqueries."""
-        if self.db is None:
-            return
-        vt = getattr(self.db, "virtual_tables", None)
-        if vt is None:
-            return
-
+        vt = self.db.virtual_tables
         # the walk is made of methods, not of closures that name each
         # other: those are reference cycles, made anew by every
         # statement, that only the collector can free
@@ -443,9 +417,6 @@ class Session:
             self.catalog.drop_table(stmt.name, if_exists=stmt.if_exists)
             return _ok()
         if isinstance(stmt, ast.TablegroupStmt):
-            if self.db is None:
-                raise NotImplementedError(
-                    "tablegroups need the storage engine")
             if stmt.op == "create":
                 self._engine.create_tablegroup(stmt.name, stmt.flag)
             else:
@@ -484,11 +455,9 @@ class Session:
         if isinstance(stmt, ast.ShowTablesStmt):
             # virtual gv$ tables are part of the schema surface: every
             # diagnostic view must be discoverable, not folklore
-            vt = getattr(self.db, "virtual_tables", None) \
-                if self.db is not None else None
             names = sorted(set(self.catalog.tables())
                            | set(self.catalog.view_names())
-                           | set(vt.names() if vt is not None else ()))
+                           | set(self.db.virtual_tables.names()))
             return Result(["table_name"],
                           {"table_name": np.array(names, dtype=object)},
                           {}, {"table_name": SqlType.string()},
@@ -527,8 +496,6 @@ class Session:
         if isinstance(stmt, ast.AlterSystemStmt):
             return self._alter_system(stmt)
         if isinstance(stmt, ast.AlterTableStmt):
-            if self.db is None:
-                raise NotImplementedError("ALTER TABLE needs a Database")
             if stmt.action == "add_column":
                 c = stmt.column
                 self._engine.alter_table(stmt.table, "add_column",
@@ -540,16 +507,12 @@ class Session:
             self.catalog.schema_version += 1
             return _ok()
         if isinstance(stmt, ast.TenantStmt):
-            if self.db is None:
-                raise NotImplementedError("tenants need a Database")
             if stmt.op == "create":
                 self.db.create_tenant(stmt.name)
             else:
                 self.db.drop_tenant(stmt.name)
             return _ok()
         if isinstance(stmt, ast.UserStmt):
-            if self.db is None:
-                raise NotImplementedError("users need a Database")
             if stmt.op == "create":
                 self.db.create_user(stmt.name, stmt.password)
             elif stmt.op == "drop":
@@ -614,9 +577,7 @@ class Session:
                  "create_table": np.array([text], dtype=object)},
                 {}, {}, rowcount=1)
         if isinstance(stmt, ast.SequenceStmt):
-            seqs = self.tenant.sequences if self.tenant is not None else None
-            if seqs is None:
-                raise NotImplementedError("sequences need a Database")
+            seqs = self.tenant.sequences
             if stmt.op == "create":
                 seqs.create(stmt.name, stmt.start, stmt.increment, stmt.cache)
             else:
@@ -665,12 +626,10 @@ class Session:
                 disp = {"executing": "RUNNING", "queued": "QUEUED",
                         "killed": "KILLED", "idle": "IDLE"}
                 rows = []
-                if self.db is not None and \
-                        getattr(self.db, "ash", None) is not None:
-                    for sid, st in self.db.ash.sessions().items():
-                        raw = st.get("state", "idle")
-                        rows.append((sid, disp.get(raw, raw.upper()),
-                                     st.get("sql", "")[:120]))
+                for sid, st in self.db.ash.sessions().items():
+                    raw = st.get("state", "idle")
+                    rows.append((sid, disp.get(raw, raw.upper()),
+                                 st.get("sql", "")[:120]))
                 rows.sort()
                 return Result(
                     ["id", "state", "info"],
@@ -695,11 +654,7 @@ class Session:
                      "value": np.array([str(shown[n]) for n in names],
                                        dtype=object)},
                     {}, {}, rowcount=len(names))
-            cfg = (self.tenant.config if self.tenant is not None
-                   else self.db.config if self.db else None)
-            if cfg is None:
-                return _ok()
-            snap = cfg.snapshot()
+            snap = self.tenant.config.snapshot()
             return Result(
                 ["name", "value"],
                 {"name": np.array(list(snap), dtype=object),
@@ -714,31 +669,19 @@ class Session:
         its next host-side checkpoint (operator close / spill chunk /
         DTL slice join / retry ladder) — and in-flight remote DTL
         fragments are cancelled over the idempotent dtl.cancel verb."""
-        adm = (getattr(self.db, "admission", None)
-               if self.db is not None else None)
-        if adm is None:
-            raise NotImplementedError("KILL needs a Database")
         # existence first (MySQL: ER_NO_SUCH_THREAD): plain KILL must
         # not plant eviction flags for ids that were never sessions
-        ash = getattr(self.db, "ash", None)
-        known = (ash is not None
-                 and stmt.session_id in ash.sessions()) \
-            or stmt.session_id == self.session_id
-        if not known:
+        if stmt.session_id not in self.db.ash.sessions():
             raise KeyError(f"unknown session id {stmt.session_id}")
         # KILL QUERY cancels the in-flight statement (rowcount 0 on an
         # idle session); plain KILL also evicts the session itself
-        found = adm.kill(stmt.session_id,
-                         query_only=(stmt.kind == "query"))
+        found = self.db.admission.kill(stmt.session_id,
+                                       query_only=(stmt.kind == "query"))
         return _ok(rowcount=1 if found else 0)
 
     def _set_var(self, stmt: ast.SetVarStmt) -> Result:
         if stmt.scope == "global":
-            cfg = (self.tenant.config if self.tenant is not None
-                   else self.db.config if self.db else None)
-            if cfg is None:
-                raise ValueError("no global config available")
-            cfg.set(stmt.name, stmt.value)
+            self.tenant.config.set(stmt.name, stmt.value)
         else:
             from oceanbase_tpu.server.config import canonical
 
@@ -748,13 +691,8 @@ class Session:
 
     def _alter_system(self, stmt: ast.AlterSystemStmt) -> Result:
         if stmt.action == "set":
-            cfg = self.db.config if self.db is not None else None
-            if cfg is None:
-                raise ValueError("ALTER SYSTEM needs a Database")
-            cfg.set(stmt.name, stmt.value)
+            self.db.config.set(stmt.name, stmt.value)
             return _ok()
-        if self.db is None:
-            raise ValueError("ALTER SYSTEM needs a Database")
         if stmt.action == "calibrate":
             # re-run the roofline probe suite on the live backend
             # (full ladder) and persist the refreshed machine constants
@@ -938,8 +876,6 @@ class Session:
         return arrays, valids, n
 
     def _finish_load(self, stmt, td, arrays, valids, n) -> Result:
-        if self.db is None:
-            raise NotImplementedError("LOAD DATA needs a Database")
         if n:
             self._engine.bulk_load(stmt.table, arrays, valids or None,
                                    version=self._txsvc.gts.get_ts())
@@ -952,28 +888,24 @@ class Session:
         """TRUNCATE TABLE: DDL semantics — implicit commit of the open
         transaction (MySQL), exclusive table lock so live transactions'
         redo lands BEFORE the WAL barrier, fresh tablet, counters reset."""
-        if self.db is None:
-            raise NotImplementedError("TRUNCATE needs a Database")
         td = self.catalog.table_def(stmt.table)  # existence check
         if self._tx is not None:
             self._txsvc.commit(self._tx)  # DDL implies COMMIT
             self._tx = None
         tx = self._txsvc.begin()
         try:
-            if self.tenant is not None:
-                # blocks until every live writer of the table finishes,
-                # so their (group-committed) redo precedes the barrier
-                self.tenant.locks.acquire(stmt.table, "X", tx.tx_id,
-                                          timeout=30.0)
+            # blocks until every live writer of the table finishes,
+            # so their (group-committed) redo precedes the barrier
+            self.tenant.locks.acquire(stmt.table, "X", tx.tx_id,
+                                      timeout=30.0)
             lsn = self._txsvc._log_batch(
                 [{"op": "truncate", "table": stmt.table}])
             self._engine.truncate_table(stmt.table, wal_lsn=lsn)
             # MySQL: TRUNCATE resets AUTO_INCREMENT
-            if self.tenant is not None:
-                for cname in getattr(td, "auto_increment_cols", []):
-                    seq = f"__ai_{stmt.table}_{cname}"
-                    self.tenant.sequences.drop(seq)
-                    self.tenant.sequences.create(seq, start=1)
+            for cname in getattr(td, "auto_increment_cols", []):
+                seq = f"__ai_{stmt.table}_{cname}"
+                self.tenant.sequences.drop(seq)
+                self.tenant.sequences.create(seq, start=1)
         finally:
             self._txsvc.commit(tx)  # releases the lock
         self.catalog.invalidate(stmt.table)
@@ -982,8 +914,6 @@ class Session:
     def _lock_table(self, stmt: ast.LockTableStmt) -> Result:
         """LOCK TABLES t READ|WRITE / UNLOCK TABLES (≙ tablelock as a tx
         operation; MySQL-flavored syntax)."""
-        if self.tenant is None:
-            raise NotImplementedError("table locks need a Database")
         if stmt.unlock:
             if self._tx is not None:
                 self.tenant.locks.release_all(self._tx.tx_id)
@@ -1001,8 +931,6 @@ class Session:
     def _maybe_freeze(self, table: str):
         """Memstore-pressure freeze: active memtable beyond the configured
         row budget flushes to L0 (≙ freeze trigger + write throttling)."""
-        if self.db is None or self.tenant is None:
-            return
         ts = self._engine.tables.get(table)
         if ts is None:
             return
@@ -1030,12 +958,7 @@ class Session:
         works with the background thread off.  The structured rows come
         back directly (the same shape gv$workload_report serves);
         SHOW WORKLOAD REPORT renders the text tree."""
-        repo = (getattr(self.db, "workload", None)
-                if self.db is not None else None)
-        if repo is None:
-            raise NotImplementedError(
-                "ANALYZE WORKLOAD REPORT needs a Database")
-        rep = repo.build_report(stmt.from_id, stmt.to_id)
+        rep = self.db.workload.build_report(stmt.from_id, stmt.to_id)
         rows = rep["rows"]
         return Result(
             ["section", "item", "value", "detail"],
@@ -1051,9 +974,7 @@ class Session:
     def _show_workload_report(self) -> Result:
         """SHOW WORKLOAD REPORT: the last ANALYZE WORKLOAD REPORT's
         indented text tree, one row per line (SHOW TRACE's style)."""
-        repo = (getattr(self.db, "workload", None)
-                if self.db is not None else None)
-        rep = repo.last_report if repo is not None else None
+        rep = self.db.workload.last_report
         lines = rep["text"].split("\n") if rep else []
         return Result(
             ["report"],
@@ -1141,11 +1062,8 @@ class Session:
         """SHOW METRICS: the cluster-merged scrape rendered as
         Prometheus text exposition, one line per row (the same dump
         ``metrics.scrape(format="prom")`` serves over the wire)."""
-        vt = getattr(self.db, "virtual_tables", None) \
-            if self.db is not None else None
-        wire = vt.scrape_cluster() if vt is not None \
-            else qmetrics.wire_snapshot()
-        lines = qmetrics.prom_text(wire).splitlines()
+        lines = qmetrics.prom_text(
+            self.db.virtual_tables.scrape_cluster()).splitlines()
         return Result(
             ["metric"],
             {"metric": np.array(lines, dtype=object)},
@@ -1159,11 +1077,8 @@ class Session:
         backends without a profiler degrade to a note."""
         from oceanbase_tpu.server import profiler as qprofiler
 
-        store = (getattr(self.db, "device_profiles", None)
-                 if self.db is not None else None)
-        profiling_on = (self.db is not None
-                        and bool(self.db.config["enable_profiling"]))
-        if store is None or not profiling_on:
+        store = self.db.device_profiles
+        if store is None or not bool(self.db.config["enable_profiling"]):
             # no store / knob off: run the statement, skip the capture
             return self.execute_stmt(stmt.stmt, params)
         tctx = qtrace.current()
@@ -1186,8 +1101,7 @@ class Session:
     def _show_profile(self) -> Result:
         """SHOW PROFILE: this session's most recent PROFILE capture as
         per-kernel rows (total/avg time, share of device time)."""
-        store = (getattr(self.db, "device_profiles", None)
-                 if self.db is not None else None)
+        store = self.db.device_profiles
         tid = getattr(self, "_last_profile_trace_id", "")
         prof = store.get(tid) if (store is not None and tid) else None
         rows = prof.rows if prof is not None else []
@@ -1239,10 +1153,8 @@ class Session:
                 {}, {"operation": SqlType.string(),
                      "tags": SqlType.string()}, rowcount=len(rows))
 
-        reg = getattr(self.db, "trace_registry", None) \
-            if self.db is not None else None
         tid = self._last_trace_id
-        spans = reg.trace(tid) if (reg is not None and tid) else []
+        spans = self.db.trace_registry.trace(tid) if tid else []
         if not spans:
             return result([])
         by_parent: dict[int, list] = {}
@@ -1282,21 +1194,18 @@ class Session:
         later plan choice."""
         from oceanbase_tpu.sql.optimizer import CostModel
 
-        units = (getattr(self.db, "cost_units", None)
-                 if self.db is not None else None)
         corrections: dict = {}
-        tc = (getattr(self.db, "time_calibration", None)
-              if self.db is not None else None)
+        tc = self.db.time_calibration
         if tc is not None:
             for r in tc.rows():
                 if r["count"] >= 3 and r["correction"] > 0.0:
                     corrections[r["op"]] = min(
                         max(float(r["correction"]), 0.25), 8.0)
-        return CostModel(units=units, corrections=corrections)
+        return CostModel(units=self.db.cost_units, corrections=corrections)
 
     def _plan_select(self, stmt: ast.SelectStmt, params):
-        seqs = self.tenant.sequences if self.tenant is not None else None
-        binder = Binder(self.catalog, params=params or [], sequences=seqs,
+        binder = Binder(self.catalog, params=params or [],
+                        sequences=self.tenant.sequences,
                         sysvars=self.variables)
         binder.cost_model = self._cost_model()
         out = binder.bind_select(stmt)
@@ -1319,8 +1228,8 @@ class Session:
             self._last_cbo_choices = []
             return hit
         qmetrics.inc("plan_cache.misses")
-        seqs = self.tenant.sequences if self.tenant is not None else None
-        binder = Binder(self.catalog, params=params or [], sequences=seqs,
+        binder = Binder(self.catalog, params=params or [],
+                        sequences=self.tenant.sequences,
                         sysvars=self.variables)
         binder.cost_model = self._cost_model()
         out = binder.bind_select(stmt)
@@ -1347,8 +1256,7 @@ class Session:
             fp = ""
         nbytes = self._PLAN_ENTRY_OVERHEAD + \
             self._PLAN_BYTES_PER_CHAR * (len(str(key[0])) + len(fp))
-        limit = (int(self.db.config["plan_cache_mem_limit"])
-                 if self.db is not None else 512 << 20)
+        limit = int(self.db.config["plan_cache_mem_limit"])
         if nbytes > limit:
             return  # a single over-budget plan is not cacheable
         old = self._plan_cache_bytes.pop(key, None)
@@ -1369,7 +1277,7 @@ class Session:
         """Read a table at the right snapshot: an active transaction sees
         its own writes plus its begin-snapshot; otherwise latest committed
         (cached device relation)."""
-        if self.db is not None and self._tx is not None:
+        if self._tx is not None:
             return self.catalog.table_data_at(
                 name, self._tx.snapshot, self._tx.tx_id)
         return self.catalog.table_data(name)
@@ -1377,8 +1285,7 @@ class Session:
     def _execute_select(self, stmt: ast.SelectStmt, params) -> Result:
         from oceanbase_tpu.exec.plan import referenced_tables
 
-        use_cache = (self.db is not None
-                     and bool(self.db.config["enable_plan_cache"])
+        use_cache = (bool(self.db.config["enable_plan_cache"])
                      and self._ash_state.get("sql"))
         tb0 = time.monotonic()
         with qtrace.span("compile", cached=int(bool(use_cache))):
@@ -1404,18 +1311,14 @@ class Session:
             # evolution consulting measured stats).  Keyed by the
             # capacity-insensitive hash so the corrected plan keeps
             # matching its own history.
-            lhash = _lhash_of(plan) if self.db is not None else ""
-            if lhash and \
-                    getattr(self.db, "plan_choice", None) is not None \
+            lhash = _lhash_of(plan)
+            if self.db.plan_choice is not None \
                     and getattr(self, "_last_cbo_choices", None):
                 # bind-time CBO beliefs land in gv$plan_choice; the
                 # measured device seconds fold in below once the plan
                 # has run
                 self.db.plan_choice.record(lhash, self._last_cbo_choices)
-            feedback_on = (
-                self.db is not None
-                and getattr(self.db, "plan_feedback", None) is not None
-                and bool(self.db.config["enable_plan_feedback"]))
+            feedback_on = bool(self.db.config["enable_plan_feedback"])
             if feedback_on:
                 corr = self.db.plan_feedback.corrections(lhash)
                 if corr:
@@ -1427,9 +1330,7 @@ class Session:
             # deciding spill from work-area estimates BEFORE execution):
             # over-budget inputs never materialize whole on device
             big = self._spill_candidates(plan)
-            if self.db is not None and \
-                    getattr(self.db, "plan_monitor", None) is not None \
-                    and self.db.config["enable_sql_plan_monitor"]:
+            if self.db.config["enable_sql_plan_monitor"]:
                 # sampled ledger collection: every execution runs the
                 # SAME monitored executable (the variant is part of the
                 # compile key — alternating it would double the plan's
@@ -1481,8 +1382,7 @@ class Session:
         # cross-node compute pushdown (px/dtl.py): ship the partial plan
         # to the cluster's data nodes instead of scanning everything on
         # this node; an open transaction keeps the own-writes read path
-        dtl = (getattr(self.db, "dtl", None)
-               if self.db is not None and self._tx is None else None)
+        dtl = self.db.dtl if self._tx is None else None
         from oceanbase_tpu.server import admission as qadmission
 
         with qtrace.span("execute") as xsp:
@@ -1597,7 +1497,7 @@ class Session:
                     logical_hash=lhash, retries=attempt, path=path,
                     host_s=times.host_s, device_s=times.device_s,
                     pred_s=pred_s, time_q=time_q)
-                if getattr(self.db, "plan_choice", None) is not None:
+                if self.db.plan_choice is not None:
                     # validate the CHOICE, not just the plan: measured
                     # device seconds against the bind-time prediction
                     self.db.plan_choice.observe(lhash, times.device_s)
@@ -1607,32 +1507,28 @@ class Session:
                     # plans, so their postorder would not line up with
                     # future binds
                     self.db.plan_feedback.observe(lhash, monitor)
-            if self.db is not None and \
-                    getattr(self.db, "plan_history", None) is not None:
-                base = self.db.plan_history.baseline_s(lhash)
-                if base > 0.0 and \
-                        exec_elapsed > base * qtrace.SLOW_FACTOR:
-                    # far over its own baseline (a stall, not a slow
-                    # plan): the tree goes to the slow ring, which fast
-                    # statements cannot evict
-                    tctx = qtrace.current()
-                    if tctx is not None:
-                        tctx.slow = True
-                if attempt == 0 and not compile_flag() and \
-                        self.db.plan_history.record(
-                            lhash, exec_elapsed,
-                            float(self.db.config[
-                                "plan_regress_threshold"])):
-                    # plan-regression watchdog: latency baselines per
-                    # logical hash, independent of the plan-monitor knob
-                    # (a regression must be visible even when per-op
-                    # collection is off).  Samples that paid an XLA
-                    # compile or a CapacityOverflow retry replay are
-                    # excluded — they measure one-time plan work, not
-                    # the plan's steady-state latency, and would inflate
-                    # the frozen baseline (blinding the watchdog) or
-                    # spike the EWMA into a false regressed flag
-                    qmetrics.inc("plan.regressions")
+            base = self.db.plan_history.baseline_s(lhash)
+            if base > 0.0 and exec_elapsed > base * qtrace.SLOW_FACTOR:
+                # far over its own baseline (a stall, not a slow
+                # plan): the tree goes to the slow ring, which fast
+                # statements cannot evict
+                tctx = qtrace.current()
+                if tctx is not None:
+                    tctx.slow = True
+            if attempt == 0 and not compile_flag() and \
+                    self.db.plan_history.record(
+                        lhash, exec_elapsed,
+                        float(self.db.config["plan_regress_threshold"])):
+                # plan-regression watchdog: latency baselines per
+                # logical hash, independent of the plan-monitor knob
+                # (a regression must be visible even when per-op
+                # collection is off).  Samples that paid an XLA
+                # compile or a CapacityOverflow retry replay are
+                # excluded — they measure one-time plan work, not
+                # the plan's steady-state latency, and would inflate
+                # the frozen baseline (blinding the watchdog) or
+                # spike the EWMA into a false regressed flag
+                qmetrics.inc("plan.regressions")
         with qtrace.span("materialize") as msp:
             return self._materialize(rel, outputs, msp.tags)
 
@@ -1747,12 +1643,12 @@ class Session:
         cache = getattr(self.catalog, "_ann_cache", None)
         if cache is None:
             cache = self.catalog._ann_cache = {}
-        ts = self._engine.tables.get(table) if self.db is not None else None
+        ts = self._engine.tables.get(table)
         if ts is not None:
             ver = ts.tablet.data_version
         else:
-            # catalog-only: set_data replaces the Relation object, so its
-            # identity is the data version
+            # no tablet (external, transient): set_data replaces the
+            # Relation object, so its identity is the data version
             ver = id(rel)
         key = (table, col, metric)
         hit = cache.get(key)
@@ -1780,7 +1676,7 @@ class Session:
         idx = IvfFlatIndex(vecs, metric=metric) \
             if approx and len(vecs) >= 4096 else jnp.asarray(vecs)
         # the cache entry holds the source Relation too: identity-keyed
-        # versions (catalog-only tables) must keep the object alive or a
+        # versions (tables without a tablet) must keep the object alive or a
         # recycled id would serve a stale index
         cache[key] = (ver, idx, rel)
         return idx
@@ -1799,7 +1695,7 @@ class Session:
         candidate set.  The plan re-applies its full filter, so the
         substitution never changes results — only how few rows reach the
         device.  -> {table: AccessChoice} for EXPLAIN."""
-        if self.db is None or not tables:
+        if not tables:
             return {}
         if not bool(self.variables.get("enable_index_access", 1)):
             return {}
@@ -1862,10 +1758,8 @@ class Session:
         execution (≙ the /*+ no_parallel */ hint)."""
         if "px_dop" in self.variables:
             dop = int(self.variables["px_dop"] or 0)
-        elif self.db is not None:
-            dop = int(self.db.config["px_default_dop"])
         else:
-            dop = 0
+            dop = int(self.db.config["px_default_dop"])
         if dop <= 1:
             return 1
         import jax
@@ -1881,15 +1775,14 @@ class Session:
             execute_plan_distributed,
         )
 
-        if self.tenant is not None:
-            if not self.tenant.px_admission.acquire(n=dop):
-                # admission denied: run serial (≙ px downgrade) — but
-                # VISIBLY: counted, span-tagged, shown by EXPLAIN
-                # ANALYZE (the silent downgrade was unobservable)
-                qmetrics.inc("admission.px_downgrades",
-                             tenant=getattr(self.tenant, "name", "sys"))
-                self._last_px_downgrade = True
-                return None
+        if not self.tenant.px_admission.acquire(n=dop):
+            # admission denied: run serial (≙ px downgrade) — but
+            # VISIBLY: counted, span-tagged, shown by EXPLAIN
+            # ANALYZE (the silent downgrade was unobservable)
+            qmetrics.inc("admission.px_downgrades",
+                         tenant=self.tenant.name)
+            self._last_px_downgrade = True
+            return None
         # estimates over ANALYZEd tables may bound a shard's budgets; a
         # guess may not
         analyzed = all(
@@ -1904,8 +1797,7 @@ class Session:
         except (NotDistributable, NotImplementedError):
             return None
         finally:
-            if self.tenant is not None:
-                self.tenant.px_admission.release(n=dop)
+            self.tenant.px_admission.release(n=dop)
         if monitor is not None:
             from oceanbase_tpu.exec.plan import q_error as _qe
 
@@ -1949,8 +1841,6 @@ class Session:
         force_largest (the CapacityOverflow backstop) the largest table
         qualifies even under budget — the plan overflowed regardless, so
         stream it."""
-        if self.db is None:
-            return set()
         if not bool(self.db.config["enable_sql_spill"]):
             return set()
         from oceanbase_tpu.exec.plan import referenced_tables
@@ -1973,7 +1863,7 @@ class Session:
         for t in refs:
             ts = self._engine.tables.get(t)
             if ts is None:
-                # catalog-only relation (load_numpy/transient): spill can
+                # no tablet (external / transient relation): spill can
                 # still stream it chunk-wise to bound intermediates
                 if self.catalog.has_table(t):
                     try:
@@ -2004,12 +1894,6 @@ class Session:
             big = {max(est, key=est.get)}
         return big
 
-    def _config(self):
-        """The configuration this session's statements read: the
-        tenant's overlay (SET GLOBAL) over the cluster's (ALTER SYSTEM)."""
-        return self.tenant.config if self.tenant is not None \
-            else self.db.config
-
     def _work_area(self, plan):
         """-> fits(table): the rows of ``table`` the work area holds.  The
         budget is ``ob_sql_work_area_percentage`` of the device's memory,
@@ -2020,7 +1904,8 @@ class Session:
         from oceanbase_tpu.exec.plan import scan_columns
         from oceanbase_tpu.server.config import work_area_bytes
 
-        cfg = self._config()
+        # the tenant's overlay (SET GLOBAL) over the cluster's (ALTER SYSTEM)
+        cfg = self.tenant.config
         rows = int(cfg["sql_work_area_rows"])
         if rows:
             return lambda table: rows
@@ -2082,9 +1967,7 @@ class Session:
         # device-resident (non-streamed) subtrees may carry IndexProbe
         # nodes; their sorted sidecars ride in the device-table dict
         self._prepare_index_probes(plan, device_tables)
-        root = (self.db.root if self.db is not None and self.db.root
-                else None)
-        sdir = os.path.join(root or "/tmp/obtpu", "tmpfile",
+        sdir = os.path.join(self.db.root or "/tmp/obtpu", "tmpfile",
                             f"q{uuid.uuid4().hex[:10]}")
         t0 = time.time()       # record timestamp (wall)
         m0 = time.monotonic()  # elapsed source (step-proof)
@@ -2095,8 +1978,8 @@ class Session:
                 # area holds of a streamed table
                 max(min(map(self._work_area(plan), big)), 1),
                 device_tables, types_by_table, big,
-                disk_budget=getattr(self.tenant, "diskmgr", None),
-                faults=getattr(self.db, "faults", None),
+                disk_budget=self.tenant.diskmgr,
+                faults=self.db.faults,
                 label=(self._ash_state.get("sql", "")[:80]
                        or f"session {self.session_id}"))
         except (NotDistributable, NotImplementedError):
@@ -2115,10 +1998,8 @@ class Session:
             "kind": stats.kind, "runs": stats.runs,
             "bytes": stats.bytes, "spilled_rows": stats.spilled_rows,
             "batches": stats.batches, "elapsed_s": elapsed})
-        if getattr(self.db, "wait_events", None) is not None:
-            self.db.wait_events.add("spill io", elapsed)
-        if getattr(self.db, "plan_monitor", None) is not None and \
-                self.db.config["enable_sql_plan_monitor"]:
+        self.db.wait_events.add("spill io", elapsed)
+        if self.db.config["enable_sql_plan_monitor"]:
             # the spill tier streams batches, so only the ROOT operator's
             # output cardinality is observable whole — still enough for
             # a q-error ledger row (plus the spill cost) on this path
@@ -2154,7 +2035,7 @@ class Session:
         return self._materialize_host(arrays, valids, dtypes, outputs)
 
     def _catalog_provider(self, name: str):
-        """Chunk provider over a catalog-only relation (load_numpy /
+        """Chunk provider over a relation without a tablet (external /
         transient): decode to host once, stream in slices so plan
         intermediates stay inside the work-area budget."""
         from oceanbase_tpu.exec.granule import numpy_chunk_provider
@@ -2236,27 +2117,24 @@ class Session:
 
         times = qplan.exec_times()
         pred_s = time_q = 0.0
-        units = (getattr(self.db, "cost_units", None)
-                 if self.db is not None else None)
+        units = self.db.cost_units
         if units is not None and times.device_s > 0.0 and \
                 times.calls > 0:
             pred_s = qcalibrate.predict_seconds(
                 units, times.flops, times.bytes, times.calls)
             time_q = qcalibrate.time_q_error(pred_s, times.device_s)
-            tc = (getattr(self.db, "time_calibration", None)
-                  if self.db is not None else None)
-            if tc is not None:
-                tc.observe(type(plan).__name__, pred_s, times.device_s,
-                           host_s=times.host_s)
+            if self.db.time_calibration is not None:
+                self.db.time_calibration.observe(
+                    type(plan).__name__, pred_s, times.device_s,
+                    host_s=times.host_s)
         return times, pred_s, time_q
 
     def _explain(self, stmt, params, analyze: bool = False) -> Result:
         if not isinstance(stmt, ast.SelectStmt):
             raise NotImplementedError("EXPLAIN supports SELECT")
         # planning for EXPLAIN must not consume sequence values
-        seqs = self.tenant.sequences if self.tenant is not None else None
         binder = Binder(self.catalog, params=params or [],
-                        sequences=_PeekSequences(seqs) if seqs else None,
+                        sequences=_PeekSequences(self.tenant.sequences),
                         sysvars=self.variables)
         plan, outputs, est = binder.bind_select(stmt)
         row_counts = None
@@ -2330,21 +2208,17 @@ class Session:
                         + (f"tq={time_q:.2f}" if time_q > 0.0
                            else "tq=uncalibrated")
                         + f" worst_phase={wname}:{wsec:.3e}s]")
-                if self.db is not None and \
-                        getattr(self.db, "plan_monitor", None) is not None:
-                    from oceanbase_tpu.exec.plan import (
-                        logical_hash as _lh,
-                    )
+                from oceanbase_tpu.exec.plan import logical_hash as _lh
 
-                    self.db.plan_monitor.record(
-                        plan.fingerprint()[:64], monitor,
-                        time.monotonic() - an0,
-                        logical_hash=_lh(plan), retries=attempt,
-                        path="serial",
-                        host_s=times.host_s, device_s=times.device_s,
-                        pred_s=pred_s, time_q=time_q)
+                self.db.plan_monitor.record(
+                    plan.fingerprint()[:64], monitor,
+                    time.monotonic() - an0,
+                    logical_hash=_lh(plan), retries=attempt,
+                    path="serial",
+                    host_s=times.host_s, device_s=times.device_s,
+                    pred_s=pred_s, time_q=time_q)
         text = format_plan(plan, row_counts=row_counts) + spill_line
-        if analyze and self.tenant is not None and self._px_dop() > 1:
+        if analyze and self._px_dop() > 1:
             # surface the px_admission verdict the statement would get
             # RIGHT NOW: a denied probe means concurrent PX statements
             # hold the tenant quota and this plan runs serial
@@ -2363,24 +2237,23 @@ class Session:
                          f"q={worst['q_error']:.2f}")
         # access-path annotations (≙ the 'Outputs & filters ... access'
         # section of the reference's EXPLAIN)
-        if self.db is not None:
-            from oceanbase_tpu.sql import access_path as ap
+        from oceanbase_tpu.sql import access_path as ap
 
-            try:
-                by_table = ap.scan_filter_ranges(plan, self._engine)
-                for t in sorted(by_table):
-                    if t not in self._engine.tables:
-                        continue
-                    choice = ap.choose_path(self._engine, t, by_table[t])
-                    if choice is None:
-                        continue
-                    via = ("PRIMARY" if choice.kind == "primary"
-                           else f"INDEX {choice.index_name}")
-                    text += (f"\naccess: {t} via {via} "
-                             f"(~{choice.est_rows} rows, "
-                             f"cols {sorted(choice.prune)})")
-            except Exception:
-                pass
+        try:
+            by_table = ap.scan_filter_ranges(plan, self._engine)
+            for t in sorted(by_table):
+                if t not in self._engine.tables:
+                    continue
+                choice = ap.choose_path(self._engine, t, by_table[t])
+                if choice is None:
+                    continue
+                via = ("PRIMARY" if choice.kind == "primary"
+                       else f"INDEX {choice.index_name}")
+                text += (f"\naccess: {t} via {via} "
+                         f"(~{choice.est_rows} rows, "
+                         f"cols {sorted(choice.prune)})")
+        except Exception:
+            pass
         lines = np.array(text.splitlines(), dtype=object)
         return Result(["plan"], {"plan": lines}, {},
                       {"plan": SqlType.string()}, rowcount=len(lines),
@@ -2401,11 +2274,6 @@ class Session:
                         tablegroup=stmt.tablegroup,
                         column_groups=stmt.column_groups,
                         auto_increment_cols=auto_cols)
-        if getattr(stmt, "indexes", None) and self.db is None:
-            # capability check BEFORE create_table: a failure must not
-            # leave a half-created table behind
-            raise NotImplementedError(
-                "secondary indexes need the storage engine")
         existed = stmt.if_not_exists and self.catalog.has_table(stmt.name)
         self.catalog.create_table(tdef, if_not_exists=stmt.if_not_exists)
         if existed:
@@ -2420,32 +2288,13 @@ class Session:
         # AUTO_INCREMENT backs onto a hidden persisted sequence (≙ table
         # auto-inc service riding the sequence allocator); the column list
         # itself persists with the table definition
-        if self.tenant is not None:
-            for cname in auto_cols:
-                seq = f"__ai_{stmt.name}_{cname}"
-                try:
-                    self.tenant.sequences.create(seq, start=1)
-                except ValueError:
-                    pass  # already exists (IF NOT EXISTS re-run)
-        if self.db is not None:
-            return _ok()  # the engine serves empty snapshots itself
-        # seed an all-dead single-row relation (static shapes need cap >= 1)
-        arrays, valids = {}, {}
-        for c in stmt.columns:
-            if c.dtype.is_string:
-                arrays[c.name] = np.array([""], dtype=object)
-            else:
-                arrays[c.name] = np.zeros(1, dtype=c.dtype.np_dtype)
-            valids[c.name] = np.array([False])
-        rel = from_numpy(arrays, types={c.name: c.dtype for c in stmt.columns},
-                         valids=valids)
-        rel = Relation(columns=rel.columns,
-                       mask=np.zeros(1, dtype=bool))
-        import jax.numpy as jnp
-
-        rel = Relation(columns=rel.columns, mask=jnp.zeros(1, dtype=jnp.bool_))
-        self.catalog.set_data(stmt.name, rel)
-        return _ok()
+        for cname in auto_cols:
+            seq = f"__ai_{stmt.name}_{cname}"
+            try:
+                self.tenant.sequences.create(seq, start=1)
+            except ValueError:
+                pass  # already exists (IF NOT EXISTS re-run)
+        return _ok()  # the engine serves empty snapshots itself
 
     def _create_index(self, stmt: ast.CreateIndexStmt) -> Result:
         """CREATE [UNIQUE] INDEX: engine-side index table + backfill
@@ -2471,8 +2320,7 @@ class Session:
                     "metric": str(stmt.options.get("metric", "l2")),
                     "options": dict(stmt.options)}
             td.aux_indexes[stmt.name] = spec
-            if self.db is not None and \
-                    stmt.table in self._engine.tables:
+            if stmt.table in self._engine.tables:
                 # persist through the slog (+ the multi-node DDL stream)
                 self._engine._log_meta({"op": "aux_index",
                                         "table": stmt.table,
@@ -2483,21 +2331,6 @@ class Session:
             if stmt.if_not_exists:
                 return _ok()
             raise ValueError(f"index {stmt.name} exists on {stmt.table}")
-        if self.db is None:
-            # catalog-only: register metadata so the optimizer can
-            # choose the index-probe access path — the sorted sidecar
-            # builds lazily from the in-memory relation at execution
-            # (no engine index table to backfill)
-            from oceanbase_tpu.catalog import IndexDef
-
-            for c in stmt.columns:
-                td.column(c)  # existence check
-            td.indexes.append(IndexDef(
-                name=stmt.name, table=stmt.table,
-                columns=list(stmt.columns), unique=stmt.unique,
-                storage_table=""))
-            self.catalog.schema_version += 1
-            return _ok()
         if self._tx is not None and stmt.table in self._tx.participants:
             raise RuntimeError(
                 "CREATE INDEX on a table already written by the open "
@@ -2552,22 +2385,10 @@ class Session:
             if cache is not None:
                 for k in [k for k in cache if k[0] == stmt.table]:
                     cache.pop(k, None)
-            if self.db is not None and stmt.table in self._engine.tables:
+            if stmt.table in self._engine.tables:
                 self._engine._log_meta({"op": "drop_aux_index",
                                         "table": stmt.table,
                                         "name": stmt.name})
-            self.catalog.schema_version += 1
-            return _ok()
-        if self.db is None:
-            # catalog-only metadata index (see _create_index)
-            before = len(td.indexes)
-            td.indexes = [ix for ix in td.indexes if ix.name != stmt.name]
-            if len(td.indexes) == before and not stmt.if_exists:
-                raise KeyError(
-                    f"index {stmt.name} not found on {stmt.table}")
-            cache = getattr(self.catalog, "_probe_cache", None)
-            if cache is not None:
-                cache.pop((stmt.table, stmt.name), None)
             self.catalog.schema_version += 1
             return _ok()
         try:
@@ -2626,8 +2447,6 @@ class Session:
     # XA transactions (externally-coordinated 2PC; ≙ ObXAService)
     # ------------------------------------------------------------------
     def _xa_store(self) -> dict:
-        if self.db is None:
-            raise NotImplementedError("XA needs a Database")
         # the store lives on the TENANT's TransService: xids, tx ids,
         # WALs, and lock tables are all tenant-scoped — a db-global
         # store would let another tenant's service commit this tx
@@ -2685,20 +2504,16 @@ class Session:
     # IF/WHILE over the shared expression engine, SQL via the session)
     # ------------------------------------------------------------------
     def _proc_store(self) -> dict:
-        if self.db is not None:
-            if not hasattr(self.db, "procedures"):
-                self.db.procedures = {}
-                self._load_procs()
-            return self.db.procedures
-        if not hasattr(self, "_procs"):
-            self._procs = {}
-        return self._procs
+        if self.db.procedures is None:
+            self.db.procedures = {}
+            self._load_procs()
+        return self.db.procedures
 
     def _procs_path(self):
         import os
 
         return (os.path.join(self.db.root, "procedures.json")
-                if self.db is not None and self.db.root else None)
+                if self.db.root else None)
 
     def _load_procs(self):
         import json
@@ -2902,7 +2717,7 @@ class Session:
         tx = self._txsvc.begin()
         return tx, tx
 
-    def _insert_tx(self, stmt: ast.InsertStmt, params) -> Result:
+    def _insert(self, stmt: ast.InsertStmt, params) -> Result:
         td = self.catalog.table_def(stmt.table)
         cols = stmt.columns or td.column_names
         rows_values: list[dict] = []
@@ -2915,11 +2730,9 @@ class Session:
             bsp.tags["rows"] = len(rows_values)
         tablet = self._engine.tables[stmt.table].tablet
         replace = getattr(stmt, "replace", False)
-        kv = None
-        if replace and self.tenant is not None:
-            from oceanbase_tpu.kv import KvTable
+        from oceanbase_tpu.kv import KvTable
 
-            kv = KvTable(self.tenant, stmt.table)
+        kv = KvTable(self.tenant, stmt.table) if replace else None
 
         def op(tx):
             with qtrace.span("dml.write", table=stmt.table,
@@ -2939,7 +2752,7 @@ class Session:
         """``dml.bind``'s loop: literal evaluation, coercion to the
         column's storage value, defaults, auto-increment."""
         if sub is None:
-            seqs = self.tenant.sequences if self.tenant is not None else None
+            seqs = self.tenant.sequences
             for row in stmt.rows:
                 if len(row) != len(cols):
                     raise ValueError("INSERT arity mismatch")
@@ -2989,8 +2802,7 @@ class Session:
                     # own-tx writes (incl. earlier rows of this statement)
                     # count as existing
                     existing = kv.get(key, snapshot=tx.snapshot,
-                                      tx_id=tx.tx_id) \
-                        if kv is not None else None
+                                      tx_id=tx.tx_id)
                     kind = "update" if existing is not None else "insert"
                 self._txsvc.write(tx, table, tablet, key, kind, values,
                                   stats)
@@ -3002,8 +2814,7 @@ class Session:
     # insert/update/delete DFOs under ONE transaction)
     # ------------------------------------------------------------------
     def _pdml_eligible(self, n_rows: int) -> bool:
-        return (self.tenant is not None and self.db is not None
-                and int(self.db.config["pdml_dop"]) > 1
+        return (int(self.db.config["pdml_dop"]) > 1
                 and n_rows >= int(self.db.config["pdml_min_rows"]))
 
     def _pdml_write(self, tx, table: str, tablet, keyed: list,
@@ -3056,8 +2867,6 @@ class Session:
             raise errs[0]
 
     def _fill_auto_increment(self, td, values: dict):
-        if self.tenant is None:
-            return
         for cname in getattr(td, "auto_increment_cols", []):
             seq = f"__ai_{td.name}_{cname}"
             if seq not in self.tenant.sequences._defs:
@@ -3139,17 +2948,17 @@ class Session:
             out.append(values)
         return out
 
-    def _update_tx(self, stmt: ast.UpdateStmt, params) -> Result:
+    def _update(self, stmt: ast.UpdateStmt, params) -> Result:
         td = self.catalog.table_def(stmt.table)
         tx, tx_hint = self._stmt_tx()
         try:
-            return self._update_tx_body(stmt, params, td, tx, tx_hint)
+            return self._update_body(stmt, params, td, tx, tx_hint)
         except Exception:
             if tx_hint is not None and tx_hint.state.value == "active":
                 self._txsvc.rollback(tx_hint)
             raise
 
-    def _update_tx_body(self, stmt, params, td, tx, tx_hint) -> Result:
+    def _update_body(self, stmt, params, td, tx, tx_hint) -> Result:
         from oceanbase_tpu.expr.compile import cast_column, eval_expr
         import numpy as _np
 
@@ -3242,16 +3051,16 @@ class Session:
         finally:
             stats.book(tags)
 
-    def _delete_tx(self, stmt: ast.DeleteStmt, params) -> Result:
+    def _delete(self, stmt: ast.DeleteStmt, params) -> Result:
         tx, tx_hint = self._stmt_tx()
         try:
-            return self._delete_tx_body(stmt, params, tx, tx_hint)
+            return self._delete_body(stmt, params, tx, tx_hint)
         except Exception:
             if tx_hint is not None and tx_hint.state.value == "active":
                 self._txsvc.rollback(tx_hint)
             raise
 
-    def _delete_tx_body(self, stmt, params, tx, tx_hint) -> Result:
+    def _delete_body(self, stmt, params, tx, tx_hint) -> Result:
         with qtrace.span("dml.match", table=stmt.table) as msp:
             rel, mask, tablet, _b, _s, full_table = self._matching_rows(
                 stmt.table, stmt.where, params, tx)
@@ -3283,14 +3092,9 @@ class Session:
         self._maybe_freeze(stmt.table)
         return _ok(rowcount=n_del)
 
-    # ------------------------------------------------------------------
-    # legacy host-side DML (catalog without a storage engine)
-    # ------------------------------------------------------------------
     def _create_table_as(self, stmt: ast.CreateTableStmt) -> Result:
         """CREATE TABLE AS SELECT: schema inferred from the result set,
         rows direct-loaded (≙ CTAS via the direct-load path)."""
-        if self.db is None:
-            raise NotImplementedError("CTAS needs a Database")
         res = self._execute_select(stmt.as_select, None)
         cols = [ColumnDef(name, res.dtypes.get(name, SqlType.int_()))
                 for name in res.names]
@@ -3316,168 +3120,7 @@ class Session:
         tdef.row_count = res.rowcount
         return _ok(rowcount=res.rowcount)
 
-    def _insert(self, stmt: ast.InsertStmt, params) -> Result:
-        if self.db is not None:
-            return self._insert_tx(stmt, params)
-        td = self.catalog.table_def(stmt.table)
-        cols = stmt.columns or td.column_names
-        if stmt.rows is not None:
-            new = {c: [] for c in cols}
-            with qtrace.span("dml.bind", table=stmt.table,
-                             rows=len(stmt.rows)):
-                self._legacy_insert_rows(stmt, params, td, cols, new)
-            n_new = len(stmt.rows)
-        else:
-            sub = self._execute_select(stmt.select, params)
-            new = {c: list(sub.arrays[sn]) for c, sn in zip(cols, sub.names)}
-            n_new = sub.rowcount
-        return self._append_rows(td, cols, new, n_new)
-
-    @staticmethod
-    def _legacy_insert_rows(stmt, params, td, cols, new: dict):
-        for row in stmt.rows:
-            if len(row) != len(cols):
-                raise ValueError("INSERT arity mismatch")
-            for c, e in zip(cols, row):
-                v, t = literal_value(_as_literal(e, params))
-                cdef = td.column(c)
-                if v is not None and cdef.dtype.kind == TypeKind.DECIMAL:
-                    # rescale the parsed fixed-point value to the
-                    # column's declared scale
-                    if t.kind == TypeKind.DECIMAL:
-                        v = _rescale(v, t.scale, cdef.dtype.scale)
-                    elif isinstance(v, int):
-                        v = v * _POW10[cdef.dtype.scale]
-                    elif isinstance(v, float):
-                        v = round(v * _POW10[cdef.dtype.scale])
-                new[c].append(v)
-
-    def _append_rows(self, td: TableDef, cols, new, n_new) -> Result:
-        # host-side append: decode existing live rows, concat, re-encode.
-        # (the storage engine replaces this with memtable writes)
-        old = self.catalog.table_data(td.name)
-        raw = to_numpy(old)
-        arrays, valids = {}, {}
-        for c in td.columns:
-            oldv = raw.get(c.name)
-            oldvalid = raw.get("__valid__" + c.name)
-            if oldv is None:
-                oldv = np.zeros(0, dtype=c.dtype.np_dtype)
-            if oldvalid is None:
-                oldvalid = np.ones(len(oldv), dtype=bool)
-            if c.name in cols:
-                newv = new[c.name]
-                newvalid = np.array([x is not None for x in newv])
-                if c.dtype.is_string:
-                    vals = np.array([x if x is not None else ""
-                                     for x in newv], dtype=object)
-                    arrays[c.name] = np.concatenate(
-                        [oldv.astype(object), vals])
-                else:
-                    conv = []
-                    for x in newv:
-                        if x is None:
-                            conv.append(0)
-                        elif c.dtype.kind == TypeKind.DECIMAL and \
-                                isinstance(x, int):
-                            conv.append(x)
-                        elif c.dtype.kind == TypeKind.DATE and \
-                                isinstance(x, str):
-                            from oceanbase_tpu.datatypes import date_to_days
-
-                            conv.append(date_to_days(x))
-                        else:
-                            conv.append(x)
-                    arrays[c.name] = np.concatenate(
-                        [oldv, np.asarray(conv, dtype=c.dtype.np_dtype)])
-            else:
-                newvalid = np.zeros(n_new, dtype=bool)
-                pad = (np.array([""] * n_new, dtype=object)
-                       if c.dtype.is_string
-                       else np.zeros(n_new, dtype=c.dtype.np_dtype))
-                arrays[c.name] = np.concatenate(
-                    [oldv.astype(object) if c.dtype.is_string else oldv, pad])
-            valids[c.name] = np.concatenate([oldvalid, newvalid])
-        types = {c.name: c.dtype for c in td.columns}
-        all_valid = {k: (None if v.all() else v) for k, v in valids.items()}
-        rel = from_numpy(arrays, types=types,
-                         valids={k: v for k, v in all_valid.items()
-                                 if v is not None})
-        self.catalog.set_data(td.name, rel)
-        td.row_count = rel.capacity
-        return _ok(rowcount=n_new)
-
-    def _update(self, stmt: ast.UpdateStmt, params) -> Result:
-        if self.db is not None:
-            return self._update_tx(stmt, params)
-        # host-side fallback (no storage engine attached)
-        td = self.catalog.table_def(stmt.table)
-        rel = self.catalog.table_data(stmt.table)
-        binder = Binder(self.catalog, params=params or [])
-        from oceanbase_tpu.sql.binder import Scope
-
-        scope = Scope()
-        rename = {}
-        for c in td.columns:
-            scope.add(c.name, c.name, alias=stmt.table)
-        from oceanbase_tpu.expr.compile import eval_expr, eval_predicate
-
-        mask = rel.mask_or_true()
-        if stmt.where is not None:
-            pred = binder.bind_expr(stmt.where, scope)
-            mask_upd = eval_predicate(pred, rel)
-        else:
-            mask_upd = mask
-        import jax.numpy as jnp
-
-        new_cols = dict(rel.columns)
-        n_upd = int(jnp.sum(mask_upd & mask))
-        for cname, e in stmt.assignments:
-            b = binder.bind_expr(e, scope)
-            newc = eval_expr(b, rel)
-            oldc = rel.columns[cname]
-            from oceanbase_tpu.expr.compile import cast_column
-
-            newc = cast_column(newc, oldc.dtype)
-            data = jnp.where(mask_upd, newc.data, oldc.data)
-            valid = None
-            if oldc.valid is not None or newc.valid is not None:
-                ov = oldc.valid_or_true()
-                nv = newc.valid_or_true()
-                valid = jnp.where(mask_upd, nv, ov)
-            new_cols[cname] = type(oldc)(data, valid, oldc.dtype, oldc.sdict)
-        self.catalog.set_data(stmt.table,
-                              Relation(columns=new_cols, mask=rel.mask))
-        return _ok(rowcount=n_upd)
-
-    def _delete(self, stmt: ast.DeleteStmt, params) -> Result:
-        if self.db is not None:
-            return self._delete_tx(stmt, params)
-        td = self.catalog.table_def(stmt.table)
-        rel = self.catalog.table_data(stmt.table)
-        binder = Binder(self.catalog, params=params or [])
-        from oceanbase_tpu.sql.binder import Scope
-
-        scope = Scope()
-        for c in td.columns:
-            scope.add(c.name, c.name, alias=stmt.table)
-        from oceanbase_tpu.expr.compile import eval_predicate
-
-        mask = rel.mask_or_true()
-        if stmt.where is not None:
-            pred = binder.bind_expr(stmt.where, scope)
-            kill = eval_predicate(pred, rel)
-        else:
-            kill = mask
-        import jax.numpy as jnp
-
-        n_del = int(jnp.sum(kill & mask))
-        self.catalog.set_data(stmt.table, rel.with_mask(mask & ~kill))
-        return _ok(rowcount=n_del)
-
     def _tx_control(self, op: str) -> Result:
-        if self.db is None:
-            return _ok()
         if self._tx is not None and getattr(self._tx, "xid", None):
             # an XA branch only ends through XA verbs (≙ XAER_RMFAIL):
             # committing it here would strand the xid in the store

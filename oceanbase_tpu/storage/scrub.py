@@ -285,10 +285,10 @@ class Scrubber:
             return []  # quiet: nothing changed since the last vote
         snap = local["snapshot"]
         votes: dict[int, dict] = {node.node_id: local["tables"]}
-        health = getattr(node, "health", None)
+        health = node.health
         for pid in sorted(peers):
             qadmission.checkpoint()  # KILL/deadline between peer votes
-            if health is not None and health.state(pid) != "up":
+            if health.state(pid) != "up":
                 continue
             try:
                 r = peers[pid].call("scrub.checksum", snapshot=snap,
@@ -378,12 +378,12 @@ class Scrubber:
         from oceanbase_tpu.storage.engine import load_manifest
 
         node = self.node
-        health = getattr(node, "health", None)
+        health = node.health
         t0 = time.monotonic()
         last_err: Exception | None = None
         for pid in sorted(node.peers):
             qadmission.checkpoint()  # KILL/deadline between candidates
-            if health is not None and health.state(pid) != "up":
+            if health.state(pid) != "up":
                 continue
             cli = node.peers[pid]
             staging = os.path.join(node.root, ".scrub_tmp")

@@ -29,7 +29,7 @@ from oceanbase_tpu.bench.tpch import (  # noqa: E402
 from oceanbase_tpu.bench.tpch_queries import QUERIES  # noqa: E402
 from oceanbase_tpu.exec.plan import exec_times  # noqa: E402
 from oceanbase_tpu.server import metrics as qmetrics  # noqa: E402
-from oceanbase_tpu.sql import Session  # noqa: E402
+from oceanbase_tpu.server import Database  # noqa: E402
 
 SF = float(os.environ.get("PARITY_SF", "1.0"))
 OUT = os.path.join(os.path.dirname(__file__), "..",
@@ -45,7 +45,7 @@ def main():
           f"(lineitem={len(tables['lineitem']['l_orderkey'])} rows)",
           flush=True)
 
-    sess = Session()
+    sess = Database().session()
     t0 = time.monotonic()
     for name, arrays in tables.items():
         sess.catalog.load_numpy(

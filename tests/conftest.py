@@ -40,6 +40,35 @@ def rng():
     return np.random.default_rng(42)
 
 
+def _sessions():
+    """The served path for a test that wants "a session": each call of
+    the yielded ``new_session()`` boots an in-memory ``Database`` and
+    hands out its ``session()``; all are closed at teardown."""
+    from oceanbase_tpu.server.database import Database
+
+    opened = []
+
+    def new_session():
+        db = Database()
+        opened.append((db.session(), db))
+        return opened[-1][0]
+
+    yield new_session
+    for s, db in opened:
+        s.close()
+        db.close()
+
+
+@pytest.fixture()
+def new_session():
+    yield from _sessions()
+
+
+@pytest.fixture(scope="module")
+def new_module_session():
+    yield from _sessions()
+
+
 def rewrite_outer_join_for_old_sqlite(sql: str, left: str, right: str,
                                       left_cols, right_cols) -> str:
     """RIGHT/FULL OUTER JOIN oracle queries for pre-3.39 sqlite: right

@@ -176,16 +176,14 @@ def test_tx_snapshot_isolation_through_spill_tier(tmp_path):
     db.close()
 
 
-def test_nested_scalar_subquery_filter_not_dropped():
+def test_nested_scalar_subquery_filter_not_dropped(new_session):
     """TPC-H Q20 shape: a correlated scalar comparison nested inside an
     IN-subquery must filter the SAME rows the sibling IN predicate
     filters — the decorrelation used to drop the comparison entirely
     (SF1 parity Q20 off-by-one)."""
     import numpy as np
 
-    from oceanbase_tpu.sql import Session
-
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("supplier", {
         "s_suppkey": np.array([1, 2]),
         "s_name": np.array(["sup1", "sup2"], dtype=object)},

@@ -23,7 +23,6 @@ from oceanbase_tpu.exec.plan import (
 )
 from oceanbase_tpu.expr import ir
 from oceanbase_tpu.server.database import Database
-from oceanbase_tpu.sql import Session
 from oceanbase_tpu.sql.parser import parse_sql
 
 
@@ -42,14 +41,14 @@ def _walk(plan):
         stack.extend(n.children())
 
 
-def _mk_indexed(seed=3, n_big=4000, n_small=60):
+def _mk_indexed(new_session, seed=3, n_big=4000, n_small=60):
     """big (indexed on k, ~8 rows/key) joined by a tiny filtered side:
     the shape where the index probe beats sorting big for a hash join."""
     rng = np.random.default_rng(seed)
     k = rng.integers(0, 500, n_big).astype(np.int64)
     v = rng.integers(0, 1000, n_big).astype(np.int64)
     tag = rng.integers(0, 100, 500).astype(np.int64)
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("big", {
         "id": np.arange(n_big, dtype=np.int64), "k": k, "v": v})
     s.catalog.load_numpy("small", {
@@ -72,10 +71,10 @@ def _oracle_sum(k, v, tag):
 # ---------------------------------------------------------------------------
 
 
-def test_index_probe_chosen_and_correct():
+def test_index_probe_chosen_and_correct(new_session):
     """The CBO picks the index probe for a small-probe/big-base join,
     and the answer matches both a host oracle and the no-index plan."""
-    s, q, k, v, tag = _mk_indexed()
+    s, q, k, v, tag = _mk_indexed(new_session)
     want = _oracle_sum(k, v, tag)
     txt = "\n".join(str(r) for r in s.execute("explain " + q).rows())
     assert "IndexProbe" in txt, txt
@@ -87,10 +86,10 @@ def test_index_probe_chosen_and_correct():
     assert s.execute(q).rows() == [(want,)]
 
 
-def test_index_probe_poison_parity(poison):
+def test_index_probe_poison_parity(poison, new_session):
     """IndexProbe is a data-reading operator: masked-dead lanes in the
     base, the probe side, or the sidecar must not influence results."""
-    s, q, _k, _v, _tag = _mk_indexed()
+    s, q, _k, _v, _tag = _mk_indexed(new_session)
     plan, _outs, _est = s._plan_select(parse_sql(q), None)
     assert any(isinstance(n, IndexProbe) for n in _walk(plan))
     tables = {t: s.catalog.table_data(t)
@@ -101,10 +100,10 @@ def test_index_probe_poison_parity(poison):
         lambda t: execute_plan(plan, t), tables)
 
 
-def test_index_probe_survives_dml_between_executions():
+def test_index_probe_survives_dml_between_executions(new_session):
     """The sidecar cache keys on snapshot identity: rows inserted after
     the first execution must be visible to the second."""
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("t", {
         "a": np.arange(100, dtype=np.int64),
         "k": (np.arange(100, dtype=np.int64) % 10)})
@@ -122,7 +121,7 @@ def test_index_probe_survives_dml_between_executions():
 # ---------------------------------------------------------------------------
 
 
-def _q17_session(seed=7, n_part=2000, n_li=12000):
+def _q17_session(new_session, seed=7, n_part=2000, n_li=12000):
     rng = np.random.default_rng(seed)
     part = {"p_partkey": np.arange(1, n_part + 1, dtype=np.int64),
             "p_brand": rng.integers(0, 25, n_part).astype(np.int64)}
@@ -130,7 +129,7 @@ def _q17_session(seed=7, n_part=2000, n_li=12000):
           "l_quantity": rng.integers(1, 51, n_li).astype(np.int64),
           "l_extendedprice":
               rng.integers(100, 100000, n_li).astype(np.int64)}
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("part", part, primary_key=["p_partkey"])
     s.catalog.load_numpy("lineitem", li)
     s.execute("analyze table part")
@@ -160,11 +159,11 @@ def _q17_oracle(part, li):
     return tot
 
 
-def test_magic_set_seeds_decorrelated_aggregate():
+def test_magic_set_seeds_decorrelated_aggregate(new_session):
     """The decorrelated AVG-per-key aggregate is seeded by a semi join
     against the filtered outer keys (magic set) and guarded by a STRICT
     Compact, and the result matches the host oracle."""
-    s, part, li = _q17_session()
+    s, part, li = _q17_session(new_session)
     plan, _outs, _est = s._plan_select(parse_sql(_Q17), None)
     semis = [n for n in _walk(plan)
              if isinstance(n, HashJoin) and n.how == "semi"]
@@ -175,8 +174,8 @@ def test_magic_set_seeds_decorrelated_aggregate():
     assert s.execute(_Q17).rows() == [(_q17_oracle(part, li),)]
 
 
-def test_magic_set_plan_poison_parity(poison):
-    s, _part, _li = _q17_session(n_part=500, n_li=3000)
+def test_magic_set_plan_poison_parity(poison, new_session):
+    s, _part, _li = _q17_session(new_session, n_part=500, n_li=3000)
     plan, _outs, _est = s._plan_select(parse_sql(_Q17), None)
     tables = {t: s.catalog.table_data(t)
               for t in referenced_tables(plan)
@@ -191,11 +190,11 @@ def test_magic_set_plan_poison_parity(poison):
 # ---------------------------------------------------------------------------
 
 
-def test_strict_compact_overflow_raises_and_rescales():
+def test_strict_compact_overflow_raises_and_rescales(new_session):
     from oceanbase_tpu.exec.diag import CapacityOverflow
     from oceanbase_tpu.sql.optimizer import scale_capacities
 
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("t", {"a": np.arange(1000, dtype=np.int64)})
     rel = s.catalog.table_data("t")
     plan = Compact(TableScan("t"), capacity=64, strict=True)
@@ -380,12 +379,12 @@ def test_running_kill_and_timeout_bump_lane_counters():
 
 
 # ---------------------------------------------------------------------------
-# catalog-only CREATE INDEX metadata
+# CREATE / DROP INDEX on a directly loaded table
 # ---------------------------------------------------------------------------
 
 
-def test_catalog_only_create_and_drop_index():
-    s = Session()
+def test_create_and_drop_index_on_a_loaded_table(new_session):
+    s = new_session()
     s.catalog.load_numpy("t", {"a": np.arange(10, dtype=np.int64),
                                "k": np.arange(10, dtype=np.int64)})
     s.execute("create index ix on t (k)")

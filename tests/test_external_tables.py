@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from oceanbase_tpu.server import Database
-from oceanbase_tpu.sql import Session
 
 
 def _write_csv(path, rows):
@@ -18,12 +17,12 @@ def _write_csv(path, rows):
             fh.write(",".join(str(x) for x in r) + "\n")
 
 
-def test_external_csv_table(tmp_path):
+def test_external_csv_table(tmp_path, new_session):
     p = tmp_path / "sales.csv"
     _write_csv(p, [(1, "north", "2024-01-05", "10.50"),
                    (2, "south", "2024-02-11", "3.25"),
                    (3, "north", "2024-03-02", "7.00")])
-    s = Session()
+    s = new_session()
     s.execute(f"create external table sales ("
               f"id int, region varchar(16), d date, amt decimal(10,2)) "
               f"location '{p}'")
@@ -45,10 +44,10 @@ def test_external_csv_table(tmp_path):
     assert not s.catalog.has_table("sales")
 
 
-def test_external_csv_reflects_file_changes(tmp_path):
+def test_external_csv_reflects_file_changes(tmp_path, new_session):
     p = tmp_path / "t.csv"
     _write_csv(p, [(1, 10)])
-    s = Session()
+    s = new_session()
     s.execute(f"create external table t (k int, v int) location '{p}'")
     assert s.execute("select count(*) from t").rows()[0][0] == 1
     import os
@@ -59,7 +58,7 @@ def test_external_csv_reflects_file_changes(tmp_path):
     assert s.execute("select count(*) from t").rows()[0][0] == 3
 
 
-def test_external_parquet_table(tmp_path):
+def test_external_parquet_table(tmp_path, new_session):
     pa = pytest.importorskip("pyarrow")
     import pyarrow.parquet as pq
 
@@ -69,7 +68,7 @@ def test_external_parquet_table(tmp_path):
         "name": pa.array(["a", "b", None]),
         "score": pa.array([1.5, 2.5, 3.5])})
     pq.write_table(table, p)
-    s = Session()
+    s = new_session()
     s.execute(f"create external table d ("
               f"k int, name varchar(8), score double) location '{p}'")
     r = s.execute("select k, name, score from d order by k")
@@ -83,12 +82,12 @@ def test_external_parquet_table(tmp_path):
     db.close()
 
 
-def test_arrow_interop_roundtrip(tmp_path):
+def test_arrow_interop_roundtrip(tmp_path, new_session):
     pa = pytest.importorskip("pyarrow")
     from oceanbase_tpu.share.external import (
         arrow_to_arrays, result_to_arrow)
 
-    s = Session()
+    s = new_session()
     t = pa.table({"k": pa.array([1, 2]),
                   "s": pa.array(["x", "y"])})
     arrays, valids, types = arrow_to_arrays(t)

@@ -6,17 +6,15 @@ import sqlite3
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
-
 
 @pytest.fixture(scope="module")
-def env():
+def env(new_module_session):
     rng = np.random.default_rng(3)
     n = 200
     a = rng.integers(-50, 50, n)
     f = rng.uniform(-5, 5, n)
     words = rng.choice(np.array(["  hello ", "World", "abcdef", "x"]), n)
-    s = Session()
+    s = new_module_session()
     s.catalog.load_numpy("t", {"a": a, "f": f, "w": words})
     conn = sqlite3.connect(":memory:")
     conn.create_function("ln", 1, math.log)
@@ -83,8 +81,8 @@ def test_null_functions(env):
     assert r == [(3, 2)]
 
 
-def test_date_functions():
-    s = Session()
+def test_date_functions(new_session):
+    s = new_session()
     from oceanbase_tpu.datatypes import SqlType, date_to_days
 
     days = np.array([date_to_days(x) for x in
@@ -104,14 +102,12 @@ def test_date_functions():
     assert r[2][0] == "2001-02-28"  # leap-day clamp
 
 
-def test_extended_function_batch():
+def test_extended_function_batch(new_session):
     """Round-4 function-surface widening (≙ src/sql/engine/expr breadth:
     string pad/search, math, conditional, date-name functions)."""
     import numpy as np
 
-    from oceanbase_tpu.sql import Session
-
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy(
         "fx", {"k": np.arange(3),
                "s": np.array(["abc", "hello world", ""], dtype=object),
@@ -148,14 +144,12 @@ def test_extended_function_batch():
     assert got == hashlib.md5(b"abc").hexdigest()
 
 
-def test_concat_ws_skips_nulls():
+def test_concat_ws_skips_nulls(new_session):
     """MySQL CONCAT_WS semantics: NULL values are skipped with their
     separator (unlike CONCAT's null propagation)."""
     import numpy as np
 
-    from oceanbase_tpu.sql import Session
-
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy(
         "cw", {"k": np.arange(3),
                "a": np.array(["x", "y", "z"], dtype=object),

@@ -11,13 +11,12 @@ import sqlite3
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
 
 N_QUERIES = 60
 
 
 @pytest.fixture(scope="module")
-def env():
+def env(new_module_session):
     rng = np.random.default_rng(11)
     n1, n2 = 400, 120
     t1 = {
@@ -32,7 +31,7 @@ def env():
         "y": rng.integers(-5, 5, n2),
         "w": rng.choice(np.array(["red", "blue", "pink"]), n2),
     }
-    s = Session()
+    s = new_module_session()
     s.catalog.load_numpy("t1", t1, valids={"b": ~nulls})
     s.catalog.load_numpy("t2", t2)
     conn = sqlite3.connect(":memory:")

@@ -6,18 +6,16 @@ src/sql/engine/px/ob_slice_calc.h:73-88).
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
-
 
 @pytest.fixture()
-def skewed():
+def skewed(new_session):
     rng = np.random.default_rng(5)
     n = 40_000
     # 80% of probe rows carry ONE key — a plain hash exchange funnels
     # them into a single destination shard
     hot = rng.random(n) < 0.8
     j = np.where(hot, 7, rng.integers(100, 5000, n))
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("probe", {
         "k": np.arange(n), "j": j,
         "v": rng.integers(0, 100, n)}, primary_key=["k"])

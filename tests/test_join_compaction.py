@@ -22,7 +22,6 @@ from oceanbase_tpu.exec import plan as pp
 from oceanbase_tpu.exec.diag import CapacityOverflow
 from oceanbase_tpu.server import Database
 from oceanbase_tpu.server import metrics as qmetrics
-from oceanbase_tpu.sql import Session
 from oceanbase_tpu.sql.optimizer import _bucket, apply_feedback
 from oceanbase_tpu.sql.parser import parse_sql
 from test_hash_partition import TABLES, _ddl, _load
@@ -170,14 +169,15 @@ def _load_pair(s):
         primary_key=["bk"])
 
 
-def test_an_overflowing_compact_replans_to_the_uncompacted_answer(tmp_path):
+def test_an_overflowing_compact_replans_to_the_uncompacted_answer(
+        tmp_path, new_session):
     """Statistics gone stale: the histogram says the interval is almost
     empty, a tenth of the rows lie in it.  The bucket is too small, the
     program reports what did not fit, the session re-plans with scaled
     budgets, and the answer is the uncompacted plan's, row for row."""
     from oceanbase_tpu.exec.plan import execute_plan
 
-    plain = Session()
+    plain = new_session()
     _load_pair(plain)  # no ANALYZE: 0.4 x 0.4 of the lanes, no Compact
     plan, _o, _e = plain._plan_select(parse_sql(SQL), None)
     assert not _nodes(plan, pp.Compact)
@@ -214,11 +214,11 @@ def test_an_overflowing_compact_replans_to_the_uncompacted_answer(tmp_path):
     db.close()
 
 
-def test_feedback_raises_a_compact_with_the_filter_under_it():
+def test_feedback_raises_a_compact_with_the_filter_under_it(new_session):
     """gv$plan_feedback's correction for the filter chain's head (the
     Compact is a pass-through with no ledger row) raises the Compact's
     bucket at bind time; it never lowers one."""
-    s = Session()
+    s = new_session()
     _load_pair(s)
     plan = pp.propagate_estimates(pp.HashJoin(
         pp.Compact(pp.Filter(pp.TableScan("ca", est_rows=N),

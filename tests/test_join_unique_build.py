@@ -33,7 +33,6 @@ from oceanbase_tpu.expr import ir
 from oceanbase_tpu.px import dtl
 from oceanbase_tpu.server import Database
 from oceanbase_tpu.server import metrics as qmetrics
-from oceanbase_tpu.sql import Session
 from oceanbase_tpu.sql.optimizer import after_overflow, without_unique_builds
 from oceanbase_tpu.sql.parser import parse_sql
 from oceanbase_tpu.vector.column import Relation, from_numpy, to_numpy
@@ -272,8 +271,8 @@ def test_q3_keeps_its_two_expanding_joins(serial):
      "select count(*), sum(bv) from ca, cb where aj = bk and av < 1200",
      [False]),
 ])
-def test_what_the_planner_marks(case, sql, marked):
-    s = Session()
+def test_what_the_planner_marks(case, sql, marked, new_session):
+    s = new_session()
     rng = np.random.default_rng(39)
     n = 8000
     s.catalog.load_numpy(

@@ -404,13 +404,12 @@ def _probe_counts():
 
 
 @pytest.mark.parametrize("path", ["serial", "px"])
-def test_join_probes_rise_by_the_executables_counts(path, monkeypatch):
+def test_join_probes_rise_by_the_executables_counts(path, monkeypatch,
+                                                    new_session):
     from oceanbase_tpu.exec import ops
     from oceanbase_tpu.exec.plan import executable_for
-    from oceanbase_tpu.sql import Session
-
     r = np.random.default_rng(5)
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("jp_a", {"ak": np.arange(400),
                                   "aj": r.integers(0, 50, 400)},
                          primary_key=["ak"])
@@ -460,11 +459,10 @@ def _groupby_counts():
     ("k", "masked"),    # a dictionary key: no sort, masked reductions
     ("g", "sort"),      # an integer key
 ])
-def test_groupby_reduces_rise_by_the_programs_kinds(key, kind, path):
-    from oceanbase_tpu.sql import Session
-
+def test_groupby_reduces_rise_by_the_programs_kinds(key, kind, path,
+                                                    new_session):
     r = np.random.default_rng(6)
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy(
         "gr_t", {"id": np.arange(600),
                  "k": r.choice(np.array(["a", "b", "c"]), 600),

@@ -8,7 +8,6 @@ src/share/stat/ob_opt_column_stat.h (equi-height histograms).
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
 from oceanbase_tpu.sql.binder import Binder
 from oceanbase_tpu.sql.parser import Parser
 
@@ -19,12 +18,12 @@ def _est(sess, sql):
     return est
 
 
-def test_histogram_improves_range_estimates():
+def test_histogram_improves_range_estimates(new_session):
     rng = np.random.default_rng(0)
     n = 20_000
     v = np.where(rng.random(n) < 0.99, rng.integers(0, 100, n),
                  rng.integers(100, 10_000, n))
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("t", {"k": np.arange(n), "v": v},
                          primary_key=["k"])
     before = _est(s, "select k from t where v >= 5000")
@@ -37,7 +36,7 @@ def test_histogram_improves_range_estimates():
     assert lo > n // 2
 
 
-def test_dp_join_order_avoids_low_ndv_edge_first():
+def test_dp_join_order_avoids_low_ndv_edge_first(new_session):
     """Q5-shaped trap: joining the low-NDV nationkey edge before the PK
     orders edge explodes the intermediate; DP must order orders before
     customer."""
@@ -45,7 +44,7 @@ def test_dp_join_order_avoids_low_ndv_edge_first():
 
     rng = np.random.default_rng(1)
     n_li, n_ord, n_cust = 50_000, 12_000, 1500
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("li", {
         "l_ok": rng.integers(0, n_ord, n_li),
         "l_sk": rng.integers(0, 100, n_li)}, primary_key=[])
@@ -89,9 +88,9 @@ def test_dp_join_order_avoids_low_ndv_edge_first():
     assert est < n_li * 4
 
 
-def test_dp_plans_are_correct_vs_greedy():
+def test_dp_plans_are_correct_vs_greedy(new_session):
     rng = np.random.default_rng(2)
-    s = Session()
+    s = new_session()
     n = 3000
     s.catalog.load_numpy("a", {"ak": np.arange(n),
                                "aj": rng.integers(0, 50, n)},
@@ -108,11 +107,14 @@ def test_dp_plans_are_correct_vs_greedy():
     import sqlite3
 
     conn = sqlite3.connect(":memory:")
+    from oceanbase_tpu.vector import to_numpy
+
     for nm in ("a", "b", "c"):
-        rel = s.catalog.table_data(nm)
-        cols = list(rel.columns)
+        # through the mask: a relation is padded to its capacity bucket
+        live = to_numpy(s.catalog.table_data(nm))
+        cols = [c for c in live if not c.startswith("__valid__")]
         conn.execute(f"create table {nm} ({', '.join(cols)})")
-        arrs = [np.asarray(rel.columns[c].data).tolist() for c in cols]
+        arrs = [live[c].tolist() for c in cols]
         conn.executemany(
             f"insert into {nm} values ({','.join('?' * len(cols))})",
             list(zip(*arrs)))
@@ -127,12 +129,12 @@ def test_dp_plans_are_correct_vs_greedy():
 N_IV = 64_000
 
 
-def _iv_session():
+def _iv_session(new_session):
     """``v`` = 0..63,999 once each, so an equi-height histogram of 64
     buckets has an edge every 1,000 and the true count of any interval is
     its width; ``w`` never analysed into a histogram (a string), ``u``
     with its histogram dropped."""
-    s = Session()
+    s = new_session()
     v = np.arange(N_IV)
     s.catalog.load_numpy(
         "iv", {"k": v, "v": v.copy(), "u": v.copy(),
@@ -144,8 +146,8 @@ def _iv_session():
 
 
 @pytest.fixture(scope="module")
-def iv():
-    return _iv_session()
+def iv(new_module_session):
+    return _iv_session(new_module_session)
 
 
 @pytest.mark.parametrize("where, rows", [
@@ -208,13 +210,13 @@ def test_what_is_not_a_pair_prices_as_before(iv, where, share):
         max(1, int(N_IV * share))
 
 
-def test_one_sided_bounds_on_skewed_data_price_as_before():
+def test_one_sided_bounds_on_skewed_data_price_as_before(new_session):
     """The bucket-level reading on data whose buckets are uneven, held
     to the searchsorted it always was."""
     rng = np.random.default_rng(3)
     n = 30_000
     v = np.sort(rng.exponential(1000.0, n).astype(np.int64))
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("sk", {"k": np.arange(n), "v": v},
                          primary_key=["k"])
     s.execute("analyze table sk")
@@ -239,7 +241,8 @@ def test_one_sided_bounds_on_skewed_data_price_as_before():
      & (t["l_discount"] >= 5) & (t["l_discount"] <= 7)
      & (t["l_quantity"] < 2400)),
 ])
-def test_tpch_range_filters_estimate_within_a_factor(qnum, table, cond):
+def test_tpch_range_filters_estimate_within_a_factor(qnum, table, cond,
+                                                     new_session):
     """Q14's and Q6's filters at the validation parameters, on the
     generator's data: the estimate of the filtered ``lineitem`` within a
     factor of 1.5 of the counted rows (it was 19x for Q14)."""
@@ -249,7 +252,7 @@ def test_tpch_range_filters_estimate_within_a_factor(qnum, table, cond):
     from oceanbase_tpu.exec.plan import q_error
 
     tables, types = gen_tpch(sf=0.02)
-    s = Session()
+    s = new_session()
     for name in ("lineitem", "part"):
         s.catalog.load_numpy(
             name, tables[name],

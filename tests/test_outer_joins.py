@@ -9,11 +9,10 @@ import numpy as np
 import pytest
 
 from oceanbase_tpu.bench.oracle import rows_match
-from oceanbase_tpu.sql import Session
 
 
 @pytest.fixture(scope="module")
-def env():
+def env(new_module_session):
     import sqlite3
 
     rng = np.random.default_rng(3)
@@ -22,7 +21,7 @@ def env():
          "av": rng.integers(0, 1000, na)}
     b = {"bk": np.arange(nb), "bj": rng.integers(40, 120, nb),
          "bv": rng.integers(0, 1000, nb)}
-    sess = Session()
+    sess = new_module_session()
     sess.catalog.load_numpy("a", a, primary_key=["ak"])
     sess.catalog.load_numpy("b", b, primary_key=["bk"])
     conn = sqlite3.connect(":memory:")

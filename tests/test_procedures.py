@@ -6,7 +6,6 @@ expression engine; traced UDFs remain the JIT analog).
 import pytest
 
 from oceanbase_tpu.server import Database
-from oceanbase_tpu.sql import Session
 
 
 def test_procedure_control_flow(tmp_path):
@@ -79,8 +78,8 @@ def test_procedure_persists_across_restart(tmp_path):
     db2.close()
 
 
-def test_procedure_in_memory_session():
-    s = Session()
+def test_procedure_in_memory_session(new_session):
+    s = new_session()
     import numpy as np
 
     s.catalog.load_numpy("t", {"k": np.arange(4),

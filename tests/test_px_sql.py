@@ -10,16 +10,15 @@ import pytest
 
 from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
 from oceanbase_tpu.bench.tpch_queries import QUERIES
-from oceanbase_tpu.sql import Session
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
                                 reason="needs 8 devices")
 
 
 @pytest.fixture(scope="module")
-def sess():
+def sess(new_module_session):
     tables, types = gen_tpch(sf=0.01)
-    s = Session()
+    s = new_module_session()
     for name, arrays in tables.items():
         s.catalog.load_numpy(
             name, arrays,

@@ -271,15 +271,3 @@ def test_bulk_load_unique_checks_existing_rows(tmp_path):
               "v": np.array([5], dtype=np.int64)},
         version=db.tenant().tx.gts.current())
     db.close()
-
-
-def test_inline_index_catalog_only_session_fails_cleanly():
-    """Review finding: inline KEY in a catalog-only session must fail
-    BEFORE creating the table."""
-    from oceanbase_tpu.sql.session import Session
-
-    s = Session()
-    with pytest.raises(NotImplementedError):
-        s.execute("create table t (a int, index ia (a))")
-    assert not s.catalog.has_table("t")
-    s.execute("create table t (a int)")  # now works

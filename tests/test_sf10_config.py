@@ -218,7 +218,7 @@ def test_the_row_knob_decides_while_it_is_not_zero(tmp_path):
         # three 8-byte columns with their validity, and the row mask
         row_bytes = 3 * (8 + 1) + 1
         assert int(db.config["sql_work_area_rows"]) == 0
-        assert qconfig.work_area_bytes(s._config()) == share
+        assert qconfig.work_area_bytes(s.tenant.config) == share
         assert s._work_area(plan)("t") == share // row_bytes
         s.execute("alter system set ob_sql_work_area_percentage = 5")
         assert s._work_area(plan)("t") == share // row_bytes

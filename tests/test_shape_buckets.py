@@ -288,14 +288,12 @@ def test_row_count_is_live_not_padded(tmp_path):
     db.close()
 
 
-def test_ann_runtime_handles_bucket_padded_suffix():
+def test_ann_runtime_handles_bucket_padded_suffix(new_session):
     """Bucket padding adds a dead SUFFIX; the ANN runtime slices it off
     instead of disabling the index access path."""
-    from oceanbase_tpu.sql import Session
-
     rng = np.random.default_rng(3)
     vecs = rng.normal(size=(100, 8)).astype(np.float32)
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("emb", {"id": np.arange(100), "v": vecs},
                          primary_key=["id"])
     rel = s.catalog.table_data("emb").pad_to(bucket_capacity(100))

@@ -3,12 +3,10 @@
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
-
 
 @pytest.fixture()
-def sess():
-    return Session()
+def sess(new_session):
+    return new_session()
 
 
 def test_create_insert_select(sess):

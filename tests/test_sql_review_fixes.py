@@ -2,13 +2,12 @@
 
 import pytest
 
-from oceanbase_tpu.sql import Session
 from oceanbase_tpu.sql.binder import BindError
 
 
 @pytest.fixture()
-def sess():
-    s = Session()
+def sess(new_session):
+    s = new_session()
     s.execute("create table a (x int, v int)")
     s.execute("insert into a values (1, 10), (2, 20)")
     s.execute("create table b (x int, z int)")

@@ -11,15 +11,14 @@ import pytest
 from oceanbase_tpu.bench.oracle import load_sqlite, rows_match, run_oracle
 from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
 from oceanbase_tpu.bench.tpch_queries import QUERIES
-from oceanbase_tpu.sql import Session
 
 SF = float(os.environ.get("TPCH_SF", "0.01"))
 
 
 @pytest.fixture(scope="module")
-def env():
+def env(new_module_session):
     tables, types = gen_tpch(sf=SF)
-    sess = Session()
+    sess = new_module_session()
     for name, arrays in tables.items():
         sess.catalog.load_numpy(
             name, arrays,
@@ -39,3 +38,17 @@ def test_tpch_query(env, qnum):
     ordered = "order by" in sql.lower() and qnum not in (2, 18, 21)
     ok, why = rows_match(got, want, ordered=ordered)
     assert ok, f"Q{qnum}: {why}\n got[:3]={got[:3]}\nwant[:3]={want[:3]}"
+
+
+def test_the_fixture_runs_under_the_plan_cache(env):
+    """The session these statements run in is a Database's: the second
+    execution of a statement is a plan-cache hit, as in the cells."""
+    from oceanbase_tpu.server import metrics as qmetrics
+
+    sess, _conn = env
+    sess.execute(QUERIES[6])
+    hits = qmetrics.counter_value("plan_cache.hits")
+    misses = qmetrics.counter_value("plan_cache.misses")
+    sess.execute(QUERIES[6])
+    assert qmetrics.counter_value("plan_cache.hits") == hits + 1
+    assert qmetrics.counter_value("plan_cache.misses") == misses

@@ -4,15 +4,14 @@ import numpy as np
 import pytest
 
 from oceanbase_tpu.server import Database
-from oceanbase_tpu.sql import Session
 
 
-def test_topn_matches_numpy_oracle(rng):
+def test_topn_matches_numpy_oracle(rng, new_session):
     n = 20000
     a = rng.integers(-1000, 1000, n)
     f = rng.random(n)
     sv = rng.choice(np.array(["aa", "bb", "cc", "dd"]), n)
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy("t", {"a": a, "f": f, "s": sv})
     got = [r[0] for r in s.execute(
         "select a from t order by a limit 7").rows()]
@@ -33,9 +32,9 @@ def test_topn_matches_numpy_oracle(rng):
     assert [r[0] for r in got] == want
 
 
-def test_topn_null_desc_with_filter():
+def test_topn_null_desc_with_filter(new_session):
     # live NULLs under DESC must outrank dead (filtered) rows
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy(
         "t", {"x": np.array([10, 500, 0, 0]),
               "flt": np.array([1, 0, 1, 1])},
@@ -45,8 +44,8 @@ def test_topn_null_desc_with_filter():
     assert r == [(10,), (None,), (None,)]
 
 
-def test_topn_with_nulls():
-    s = Session()
+def test_topn_with_nulls(new_session):
+    s = new_session()
     s.catalog.load_numpy("t", {"x": np.array([5, 1, 9, 3])},
                          valids={"x": np.array([True, False, True, True])})
     r = s.execute("select x from t order by x limit 2").rows()

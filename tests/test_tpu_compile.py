@@ -62,13 +62,12 @@ def _fits(compiled):
             + ma.temp_size_in_bytes) < V5E_HBM_BYTES, ma
 
 
-def _tpch_session(names=None, analyze=False):
+def _tpch_session(new_session, names=None, analyze=False):
     """An SF0.01 load of ``names`` (all eight tables by default)."""
     from oceanbase_tpu.bench.tpch import TPCH_PRIMARY_KEYS, gen_tpch
-    from oceanbase_tpu.sql import Session
 
     tables, types = gen_tpch(sf=0.01)
-    sess = Session()
+    sess = new_session()
     for name in names or tables:
         arrays = tables[name]
         sess.catalog.load_numpy(
@@ -81,8 +80,8 @@ def _tpch_session(names=None, analyze=False):
 
 
 @pytest.fixture(scope="module")
-def tpch_session():
-    return _tpch_session()
+def tpch_session(new_module_session):
+    return _tpch_session(new_module_session)
 
 
 def _compile_programs_of(session, sql, one_chip, monkeypatch):
@@ -119,7 +118,7 @@ def test_plan_program_compiles_for_v5e(qnum, tpch_session, one_chip,
 
 def test_compacted_join_input_compiles_for_v5e(one_chip,
                                                no_persistent_cache,
-                                               monkeypatch):
+                                               monkeypatch, new_session):
     """Q14 as the benchmark runs it, with ANALYZE'd statistics: the
     filtered ``lineitem`` is compacted to its estimate's bucket under the
     join (the case above loads without statistics and goes on compiling
@@ -128,7 +127,7 @@ def test_compacted_join_input_compiles_for_v5e(one_chip,
     from oceanbase_tpu.exec import plan as qplan
     from oceanbase_tpu.sql.parser import parse_sql
 
-    sess = _tpch_session(("lineitem", "part"), analyze=True)
+    sess = _tpch_session(new_session, ("lineitem", "part"), analyze=True)
     plan, _outs, _est = sess._plan_select(parse_sql(QUERIES[14]), None)
     compacts = [n for n in qplan._postorder(plan)
                 if isinstance(n, qplan.Compact)]
@@ -440,12 +439,13 @@ V5E_PROGRAM_BYTES = int(15.75 * 2**30)
 
 
 @pytest.fixture(scope="module")
-def sf10_session():
+def sf10_session(new_module_session):
     """An SF0.01 load whose statistics say SF10: rows and key
     cardinalities x 1,000 (histograms and frequency lists describe
     distributions and stay), so the binder sizes every capacity as it
     does over the real tables, and nothing of that size exists here."""
-    sess = _tpch_session(("lineitem", "part"), analyze=True)
+    sess = _tpch_session(new_module_session, ("lineitem", "part"),
+                         analyze=True)
     for name in ("lineitem", "part"):
         td = sess.catalog.table_def(name)
         rows = td.row_count
@@ -512,12 +512,13 @@ def test_sf10_plan_compiles_for_v5e_and_fits(qnum, sf10_session, one_chip,
 
 
 @pytest.fixture(scope="module")
-def sf10_orders_session():
+def sf10_orders_session(new_module_session):
     """``tpch_sf10_orders``' three tables at SF 0.01 with statistics that
     say SF10 (rows x 1,000; a key's distinct values as ANALYZE finds them
     at SF10, ``SF10_NDV``; histograms and samples describe distributions
     and stay), and the scans' lanes SF10's buckets."""
-    sess = _tpch_session(("lineitem", "orders", "customer"), analyze=True)
+    sess = _tpch_session(new_module_session,
+                         ("lineitem", "orders", "customer"), analyze=True)
     ndv = dict(SF10_NDV, c_custkey=1_500_000, c_name=1_500_000,
                o_comment=150_000, o_totalprice=14_000_000)
     for name in ("lineitem", "orders", "customer"):

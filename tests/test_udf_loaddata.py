@@ -5,16 +5,15 @@ import pytest
 
 from oceanbase_tpu.expr.compile import register_udf, unregister_udf
 from oceanbase_tpu.server import Database
-from oceanbase_tpu.sql import Session
 
 
-def test_udf_traced_into_plan(rng):
+def test_udf_traced_into_plan(rng, new_session):
     import jax.numpy as jnp
 
     register_udf("sigmoid_cents",
                  lambda x: 1.0 / (1.0 + jnp.exp(-x.astype(jnp.float64) / 100)))
     try:
-        s = Session()
+        s = new_session()
         s.catalog.load_numpy("t", {"v": np.array([0, 100, -100])})
         r = s.execute("select v, sigmoid_cents(v) as p from t order by v")
         rows = r.rows()

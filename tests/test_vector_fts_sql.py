@@ -9,21 +9,19 @@ string-dictionary domain (host LUT + device gather).
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
 
-
-def _vec_env(n=2000, d=16, seed=0):
+def _vec_env(new_session, n=2000, d=16, seed=0):
     rng = np.random.default_rng(seed)
     vecs = rng.normal(size=(n, d)).astype(np.float32)
-    s = Session()
+    s = new_session()
     s.catalog.load_numpy(
         "emb", {"id": np.arange(n), "v": vecs, "tag": np.arange(n) % 5},
         primary_key=["id"])
     return s, vecs
 
 
-def test_vector_type_and_distance_functions():
-    s, vecs = _vec_env()
+def test_vector_type_and_distance_functions(new_session):
+    s, vecs = _vec_env(new_session)
     q = vecs[7]
     qtxt = "[" + ", ".join(f"{x:.6f}" for x in q) + "]"
     r = s.execute(f"select id, l2_distance(v, '{qtxt}') as d from emb "
@@ -36,8 +34,8 @@ def test_vector_type_and_distance_functions():
     assert [r0[0] for r0 in rows] == exp
 
 
-def test_vector_index_topk_exact_parity():
-    s, vecs = _vec_env()
+def test_vector_index_topk_exact_parity(new_session):
+    s, vecs = _vec_env(new_session)
     s.execute("create vector index iv on emb (v) with (metric = 'l2')")
     q = vecs[123] + 0.01
     qtxt = "[" + ", ".join(f"{x:.6f}" for x in q) + "]"
@@ -51,8 +49,8 @@ def test_vector_index_topk_exact_parity():
     assert any(k[0] == "emb" for k in s.catalog._ann_cache)
 
 
-def test_vector_cosine_index():
-    s, vecs = _vec_env()
+def test_vector_cosine_index(new_session):
+    s, vecs = _vec_env(new_session)
     s.execute("create vector index ic on emb (v) "
               "with (metric = 'cosine')")
     q = vecs[55]
@@ -77,8 +75,8 @@ def test_vector_insert_through_engine(tmp_path):
     db.close()
 
 
-def test_fulltext_match_against():
-    s = Session()
+def test_fulltext_match_against(new_session):
+    s = new_session()
     docs = np.array([
         "the quick brown fox", "jumped over the lazy dog",
         "quick quick slow", "a dog and a fox", "nothing relevant here",
@@ -132,8 +130,8 @@ def test_vector_index_persists_across_restart(tmp_path):
     db3.close()
 
 
-def test_empty_vector_table_create():
-    s = Session()
+def test_empty_vector_table_create(new_session):
+    s = new_session()
     import numpy as np
 
     # a VECTOR column on a table created without data must not crash
@@ -145,12 +143,12 @@ def test_empty_vector_table_create():
     assert s.catalog.table_def("ev").column("v").dtype.precision == 3
 
 
-def test_vector_index_approximate_opt_in():
+def test_vector_index_approximate_opt_in(new_session):
     """IVF recall only engages when the index opts in WITH
     (approximate = true); a plain vector index keeps exact answers."""
     import numpy as np
 
-    s, vecs = _vec_env(n=5000, d=8, seed=4)
+    s, vecs = _vec_env(new_session, n=5000, d=8, seed=4)
     s.execute("create vector index ia on emb (v) "
               "with (metric = 'l2', approximate = true)")
     q = vecs[42]

@@ -5,16 +5,14 @@ import sqlite3
 import numpy as np
 import pytest
 
-from oceanbase_tpu.sql import Session
-
 
 @pytest.fixture(scope="module")
-def env(rng=np.random.default_rng(7)):
+def env(new_module_session, rng=np.random.default_rng(7)):
     n = 500
     dept = rng.integers(0, 5, n)
     sal = rng.integers(1000, 9000, n)
     emp = np.arange(n)
-    sess = Session()
+    sess = new_module_session()
     sess.catalog.load_numpy("emp", {"eid": emp, "dept": dept, "sal": sal})
     conn = sqlite3.connect(":memory:")
     conn.execute("create table emp (eid, dept, sal)")

@@ -8,11 +8,10 @@ import numpy as np
 import pytest
 
 from oceanbase_tpu.bench.oracle import load_sqlite, rows_match, run_oracle
-from oceanbase_tpu.sql import Session
 
 
 @pytest.fixture(scope="module")
-def env():
+def env(new_module_session):
     rng = np.random.default_rng(7)
     n = 500
     tables = {
@@ -23,7 +22,7 @@ def env():
         }
     }
     # some NULLs in v via a second nullable column
-    sess = Session()
+    sess = new_module_session()
     sess.catalog.load_numpy("t", tables["t"], primary_key=["k"])
     conn = load_sqlite(tables, {})
     return sess, conn
@@ -83,8 +82,8 @@ def test_window_oracle_parity(env, qi):
     assert ok, f"{sql}\n{why}\n got={got[:5]}\nwant={want[:5]}"
 
 
-def test_window_null_handling():
-    sess = Session()
+def test_window_null_handling(new_session):
+    sess = new_session()
     n = 60
     v = np.arange(n, dtype=np.int64)
     valid = (np.arange(n) % 5) != 0
