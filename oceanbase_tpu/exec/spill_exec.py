@@ -8,29 +8,40 @@ recursive hash-partition join (ob_hash_join_vec_op.h:413), and the
 dump-capable group-by (ob_hash_groupby_vec_op.cpp) — all backed by the
 temp-file system (src/storage/tmp_file).
 
-The TPU shape of the same idea: the big table streams granule-by-granule
-through a compiled device chunk program (scan/filter/project and partial
-aggregation stay on-chip); host-side chunk streams carry what cannot fit
-— sorted runs (exec/external_sort.py), hash partitions
-(exec/spill.py::partitioned_join_spilled), and sorted partial-aggregate
-runs merged by key — in the temp-file store (storage/tmpfile.py).
-Small tables lower whole on device; per-batch operators run the same
-`exec.ops` kernels eagerly.
+The TPU shape of the same idea, in two halves.
 
-Supported plan shapes (dispatch in :func:`execute_spilled`):
+ON THE DEVICE (``exec/granule.py``): a plan that is a union over the
+granules of ONE over-budget table (a scan pipeline, probes of
+device-resident build sides, under a group-by or a scalar aggregate)
+streams that table granule by granule through one cached chunk program:
+scan, filter, project, probe and the partial aggregate run there, the
+partial states stay on the device and one more cached program merges
+them, finishes the aggregate and applies the coordinator chain.  Nothing
+of such a statement crosses to the host but its result.
 
-- ``[Project*/Limit?/Sort?] over scan-pipeline``          -> streamed sort
-- ``... over GroupBy over scan-pipeline``                 -> partial
-  group-by per granule, disk merge by key (unbounded NDV)
-- ``... over ScalarAgg over scan-pipeline``               -> partial fold
-- ``... over [GroupBy|ScalarAgg]? over join tree``        -> the join tree
-  streams: each HashJoin either probes a device-resident build side
-  (small side fits the budget) batch-by-batch, or — when both sides are
-  over budget — co-partitions to disk.  LEFT joins stream only on the
-  preserved side (unmatched-build emission needs the whole build).
+ON THE HOST AND THE DISK (here): what does not fit the device that way.
+A streamed ORDER BY drains each granule's surviving rows to the host and
+sorts them in runs (exec/external_sort.py); a group-by whose partial
+states outgrow the work area drains them and merges sorted runs by key;
+a join with both sides over the budget co-partitions both streams
+(exec/spill.py::partitioned_join_spilled) — all in the temp-file store
+(storage/tmpfile.py), capped by ``temporary_file_max_disk_size``.
+Per-batch operators of this half run the same `exec.ops` kernels
+eagerly.
 
-Anything else raises NotDistributable and the session falls back to the
-in-memory engine.
+Plan shapes (dispatch in :func:`execute_spilled`):
+
+- ``[Project*/Limit?/Sort?] over [GroupBy|ScalarAgg] over a union over
+  one table's granules``                       -> all on the device
+- the same with a group-by state over the budget -> partials to the host,
+  disk merge by key (unbounded NDV)
+- ``[Project*/Limit?/Sort?] over such a union`` -> streamed sort
+- a join tree with two over-budget sides        -> each HashJoin either
+  probes a device-resident build side batch by batch or co-partitions to
+  disk.  LEFT joins stream only on the preserved side.
+
+Anything else raises NotDistributable; the session counts it
+(``spill.fallbacks{reason}``) and runs the resident plan.
 """
 
 from __future__ import annotations
@@ -38,7 +49,6 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass, field
 
-import jax
 import numpy as np
 
 from oceanbase_tpu.exec import diag, ops
@@ -46,11 +56,11 @@ from oceanbase_tpu.exec import plan as pp
 from oceanbase_tpu.exec.external_sort import external_sort
 from oceanbase_tpu.exec.granule import (
     DEFAULT_CHUNK_ROWS,
-    _chunk_to_relation,
+    GranulePlan,
     _find_single_scan,
-    _global_dicts,
-    extract_column_bounds,
-    snap_chunk_rows,
+    granule_rows_for,
+    merge_outputs,
+    stream_outputs,
 )
 from oceanbase_tpu.exec.spill import partitioned_join_spilled
 from oceanbase_tpu.expr import ir
@@ -71,6 +81,9 @@ qmetrics.declare("spill.rows", "counter",
                  "rows that crossed the host/disk boundary")
 qmetrics.declare("spill.execute_s", "histogram",
                  "spilled-query wall time", unit="s")
+qmetrics.declare("spill.fallbacks", "counter",
+                 "statements priced over the work area that could not "
+                 "stream and ran the resident plan, by reason")
 
 OUT_CHUNK = 1 << 16
 
@@ -93,9 +106,11 @@ class SpillStats:
 class _Ctx:
     def __init__(self, store: TempFileStore, budget_rows: int,
                  chunk_rows: int, providers: dict, device_tables: dict,
-                 types_by_table: dict, big_tables: set):
+                 types_by_table: dict, big_tables: set,
+                 budget_bytes: int | None = None):
         self.store = store
         self.budget_rows = budget_rows
+        self.budget_bytes = budget_bytes
         self.chunk_rows = chunk_rows
         self.providers = providers
         self.device_tables = device_tables
@@ -116,6 +131,30 @@ class _Ctx:
             self.dtypes[name] = col.dtype
 
 
+@dataclass
+class Spilled:
+    """A streamed statement's result: ``relation`` where it was finished
+    on the device, else the host columns the disk half produced."""
+
+    stats: SpillStats
+    relation: Relation | None = None
+    arrays: dict = field(default_factory=dict)
+    valids: dict = field(default_factory=dict)
+    dtypes: dict = field(default_factory=dict)
+
+    def host(self):
+        """-> (arrays, valids, dtypes, stats), a device result fetched."""
+        if self.relation is not None:
+            raw = to_numpy(self.relation)
+            cols = [c for c in raw if not c.startswith("__valid__")]
+            self.arrays = {c: raw[c] for c in cols}
+            self.valids = {c: raw["__valid__" + c] for c in cols
+                           if "__valid__" + c in raw}
+            self.dtypes = {c: self.relation.columns[c].dtype for c in cols}
+            self.relation = None
+        return self.arrays, self.valids, self.dtypes, self.stats
+
+
 # ---------------------------------------------------------------------------
 # entry
 # ---------------------------------------------------------------------------
@@ -126,40 +165,32 @@ def execute_spilled(plan: pp.PlanNode, providers: dict, spill_dir: str,
                     types_by_table: dict | None = None,
                     big_tables: set | None = None,
                     chunk_rows: int = DEFAULT_CHUNK_ROWS,
-                    disk_budget=None, faults=None, label: str = ""):
-    """Run ``plan`` with disk spill for everything over ``budget_rows``.
+                    disk_budget=None, faults=None, label: str = "",
+                    budget_bytes: int | None = None) -> Spilled:
+    """Run ``plan`` with the over-budget tables streamed, and disk spill
+    for everything over ``budget_rows``.
 
     providers: {table: chunk_provider} for the over-budget tables
     (re-iterable granule streams).  device_tables: {table: Relation} for
-    every other referenced table (lowered whole).  -> (arrays, valids,
-    dtypes, SpillStats); raises NotDistributable for unsupported shapes.
+    every other referenced table (lowered whole).  The granule is the
+    largest ladder rung, ``chunk_rows`` at most, of which the buffers in
+    flight fit ``budget_rows`` rows (``budget_bytes``, where the work area
+    is one in bytes, is asserted against the granule's real widths).
+    -> Spilled; raises NotDistributable for unsupported shapes.
 
     ``disk_budget``/``faults``/``label`` thread the disk-pressure plane
     into the temp-file store: chunk writes are accounted against the
-    tenant spill budget (SpillBudgetExceeded kills just this statement)
-    and consult the fault plane (seeded ENOSPC/EIO, kind="spill").
+    tenant's ``temporary_file_max_disk_size`` (SpillBudgetExceeded kills
+    just this statement) and consult the fault plane (seeded ENOSPC/EIO,
+    kind="spill").
     """
-    # granule capacity rides the shared bucket ladder so the per-chunk
-    # device programs compile once per ladder rung, not per config value
-    chunk_rows = snap_chunk_rows(chunk_rows)
-    top, scalar_agg, droot = split_top(plan)
-    group_node = None
-    if isinstance(droot, pp.GroupBy):
-        group_node = droot
-        inner = droot.child
-    else:
-        inner = droot
+    chunk_rows = granule_rows_for(budget_rows, chunk_rows)
     big = set(big_tables if big_tables is not None else providers)
     if not big:
         raise NotDistributable("no over-budget table to stream")
-
-    def _split(aggs):
-        # the spill tier's public contract is NotDistributable for every
-        # unsupported shape — including non-splittable aggregates
-        try:
-            return split_aggs(aggs)
-        except NotImplementedError as e:
-            raise NotDistributable(str(e)) from None
+    missing = big - set(providers)
+    if missing:
+        raise NotDistributable(f"no chunk provider for {sorted(missing)}")
 
     import time as _time
 
@@ -168,32 +199,17 @@ def execute_spilled(plan: pp.PlanNode, providers: dict, spill_dir: str,
                        label=label) as store, \
             qtrace.span("spill.execute") as tsp:
         ctx = _Ctx(store, budget_rows, chunk_rows, providers,
-                   device_tables or {}, types_by_table or {}, big)
+                   device_tables or {}, types_by_table or {}, big,
+                   budget_bytes)
+        gp = None
+        if len(big) == 1:
+            try:
+                gp = GranulePlan(plan, next(iter(big)), chunk_rows)
+            except NotDistributable:
+                gp = None       # the host half may still take the shape
         try:
-            batches = _stream_subtree(ctx, inner)
-            if group_node is not None:
-                partial_specs, final_specs, post = \
-                    _split(group_node.aggs)
-                keys = group_node.keys
-                batches = _partial_groupby_batches(ctx, batches, keys,
-                                                   partial_specs)
-                batches = _merge_group_partials(ctx, batches, list(keys),
-                                                final_specs, post)
-                ctx.stats.kind = "groupby"
-            elif scalar_agg is not None:
-                partial_specs, final_specs, post = \
-                    _split(scalar_agg.aggs)
-                batches = _partial_scalar_batches(ctx, batches,
-                                                  partial_specs)
-                batches = _scalar_final(ctx, batches, final_specs, post)
-                ctx.stats.kind = "scalar"
-            else:
-                ctx.stats.kind = "sort"
-            # the granule streams above are lazy: _finish drives them,
-            # so the whole spill pipeline's work lands inside this span
-            # (closing at the host result boundary)
-            with qtrace.span("spill.finish"):
-                arrays, valids = _finish(ctx, batches, top)
+            out = _stream_one_table(ctx, gp) if gp is not None \
+                else _stream_tree(ctx, plan)
         finally:
             ctx.snap_store()
         if any(k == "join" for k, _ in ctx.stats.ops):
@@ -209,7 +225,112 @@ def execute_spilled(plan: pp.PlanNode, providers: dict, spill_dir: str,
                      kind=ctx.stats.kind)
         qmetrics.observe("spill.execute_s", _time.monotonic() - m0,
                          kind=ctx.stats.kind)
-        return arrays, valids, dict(ctx.dtypes), ctx.stats
+        return out
+
+
+def _has_join(node: pp.PlanNode) -> bool:
+    return isinstance(node, (pp.HashJoin, pp.SemiJoinResidual,
+                             pp.IndexProbe)) \
+        or any(_has_join(c) for c in node.children())
+
+
+def _granule_outputs(ctx: _Ctx, gp: GranulePlan):
+    """The device outputs of ``gp``'s chunk program, a granule each."""
+    for out in stream_outputs(gp, ctx.providers[gp.table],
+                              ctx.device_tables,
+                              ctx.types_by_table.get(gp.table),
+                              ctx.budget_bytes):
+        ctx.stats.batches += 1
+        yield out
+
+
+def _stream_one_table(ctx: _Ctx, gp: GranulePlan) -> Spilled:
+    """``gp.table`` streams through one chunk program.  An aggregate's
+    partial states stay on the device while they fit the work area beside
+    the granules, and merge there; states that outgrow it, and the rows of
+    a statement that only sorts, drain to the host half."""
+    ctx.note("scan-stream", gp.table)
+    if _has_join(gp.inner):
+        ctx.note("join", "probe-resident")
+    ctx.stats.kind = ("groupby" if gp.group is not None else
+                      "scalar" if gp.scalar is not None else "sort")
+    stream = _granule_outputs(ctx, gp)
+    if gp.aggregates:
+        held, lanes, drained = [], 0, False
+        for out in stream:
+            held.append(out)
+            lanes += out.capacity
+            # the merge program pads its inputs to a power of two
+            if gp.group is not None and 2 * lanes > ctx.budget_rows:
+                drained = True
+                break
+        if not drained:
+            return Spilled(ctx.stats, relation=merge_outputs(gp, held))
+        ctx.note("groupby", "state over the work area: disk merge")
+
+        def partial_batches():
+            for out in held:
+                yield from _host_batch(ctx, out, counted=False)
+            held.clear()
+            for out in stream:
+                yield from _host_batch(ctx, out, counted=False)
+
+        batches = _merge_group_partials(
+            ctx, partial_batches(), list(gp.group.keys), gp.final_specs,
+            gp.post)
+    else:
+        def batches_of():
+            for out in stream:
+                yield from _host_batch(ctx, out, counted=False)
+
+        batches = batches_of()
+    with qtrace.span("spill.finish"):
+        arrays, valids = _finish(ctx, batches, gp.top)
+    return Spilled(ctx.stats, None, arrays, valids, dict(ctx.dtypes))
+
+
+def _stream_tree(ctx: _Ctx, plan: pp.PlanNode) -> Spilled:
+    """The host half's general walk: every over-budget scan streams to
+    host batches, joins between two of them co-partition to disk, the
+    aggregate folds batch by batch."""
+    top, scalar_agg, droot = split_top(plan)
+    group_node = None
+    if isinstance(droot, pp.GroupBy):
+        group_node = droot
+        inner = droot.child
+    else:
+        inner = droot
+
+    def _split(aggs):
+        # the spill tier's public contract is NotDistributable for every
+        # unsupported shape — including non-splittable aggregates
+        try:
+            return split_aggs(aggs)
+        except NotImplementedError as e:
+            raise NotDistributable(str(e)) from None
+
+    batches = _stream_subtree(ctx, inner)
+    if group_node is not None:
+        partial_specs, final_specs, post = _split(group_node.aggs)
+        keys = group_node.keys
+        batches = _partial_groupby_batches(ctx, batches, keys,
+                                           partial_specs)
+        batches = _merge_group_partials(ctx, batches, list(keys),
+                                        final_specs, post)
+        ctx.stats.kind = "groupby"
+    elif scalar_agg is not None:
+        partial_specs, final_specs, post = _split(scalar_agg.aggs)
+        batches = _partial_scalar_batches(ctx, batches, partial_specs)
+        batches = _scalar_final(ctx, batches, final_specs, post)
+        ctx.stats.kind = "scalar"
+    else:
+        ctx.stats.kind = "sort"
+    # the granule streams above are lazy: _finish drives them, so the
+    # whole spill pipeline's work lands inside the caller's span
+    # (closing at the host result boundary)
+    with qtrace.span("spill.finish"):
+        arrays, valids = _finish(ctx, batches, top)
+    return Spilled(ctx.stats, None, arrays, valids, dict(ctx.dtypes))
 
 
 # ---------------------------------------------------------------------------
@@ -247,74 +368,29 @@ def _stream_subtree(ctx: _Ctx, node: pp.PlanNode):
 
 
 def _scan_batches(ctx: _Ctx, subtree: pp.PlanNode, table: str):
-    """Granules -> compiled device scan/filter/project -> host batches.
-    A dead probe granule runs first to capture output dtypes (and costs
-    one compile, which the real granules reuse)."""
-    provider = ctx.providers[table]
-    types = ctx.types_by_table.get(table) or {}
-    gdicts = _global_dicts(provider, table, ctx.chunk_rows)
-    bounds = extract_column_bounds(subtree)
-    chunk_rows = ctx.chunk_rows
-
-    @jax.jit
-    def chunk_fn(tables):
-        return ops.compact(pp._lower_inner(subtree, tables))
+    """Granules -> the cached chunk program of a scan pipeline -> host
+    batches of the rows that survive it."""
+    gp = GranulePlan(subtree, table, ctx.chunk_rows, subtree=True)
 
     def gen():
-        import jax.numpy as jnp
-
-        probe = _dead_granule(types, gdicts, chunk_rows)
-        if probe is not None:
-            out = chunk_fn({table: probe})
-            ctx.record_dtypes(out)
-        from oceanbase_tpu.exec.granule import prefetch_iter
-
-        for arrays, valids in prefetch_iter(
-                provider(table, chunk_rows, bounds)):
-            n = len(next(iter(arrays.values()))) if arrays else 0
-            if n == 0:
-                continue
-            rel = _chunk_to_relation(arrays, valids, types, gdicts,
-                                     chunk_rows, n)
-            if n < chunk_rows and rel.mask is None:
-                m = np.zeros(chunk_rows, dtype=bool)
-                m[:n] = True
-                rel = Relation(columns=rel.columns, mask=jnp.asarray(m))
-            out = chunk_fn({table: rel})
-            ctx.record_dtypes(out)
-            yield from _host_batch(ctx, out)
+        for out in _granule_outputs(ctx, gp):
+            yield from _host_batch(ctx, out, counted=False)
 
     ctx.note("scan-stream", table)
     return gen()
 
 
-def _dead_granule(types: dict, gdicts: dict, chunk_rows: int):
-    """All-dead fixed-shape granule for dtype probing (cheap: one row of
-    zeros padded to capacity)."""
-    import jax.numpy as jnp
-
-    if not types:
-        return None
-    arrays = {}
-    for c, t in types.items():
-        if t.is_string:
-            arrays[c] = np.array([""], dtype=object)
-        else:
-            arrays[c] = np.zeros(1, dtype=t.np_dtype)
-    rel = _chunk_to_relation(arrays, {}, types, gdicts, chunk_rows, 1)
-    return Relation(columns=rel.columns,
-                    mask=jnp.zeros(rel.capacity, dtype=jnp.bool_))
-
-
-def _host_batch(ctx: _Ctx, rel: Relation):
+def _host_batch(ctx: _Ctx, rel: Relation, counted: bool = True):
     """Device relation -> one host (arrays, valids) batch (live rows).
 
     Every produced batch funnels through here, which makes it the
     spill tier's per-chunk cancel/deadline checkpoint: KILL and
-    query_timeout_s observe between chunk programs, host-side."""
+    query_timeout_s observe between chunk programs, host-side.
+    ``counted``: a granule's output is counted where it was made."""
     from oceanbase_tpu.server import admission as qadmission
 
     qadmission.checkpoint()
+    ctx.record_dtypes(rel)
     host = to_numpy(rel)
     cols = [c for c in host if not c.startswith("__valid__")]
     if not cols:
@@ -323,7 +399,7 @@ def _host_batch(ctx: _Ctx, rel: Relation):
     if len(next(iter(arrays.values()))) == 0:
         return
     valids = {c: host.get("__valid__" + c) for c in cols}
-    ctx.stats.batches += 1
+    ctx.stats.batches += counted
     yield arrays, valids
 
 
@@ -513,7 +589,7 @@ def _scalar_final(ctx: _Ctx, batches, final_specs, post):
         arrays, valids = _concat_batches(parts_a, parts_v)
         starts = np.array([0])
         out_a, out_v = _reduce_groups(arrays, valids, [], final_specs,
-                                      starts)
+                                      starts, ctx.dtypes)
         yield from _post_project(ctx, out_a, out_v, {}, post)
 
     return gen()
@@ -548,7 +624,8 @@ def _merge_group_partials(ctx: _Ctx, batches, key_names, final_specs,
                 head_v = {k: (v[:cut] if v is not None else None)
                           for k, v in valids.items()}
                 out_a, out_v = _reduce_groups(
-                    head_a, head_v, key_names, final_specs, starts[:-1])
+                    head_a, head_v, key_names, final_specs, starts[:-1],
+                    ctx.dtypes)
                 yield from _post_project(ctx, out_a, out_v,
                                          key_names, post)
             cut = starts[-1] if len(starts) else 0
@@ -560,7 +637,7 @@ def _merge_group_partials(ctx: _Ctx, batches, key_names, final_specs,
             arrays, valids = carry
             starts = _group_starts(arrays, valids, key_names)
             out_a, out_v = _reduce_groups(arrays, valids, key_names,
-                                          final_specs, starts)
+                                          final_specs, starts, ctx.dtypes)
             yield from _post_project(ctx, out_a, out_v, key_names, post)
 
     return gen()
@@ -613,7 +690,8 @@ def _group_starts(arrays, valids, key_names) -> np.ndarray:
 _INT_SENT = {"min": np.iinfo(np.int64).max, "max": np.iinfo(np.int64).min}
 
 
-def _reduce_groups(arrays, valids, key_names, final_specs, starts):
+def _reduce_groups(arrays, valids, key_names, final_specs, starts,
+                   dtypes: dict | None = None):
     """Merge partial-aggregate rows per equal-key group (vectorized
     ufunc.reduceat; object/NULL-heavy min/max falls back to a per-group
     loop)."""
@@ -622,6 +700,10 @@ def _reduce_groups(arrays, valids, key_names, final_specs, starts):
              for k in key_names}
     for spec in final_specs:
         pname = spec.arg.name
+        if dtypes is not None and pname in dtypes:
+            # a sum / min / max of a partial keeps the partial's type (a
+            # DECIMAL's scale rides it to the post projection)
+            dtypes[spec.name] = dtypes[pname]
         a = arrays[pname]
         v = valids.get(pname)
         if spec.fn == "sum":

@@ -339,7 +339,7 @@ class NodeServer:
         budget crossings (and read-only auto-exit) must not ride out
         the checkpoint-loop cadence."""
         self.config.set(str(name), value)
-        if str(name).endswith("_disk_limit_bytes"):
+        if str(name) in self.tenant.diskmgr.LIMIT_PARAMS.values():
             self.tenant.diskmgr.poll(force=True)
         return {"node_id": self.node_id, "name": str(name),
                 "read_only": bool(self.tenant.diskmgr.read_only)}

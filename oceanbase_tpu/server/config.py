@@ -181,7 +181,7 @@ DEF("data_disk_limit_bytes", 0, "cap",
     "per-tenant data directory (segments + manifest + slog) budget; "
     "at the limit the tenant enters read-only until space frees",
     _nonneg)
-DEF("spill_disk_limit_bytes", 0, "cap",
+DEF("temporary_file_max_disk_size", 0, "cap",
     "per-tenant temp-file (spill) byte budget; exhaustion kills only "
     "the spilling statement (typed SpillBudgetExceeded) — ≙ the "
     "tmp-file quota", _nonneg)

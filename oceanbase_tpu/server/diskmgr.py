@@ -94,7 +94,7 @@ class TenantReadOnly(RuntimeError):
 
 
 class SpillBudgetExceeded(RuntimeError):
-    """The statement's spill would exceed spill_disk_limit_bytes.
+    """The statement's spill would exceed temporary_file_max_disk_size.
     Only this statement dies; the durable surface is untouched."""
 
 
@@ -171,8 +171,14 @@ class DiskManager:
         self._spill: dict[int, dict] = {}
 
     # -- knobs ---------------------------------------------------------
+    #: the parameter that caps each surface (the temp-file store's is
+    #: upstream's own name)
+    LIMIT_PARAMS = {"log": "log_disk_limit_bytes",
+                    "data": "data_disk_limit_bytes",
+                    "spill": "temporary_file_max_disk_size"}
+
     def limit(self, surface: str) -> int:
-        return int(self.config[f"{surface}_disk_limit_bytes"])
+        return int(self.config[self.LIMIT_PARAMS[surface]])
 
     def threshold_pct(self) -> int:
         return int(self.config["log_disk_utilization_threshold"])
@@ -327,7 +333,7 @@ class DiskManager:
         qmetrics.inc("disk.spill_rejections")
         raise SpillBudgetExceeded(
             f"statement spill would reach {pass_total} bytes "
-            f"(spill_disk_limit_bytes={limit}); statement killed, "
+            f"(temporary_file_max_disk_size={limit}); statement killed, "
             f"durable surface untouched")
 
     def release_spill(self, store=None, nbytes: int | None = None):

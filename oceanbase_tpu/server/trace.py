@@ -94,6 +94,11 @@ PHASE_OF = {
     "px.unshard": "unshard_s",
     "px.merge": "merge_s",
     "px.device_wait": "device_s",
+    # a streamed statement: a granule's chunk program (dispatch and the
+    # wait for it), the merge of the partial states around its own plan
+    # program; fetch and upload run on the producer thread and book none
+    "granule.program": "device_s",
+    "granule.merge": "merge_s",
     # the write path: a DML statement's own work, the commit, what the
     # commit waits for its replicated log, a foreground flush
     "dml.bind": "dml_s",

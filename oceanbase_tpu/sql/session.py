@@ -126,6 +126,9 @@ class Session:
         # budget was raised by}: a statement that overflowed an exchange
         # starts its next execution at the budget that cleared it
         self._px_budgets: dict[str, dict] = {}
+        #: capacity factor a streamed plan's granule programs cleared at,
+        #: by logical plan (the spill tier's half of the retry ladder)
+        self._spill_factors: dict[str, int] = {}
         self._plan_cache_bytes: dict[tuple, int] = {}
         self._plan_cache_total = 0
         self._last_spill = None  # SpillStats of the last spilled query
@@ -1887,7 +1890,10 @@ class Session:
         # a table at a time, as the spill tier streams: the rows reaching
         # the plan against the rows of it the work area holds
         big = {t for t, e in est.items() if e > fits(t)}
-        if not force_largest:
+        if not force_largest and any(t in self._engine.tables for t in refs):
+            # a statement over stored tables was priced; one that reads
+            # only what the session just materialised (gv$ tables, a
+            # transient relation) had nothing to keep resident or stream
             qmetrics.inc("sql.work_area_decisions",
                          kind="spill" if big else "resident")
         if not big and force_largest and est:
@@ -1929,15 +1935,20 @@ class Session:
         return fits
 
     def _try_spilled(self, plan, outputs, big: set):
-        """Execute through exec/spill_exec (granule streams + temp-file
-        runs).  -> Result, or None when the plan shape is unsupported
-        (caller falls back to the in-memory engine)."""
+        """Execute through exec/spill_exec (granule streams, device
+        merge, temp-file runs).  -> Result, or None when the plan shape
+        cannot stream: the caller then runs the resident plan, which the
+        budget refused, so the fall-back is counted by its reason
+        (``spill.fallbacks{reason}``) and tagged on the statement's
+        trace."""
         import os
         import uuid
 
         from oceanbase_tpu.exec import spill_exec
+        from oceanbase_tpu.exec.granule import segment_chunk_provider
         from oceanbase_tpu.exec.plan import referenced_tables
         from oceanbase_tpu.px.planner import NotDistributable
+        from oceanbase_tpu.server.config import work_area_bytes
 
         # ONE read point for every table in the query (big streams and
         # small device relations alike) — a commit landing mid-query must
@@ -1950,7 +1961,7 @@ class Session:
         for t in referenced_tables(plan):
             ts = self._engine.tables.get(t)
             if t in big and ts is not None:
-                providers[t] = self._spill_provider(ts.tablet, snap)
+                providers[t] = segment_chunk_provider(ts.tablet, snap)
                 types_by_table[t] = {c.name: c.dtype
                                      for c in ts.tdef.columns}
             elif t in big and self.catalog.has_table(t):
@@ -1969,24 +1980,58 @@ class Session:
         self._prepare_index_probes(plan, device_tables)
         sdir = os.path.join(self.db.root or "/tmp/obtpu", "tmpfile",
                             f"q{uuid.uuid4().hex[:10]}")
+        cfg = self.tenant.config
         t0 = time.time()       # record timestamp (wall)
         m0 = time.monotonic()  # elapsed source (step-proof)
+        # a granule program's static budgets overflow as a resident
+        # plan's do: the same ladder, and the factor that cleared is
+        # where this plan's next execution starts
+        from oceanbase_tpu.exec.plan import logical_hash
+
+        lhash = logical_hash(plan)
+        factor = self._spill_factors.get(lhash, 1)
+        retries = int(self.variables["max_capacity_retry"])
         try:
-            arrays, valids, dtypes, stats = spill_exec.execute_spilled(
-                plan, providers, sdir,
-                # its sorts and joins count rows: the fewest the work
-                # area holds of a streamed table
-                max(min(map(self._work_area(plan), big)), 1),
-                device_tables, types_by_table, big,
-                disk_budget=self.tenant.diskmgr,
-                faults=self.db.faults,
-                label=(self._ash_state.get("sql", "")[:80]
-                       or f"session {self.session_id}"))
-        except (NotDistributable, NotImplementedError):
+            for attempt in range(retries + 1):
+                try:
+                    out = spill_exec.execute_spilled(
+                        plan if factor == 1
+                        else scale_capacities(plan, factor),
+                        providers, sdir,
+                        # its sorts and joins count rows: the fewest the
+                        # work area holds of a streamed table
+                        max(min(map(self._work_area(plan), big)), 1),
+                        device_tables, types_by_table, big,
+                        disk_budget=self.tenant.diskmgr,
+                        faults=self.db.faults,
+                        label=(self._ash_state.get("sql", "")[:80]
+                               or f"session {self.session_id}"),
+                        budget_bytes=None
+                        if int(cfg["sql_work_area_rows"])
+                        else work_area_bytes(cfg))
+                    break
+                except CapacityOverflow:
+                    if attempt >= retries:
+                        raise
+                    qmetrics.inc("plan.capacity_retries")
+                    factor *= 4
+                    self._spill_factors[lhash] = factor
+        except (NotDistributable, NotImplementedError) as e:
             # unsupported shape OR a non-splittable aggregate
-            # (count_distinct) — fall back to the in-memory engine
+            # (count_distinct): the resident engine answers
+            reason = str(e)[:60] or type(e).__name__
+            qmetrics.inc("spill.fallbacks", reason=reason)
+            with qtrace.span("spill.fallback", reason=reason):
+                pass
             return None
+        stats = out.stats
         self._last_spill = stats
+        # a statement finished on the device leaves as any plan's result
+        # does; the host half hands its columns over
+        with qtrace.span("materialize") as msp:
+            result = self._materialize(out.relation, outputs, msp.tags) \
+                if out.relation is not None else self._materialize_host(
+                    out.arrays, out.valids, out.dtypes, outputs)
         elapsed = time.monotonic() - m0
         try:
             plan_hash = plan.fingerprint()[:64]
@@ -2007,8 +2052,7 @@ class Session:
             from oceanbase_tpu.exec.plan import monitored_postorder
             from oceanbase_tpu.exec.plan import q_error as _qe
 
-            n_out = (len(next(iter(arrays.values())))
-                     if arrays else 0)
+            n_out = result.rowcount
             # the row must describe the operator that OWNS its postorder
             # position: a pass-through root (Sort/Project) emits no
             # monitor lane, so name/est come from the last MONITORED
@@ -2032,7 +2076,7 @@ class Session:
                 spill_bytes=stats.bytes, path="spill",
                 host_s=times.host_s, device_s=times.device_s,
                 pred_s=pred_s, time_q=time_q)
-        return self._materialize_host(arrays, valids, dtypes, outputs)
+        return result
 
     def _catalog_provider(self, name: str):
         """Chunk provider over a relation without a tablet (external /
@@ -2047,22 +2091,6 @@ class Session:
         valids = {k[len("__valid__"):]: v for k, v in raw.items()
                   if k.startswith("__valid__")}
         return numpy_chunk_provider(arrays, valids)
-
-    @staticmethod
-    def _spill_provider(tablet, snapshot: int):
-        """Chunk provider over one tablet (partitions chain in order)."""
-        from oceanbase_tpu.exec.granule import segment_chunk_provider
-
-        parts = getattr(tablet, "partitions", None)
-        if parts is None:
-            return segment_chunk_provider(tablet, snapshot)
-        provs = [segment_chunk_provider(p, snapshot) for p in parts]
-
-        def provider(table, chunk_rows, bounds=None):
-            for p in provs:
-                yield from p(table, chunk_rows, bounds)
-
-        return provider
 
     def _materialize_host(self, arrays, valids, dtypes, outputs) -> Result:
         """Result from host columns (the spill path's output boundary —

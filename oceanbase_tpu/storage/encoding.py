@@ -221,3 +221,21 @@ def decode_column(ec: EncodedColumn, out_dtype=None) -> np.ndarray:
     if out_dtype is not None and data.dtype != out_dtype:
         data = data.astype(out_dtype)
     return data
+
+
+def decode_column_into(ec: EncodedColumn, out: np.ndarray, lut=None):
+    """Decode one chunk into ``out`` (``ec.n`` elements of the reader's
+    buffer): a granule's chunks land in one array a column, with no
+    concatenation after.  ``lut``: for an ``sdict`` chunk, the table from
+    its own codes to the codes the reader wants (the strings are not
+    touched)."""
+    if ec.encoding == "sdict":
+        if lut is None:
+            raise ValueError("a string chunk decodes into codes of a "
+                             "dictionary: pass its table")
+        np.take(lut, ec.payload["codes"], out=out)
+    elif ec.encoding == "dict" and \
+            ec.payload["values"].dtype == out.dtype:
+        np.take(ec.payload["values"], ec.payload["codes"], out=out)
+    else:
+        out[:] = decode_column(ec)

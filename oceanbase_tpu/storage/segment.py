@@ -46,6 +46,9 @@ class Segment:
     # to snapshots >= max_version
     min_version: int = 0
     max_version: int = 0
+    #: what readers derive from the (immutable) chunks once and keep: a
+    #: string column's merged dictionary, whether the keys are unique
+    cache: dict = field(default_factory=dict, repr=False, compare=False)
 
     @property
     def n_chunks(self) -> int:

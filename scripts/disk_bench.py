@@ -71,7 +71,7 @@ def workload_block(s, keys, n_writes=40):
 
 def _set_limits(s, lim):
     for knob in ("log_disk_limit_bytes", "data_disk_limit_bytes",
-                 "spill_disk_limit_bytes"):
+                 "temporary_file_max_disk_size"):
         s.execute(f"alter system set {knob} = {lim}")
 
 

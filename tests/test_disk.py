@@ -71,7 +71,7 @@ def _leader(tmp_path, n_entries=0):
 
 def _cfg(**kw):
     cfg = {"log_disk_limit_bytes": 0, "data_disk_limit_bytes": 0,
-           "spill_disk_limit_bytes": 0,
+           "temporary_file_max_disk_size": 0,
            "log_disk_utilization_threshold": 80}
     cfg.update(kw)
     return cfg
@@ -295,7 +295,7 @@ def test_spill_fault_typed_no_residue(tmp_path, action, exc_type):
 
 
 def test_spill_budget_kills_statement_only(tmp_path):
-    dm = DiskManager(_cfg(spill_disk_limit_bytes=1), paths={},
+    dm = DiskManager(_cfg(temporary_file_max_disk_size=1), paths={},
                      poll_interval_s=0.0)
     big = {"x": np.random.default_rng(0).integers(0, 1 << 30, 4096)}
     with TempFileStore(str(tmp_path / "spill"), budget=dm,
@@ -313,7 +313,7 @@ def test_spill_budget_kills_statement_only(tmp_path):
 
 
 def test_spill_accounting_admit_release_and_stats(tmp_path):
-    dm = DiskManager(_cfg(spill_disk_limit_bytes=1 << 20), paths={})
+    dm = DiskManager(_cfg(temporary_file_max_disk_size=1 << 20), paths={})
     arrays = {"x": np.arange(256, dtype=np.int64)}
     with TempFileStore(str(tmp_path / "s"), budget=dm,
                        label="select heavy") as store:
@@ -514,7 +514,7 @@ def test_statement_spill_budget_via_sql(tmp_path):
     s.execute("insert into t values " + ", ".join(
         f"({i}, {(i * 7919) % 100000})" for i in range(3000)))
     s.execute("alter system set sql_work_area_rows = 100")
-    s.execute("alter system set spill_disk_limit_bytes = 1")
+    s.execute("alter system set temporary_file_max_disk_size = 1")
     with pytest.raises(SpillBudgetExceeded):
         s.execute("select k, v from t order by v, k")
     dm = db.tenant("sys").diskmgr
@@ -524,7 +524,7 @@ def test_statement_spill_budget_via_sql(tmp_path):
     s.execute("insert into t values (9001, 1)")
     assert s.execute("select count(*) from t").rows()[0][0] == 3001
     # with a sane budget the same statement completes spilled
-    s.execute("alter system set spill_disk_limit_bytes = 1073741824")
+    s.execute("alter system set temporary_file_max_disk_size = 1073741824")
     got = s.execute("select k, v from t order by v, k").rows()
     assert len(got) == 3001
     assert got == sorted(got, key=lambda r: (r[1], r[0]))

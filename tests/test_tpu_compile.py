@@ -511,6 +511,64 @@ def test_sf10_plan_compiles_for_v5e_and_fits(qnum, sf10_session, one_chip,
         assert len(gathers) <= 7 + 2, gathers
 
 
+@pytest.mark.parametrize("qnum", [1, 6, 14])
+def test_sf10_wa5_chunk_program_compiles_for_v5e_and_fits(
+        qnum, sf10_session, one_chip, no_persistent_cache):
+    """The chunk program of a statement of ``tpch_sf10_wa5.streamed``
+    (``exec/granule.py::GranulePlan``: the plan under its aggregate with
+    the partial aggregate on top) lowered for the described chip over ONE
+    granule of 2,097,152 lanes of ``lineitem`` (the columns the plan
+    reaches, with the row mask every granule carries) and, for Q14, the
+    resident ``part`` at SF10's lanes; and the merge program over 32
+    partial states.  A granule's program and its four buffers in flight
+    lie under 5 % of the chip's memory by the compiler's own analysis."""
+    from oceanbase_tpu.bench.tpch_queries import QUERIES
+    from oceanbase_tpu.exec import granule
+    from oceanbase_tpu.exec import plan as qplan
+    from oceanbase_tpu.sql.parser import parse_sql
+
+    sess = sf10_session
+    plan, _outs, _est = sess._plan_select(parse_sql(QUERIES[qnum]), None)
+    lanes = granule.DEFAULT_CHUNK_ROWS
+    gp = granule.GranulePlan(plan, "lineitem", lanes)
+    assert gp.aggregates and (gp.group is not None) == (qnum == 1)
+    bundle = gp.chunk_executable()
+    mentioned, renames = qplan.scan_columns(gp.chunk)
+    tables = {}
+    for name in qplan.referenced_tables(gp.chunk):
+        rel = qplan.narrowed(sess.catalog.table_data(name), mentioned,
+                             renames.get(name))
+        n = lanes if name == "lineitem" else SF10_LANES[name]
+        tables[name] = jax.tree.map(
+            lambda x, n=n: jax.ShapeDtypeStruct(
+                (n,) + x.shape[1:], x.dtype, sharding=one_chip),
+            rel.pad_to(rel.capacity + 1))
+    compiled = bundle._run.lower(tables).compile()
+    ma = compiled.memory_analysis()
+    granule_bytes = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree.leaves(tables["lineitem"]))
+    print(f"Q{qnum} chunk program over {lanes} lanes: granule "
+          f"{granule_bytes}, arguments {ma.argument_size_in_bytes}, "
+          f"outputs {ma.output_size_in_bytes}, temporaries "
+          f"{ma.temp_size_in_bytes} bytes")
+    work_area = V5E_HBM_BYTES * 5 // 100
+    assert granule_bytes * granule.BUFFERS_IN_FLIGHT < work_area
+    assert (granule_bytes * (granule.BUFFERS_IN_FLIGHT - 1)
+            + ma.output_size_in_bytes + ma.temp_size_in_bytes) < work_area
+    # the merge program over the partial states of 29 granules (32 inputs)
+    out = jax.eval_shape(bundle._run, tables)[0]
+    n_in = granule.merge_inputs(29)
+    merge = gp.merge_plan(n_in)
+    key = merge.fingerprint()
+    mb = qplan.executable_for(
+        qplan.Program(qplan._lower, (merge,), key, key), False)
+    parts = {granule._PARTIAL.format(i): jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        out) for i in range(n_in)}
+    _fits(mb._run.lower(parts).compile())
+
+
 @pytest.fixture(scope="module")
 def sf10_orders_session(new_module_session):
     """``tpch_sf10_orders``' three tables at SF 0.01 with statistics that
