@@ -14,18 +14,23 @@ granule is a fixed-shape host->HBM chunk, and one statement is:
 - host -> device (``granule.upload``): the granule's columns padded to
   the granule shape, always with a row mask, so every granule of a
   statement has one input signature;
-- device (``granule.program``): ONE program per (plan fingerprint,
-  granule shape) from the executable cache every plan uses
+- device (``granule.program``): ONE program per (chunk plan as it is
+  lowered, granule shape) from the executable cache every plan uses
   (``exec/plan.py::executable_for``, rows in ``gv$plan_cache``): scan,
   filter, project, the probes of device-resident build sides and the
-  partial aggregate.  Partial states stay on the device;
+  partial aggregate, each BUDGETED FOR ONE GRANULE (``granule_budget``,
+  the one place that says what that is).  Partial states stay on the
+  device;
 - device (``granule.merge``): the partial states, the final aggregate, the
   post projection and the coordinator chain (sort / limit / project) as
   one more cached program.
 
 Uploads overlap the previous granule's program (``prefetch_iter``); the
 granule's bytes times the buffers in flight stay under the work area
-(``stream_outputs`` asserts it).  What does not fit the device this way
+(``stream_outputs`` asserts it).  A granule that drops a row for its
+budget ends the stream there (``diag.CapacityOverflow``): the session
+re-plans by what was dropped and keeps the factor that cleared.  What
+does not fit the device this way
 (sorted runs, group-by states over the budget, joins with both sides over
 it) is ``exec/spill_exec.py``'s, on the host and in the temp-file store.
 """
@@ -33,6 +38,7 @@ it) is ``exec/spill_exec.py``'s, on the host and in the temp-file store.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import numpy as np
@@ -66,6 +72,14 @@ qmetrics.declare("granule.upload_bytes", "counter",
                  "bytes of granule columns copied host -> device")
 qmetrics.declare("granule.pruned_chunks", "counter",
                  "segment chunks zone maps skipped before decode")
+qmetrics.declare("granule.budget_lanes", "counter",
+                 "per chunk-program execution, the capacities its nodes "
+                 "over the streamed table were lowered with (Compact, "
+                 "join outputs: granule_budget's share of the plan's)")
+qmetrics.declare("granule.plan_budget_lanes", "counter",
+                 "per chunk-program execution, the capacities the "
+                 "statement's plan gave the same nodes (over "
+                 "granule.budget_lanes: what a granule's budget saves)")
 
 
 def snap_chunk_rows(chunk_rows: int) -> int:
@@ -389,9 +403,10 @@ def _uploaded(granules, types, dicts, chunk_rows, counts):
 class _Counts:
     """What one statement's stream did; booked once, at its end."""
 
-    def __init__(self, provider):
+    def __init__(self, provider, gp: "GranulePlan"):
         self.granules = self.rows = self.upload_bytes = self.pruned = 0
-        self._provider = provider
+        self.programs = 0       # chunk-program executions
+        self._provider, self._gp = provider, gp
         self._seen = 0
 
     def take_pruned(self) -> int:
@@ -406,6 +421,10 @@ class _Counts:
         qmetrics.inc("granule.rows", self.rows)
         qmetrics.inc("granule.upload_bytes", self.upload_bytes)
         qmetrics.inc("granule.pruned_chunks", self.pruned)
+        qmetrics.inc("granule.budget_lanes",
+                     self.programs * self._gp.budget_lanes)
+        qmetrics.inc("granule.plan_budget_lanes",
+                     self.programs * self._gp.plan_budget_lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -444,11 +463,88 @@ def _scans_of(node: pp.PlanNode, table: str) -> int:
     return n + sum(_scans_of(c, table) for c in node.children())
 
 
+def granule_budget(capacity: int, chunk_rows: int,
+                   share: float | None = None,
+                   within_granule: bool = True) -> int:
+    """The lanes ONE granule's chunk program gives a node to which the
+    statement's plan gave ``capacity`` (the binder's slack and whatever
+    the overflow ladder multiplied in are in it).  The one place that
+    says what a granule's budget is:
+
+    - ``share``, the granule's lanes over the streamed table's rows as the
+      estimate saw them: rows that DIVIDE among the granules (a
+      ``Compact``'s survivors, a join's matches) get that share of the
+      capacity, rounded UP to the bucket ladder.  ``None`` for what does
+      not divide: a group-by's groups (each of Q1's granules holds all
+      four);
+    - ``within_granule``: the node puts out no more rows than its granule
+      has lanes (a ``Compact``, a group-by; not a join, which may expand);
+    - never above ``capacity``: a budget the ladder raised until nothing
+      dropped ends at the program the whole table's capacities give."""
+    budget = capacity
+    if share is not None:
+        budget = min(budget, bucket_capacity(math.ceil(capacity * share)))
+    return min(budget, chunk_rows) if within_granule else budget
+
+
+def _streamed_share(inner: pp.PlanNode, table: str,
+                    chunk_rows: int) -> float | None:
+    """A granule's share of ``table``: its lanes over the rows the
+    estimate gave the table's one scan; ``None`` where the scan carries
+    no estimate."""
+    (scan,) = [n for n in pp._postorder(inner)
+               if isinstance(n, pp.TableScan) and n.table == table]
+    return min(chunk_rows / scan.est_rows, 1.0) if scan.est_rows else None
+
+
+def _rebudgeted(node: pp.PlanNode, table: str, chunk_rows: int,
+                share: float, lanes: list) -> pp.PlanNode:
+    """``node`` (what ``linear_in`` accepts) with every static capacity
+    over the streamed ``table`` at the granule's budget.  A subtree that
+    does not scan ``table`` keeps its capacities: it sees the whole of
+    its tables in every granule; a node without a capacity takes its
+    input's lanes and goes on doing so.  ``lanes``: [lowered, the
+    plan's], the re-budgeted nodes' capacities summed."""
+    if table not in pp.referenced_tables(node):
+        return node
+    updates = {}
+    for f in ("child", "left", "right"):
+        kid = getattr(node, f, None)
+        if kid is not None:
+            new = _rebudgeted(kid, table, chunk_rows, share, lanes)
+            if new is not kid:
+                updates[f] = new
+    budget = "capacity" if isinstance(node, pp.Compact) else "out_capacity"
+    given = getattr(node, budget, None)
+    if given is not None:
+        mine = granule_budget(given, chunk_rows, share,
+                              within_granule=isinstance(node, pp.Compact))
+        lanes[0] += mine
+        lanes[1] += given
+        if mine != given:
+            updates[budget] = mine
+    return dataclasses.replace(node, **updates) if updates else node
+
+
 class GranulePlan:
     """A plan split for granule streaming over ONE table: the program a
     granule runs (``chunk``: the plan's subtree under its aggregate, with
     the partial aggregate on top), and the program that finishes the
-    statement over the granules' outputs (``merge_plan``)."""
+    statement over the granules' outputs (``merge_plan``).
+
+    The chunk program is budgeted for ONE granule (``granule_budget``):
+    the binder sized the subtree for the whole table, a granule holds its
+    share.  Every ``Compact.capacity`` and join ``out_capacity`` on a node
+    whose subtree scans the streamed table shrinks to the granule's share
+    of the estimate (Q14 at SF10: 2,097,152 lanes of 60.0M rows, so the
+    2,097,152-lane bucket of the month's filter becomes 131,072); the
+    partial group-by keeps its capacity under the granule's lanes and is
+    not scaled (groups do not divide among granules); resident subtrees
+    and a plan whose streamed scan carries no estimate keep what they
+    have.  A granule that holds more than its budget (a table clustered on
+    the filter's column) overflows as any static budget does, and the
+    ladder's factor, applied to the plan BEFORE the share, ends at the
+    whole table's capacities."""
 
     def __init__(self, plan: pp.PlanNode, table: str, chunk_rows: int,
                  subtree: bool = False):
@@ -467,6 +563,15 @@ class GranulePlan:
             raise NotDistributable(
                 f"the plan under its aggregate is not a union over the "
                 f"granules of {table}")
+        #: the re-budgeted nodes' capacities as lowered and as the
+        #: statement's plan has them (``granule.budget_lanes`` and
+        #: ``granule.plan_budget_lanes`` count them an execution)
+        self.budget_lanes = self.plan_budget_lanes = 0
+        share = _streamed_share(inner, table, chunk_rows)
+        if share is not None:
+            lanes = [0, 0]
+            inner = _rebudgeted(inner, table, chunk_rows, share, lanes)
+            self.budget_lanes, self.plan_budget_lanes = lanes
         self.plan, self.table, self.chunk_rows = plan, table, chunk_rows
         self.top, self.inner = top, inner
         self.group, self.scalar = group, scalar_agg
@@ -478,27 +583,30 @@ class GranulePlan:
             except NotImplementedError as e:
                 raise NotDistributable(str(e)) from None
         if group is not None:
-            # a granule holds no more groups than it has lanes
             self.chunk = dataclasses.replace(
-                group, aggs=partial, below_join=False,
-                out_capacity=min(group.out_capacity or chunk_rows,
-                                 chunk_rows))
+                group, child=inner, aggs=partial, below_join=False,
+                out_capacity=granule_budget(
+                    group.out_capacity or chunk_rows, chunk_rows))
         elif scalar_agg is not None:
             self.chunk = dataclasses.replace(scalar_agg, child=inner,
                                              aggs=partial)
         else:
             self.chunk = inner
-        self.fingerprint = plan.fingerprint()
 
     @property
     def aggregates(self) -> bool:
         return self.group is not None or self.scalar is not None
 
     def chunk_executable(self):
-        key = ("granule", self.fingerprint, self.chunk_rows)
+        """The chunk program, cached by the chunk plan AS LOWERED: the
+        budgets are a function of the statistics (``est_rows`` is outside
+        a plan's fingerprint on purpose), so two executions whose
+        estimates give another bucket are two programs."""
+        fingerprint = self.chunk.fingerprint()
         return pp.executable_for(pp.Program(
-            pp._lower, (self.chunk,), key,
-            f"granule(lanes={self.chunk_rows}) {self.fingerprint}"))
+            pp._lower, (self.chunk,),
+            ("granule", fingerprint, self.chunk_rows),
+            f"granule(lanes={self.chunk_rows}) {fingerprint}"))
 
     def merge_plan(self, n_inputs: int) -> pp.PlanNode:
         """The statement's rest over ``n_inputs`` granule outputs: their
@@ -531,10 +639,12 @@ def merge_inputs(n: int) -> int:
 def stream_outputs(gp: GranulePlan, provider, device_tables: dict,
                    types: dict | None, budget_bytes: int | None = None):
     """Run ``gp.chunk`` over every granule of ``gp.table`` -> iterator of
-    the granules' device outputs, overflow checked at its end
-    (``diag.CapacityOverflow``, as ``execute_plan`` raises it).  A scan
-    that yields no granule runs the program once over an all-dead one:
-    the statement answers as the resident plan does over no rows."""
+    the granules' device outputs.  The FIRST granule that drops a row for
+    a static budget ends the stream (``diag.CapacityOverflow``, as
+    ``execute_plan`` raises it): a re-plan costs the granules up to that
+    one, not the table.  A scan that yields no granule runs the program
+    once over an all-dead one: the statement answers as the resident plan
+    does over no rows."""
     from oceanbase_tpu.server import admission as qadmission
 
     table, chunk_rows = gp.table, gp.chunk_rows
@@ -559,8 +669,7 @@ def stream_outputs(gp: GranulePlan, provider, device_tables: dict,
             f"{BUFFERS_IN_FLIGHT} granules of {chunk_rows} lanes x {lane} B "
             f"= {need} B over the work area's {budget_bytes} B")
     exe = gp.chunk_executable()
-    counts = _Counts(provider)
-    totals = []
+    counts = _Counts(provider, gp)
     granules = provider(table, chunk_rows, bounds, names) if takes_names \
         else provider(table, chunk_rows, bounds)
     ctx = qtrace.current()
@@ -574,13 +683,14 @@ def stream_outputs(gp: GranulePlan, provider, device_tables: dict,
             (out, lanes, total, _mon), compiled_now, _fl, _nb, noted = \
                 exe.call({**device_tables, table: rel})
             exe.stats.executions += 1
+            counts.programs += 1
             if compiled_now:
                 psp.tags["compiled"] = 1
             # the wait bounds the buffers in flight: this granule's
             # columns go when its program has read them
             jax.block_until_ready(total)  # obcheck: ok(trace.host-sync)
         diag.book_notes(noted)
-        totals.append((total, lanes))
+        _check_overflow(exe, total, lanes)
         return out
 
     try:
@@ -600,32 +710,28 @@ def stream_outputs(gp: GranulePlan, provider, device_tables: dict,
                 scan_types, dicts, chunk_rows, 0)
             yield run(rel)
     finally:
+        # a stream that ends early (an overflow, a LIMIT) stops its
+        # producer here, so that a re-plan's stream never runs beside it
+        stream.close()
         counts.book()
-    _check_overflow(exe, totals)
 
 
-def _check_overflow(exe, totals):
-    """The granules' overflow totals, read once each (they are on the
-    host by now) -> ``diag.CapacityOverflow`` with the lanes that dropped
+def _check_overflow(exe, total, lanes):
+    """One granule's overflow total, read where the consumer has waited
+    for it anyway -> ``diag.CapacityOverflow`` with the lanes that dropped
     rows, the count lanes booked otherwise."""
-    n_diag = len(exe.diag_names)
-    drops: dict = {}
-    for total, lanes in totals:
-        head = np.asarray(total).reshape(-1)  # obcheck: ok(trace.host-sync)
-        if int(head[0]) == 0:
-            diag.book_counts(exe.count_names, head[1:])
-            continue
-        for (name, cap), v in zip(exe.diag_names, lanes[:n_diag]):
-            v = int(v)  # obcheck: ok(trace.host-sync)
-            if v > 0:
-                drops[name, cap] = max(drops.get((name, cap), 0), v)
-    if drops:
-        found = [(n, cap, v) for (n, cap), v in drops.items()]
-        raise diag.CapacityOverflow(
-            "granule program capacity exceeded ("
-            + ", ".join(f"{n}={v}" for n, _c, v in found)
-            + " rows dropped); re-plan with larger out_capacity",
-            drops=found)
+    head = np.asarray(total).reshape(-1)  # obcheck: ok(trace.host-sync)
+    if int(head[0]) == 0:
+        diag.book_counts(exe.count_names, head[1:])
+        return
+    found = [(name, cap, v) for (name, cap), lane
+             in zip(exe.diag_names, lanes)
+             if (v := int(lane)) > 0]  # obcheck: ok(trace.host-sync)
+    raise diag.CapacityOverflow(
+        "granule program capacity exceeded ("
+        + ", ".join(f"{n}={v}" for n, _c, v in found)
+        + " rows dropped); re-plan with larger out_capacity",
+        drops=found)
 
 
 def merge_outputs(gp: GranulePlan, outputs: list) -> Relation:

@@ -29,7 +29,7 @@ from oceanbase_tpu.server import metrics as qmetrics
 from oceanbase_tpu.server import trace as qtrace
 from oceanbase_tpu.sql import ast
 from oceanbase_tpu.sql.binder import Binder
-from oceanbase_tpu.sql.optimizer import scale_capacities
+from oceanbase_tpu.sql.optimizer import after_overflow, scale_capacities
 from oceanbase_tpu.sql.parser import parse_sql
 from oceanbase_tpu.tx.service import WriteStats
 from oceanbase_tpu.vector import Relation, from_numpy, to_numpy
@@ -2010,11 +2010,17 @@ class Session:
                         if int(cfg["sql_work_area_rows"])
                         else work_area_bytes(cfg))
                     break
-                except CapacityOverflow:
+                except CapacityOverflow as ovf:
                     if attempt >= retries:
                         raise
                     qmetrics.inc("plan.capacity_retries")
-                    factor *= 4
+                    # the stream stopped at the first granule that
+                    # dropped rows and says how many: grow by that, as
+                    # the resident ladder does
+                    plan, step = after_overflow(
+                        plan, ovf.drops,
+                        jump=bool(self.db.config["enable_plan_feedback"]))
+                    factor *= step
                     self._spill_factors[lhash] = factor
         except (NotDistributable, NotImplementedError) as e:
             # unsupported shape OR a non-splittable aggregate

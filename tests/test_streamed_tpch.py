@@ -14,7 +14,13 @@ and answer as the exact references and the resident plan do.
   segments over the snapshot;
 - a second execution compiles nothing; a statement that cannot stream is
   counted by its reason and still answers; the granule's buffers are held
-  under the work area.
+  under the work area;
+- a chunk program is budgeted for ONE granule (``granule.granule_budget``):
+  capacities over the streamed table shrink to the granule's share of the
+  estimate and a resident subtree's stay; a table clustered on the filter's
+  column overflows ONE granule, the stream stops there, the session re-runs
+  once and keeps the factor; the program's cache key holds the budgets; a
+  scan with no estimate keeps the plan's capacities.
 """
 
 import os
@@ -447,3 +453,218 @@ def test_buffers_over_the_work_area_are_refused(loaded):
     from oceanbase_tpu.vector import to_numpy
 
     assert to_numpy(out)["s"][0] > 0
+
+
+# -- (5) a granule's budget --------------------------------------------------------
+
+@pytest.mark.parametrize("capacity, chunk_rows, share, within, want", [
+    # Q14 at SF10: the month's filter in a 2,097,152-lane bucket, a granule
+    # of 2,097,152 lanes of 60.0M rows: 73,300 -> the ladder's 131,072
+    (1 << 21, 1 << 21, (1 << 21) / 59_986_052, True, 1 << 17),
+    # other statistics, one rung lower
+    (1 << 20, 1 << 21, (1 << 21) / 59_986_052, True, 1 << 16),
+    # a join's output is not bound by the granule's lanes, a Compact's is
+    (1 << 24, 1 << 12, 0.5, False, 1 << 23),
+    (1 << 24, 1 << 12, 0.5, True, 1 << 12),
+    # never above the node's own capacity; the ladder's floor
+    (100, 8192, 0.9, True, 100),
+    (4096, 8192, 1 / 1024, True, 64),
+    # a table that fits one granule: the share is the whole
+    (4096, 8192, 1.0, True, 4096),
+    # what does not divide among granules (a group-by's groups): the
+    # granule's lanes bound it and nothing else
+    (64, 8192, None, True, 64),
+    (1 << 20, 8192, None, True, 8192),
+])
+def test_a_granules_budget(capacity, chunk_rows, share, within, want):
+    assert granule.granule_budget(capacity, chunk_rows, share,
+                                  within_granule=within) == want
+
+
+def _nodes(plan, kind):
+    from oceanbase_tpu.exec import plan as pp
+
+    return [n for n in pp._postorder(plan) if isinstance(n, kind)]
+
+
+@pytest.mark.parametrize("name", ["tpch_q14_sf10", "tpch_q3"])
+def test_the_chunk_program_is_budgeted_for_one_granule(loaded, dataset, name):
+    """Capacities over ``lineitem`` are the granule's share of the plan's,
+    a subtree over resident tables keeps its own (Q3's join of customer
+    and orders), the counters say both sums an execution, and the answer
+    is the reference's and the resident plan's."""
+    from oceanbase_tpu.exec import plan as pp
+    from oceanbase_tpu.sql.parser import parse_sql
+
+    _ds, tables, _types = dataset
+    st = _statement(name)
+    params = btraffic.validation_params(st)
+    sql = btraffic.render(st, params)
+    plan, _outs, _est = loaded._plan_select(parse_sql(sql), None)
+    lanes = granule.granule_rows_for(STREAM_ROWS)
+    gp = granule.GranulePlan(plan, "lineitem", lanes)
+    (scan,) = [n for n in _nodes(plan, pp.TableScan)
+               if n.table == "lineitem"]
+    share = lanes / scan.est_rows
+    assert 1 / 16 < share < 1 / 14
+    lowered = given = 0
+    for kind, field in ((pp.Compact, "capacity"),
+                        (pp.HashJoin, "out_capacity")):
+        for was, now in zip(_nodes(plan, kind), _nodes(gp.chunk, kind)):
+            cap = getattr(was, field)
+            if "lineitem" not in pp.referenced_tables(was):
+                assert now is was       # the resident subtree, untouched
+                continue
+            want = min(cap, granule.bucket_capacity(int(cap * share) + 1))
+            assert getattr(now, field) == want < cap
+            lowered, given = lowered + want, given + cap
+    assert (gp.budget_lanes, gp.plan_budget_lanes) == (lowered, given)
+    assert 0 < lowered * 8 <= given
+    if name == "tpch_q14_sf10":
+        (compact,) = _nodes(gp.chunk, pp.Compact)
+        assert compact.strict and compact.capacity == 512
+    else:
+        resident = [n for n in _nodes(plan, pp.HashJoin)
+                    if "lineitem" not in pp.referenced_tables(n)]
+        assert resident and all(n.out_capacity for n in resident)
+    b0 = _counter("granule.budget_lanes")
+    p0 = _counter("granule.plan_budget_lanes")
+    res, granules, fallbacks = _run(loaded, sql, streamed=True)
+    assert granules >= 8 and fallbacks == 0
+    assert _counter("granule.budget_lanes") - b0 == granules * lowered
+    assert _counter("granule.plan_budget_lanes") - p0 == granules * given
+    resident_res, _, _ = _run(loaded, sql, streamed=False)
+    assert res.rows() == resident_res.rows()
+    if name == "tpch_q14_sf10":
+        ref = bspec.load_module("references", st["reference"]["exact"])
+        assert ref.extract(list(res.names), res.arrays) == \
+            ref.answer(tables, params)
+
+
+def test_q1_and_q6_have_no_node_to_budget(loaded):
+    """No ``Compact``, no join: the counters stay, Q1's group-by keeps its
+    capacity under the granule's lanes (groups do not divide)."""
+    from oceanbase_tpu.exec import plan as pp
+    from oceanbase_tpu.sql.parser import parse_sql
+
+    for name in ("tpch_q1_sf10", "tpch_q6"):
+        st = _statement(name)
+        sql = btraffic.render(st, btraffic.validation_params(st))
+        plan, _outs, _est = loaded._plan_select(parse_sql(sql), None)
+        gp = granule.GranulePlan(plan, "lineitem", 8192)
+        assert (gp.budget_lanes, gp.plan_budget_lanes) == (0, 0)
+        if gp.group is not None:
+            assert gp.chunk.out_capacity == min(gp.group.out_capacity, 8192)
+        b0 = _counter("granule.plan_budget_lanes")
+        _run(loaded, sql, streamed=True)
+        assert _counter("granule.plan_budget_lanes") == b0
+
+
+def test_a_clustered_table_costs_one_restart_and_is_remembered(tmp_path):
+    """Every survivor of the filter lies in ONE granule (the table is
+    clustered on the filter's column), sixteen times that granule's share:
+    the stream stops at that granule, the session re-plans ONCE by what
+    was dropped, answers exactly, and starts the next execution at the
+    factor that cleared."""
+    db = Database(str(tmp_path / "db"))
+    s = db.session()
+    n = 65536
+    rng = np.random.default_rng(49)
+    k = np.arange(n, dtype=np.int64)
+    fk = rng.integers(0, 1000, n).astype(np.int64)
+    v = rng.integers(1, 100, n).astype(np.int64)
+    w = rng.integers(1, 10, 1000).astype(np.int64)
+    s.catalog.load_numpy("fact", {"k": k, "d": k // 64, "fk": fk, "v": v},
+                         primary_key=["k"])
+    s.catalog.load_numpy("dim", {"pk": np.arange(1000, dtype=np.int64),
+                                 "w": w}, primary_key=["pk"])
+    s.execute("analyze table fact")
+    s.execute("analyze table dim")
+    s.execute("set px_dop = 1")
+    sql = ("select sum(f.v * d.w), count(*) from fact f, dim d "
+           "where f.fk = d.pk and f.d >= 100 and f.d < 116")
+    live = (k // 64 >= 100) & (k // 64 < 116)
+    want = [(int((v[live] * w[fk[live]]).sum()), int(live.sum()))]
+    assert want[0][1] == 1024
+    # 16 granules of 4,096 lanes; the survivors are rows 6,400-7,423: all
+    # in the second, whose budget is a sixteenth of the plan's 2,048
+    s.execute("alter system set sql_work_area_rows = 16384")
+
+    def execute():
+        r0 = _counter("plan.capacity_retries")
+        s._last_spill = None
+        rows = s.execute(sql).rows()
+        assert s._last_spill is not None
+        programs = [r[0].strip() for r in s.execute("show trace").rows()
+                    ].count("granule.program")
+        return rows, _counter("plan.capacity_retries") - r0, programs
+
+    rows, retries, programs = execute()
+    assert rows == want
+    # two programs until the overflow (not sixteen), then the whole table
+    assert (retries, programs) == (1, 2 + 16)
+    assert list(s._spill_factors.values()) == [16]
+    rows, retries, programs = execute()
+    assert (rows, retries, programs) == (want, 0, 16)
+    s.execute("alter system set sql_work_area_rows = 16777216")
+    s._last_spill = None
+    assert s.execute(sql).rows() == want and s._last_spill is None
+    s.close()
+    db.close()
+
+
+def _compacted_sum(est_rows):
+    """sum(v) over a strict ``Compact`` of the rows with ``v < 40`` of a
+    scan estimated at ``est_rows`` rows."""
+    from oceanbase_tpu.exec.ops import AggSpec
+    from oceanbase_tpu.exec.plan import Compact, Filter, ScalarAgg, TableScan
+    from oceanbase_tpu.expr import ir
+
+    scan = TableScan("t", rename={"v": "t_v_0"}, est_rows=est_rows)
+    return ScalarAgg(Compact(Filter(scan, ir.col("t_v_0") < ir.lit(40)),
+                             capacity=4096, strict=True),
+                     [AggSpec("s", "sum", ir.col("t_v_0"))])
+
+
+def test_the_chunk_programs_key_holds_its_budgets():
+    """Two executions whose statistics differ enough to change the bucket
+    share a plan fingerprint (``est_rows`` is outside it) and NOT a chunk
+    program; equal statistics share one.  A scan with no estimate keeps
+    the plan's capacities."""
+    from oceanbase_tpu.exec.plan import Compact
+    from oceanbase_tpu.vector import to_numpy
+
+    n = 16384
+    v = (np.arange(n, dtype=np.int64) * 7919) % 1000
+    prov = granule.numpy_chunk_provider({"v": v})
+    types = {"v": SqlType.int_()}
+    small, large, none = (_compacted_sum(e) for e in (n, 64 * n, None))
+    assert small.fingerprint() == large.fingerprint() == none.fingerprint()
+    plans = {e: granule.GranulePlan(p, "t", 1024)
+             for e, p in (("small", small), ("large", large), ("none", none))}
+    caps = {e: _nodes(gp.chunk, Compact)[0].capacity
+            for e, gp in plans.items()}
+    # 1,024 of 16,384 rows: a sixteenth of 4,096; of 1M rows: the floor;
+    # no estimate: the plan's own
+    assert caps == {"small": 256, "large": 64, "none": 4096}
+    assert plans["none"].inner is none.child
+    # a scan pipeline inside a larger plan (the host half's walk) is
+    # budgeted the same way
+    sub = granule.GranulePlan(small.child, "t", 1024, subtree=True)
+    assert sub.chunk.capacity == 256 and not sub.aggregates
+    assert (plans["none"].budget_lanes, plans["none"].plan_budget_lanes) \
+        == (0, 0)
+    exes = {e: gp.chunk_executable() for e, gp in plans.items()}
+    assert len({id(x) for x in exes.values()}) == 3
+    assert granule.GranulePlan(_compacted_sum(n + 1), "t", 1024) \
+        .chunk_executable() is exes["small"]
+    # three rows of gv$plan_cache, each under the granule's lanes
+    assert len({x.stats.plan_hash for x in exes.values()}) == 3
+    assert all(x.stats.plan_text.startswith("granule(lanes=1024) ")
+               for x in exes.values())
+    # 40 of every 1,000 values survive: 41 a granule, so each budget holds
+    want = int(v[v < 40].sum())
+    for plan in (small, large, none):
+        out = granule.execute_streamed(plan, prov, chunk_rows=1024,
+                                       types=types)
+        assert int(to_numpy(out)["s"][0]) == want

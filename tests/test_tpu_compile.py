@@ -532,18 +532,22 @@ def test_sf10_wa5_chunk_program_compiles_for_v5e_and_fits(
     lanes = granule.DEFAULT_CHUNK_ROWS
     gp = granule.GranulePlan(plan, "lineitem", lanes)
     assert gp.aggregates and (gp.group is not None) == (qnum == 1)
-    bundle = gp.chunk_executable()
-    mentioned, renames = qplan.scan_columns(gp.chunk)
-    tables = {}
-    for name in qplan.referenced_tables(gp.chunk):
-        rel = qplan.narrowed(sess.catalog.table_data(name), mentioned,
-                             renames.get(name))
-        n = lanes if name == "lineitem" else SF10_LANES[name]
-        tables[name] = jax.tree.map(
-            lambda x, n=n: jax.ShapeDtypeStruct(
-                (n,) + x.shape[1:], x.dtype, sharding=one_chip),
-            rel.pad_to(rel.capacity + 1))
-    compiled = bundle._run.lower(tables).compile()
+
+    def compiled_chunk(gp):
+        bundle = gp.chunk_executable()
+        mentioned, renames = qplan.scan_columns(gp.chunk)
+        tables = {}
+        for name in qplan.referenced_tables(gp.chunk):
+            rel = qplan.narrowed(sess.catalog.table_data(name), mentioned,
+                                 renames.get(name))
+            n = lanes if name == "lineitem" else SF10_LANES[name]
+            tables[name] = jax.tree.map(
+                lambda x, n=n: jax.ShapeDtypeStruct(
+                    (n,) + x.shape[1:], x.dtype, sharding=one_chip),
+                rel.pad_to(rel.capacity + 1))
+        return bundle, tables, bundle._run.lower(tables).compile()
+
+    bundle, tables, compiled = compiled_chunk(gp)
     ma = compiled.memory_analysis()
     granule_bytes = sum(
         int(np.prod(x.shape)) * x.dtype.itemsize
@@ -552,6 +556,53 @@ def test_sf10_wa5_chunk_program_compiles_for_v5e_and_fits(
           f"{granule_bytes}, arguments {ma.argument_size_in_bytes}, "
           f"outputs {ma.output_size_in_bytes}, temporaries "
           f"{ma.temp_size_in_bytes} bytes")
+    if qnum == 14:
+        # the chunk program is budgeted for ONE granule: the month's
+        # filter compacts into the granule's share of the plan's bucket
+        # (2,097,152 lanes of 60.2M rows of 2,097,152: 131,072), and as in
+        # the resident plan above every gather runs on that bucket.  The
+        # program over the plan's own capacities (what a scan with no
+        # estimate keeps) gathered ten times over 2,097,152 lanes
+        (was,) = [n for n in qplan._postorder(plan)
+                  if isinstance(n, qplan.Compact)]
+        (now,) = [n for n in qplan._postorder(gp.chunk)
+                  if isinstance(n, qplan.Compact)]
+        (join,) = [n for n in qplan._postorder(gp.chunk)
+                   if isinstance(n, qplan.HashJoin)]
+        assert (was.capacity, now.capacity) == (lanes, 131_072)
+        assert now.strict and join.build_unique and join.left is now
+        # 131,072 probe lanes against part's 2,097,152 keys still rank by
+        # merging (two sorts of 2,228,224 lanes); one rung lower would search
+        assert bundle._noted["probe", "merge"] == 1
+        assert (gp.budget_lanes, gp.plan_budget_lanes) == \
+            (2 * 131_072, 2 * lanes)
+
+        def gathers(text):
+            return re.findall(r"= (\w+)\[(\d+)\]\S* gather\((%\S+),", text)
+
+        (scan,) = [n for n in qplan._postorder(plan)
+                   if isinstance(n, qplan.TableScan)
+                   and n.table == "lineitem"]
+        scan.est_rows, est = None, scan.est_rows
+        try:
+            plain = granule.GranulePlan(plan, "lineitem", lanes)
+        finally:
+            scan.est_rows = est
+        assert plain.budget_lanes == 0 and [
+            n.capacity for n in qplan._postorder(plain.chunk)
+            if isinstance(n, qplan.Compact)] == [lanes]
+        before = compiled_chunk(plain)[2]
+        got, had = gathers(compiled.as_text()), gathers(before.as_text())
+        message = (
+            f"temporaries {before.memory_analysis().temp_size_in_bytes} -> "
+            f"{ma.temp_size_in_bytes} bytes, gathers {had} -> {got}")
+        print("Q14 chunk program, the plan's capacities -> a granule's: "
+              + message)
+        assert got and all(n == "131072" for _t, n, _src in got), message
+        assert any(n == str(lanes) for _t, n, _src in had), message
+        assert " scatter(" not in compiled.as_text()
+        assert ma.temp_size_in_bytes < \
+            before.memory_analysis().temp_size_in_bytes, message
     work_area = V5E_HBM_BYTES * 5 // 100
     assert granule_bytes * granule.BUFFERS_IN_FLIGHT < work_area
     assert (granule_bytes * (granule.BUFFERS_IN_FLIGHT - 1)
